@@ -1,0 +1,612 @@
+#!/usr/bin/env python3
+"""chip_smoke.py: the quickest proof that the main path still starts on the TPU.
+
+One process, no arguments, run from the repo root:
+
+    python chip_smoke.py
+
+It refuses any backend but ``tpu`` before building data, then drives
+
+1. the kernel leg: each of the four Pallas kernels compiled
+   (``interpret=False``) at the widths the repo's cells use and compared with
+   its plain-``jnp`` reference;
+2. the main leg: SC25-shaped EGNN (hidden 866, 4 conv layers) through
+   ``run_training`` -> ``run_prediction`` -> ``run_server`` in this process,
+   with the lowered programs checked for Mosaic custom calls and the kernel
+   route compared with the dense route on one real batch;
+3. the second-order leg: one ``compute_grad_energy`` epoch at the
+   ``examples/md17`` widths through the sorted-segment kernel;
+4. with more than one device, the same EGNN through the mesh step
+   (``Optimizer.zero_stage: 2``) over all of them.
+
+Nothing is caught and reported as data: a failed check raises, the exit code
+is non-zero and no result line is printed. The last stdout line of a passing
+run is one JSON object starting ``{"ok": true, "device": {...}}``.
+
+Every timing printed here is a set-up fact (compile, first step), not a
+throughput.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import shutil
+import sys
+import tempfile
+import time
+from unittest import mock
+
+import numpy as np
+
+# The one model with a history on the chip: bench.py `_production_workload`
+# (reference: examples/multibranch/multibranch_GFM260_SC25.json).
+HIDDEN, HEAD_DIM, CONV_LAYERS, BATCH, NUM_GRAPHS = 866, 889, 4, 32, 128
+
+
+def require_tpu() -> dict:
+    """First act: initialise JAX and stamp the device, or refuse."""
+    import jax
+    import jaxlib
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        sys.exit(
+            f"chip_smoke: JAX initialised backend {backend!r}, not 'tpu'; "
+            "nothing was run"
+        )
+    devices = jax.devices()
+    stamp = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    try:
+        from importlib.metadata import version
+
+        libtpu = version("libtpu")
+    except Exception:  # metadata only decorates the banner
+        libtpu = "unknown"
+    print(
+        f"platform: {stamp['platform']}  device_kind: {stamp['kind']}  "
+        f"devices: {stamp['count']}  jax {jax.__version__}  "
+        f"jaxlib {jaxlib.__version__}  libtpu {libtpu}",
+        flush=True,
+    )
+    return stamp
+
+
+def _rel_err(out, ref) -> float:
+    """max|out - ref| over max|ref|: one number per comparison, insensitive
+    to the near-zero entries an elementwise rtol would trip on."""
+    out = np.asarray(out, np.float64)
+    ref = np.asarray(ref, np.float64)
+    assert out.shape == ref.shape, (out.shape, ref.shape)
+    assert np.isfinite(out).all(), "non-finite kernel output"
+    return float(np.max(np.abs(out - ref)) / (np.max(np.abs(ref)) + 1e-30))
+
+
+def _check(name: str, err: float, tol: float) -> None:
+    print(f"  {name}: rel_err {err:.3e} (tol {tol:.0e})", flush=True)
+    if not err <= tol:
+        raise AssertionError(f"{name}: rel_err {err:.3e} exceeds {tol:.0e}")
+
+
+# ---------------------------------------------------------------------------
+# kernel leg
+# ---------------------------------------------------------------------------
+
+# Tolerances, as max|out-ref|/max|ref| against an f32 reference computed at
+# matmul precision "highest" from the SAME (possibly bf16-rounded) inputs:
+#  - f32 streams: the kernels ask the MXU for fp32 contraction
+#    (ops/pallas_segment.py mxu_precision), so only the summation order
+#    differs from the reference. Measured on a v5e: 1e-7 .. 1.7e-6 (flash,
+#    whose exp() adds a few ulp); 2e-5 leaves 10x and still fails on a
+#    single dropped edge (O(1e-3) here) or a bf16-rounded operand (2e-3).
+#  - bf16 streams: up to four intermediates (gathered pre-activation,
+#    hidden, message, output) are rounded to bf16, 2^-8 = 3.9e-3 each, so
+#    1.6e-2. Measured: 2.6e-3 .. 3.0e-3.
+TOL = {"float32": 2e-5, "bfloat16": 1.6e-2}
+
+
+def _sorted_ids(rng, n_nodes: int, max_degree: int, n_padding: int):
+    """Receiver-sorted edge ids the way GraphLoader(sort_edges=True) lays a
+    padded batch out: real nodes hold 0..max_degree edges, every padding
+    edge lands on the FINAL dummy node (whose output row is unspecified)."""
+    deg = rng.integers(0, max_degree + 1, n_nodes - 1)
+    ids = np.repeat(np.arange(n_nodes - 1), deg)
+    return np.concatenate([ids, np.full(n_padding, n_nodes - 1)]).astype(np.int32)
+
+
+def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
+                 interpret=False):
+    """Yield ``(name, dtype_name, check)``: ``check()`` compiles one kernel
+    at one width and dtype, with the tile plan ``tune.runtime.tile_plan``
+    returns on this device, and returns ``[(label, rel_err), ...]`` against
+    the kernel's plain-jnp reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.ops.pallas_flash_attention import (
+        flash_self_attention,
+        reference_gathered_attention,
+    )
+    from hydragnn_tpu.ops.pallas_fused_edge import (
+        fused_edge_message_sum,
+        reference_edge_message_sum,
+    )
+    from hydragnn_tpu.ops.pallas_multi_agg import (
+        fused_multi_agg,
+        reference_multi_agg,
+    )
+    from hydragnn_tpu.ops.pallas_segment import sorted_segment_sum
+    from hydragnn_tpu.tune.runtime import tile_plan
+
+    rng = np.random.default_rng(0)
+    ids_np = _sorted_ids(rng, n_nodes, max_degree, n_padding=300)
+    ids = jnp.asarray(ids_np)
+    e = ids_np.shape[0]
+    real = slice(0, n_nodes - 1)  # the dummy node's row is unspecified
+    shape_key = {"edges": e, "num_segments": n_nodes, "max_degree": max_degree}
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def arr(shape, dtype, scale=1.0):
+        return jnp.asarray(rng.normal(size=shape) * scale, dtype)
+
+    def segment(c, dtype):
+        msg = arr((e, c), dtype)
+        plan = tile_plan("segment_sum", {**shape_key, "channels": c}, dtype)
+        out = jax.jit(lambda m: sorted_segment_sum(
+            m, ids, n_nodes, max_degree, plan["block_rows"],
+            plan["block_edges"], plan["block_cols"], interpret))(msg)
+        ref = jax.ops.segment_sum(f32(msg), ids, num_segments=n_nodes)
+        return [(str(plan), _rel_err(out[real], ref[real]))]
+
+    def fused_edge(c, dtype):
+        nrecv, ein = arr((n_nodes, c), dtype), arr((e, c), dtype)
+        w, b = arr((c, c), dtype, c ** -0.5), arr((c,), dtype)
+        plan = tile_plan("fused_edge", {
+            **shape_key, "ci": c, "co": c, "dtype": jnp.dtype(dtype).name,
+        }, dtype)
+        out = jax.jit(lambda nr, x, w_, b_: fused_edge_message_sum(
+            nr, x, w_, b_, ids, n_nodes, max_degree, plan["block_rows"],
+            plan["block_edges"], plan["block_cols"], interpret,
+        ))(nrecv, ein, w, b)
+        with jax.default_matmul_precision("highest"):
+            ref = reference_edge_message_sum(
+                f32(nrecv), f32(ein), f32(w), f32(b), ids, n_nodes)
+        return [(str(plan), _rel_err(out[real], ref[real]))]
+
+    def multi_agg(c, dtype):
+        nrecv, ein = arr((n_nodes, c), dtype), arr((e, c), dtype)
+        # the Hadamard gate operand (PNAPlus) rides the 256-wide case only
+        gate = arr((e, c), dtype) if c == 256 else None
+        plan = tile_plan("multi_agg", {
+            **shape_key, "channels": c, "has_recv": True,
+            "has_gate": gate is not None, "dtype": jnp.dtype(dtype).name,
+        }, dtype)
+        outs = jax.jit(lambda nr, x, g: fused_multi_agg(
+            nr, x, g, ids, n_nodes, max_degree, plan["block_rows"],
+            plan["block_edges"], plan["block_cols"], interpret,
+        ))(nrecv, ein, gate)
+        # the reference forms the message in the stream dtype exactly like
+        # the kernel, then takes f32 moments
+        refs = reference_multi_agg(nrecv, ein, gate, ids, n_nodes)
+        return [
+            (f"{moment} {plan}", _rel_err(o[real], r[real]))
+            for o, r, moment in zip(
+                outs, refs, ("sum", "count", "min", "max", "sumsq"))
+        ]
+
+    def flash(sizes, dtype):
+        nmax, n_real, pad = max(sizes), sum(sizes), 9
+        node_graph = jnp.asarray(np.concatenate(
+            [np.full(s, i) for i, s in enumerate(sizes)]
+            + [np.full(pad, len(sizes))]).astype(np.int32))
+        node_mask = jnp.asarray(np.arange(n_real + pad) < n_real)
+        n, g = n_real + pad, len(sizes) + 1
+        q, k, v = (arr((n, 8, 32), dtype) for _ in range(3))
+        plan = tile_plan("flash_attention", {
+            "nodes": n, "heads": 8, "head_dim": 32,
+            "max_nodes_per_graph": nmax}, dtype)
+        out = jax.jit(lambda q_, k_, v_: flash_self_attention(
+            q_, k_, v_, node_graph, node_mask, g, nmax,
+            plan["block_q"], plan["block_k"], interpret))(q, k, v)
+        with jax.default_matmul_precision("highest"):
+            ref = reference_gathered_attention(
+                f32(q), f32(k), f32(v), node_graph, node_mask, g, nmax)
+        return [(str(plan), _rel_err(out[:n_real], ref[:n_real]))]
+
+    for dtype in (jnp.bfloat16, jnp.float32):
+        dt = jnp.dtype(dtype).name
+        for c in channels:
+            for kernel in (segment, fused_edge, multi_agg):
+                yield (f"{kernel.__name__} c={c} {dt}", dt,
+                       lambda k=kernel, c=c, d=dtype: k(c, d))
+        # flash at 8 heads x 32 (the GPS cells' hidden 256): a packed batch
+        # of <= 70-node graphs, and one long graph of >= 1024 nodes
+        for sizes in ([70, 64, 31, 70, 58, 70, 12, 66, 70, 45, 70, 70, 53,
+                       69, 70, 41], [1100]):
+            yield (f"flash graphs={len(sizes)} nmax={max(sizes)} {dt}", dt,
+                   lambda s=sizes, d=dtype: flash(s, d))
+
+
+def kernel_leg(**shape) -> None:
+    for name, dt, check in kernel_cases(**shape):
+        for label, err in check():
+            _check(f"{name} {label}", err, TOL[dt])
+
+
+# ---------------------------------------------------------------------------
+# main leg
+# ---------------------------------------------------------------------------
+
+
+def egnn_config(hidden=HIDDEN, head_dim=HEAD_DIM, batch_size=BATCH,
+                num_epoch=2, **training):
+    """The architecture dict of bench.py `_production_workload`."""
+    return {
+        "Verbosity": {"level": 1},
+        "Dataset": {
+            "name": "oc20_shaped",
+            "node_features": {
+                "name": ["atomic_number", "cartesian_coordinates", "forces"],
+                "dim": [1, 3, 3],
+            },
+            "graph_features": {"name": ["energy"], "dim": [1]},
+        },
+        "NeuralNetwork": {
+            "Architecture": {
+                "mpnn_type": "EGNN",
+                "equivariance": True,
+                "radius": 5.0,
+                "max_neighbours": 20,
+                "hidden_dim": hidden,
+                "num_conv_layers": CONV_LAYERS,
+                "task_weights": [1.0, 100.0],
+                "output_heads": {
+                    "graph": {
+                        "num_sharedlayers": 2,
+                        "dim_sharedlayers": 50,
+                        "num_headlayers": 3,
+                        "dim_headlayers": [head_dim] * 3,
+                    },
+                    "node": {
+                        "num_headlayers": 3,
+                        "dim_headlayers": [head_dim] * 3,
+                        "type": "mlp",
+                    },
+                },
+            },
+            "Variables_of_interest": {
+                "input_node_features": [0, 1],
+                "output_names": ["energy", "forces"],
+                "output_index": [0, 2],
+                "type": ["graph", "node"],
+            },
+            "Training": {
+                "batch_size": batch_size,
+                "num_epoch": num_epoch,
+                "loss_function_type": "mae",
+                "pack_batches": True,
+                "mixed_precision": True,
+                "Optimizer": {"type": "AdamW", "learning_rate": 1e-3},
+                **training,
+            },
+        },
+    }
+
+
+def _assert_kernel_routes_on(config) -> None:
+    arch = config["NeuralNetwork"]["Architecture"]
+    assert arch["use_sorted_aggregation"] is True, arch["use_sorted_aggregation"]
+    assert arch["use_fused_edge_kernel"] is True, arch["use_fused_edge_kernel"]
+    assert arch["max_in_degree"] > 0, arch["max_in_degree"]
+
+
+def _assert_losses(hist, falling=True) -> None:
+    losses = [*hist["train"], *hist["val"], *hist["test"]]
+    assert losses and np.isfinite(losses).all(), hist
+    if falling:
+        # the epoch-mean MAE under AdamW 1e-3 falls from the first epoch on
+        # this data; 2% is the bf16 + few-steps-per-epoch noise allowance
+        assert hist["train"][1] <= hist["train"][0] * 1.02, hist["train"]
+
+
+def _mosaic_calls(lowered) -> int:
+    return lowered.as_text().count("tpu_custom_call")
+
+
+def main_leg(hidden=HIDDEN, head_dim=HEAD_DIM, num_graphs=NUM_GRAPHS,
+             batch_size=BATCH, check_lowering=True) -> dict:
+    import jax
+
+    import hydragnn_tpu
+    from hydragnn_tpu.data import neighbors
+    from hydragnn_tpu.data.pipeline import split_dataset
+    from hydragnn_tpu.data.synthetic import oc20_shaped_dataset
+    from hydragnn_tpu.train import make_eval_step, make_optimizer, make_train_step
+    from hydragnn_tpu.utils.timers import Timer
+
+    datasets = split_dataset(oc20_shaped_dataset(num_graphs), 0.7, seed=0)
+    t0 = time.perf_counter()
+    model, state, hist, config, loaders, _ = hydragnn_tpu.run_training(
+        egnn_config(hidden, head_dim, batch_size), datasets=datasets
+    )
+    train_s = time.perf_counter() - t0
+    ttfs = Timer.totals()["time_to_first_step"]
+    _assert_kernel_routes_on(config)
+    _assert_losses(hist)
+    # graphs this small stay on scipy's KD-tree: the native cell-list
+    # library (g++-built) is never asked for, so it cannot degrade silently
+    assert neighbors._native is None, "smoke unexpectedly built native neighbors"
+
+    training = config["NeuralNetwork"]["Training"]
+    batch = next(iter(loaders[2]))
+    if check_lowering:
+        # no hidden route: the programs the loop ran must carry the Mosaic
+        # kernels, at least one per conv layer
+        eval_step = make_eval_step(model, mixed_precision=True)
+        n_fwd = _mosaic_calls(eval_step.lower(state, batch))
+        train_step = make_train_step(
+            model, make_optimizer(training["Optimizer"]), mixed_precision=True
+        )
+        n_train = _mosaic_calls(
+            train_step.lower(state, batch, jax.random.PRNGKey(0))
+        )
+        print(f"  tpu_custom_call sites: forward {n_fwd}, train step {n_train}")
+        assert n_fwd >= CONV_LAYERS and n_train >= CONV_LAYERS, (n_fwd, n_train)
+
+    # kernel route == dense route on one real batch, f32 at "highest": the
+    # two differ only in summation order (tolerance as in the kernel leg)
+    outs = {}
+    for route in ("1", "0"):  # read at trace time (ops/segment.py)
+        with mock.patch.dict(os.environ, HYDRAGNN_PALLAS_SEGMENT=route), \
+                jax.default_matmul_precision("highest"):
+            outs[route] = make_eval_step(model)(state, batch)[2]
+    for name in outs["1"]:
+        mask = np.asarray(
+            batch.graph_mask if name == "energy" else batch.node_mask)
+        _check(f"model {name}: kernel vs dense route",
+               _rel_err(np.asarray(outs["1"][name])[mask],
+                        np.asarray(outs["0"][name])[mask]), TOL["float32"])
+
+    tot, tasks, preds, trues = hydragnn_tpu.run_prediction(
+        config, model_state=state, datasets=datasets
+    )
+    n_test = len(datasets[2])
+    n_test_nodes = sum(g.num_nodes for g in datasets[2])
+    assert np.isfinite(tot) and np.isfinite(list(tasks.values())).all(), tasks
+    assert preds["energy"].shape == (n_test, 1), preds["energy"].shape
+    assert preds["forces"].shape == (n_test_nodes, 3), preds["forces"].shape
+    assert all(np.isfinite(p).all() for p in preds.values())
+    # run_prediction of the in-memory state repeats the loop's last test pass
+    assert abs(tot - hist["test"][-1]) <= 1e-5 * max(1.0, abs(tot)), (
+        tot, hist["test"][-1])
+
+    requests = datasets[2][:4]
+    with hydragnn_tpu.run_server(config, datasets=datasets) as server:
+        assert server.wait_ready(600), f"server not ready: {server.failed}"
+        handles = [server.submit(g) for g in requests]
+        results = [h.result(120) for h in handles]
+        stats = server.stats()
+    assert stats["completed"] == len(requests) and stats["failed_batches"] == 0, stats
+    assert stats["retrace_violations"] == 0, stats
+    for g, r in zip(requests, results):
+        assert r["energy"].shape == (1,) and r["forces"].shape == (g.num_nodes, 3)
+        assert np.isfinite(r["energy"]).all() and np.isfinite(r["forces"]).all()
+    print(f"  served {stats['completed']} requests in {stats['batches']} "
+          f"batch(es) from checkpoint {stats['current_checkpoint']}")
+    return {
+        "losses": {k: [float(x) for x in hist[k]] for k in ("train", "val", "test")},
+        "time_to_first_step_s": round(ttfs, 3),
+        "train_wall_s": round(train_s, 3),
+    }
+
+
+def second_order_leg(check_lowering=True) -> dict:
+    """compute_grad_energy (forces = -dE/dpos inside the loss, differentiated
+    again by the training grad) through the sorted-segment kernel's
+    custom_jvp, at the examples/md17/md17.json widths."""
+    import jax
+
+    import hydragnn_tpu
+    from hydragnn_tpu.data import md17_shaped_dataset
+    from hydragnn_tpu.data.pipeline import split_dataset
+    from hydragnn_tpu.train import make_optimizer, make_train_step
+
+    config = {
+        "Verbosity": {"level": 1},
+        "Dataset": {"name": "md17_shaped",
+                    "node_features": {"name": ["atomic_number"], "dim": [1]}},
+        "NeuralNetwork": {
+            "Architecture": {
+                "mpnn_type": "SchNet", "radius": 5.0, "max_neighbours": 32,
+                "hidden_dim": 64, "num_conv_layers": 3, "task_weights": [1.0],
+                "output_heads": {"node": {
+                    "num_headlayers": 2, "dim_headlayers": [64, 64],
+                    "type": "mlp"}},
+            },
+            "Variables_of_interest": {
+                "input_node_features": [0], "output_names": ["graph_energy"],
+                "output_index": [0], "output_dim": [1], "type": ["node"],
+            },
+            "Training": {
+                "num_epoch": 2, "batch_size": 32, "compute_grad_energy": True,
+                "loss_function_type": "mae",
+                "Optimizer": {"type": "AdamW", "learning_rate": 2e-3},
+            },
+        },
+    }
+    datasets = split_dataset(md17_shaped_dataset(128), 0.7, seed=0)
+    model, state, hist, config, loaders, _ = hydragnn_tpu.run_training(
+        config, datasets=datasets
+    )
+    _assert_kernel_routes_on(config)
+    _assert_losses(hist)
+    if check_lowering:
+        step = make_train_step(
+            model, make_optimizer(config["NeuralNetwork"]["Training"]["Optimizer"]),
+            compute_grad_energy=True,
+        )
+        n_calls = _mosaic_calls(step.lower(
+            state, next(iter(loaders[0])), jax.random.PRNGKey(0)))
+        print(f"  tpu_custom_call sites in the energy-force train step: {n_calls}")
+        n_layers = config["NeuralNetwork"]["Architecture"]["num_conv_layers"]
+        assert n_calls >= n_layers, n_calls
+    return {"losses": {"train": [float(x) for x in hist["train"]]}}
+
+
+# ---------------------------------------------------------------------------
+# multi-device leg (builder-run on a four-chip host; skipped on one chip)
+# ---------------------------------------------------------------------------
+
+
+def _peak_bytes_per_device() -> list:
+    import jax
+
+    return [
+        {"id": d.id, "peak_bytes_in_use": d.memory_stats()["peak_bytes_in_use"]}
+        for d in jax.local_devices()
+    ]
+
+
+def mesh_leg(hidden=HIDDEN, head_dim=HEAD_DIM, batch_size=BATCH) -> dict:
+    """The same EGNN through the mesh step over every local device.
+
+    ``Optimizer.zero_stage: 2`` is the setting that reaches
+    ``make_mesh_train_step`` on one host. The train split is exactly one
+    global batch, so epoch 0's train loss IS the first-step loss, and the
+    one-chip value on the same global batch is the plain single-device
+    step's loss per shard row, combined by real-graph count exactly as the
+    mesh step's pmean does (parallel/engine.py unrouted_grads)."""
+    import jax
+    import jax.numpy as jnp
+
+    import hydragnn_tpu
+    from hydragnn_tpu.data.synthetic import oc20_shaped_dataset
+    from hydragnn_tpu.models import init_model
+    from hydragnn_tpu.train import TrainState, make_optimizer, make_train_step
+
+    n_dev = jax.local_device_count()
+    graphs = oc20_shaped_dataset(batch_size + 16)
+    datasets = (graphs[:batch_size], graphs[batch_size:batch_size + 8],
+                graphs[batch_size + 8:])
+    config = egnn_config(hidden, head_dim, batch_size, pack_batches=False,
+                         num_pad_buckets=1)
+    config["NeuralNetwork"]["Training"]["Optimizer"]["zero_stage"] = 2
+    model, state, hist, config, loaders, _ = hydragnn_tpu.run_training(
+        config, datasets=datasets
+    )
+    _assert_kernel_routes_on(config)
+    # finite only: with ONE step per epoch the second value is the loss
+    # right after the first AdamW step, which at width 866 overshoots
+    # (measured on 4 chips: 1.819 -> 2.746) where an epoch mean would fall
+    _assert_losses(hist, falling=False)
+
+    # every device holds a slice of the ZeRO moments ...
+    moments = [
+        x for x in jax.tree_util.tree_leaves(state.opt_state)
+        if hasattr(x, "sharding") and x.ndim >= 1
+        and not x.sharding.is_fully_replicated
+    ]
+    assert moments, "no optimizer-state leaf is sharded under zero_stage 2"
+    for x in moments:
+        assert len({s.device for s in x.addressable_shards}) == n_dev
+        assert x.addressable_shards[0].data.shape[0] * n_dev == x.shape[0]
+    # ... and did work: its peak is at least one replica of the parameters
+    # (an idle v5e chip reports ~27 KB, so "> 0" would prove nothing)
+    param_bytes = sum(
+        x.size * x.dtype.itemsize for x in jax.tree_util.tree_leaves(state.params))
+    per_device = _peak_bytes_per_device()
+    assert all(d["peak_bytes_in_use"] >= param_bytes for d in per_device), (
+        param_bytes, per_device)
+
+    # one-chip reference for the first step, on the same global batch
+    train_loader = loaders[0]
+    train_loader.set_epoch(0)
+    stacked = next(iter(train_loader))
+    assert np.asarray(stacked.graph_mask).shape[0] == n_dev
+    rows = [jax.tree_util.tree_map(lambda x, i=i: np.asarray(x)[i], stacked)
+            for i in range(n_dev)]
+    counts = [float(np.asarray(r.graph_mask).sum()) for r in rows]
+    assert all(c > 0 for c in counts), counts  # every device holds a shard
+    training = config["NeuralNetwork"]["Training"]
+    tx = make_optimizer(training["Optimizer"])
+    variables = init_model(model, rows[0], seed=int(training.get("seed", 0)))
+    step = make_train_step(model, tx, mixed_precision=True)
+    row_losses = []
+    for row in rows:
+        fresh = TrainState.create(
+            jax.tree_util.tree_map(lambda x: jnp.array(x, copy=True), variables), tx)
+        row_losses.append(float(step(fresh, row, jax.random.PRNGKey(0))[1]))
+    one_chip = float(np.dot(row_losses, counts) / np.sum(counts))
+    mesh_loss = float(hist["train"][0])
+    # __graft_entry__.dryrun_multichip's assert_matches tolerance
+    delta = abs(mesh_loss - one_chip)
+    print(f"  first-step loss: mesh {mesh_loss:.6f} vs one-chip {one_chip:.6f} "
+          f"(|delta| {delta:.2e})")
+    assert delta <= 5e-4 * max(1.0, abs(one_chip)), (mesh_loss, one_chip)
+    return {
+        "devices": n_dev,
+        "first_step_loss": mesh_loss,
+        "one_chip_loss": one_chip,
+        "sharded_moment_leaves": len(moments),
+        "param_bytes": int(param_bytes),
+        "graphs_per_device": counts,
+        "per_device": per_device,
+        "losses": {"train": [float(x) for x in hist["train"]]},
+    }
+
+
+def main() -> int:
+    t_start = time.perf_counter()
+    device = require_tpu()
+    import jax
+
+    from hydragnn_tpu.train.compile_plane import (
+        compile_metrics,
+        setup_compile_cache,
+    )
+
+    # cache every program, however quick its compile: the second run of a
+    # pair must then report hits and NO miss, with no threshold to straddle
+    os.environ.setdefault("HYDRAGNN_COMPILE_CACHE_MIN_SECS", "0")
+    cache_dir = setup_compile_cache()
+    repo = os.path.dirname(os.path.abspath(__file__))
+    # run logs, checkpoints and tuned tables land in a fresh directory, so
+    # the smoke reads nothing it did not write in this run; only the compile
+    # cache (train/compile_plane.py compile_cache_dir) outlives it
+    workdir = tempfile.mkdtemp(prefix="chip_smoke_")
+    os.chdir(workdir)
+    todo = [("kernels", kernel_leg), ("main", main_leg),
+            ("second_order", second_order_leg)]
+    if jax.local_device_count() > 1:
+        todo.append(("mesh", mesh_leg))
+    legs = {}
+    try:
+        for name, leg in todo:
+            print(f"[{name}]", flush=True)
+            t0 = time.perf_counter()
+            legs[name] = {"ok": True, **(leg() or {}),
+                          "wall_s": round(time.perf_counter() - t0, 1)}
+    finally:
+        os.chdir(repo)
+        shutil.rmtree(workdir, ignore_errors=True)
+    metrics = compile_metrics()
+    print(json.dumps({
+        "ok": True,
+        "device": device,
+        "legs": legs,
+        "time_to_first_step_s": legs["main"]["time_to_first_step_s"],
+        "compile_s": round(metrics["backend_compile_s"], 2),
+        "cache_hits": int(metrics["cache_hits"]),
+        "cache_misses": int(metrics["cache_misses"]),
+        "cache_dir": cache_dir,
+        "wall_s": round(time.perf_counter() - t_start, 1),
+    }))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

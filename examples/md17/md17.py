@@ -14,7 +14,7 @@ dataset builder takes one of two sources:
 
 Either source is written once through ``ColumnarWriter`` and read back via
 ``Dataset.format: "columnar"``. Prints the test-set force MAE — the
-BASELINE.md "MD17-shaped force MAE" row.
+PERF.md "MD17-shaped force MAE" row.
 
     python examples/md17/md17.py [--mpnn_type SchNet] [--num_samples 512]
 """
@@ -37,7 +37,7 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 
 # bump when md17_shaped_dataset's distribution changes (v2 = the round-5
 # Boltzmann-style force-cap acceptance): a stale shard must not silently
-# produce numbers that don't correspond to the BASELINE.md recipe
+# produce numbers that don't correspond to the PERF.md recipe
 _GEN_VERSION = 2
 
 

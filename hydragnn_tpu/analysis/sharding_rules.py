@@ -18,7 +18,7 @@ Scope: every package module OUTSIDE ``parallel/``. Flagged call targets:
   the engine's ``_constrain`` (driven by the table's grads/params rules);
 - ``NamedSharding(...)`` — device placement belongs in
   ``engine.place_state`` / the mesh helpers;
-- ``shard_map(...)`` / ``compat_shard_map(...)`` — per-device program
+- ``shard_map(...)`` — per-device program
   boundaries belong in the engine's step builders.
 
 Mentions in strings/comments and ``isinstance(x, NamedSharding)`` type
@@ -40,7 +40,6 @@ _FORBIDDEN = (
     "with_sharding_constraint",
     "NamedSharding",
     "shard_map",
-    "compat_shard_map",
 )
 
 _HINTS = {
@@ -57,7 +56,6 @@ _HINTS = {
         "builders; add a rule preset instead of a bespoke shard_map"
     ),
 }
-_HINTS["compat_shard_map"] = _HINTS["shard_map"]
 
 
 def run(repo: Repo) -> List[Finding]:

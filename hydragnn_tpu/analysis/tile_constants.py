@@ -15,7 +15,7 @@ are the same value by construction (docs/TUNING.md).
 Rule (package-wide, two exemptions):
 
 - a numeric literal passed as a ``block_rows`` / ``block_edges`` /
-  ``block_cols`` / ``block_q`` / ``block_k`` / ``chunk_edges`` keyword is
+  ``block_cols`` / ``block_q`` / ``block_k`` keyword is
   a finding — route the call through ``tile_plan`` (or waive with the
   reason the pinned value is load-bearing);
 - ``ops/pallas_*.py`` is exempt: the kernel modules OWN their pinned
@@ -39,7 +39,7 @@ CHECKER_ID = "tile_constants"
 # the tile-plan keyword surface across the four Pallas kernels
 TILE_KWARGS = frozenset((
     "block_rows", "block_edges", "block_cols",
-    "block_q", "block_k", "chunk_edges",
+    "block_q", "block_k",
 ))
 
 

@@ -149,10 +149,7 @@ def _zero_stage(training: Dict[str, Any]) -> int:
 
 def _wants_zero2_mesh(training: Dict[str, Any]) -> bool:
     """Whether a single-host multi-device run must take the mesh step for
-    ZeRO-2 (the gradient constraint lives inside the mesh step). ONE
-    predicate shared by prepare_data's loader gate and run_training's
-    step selection — they must agree or the mesh step sees unstacked
-    batches."""
+    ZeRO-2 (the gradient constraint lives inside the mesh step)."""
     import jax
 
     if _zero_stage(training) < 2:
@@ -165,6 +162,29 @@ def _wants_zero2_mesh(training: Dict[str, Any]) -> bool:
             "decoders, not gradients/moments); drop one of the two"
         )
     return jax.process_count() == 1 and jax.local_device_count() > 1
+
+
+def _wants_mesh_step(config: Dict[str, Any]) -> bool:
+    """Whether a single-host multi-device run takes the (unrouted) mesh
+    step over every local device: ZeRO-2/3, or an EXPLICIT
+    ``Parallel.rules`` table ("dp" included). ONE predicate shared by
+    prepare_data's loader gate and run_training's step selection — they
+    must agree or the mesh step sees unstacked batches.
+
+    The implicit default (no ``Parallel.rules``, no ZeRO stage >= 2) stays
+    on one device: run_training then says once how many it leaves idle."""
+    import jax
+
+    training = config["NeuralNetwork"]["Training"]
+    if _wants_zero2_mesh(training):
+        return True
+    explicit = (config.get("Parallel") or {}).get("rules") is not None
+    return (
+        explicit
+        and not bool(training.get("branch_parallel", False))
+        and jax.process_count() == 1
+        and jax.local_device_count() > 1
+    )
 
 
 def resolve_parallel(config: Dict[str, Any]):
@@ -341,10 +361,10 @@ def prepare_data(
         and jax.local_device_count() > 1
     ):
         num_shards = jax.local_device_count()
-    # single-host ZeRO-2 runs the mesh step (the gradient-sharding
-    # constraint lives there), so its batches must be stacked too —
-    # _wants_zero2_mesh is the SAME predicate run_training uses
-    if _wants_zero2_mesh(training):
+    # single-host mesh-step runs (ZeRO-2/3, or an explicit Parallel.rules
+    # table) need stacked batches too — _wants_mesh_step is the SAME
+    # predicate run_training uses
+    if _wants_mesh_step(config):
         num_shards = jax.local_device_count()
     if batch_size % num_shards != 0:
         raise ValueError(
@@ -623,10 +643,11 @@ def _(config: dict, datasets=None, verbosity: Optional[int] = None):
     # persistent XLA compilation cache (train/compile_plane.py): activated
     # BEFORE the first jit touch (model init below compiles too), so
     # restarts/rollbacks/resumes deserialize executables instead of
-    # recompiling. Training.compile_cache_dir / HYDRAGNN_COMPILE_CACHE.
+    # recompiling. Placed by compile_cache_dir(): JAX_COMPILATION_CACHE_DIR,
+    # else <checkout>/logs/xla_cache.
     from .train.compile_plane import setup_compile_cache
 
-    setup_compile_cache(config["NeuralNetwork"]["Training"], log_name)
+    setup_compile_cache(config["NeuralNetwork"]["Training"])
 
     multihost = jax.process_count() > 1
     training = config["NeuralNetwork"]["Training"]
@@ -823,11 +844,12 @@ def _(config: dict, datasets=None, verbosity: Optional[int] = None):
     # for the loader num_shards gate (unstacked batches would break it);
     # resolve_parallel normalized zero_stage from the table, so inline
     # tables with grads/params rules take this gate too
-    zero2_mesh = _wants_zero2_mesh(training) and not multihost
+    single_host_mesh = _wants_mesh_step(config) and not multihost
     if (
         use_zero
         and zero_stage < 2
         and not multihost
+        and not single_host_mesh
         and not training.get("branch_parallel", False)
         and len(jax.devices()) > 1
     ):
@@ -865,7 +887,7 @@ def _(config: dict, datasets=None, verbosity: Optional[int] = None):
             f">=2 local devices (have {jax.local_device_count()}): "
             "prepare_data could not build branch-routed loaders"
         )
-    if multihost or branch_parallel or zero2_mesh:
+    if multihost or branch_parallel or single_host_mesh:
         # the ONE mesh-step path (parallel/engine.py): the rule table
         # decides placement, in-step constraints, and routing — dp /
         # ZeRO-2/3 / branch-parallel are presets, not code paths
@@ -933,6 +955,19 @@ def _(config: dict, datasets=None, verbosity: Optional[int] = None):
             lambda s, b: _peval(s, promote_batch(b, mesh)) + (None,),
             _peval,
             lambda b: promote_batch(b, mesh),
+        )
+
+    if step_fn is None and not placement_fns and jax.local_device_count() > 1:
+        # the implicit default trains on ONE device of a multi-device host
+        # (tier-1 runs on 8 virtual devices and relies on it): say so once,
+        # so idle chips are a visible choice and not a silent one
+        import sys as _sys
+
+        print(
+            f"[hydragnn_tpu] training on 1 of {jax.local_device_count()} "
+            "local devices; set Parallel.rules: \"dp\" (or "
+            "Optimizer.zero_stage: 2) to train over all of them",
+            file=_sys.stderr,
         )
 
     # sharding-layout inspector (obs/sharding.py): whenever a placement
@@ -1162,9 +1197,7 @@ def _(config: dict, model_state=None, datasets=None):
     # repaying the full compile bill (train/compile_plane.py)
     from .train.compile_plane import setup_compile_cache
 
-    setup_compile_cache(
-        config["NeuralNetwork"]["Training"], get_log_name_config(config)
-    )
+    setup_compile_cache(config["NeuralNetwork"]["Training"])
     model = create_model(config)
     if model_state is None:
         variables = init_model(model, next(iter(test_loader)), seed=0)
@@ -1265,7 +1298,7 @@ def _(config: dict, datasets=None, install_sigterm: bool = False):
     # a server restart deserializes the warmed ladder instead of recompiling
     from .train.compile_plane import setup_compile_cache
 
-    setup_compile_cache(config["NeuralNetwork"]["Training"], log_name)
+    setup_compile_cache(config["NeuralNetwork"]["Training"])
     model = create_model(config)
     variables = init_model(model, next(iter(test_loader)), seed=0)
     try:

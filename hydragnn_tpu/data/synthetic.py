@@ -193,7 +193,7 @@ def oc20_shaped_dataset(
 ) -> List[Graph]:
     """OC20-S2EF-*shaped* workload: catalyst-slab-like configurations whose
     node-count and degree distributions match the real benchmark target
-    (BASELINE.md north star; the dataset itself cannot be downloaded in this
+    (PERF.md; the dataset itself cannot be downloaded in this
     image). Sizes are lognormal with mean ~73 atoms clipped to [20, 225]
     (the OC20 slab range); positions are FCC-packed at a metallic lattice
     constant so ``radius``/``max_neighbours`` produce the capped ~20-degree
@@ -252,7 +252,7 @@ def md17_shaped_dataset(
     """MD17-(aspirin)-*shaped* workload: one fixed 21-atom molecule (the
     aspirin C9H8O4 composition) whose configurations are thermal perturbations
     of a common template — the structure of the real MD17 benchmark
-    (BASELINE.md; reference: examples/md17). Targets are LJ energies/forces
+    (PERF.md; reference: examples/md17). Targets are LJ energies/forces
     evaluated on each perturbed geometry, so force MAE measured on this task
     exercises exactly the energy+force training path at MD17's scale.
     """

@@ -212,7 +212,7 @@ class RingSelfAttention(nn.Module):
         v = v.reshape(-1, H, d)
         mesh, axis = current_sp()
         if mesh is not None:
-            from ..parallel.mesh import compat_shard_map as shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             from ..parallel.ring_attention import ring_self_attention

@@ -1316,11 +1316,11 @@ def r_cold_start(s: RunStreams, cfg: DoctorConfig) -> List[Finding]:
         f"miss(es) (hits: {rep.get('cache_hits')}) — the restart paid "
         f"time_to_first_step={rep.get('time_to_first_step')}s in "
         "recompilation the persistent cache should have absorbed",
-        "check Training.compile_cache_dir points at the SAME directory "
-        "as the original run (the default is per-run-name, so a renamed "
-        "run cold-starts by construction) and that HYDRAGNN_COMPILE_CACHE "
-        "is not overriding it; a jax/jaxlib upgrade also invalidates "
-        "every key",
+        "check both runs resolved the SAME cache directory "
+        "(JAX_COMPILATION_CACHE_DIR, else <checkout>/logs/xla_cache — "
+        "train/compile_plane.py compile_cache_dir) and that "
+        "Training.compile_cache_dir / HYDRAGNN_COMPILE_CACHE did not "
+        "switch it off; a jax/jaxlib upgrade also invalidates every key",
         evidence=[{"compile_report": {
             k: rep.get(k) for k in ("cache_hits", "cache_misses",
                                     "time_to_first_step", "mode")}}],
@@ -1696,7 +1696,7 @@ def diff_runs(
             f"({ttfs_b / ttfs_a:.1f}x) with cache misses "
             f"{sum_b.get('cache_misses')} vs {sum_a.get('cache_misses')}",
             "run B recompiled what run A served from cache — check "
-            "Training.compile_cache_dir stability across the two runs "
+            "JAX_COMPILATION_CACHE_DIR stability across the two runs "
             "and whether the step program changed (the retrace sentinel "
             "report names the differing avals)",
             data={"ttfs_a": ttfs_a, "ttfs_b": ttfs_b},

@@ -60,7 +60,7 @@ from jax.experimental import pallas as pl
 from ..utils import envflags
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_segment import _pad_to
+from .pallas_segment import _pad_to, mxu_precision
 
 # masking constant: large-negative instead of finfo.min so the f32
 # running-max arithmetic (exp of differences) never overflows; shared by
@@ -206,6 +206,7 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             q,
             k_ref[0],
             (((1,), (1,)), ((), ())),  # contract the head dim: q @ k.T
+            precision=mxu_precision(q.dtype),
             preferred_element_type=jnp.float32,
         ) * scale  # [Bq, Bk] f32
         # same-graph mask from the streamed graph-id column/row; padding
@@ -226,6 +227,7 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             p.astype(v_ref.dtype),  # bf16 streams hit the MXU fast path
             v_ref[0],
             (((1,), (0,)), ((), ())),
+            precision=mxu_precision(v_ref.dtype),
             preferred_element_type=jnp.float32,
         )
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_segment import _pad_to
+from .pallas_segment import _pad_to, mxu_precision
 
 
 def reference_edge_message_sum(
@@ -83,18 +83,21 @@ def _kernel(estart_ref, ids_ref, nrecv_ref, ein_ref, w_ref, b_ref, out_ref):
 
     nb = out_ref.shape[0]
     dtype = ein_ref.dtype
+    precision = mxu_precision(dtype)
     # in-register one-hot: edge e belongs to local row r iff its receiver id
     # equals j*Nb + r; padding edges carry id -1 and never match
     rows = j * nb + jax.lax.broadcasted_iota(jnp.int32, (1, nb), 1)
     mine = (ids_ref[:] == rows).astype(dtype)  # [Eb, Nb]
     # in-kernel receiver gather: each one-hot row copies exactly one row of
     # the receiver-projected node block (exact in any dtype — the f32
-    # accumulation sums a single product 1.0 * x). Unowned/padding edges get
-    # a zero row; their messages are zeroed by the same one-hot below.
+    # accumulation sums a single product 1.0 * x, at the precision
+    # mxu_precision asks for). Unowned/padding edges get a zero row; their
+    # messages are zeroed by the same one-hot below.
     pre = jax.lax.dot_general(
         mine,
         nrecv_ref[:],
         (((1,), (0,)), ((), ())),
+        precision=precision,
         preferred_element_type=jnp.float32,
     ).astype(dtype) + ein_ref[:]
     h = jnp.maximum(pre, jnp.zeros((), dtype))
@@ -102,6 +105,7 @@ def _kernel(estart_ref, ids_ref, nrecv_ref, ein_ref, w_ref, b_ref, out_ref):
         h,
         w_ref[:],
         (((1,), (0,)), ((), ())),
+        precision=precision,
         preferred_element_type=jnp.float32,
     ) + b_ref[:].astype(jnp.float32)
     # round the message to the streaming dtype before accumulating, matching
@@ -112,6 +116,7 @@ def _kernel(estart_ref, ids_ref, nrecv_ref, ein_ref, w_ref, b_ref, out_ref):
         mine,
         msg,
         (((0,), (0,)), ((), ())),  # contract over the edge axis
+        precision=precision,
         preferred_element_type=jnp.float32,
     )
 

@@ -36,10 +36,15 @@ It extends the sorted-edge grid/``estart`` scheme of
   ``gate``; PNAEq passes its post-MLP message as ``edge_in`` directly and
   skips the in-kernel gather);
 - sum and sumsq accumulate as ``mine.T @ msg`` MXU contractions
-  (f32 accumulation); min/max have no matmul form, so they reduce on the
-  VPU in ``chunk_edges``-sized sub-windows via a masked 3D where
-  ([chunk, Nb, Cb] resident in VMEM) — VPU cycles that were previously
-  stalled on the four separate [E, C] HBM traversals.
+  (f32 accumulation); min/max have no matmul form, but the receivers are
+  SORTED, so each node's edges are one contiguous run of the window: a
+  segmented running min/max along the edge axis (log2(max_degree)
+  ``pltpu.roll`` + compare + select steps on the [Eb, Cb] tile) leaves
+  every run's extremum on its LAST edge, and the same one-hot contraction
+  that scatters the sums then copies those run-end values onto their node
+  rows. Everything stays 2-D: the first form of this kernel reduced a
+  [chunk, Nb, Cb] masked ``where``, which Mosaic refuses to lay out (an
+  ``i1`` [chunk, Nb] -> [chunk, Nb, 1] shape cast; TPU v5e, jax 0.9.0).
 
 Differentiation: ``jax.custom_jvp`` whose tangent rule is the PLAIN-jnp
 dense reference pushed through ``jax.jvp`` — the recompute schedule
@@ -61,7 +66,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_segment import _pad_to
+from .pallas_segment import _pad_to, mxu_precision
 
 # min/max accumulator sentinel: large enough that no real message reaches
 # it, small enough that +/-_BIG survives an f32 round-trip exactly
@@ -109,7 +114,7 @@ def reference_multi_agg(node_recv, edge_in, gate, segment_ids, num_segments,
     return s, cnt, mn, mx, ssq
 
 
-def _make_kernel(has_recv: bool, has_gate: bool, chunk: int):
+def _make_kernel(has_recv: bool, has_gate: bool, max_degree: int):
     def kernel(estart_ref, *refs):
         i = 1
         ids_ref = refs[0]
@@ -131,54 +136,84 @@ def _make_kernel(has_recv: bool, has_gate: bool, chunk: int):
         j = pl.program_id(1)
         nb = s_ref.shape[0]
         dtype = ein_ref.dtype
+        precision = mxu_precision(dtype)
         # in-register one-hot: edge e belongs to local row r iff its
         # receiver id equals j*Nb + r; padding edges carry id -1 and never
         # match, so they are excluded from every moment
         rows = j * nb + jax.lax.broadcasted_iota(jnp.int32, (1, nb), 1)
-        mine = ids_ref[:] == rows  # [Eb, Nb] bool
-        minef = mine.astype(dtype)
+        ids = ids_ref[:]  # [Eb, 1]
+        mine32 = (ids == rows).astype(jnp.float32)  # [Eb, Nb]
+        minef = mine32.astype(dtype)
         msg = ein_ref[:]
         if has_recv:
             # in-kernel receiver gather: each one-hot row copies exactly one
             # row of the receiver-projected node block (exact in any dtype).
             # Edges owned by other row blocks get a zero gather row — their
-            # (wrong) message is zeroed by the same one-hot in the sum dots
-            # and masked out of the min/max by `mine` below.
+            # (wrong) message is zeroed by the same one-hot in every
+            # contraction below.
             msg = jax.lax.dot_general(
                 minef,
                 nrecv_ref[:],
                 (((1,), (0,)), ((), ())),
+                precision=precision,
                 preferred_element_type=jnp.float32,
             ).astype(dtype) + msg
         if has_gate:
             msg = msg * gate_ref[:]
         msg32 = msg.astype(jnp.float32)
-        # sum / sumsq: MXU one-hot contractions over the edge axis, f32
-        # accumulation (sumsq squares in f32 — see reference_multi_agg)
-        s_ref[:] += jax.lax.dot_general(
-            minef,
-            msg,
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        fp32 = jax.lax.Precision.HIGHEST
+
+        def scatter(values, precision):
+            # [Nb, Cb] += one-hot.T @ values: contract over the edge axis
+            return jax.lax.dot_general(
+                mine32 if values.dtype == jnp.float32 else minef,
+                values,
+                (((0,), (0,)), ((), ())),
+                precision=precision,
+                preferred_element_type=jnp.float32,
+            )
+
+        # sum / sumsq: f32 accumulation. The squares are f32 whatever the
+        # stream dtype (see reference_multi_agg), so their contraction is
+        # always fp32: at DEFAULT the MXU would round them back to bf16
+        s_ref[:] += scatter(msg, precision)
+        ssq_ref[:] += scatter(msg32 * msg32, fp32)
+
+        # min / max: segmented running extremum over each receiver's
+        # contiguous run of edges. After steps d = 1, 2, 4, ... the value on
+        # edge e covers the last 2d edges of its run, so ceil(log2(run))
+        # steps suffice and real runs are bounded by max_degree (the dummy
+        # node's longer run stays unspecified, like every moment of it).
+        eb, cb = msg32.shape
+        idf = jnp.broadcast_to(ids, (eb, cb))
+        edge = jax.lax.broadcasted_iota(jnp.int32, (eb, cb), 0)
+        lo = hi = msg32
+        d = 1
+        while d < min(max_degree, eb):
+            # roll by d along the edge axis: row e sees row e - d (rows
+            # e < d wrap around and are masked out)
+            same = (pltpu.roll(idf, d, 0) == idf) & (edge >= d)
+            lo = jnp.where(same, jnp.minimum(lo, pltpu.roll(lo, d, 0)), lo)
+            hi = jnp.where(same, jnp.maximum(hi, pltpu.roll(hi, d, 0)), hi)
+            d *= 2
+        # a run ends where the next edge has another receiver (or the
+        # window ends: a run split across two windows contributes its two
+        # partial extrema, merged by the accumulators' min/max)
+        ends = (pltpu.roll(idf, eb - 1, 0) != idf) | (edge == eb - 1)
+        # run-end values ride the one-hot contraction onto their node rows:
+        # one 1.0 * x product per (node, window), exact under fp32
+        # contraction; rows with no run end in this window are left alone
+        present = scatter(ends.astype(jnp.float32), None) > 0.5
+        mn_ref[:] = jnp.where(
+            present,
+            jnp.minimum(mn_ref[:], scatter(jnp.where(ends, lo, 0.0), fp32)),
+            mn_ref[:],
         )
-        ssq_ref[:] += jax.lax.dot_general(
-            mine.astype(jnp.float32),
-            msg32 * msg32,
-            (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        mx_ref[:] = jnp.where(
+            present,
+            jnp.maximum(mx_ref[:], scatter(jnp.where(ends, hi, 0.0), fp32)),
+            mx_ref[:],
         )
-        # min / max: no matmul form — masked VPU reduction over the edge
-        # window in `chunk`-sized sub-windows ([chunk, Nb, Cb] resident)
-        mn = mn_ref[:]
-        mx = mx_ref[:]
-        eb = msg.shape[0]
-        for c0 in range(0, eb, chunk):
-            m3 = mine[c0:c0 + chunk][:, :, None]   # [chunk, Nb, 1]
-            v3 = msg32[c0:c0 + chunk][:, None, :]  # [chunk, 1, Cb]
-            mn = jnp.minimum(mn, jnp.min(jnp.where(m3, v3, _BIG), axis=0))
-            mx = jnp.maximum(mx, jnp.max(jnp.where(m3, v3, -_BIG), axis=0))
-        mn_ref[:] = mn
-        mx_ref[:] = mx
 
     return kernel
 
@@ -186,16 +221,16 @@ def _make_kernel(has_recv: bool, has_gate: bool, chunk: int):
 # tuned-table key component (tune/table.py): bump on any change to the
 # kernel's schedule, block layout, or semantics — stale tuned entries must
 # miss, not steer a different program
-KERNEL_VERSION = 1
+KERNEL_VERSION = 2
 
 
 def normalize_tiles(
     c, dtype, has_recv, has_gate,
-    block_rows=128, block_edges=512, block_cols=128, chunk_edges=32,
+    block_rows=128, block_edges=512, block_cols=128,
 ):
     """Clamp a candidate tile plan to what ``_forward`` will actually run:
     ``block_cols`` to the lane-padded channel width, ``block_edges`` by the
-    VMEM-fit shrink loop, ``chunk_edges`` to the surviving edge window.
+    VMEM-fit shrink loop.
 
     This is the one clamp site — ``_forward`` consumes its result, and the
     routing layer (ops/segment.py) normalizes BEFORE the values become
@@ -206,12 +241,12 @@ def normalize_tiles(
     nb, eb = block_rows, block_edges
     c128 = c + (-c) % 128
     cb = min(block_cols, c128)
-    chunk = min(chunk_edges, eb)
 
     # VMEM fit: shrink the edge window until the resident working set —
-    # double-buffered streams, the four f32 accumulators, msg32, and the
-    # [chunk, Nb, Cb] min/max temporary — fits comfortably. As in the
-    # fused edge kernel, the redundant-revisit cost is eb-invariant
+    # double-buffered streams, the four f32 accumulators, the one-hot and
+    # the [Eb, Cb] f32 temporaries of the min/max scan (msg32, lo, hi, ids,
+    # iota, two rolled copies) — fits comfortably. As in the fused edge
+    # kernel, the redundant-revisit cost is eb-invariant
     # (K ~ Nb*max_degree/eb), so shrinking eb is nearly free.
     itemsize = jnp.dtype(dtype).itemsize
 
@@ -219,20 +254,19 @@ def normalize_tiles(
         return (
             2 * eb_ * cb * itemsize * (1 + int(has_gate))  # edge streams
             + 2 * nb * cb * itemsize * int(has_recv)       # node_recv block
-            + 4 * nb * cb * 4                              # accumulators
-            + 2 * eb_ * cb * 4                             # msg + msg32
-            + min(chunk, eb_) * nb * cb * 4                # min/max select
+            + 2 * 4 * nb * cb * 4                          # accumulators
+            + eb_ * nb * 4                                 # one-hot
+            + 8 * eb_ * cb * 4                             # scan temporaries
         )
 
     while eb > 128 and _vmem_estimate(eb) > 12 * 1024 * 1024:
         eb //= 2
-    chunk = min(chunk, eb)
-    return nb, eb, cb, chunk
+    return nb, eb, cb
 
 
 def _forward(
     node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
-    block_rows, block_edges, block_cols, chunk_edges, interpret,
+    block_rows, block_edges, block_cols, interpret,
 ):
     e, c = edge_in.shape
     dtype = edge_in.dtype
@@ -243,9 +277,8 @@ def _forward(
     if has_gate:
         assert gate.shape == edge_in.shape, (gate.shape, edge_in.shape)
 
-    nb, eb, cb, chunk = normalize_tiles(
-        c, dtype, has_recv, has_gate,
-        block_rows, block_edges, block_cols, chunk_edges,
+    nb, eb, cb = normalize_tiles(
+        c, dtype, has_recv, has_gate, block_rows, block_edges, block_cols,
     )
 
     ids = segment_ids.astype(jnp.int32)
@@ -304,7 +337,7 @@ def _forward(
     grid = (c_pad // cb, j_blocks, k_windows)
     moment = jax.ShapeDtypeStruct((n_pad, c_pad), jnp.float32)
     s, mn, mx, ssq = pl.pallas_call(
-        _make_kernel(has_recv, has_gate, chunk),
+        _make_kernel(has_recv, has_gate, max_degree),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -329,7 +362,7 @@ def _forward(
     return s, cnt, mn, mx, ssq
 
 
-@functools.partial(jax.custom_jvp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_jvp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def fused_multi_agg(
     node_recv,
     edge_in,
@@ -340,7 +373,6 @@ def fused_multi_agg(
     block_rows: int = 128,
     block_edges: int = 512,
     block_cols: int = 128,
-    chunk_edges: int = 32,
     interpret: bool = False,
 ):
     """Fused multi-moment aggregation of ``(node_recv[ids] + edge_in) *
@@ -364,18 +396,18 @@ def fused_multi_agg(
     """
     return _forward(
         node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
-        block_rows, block_edges, block_cols, chunk_edges, interpret,
+        block_rows, block_edges, block_cols, interpret,
     )
 
 
 @fused_multi_agg.defjvp
 def _jvp(num_segments, max_degree, block_rows, block_edges, block_cols,
-         chunk_edges, interpret, primals, tangents):
+         interpret, primals, tangents):
     node_recv, edge_in, gate, segment_ids = primals
     t_nr, t_ei, t_g, _ = tangents
     out = fused_multi_agg(
         node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
-        block_rows, block_edges, block_cols, chunk_edges, interpret,
+        block_rows, block_edges, block_cols, interpret,
     )
     # tangent in PLAIN jnp: the dense reference pushed through jax.jvp.
     # Reverse mode transposes it into a gather + elementwise + segment-op

@@ -210,7 +210,7 @@ def multi_moment_agg(
         return fused_multi_agg(
             node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
             block_rows=plan["block_rows"], block_edges=plan["block_edges"],
-            block_cols=plan["block_cols"], chunk_edges=plan["chunk_edges"],
+            block_cols=plan["block_cols"],
             interpret=jax.default_backend() != "tpu",
         )
     return reference_multi_agg(

@@ -53,7 +53,9 @@ from ..models.base import HydraModel
 from ..train.loss import compute_loss
 from ..train.state import TrainState
 from . import rules as R
-from .mesh import DATA_AXIS, batch_axes, compat_shard_map as shard_map
+from jax import shard_map
+
+from .mesh import DATA_AXIS, batch_axes
 
 
 @dataclasses.dataclass

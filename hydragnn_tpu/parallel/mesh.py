@@ -39,39 +39,6 @@ BRANCH_AXIS = "branch"
 MODEL_AXIS = "model"
 
 
-def compat_shard_map(*args, **kwargs):
-    """``jax.shard_map`` across jax versions: the public name lived in
-    ``jax.experimental.shard_map`` before 0.5, and the replication-check
-    kwarg was renamed ``check_rep`` -> ``check_vma``. Callers use the NEW
-    spelling; this translates for older runtimes by inspecting the actual
-    signature (import location alone doesn't pin the kwarg name)."""
-    try:
-        from jax import shard_map as _sm
-
-        old_location = False
-    except ImportError:  # jax < 0.5 keeps shard_map in experimental
-        from jax.experimental.shard_map import shard_map as _sm
-
-        old_location = True
-    if "check_vma" in kwargs:
-        import inspect
-
-        try:
-            params = inspect.signature(_sm).parameters
-        except (TypeError, ValueError):  # pragma: no cover - C callables
-            params = None
-        if params is not None:
-            if "check_vma" not in params and "check_rep" in params:
-                kwargs["check_rep"] = kwargs.pop("check_vma")
-        elif old_location:
-            # uninspectable + experimental location: the old spelling is
-            # the only one that can exist there
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        # uninspectable at the NEW location: keep the new spelling — that
-        # is the environment the callers are written for
-    return _sm(*args, **kwargs)
-
-
 def make_mesh(
     devices: Optional[Sequence[jax.Device]] = None,
     branch_size: int = 1,
@@ -395,10 +362,9 @@ def setup_distributed() -> None:
 
     Must run before anything touches the XLA backend — including
     ``jax.process_count()`` — so the already-initialized guard uses
-    ``jax.distributed.is_initialized()``, which doesn't (older jaxlibs
-    lack the helper entirely: treat that as not-initialized).
+    ``jax.distributed.is_initialized()``, which doesn't.
     """
-    if getattr(jax.distributed, "is_initialized", lambda: False)():
+    if jax.distributed.is_initialized():
         return
     coord = envflags.env_str("HYDRAGNN_COORDINATOR") or os.environ.get(
         "JAX_COORDINATOR_ADDRESS"

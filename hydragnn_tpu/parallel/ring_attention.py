@@ -158,7 +158,7 @@ def sharded_global_attention(mesh, axis_name: str = "data",
     (q, k, v, key_mask) -> out, all ``[N_global, H, dh]`` sharded the same
     way. The convenience wrapper around ``ring_self_attention`` for the
     giant-graph regime (docs/MULTIHOST.md)."""
-    from .mesh import compat_shard_map as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     fn = shard_map(

@@ -79,16 +79,12 @@ KERNELS: Dict[str, KernelSpec] = {
     ),
     MULTI_AGG: KernelSpec(
         kernel=MULTI_AGG,
-        params=("block_rows", "block_edges", "block_cols", "chunk_edges"),
-        defaults={
-            "block_rows": 128, "block_edges": 512, "block_cols": 128,
-            "chunk_edges": 32,
-        },
+        params=("block_rows", "block_edges", "block_cols"),
+        defaults={"block_rows": 128, "block_edges": 512, "block_cols": 128},
         grid={
             "block_rows": (64, 128, 256),
             "block_edges": (256, 512, 1024),
             "block_cols": (128, 256),
-            "chunk_edges": (16, 32, 64),
         },
     ),
     FLASH: KernelSpec(
@@ -166,15 +162,13 @@ def normalize(kernel: str, plan: Dict[str, int],
     if kernel == MULTI_AGG:
         from ..ops.pallas_multi_agg import normalize_tiles
 
-        nb, eb, cb, chunk = normalize_tiles(
+        nb, eb, cb = normalize_tiles(
             int(shapes["channels"]), shapes.get("dtype", "float32"),
             bool(shapes.get("has_recv", True)),
             bool(shapes.get("has_gate", False)),
             p["block_rows"], p["block_edges"], p["block_cols"],
-            p["chunk_edges"],
         )
-        return {"block_rows": nb, "block_edges": eb, "block_cols": cb,
-                "chunk_edges": chunk}
+        return {"block_rows": nb, "block_edges": eb, "block_cols": cb}
     if kernel == FLASH:
         from ..ops.pallas_flash_attention import normalize_tiles
 

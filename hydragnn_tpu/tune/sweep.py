@@ -111,7 +111,7 @@ def build_call(kernel: str, shapes: Dict[str, Any], dtype: str,
         ids = jnp.asarray(_sorted_ids(e, n, d))
         fn = jax.jit(lambda nr, x, g: fused_multi_agg(
             nr, x, g, ids, n, d, plan["block_rows"], plan["block_edges"],
-            plan["block_cols"], plan["chunk_edges"], interpret,
+            plan["block_cols"], interpret,
         ))
         return lambda: fn(nrecv, ein, gate)
     if kernel == plans.FLASH:

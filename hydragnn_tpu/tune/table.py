@@ -2,10 +2,9 @@
 
 One JSON file per tuned entry, named by the sha256 of its canonical key —
 ``(kernel id, kernel version, device kind, dtype, normalized shape
-signature)`` — in a directory that lives next to the compile cache
+signature)`` — in a directory under the run's log dir
 (default ``./logs/<run>/tuned_table``; ``Training.autotune_cache_dir``
-redirects, ``HYDRAGNN_TUNE_CACHE`` env always wins, same grammar as the
-compile cache's resolution in train/compile_plane.py).
+redirects, ``HYDRAGNN_TUNE_CACHE`` env always wins).
 
 Invalidation is entirely in the key: a kernel schedule change bumps its
 module's ``KERNEL_VERSION``, a different chip generation reports a
@@ -80,13 +79,12 @@ def entry_key(
 def resolve_tune_cache(
     training: Dict[str, Any], log_name: Optional[str] = None
 ) -> Optional[str]:
-    """Resolve the tuned-table directory, mirroring the compile cache's
-    grammar (train/compile_plane.py ``setup_compile_cache``):
+    """Resolve the tuned-table directory:
     ``HYDRAGNN_TUNE_CACHE`` env (``0``/``off``/``none`` disables, ``1``
     forces the config/default resolution back on, a path overrides), then
     ``Training.autotune_cache_dir`` (``false`` disables, a path
-    overrides), else ``./logs/<run>/tuned_table`` next to the compile
-    cache. Returns the directory, or None when disabled."""
+    overrides), else ``./logs/<run>/tuned_table`` under the run's log
+    dir. Returns the directory, or None when disabled."""
     env = envflags.env_str("HYDRAGNN_TUNE_CACHE")
     cfg = training.get("autotune_cache_dir")
     if env is not None:

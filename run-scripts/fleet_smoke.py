@@ -47,8 +47,6 @@ import time
 
 sys.path.insert(0, {repo!r})
 import jax
-if not hasattr(jax.distributed, "is_initialized"):
-    jax.distributed.is_initialized = lambda: False
 import numpy as np
 
 HOST = int(os.environ["HYDRAGNN_FLEET_HOST_INDEX"])
@@ -87,11 +85,9 @@ def make_cfg(fleet, num_epoch):
                 "num_epoch": num_epoch, "batch_size": 8, "seed": 11,
                 "num_pad_buckets": 2,
                 # "analysis": blocking AOT warm-up WITHOUT a persistent
-                # cache — this image's jaxlib segfaults computing the
-                # persistent-cache key for the zero-2 mesh program
-                # (pre-existing, cache-key _canonicalize_ir), so the
-                # children run cache-less; the analysis mode still fills
-                # the FLOPs/HBM/collective tables the smoke asserts
+                # cache (the children run cache-less, smoke_env.py); the
+                # analysis mode still fills the FLOPs/HBM/collective
+                # tables the smoke asserts
                 "precompile": "analysis" if fleet else "off",
                 # zero-2 engages the mesh step on the 2-device CPU mesh:
                 # real psum/reduce-scatter collectives in the HLO
@@ -412,10 +408,8 @@ from smoke_env import child_env  # noqa: E402
 
 def _env(extra=None):
     # 2 virtual devices: the zero-2 mesh step with real collectives,
-    # independent of ci.sh's 8-device flag. Cache-less children: this
-    # image's jaxlib segfaults in the persistent-cache key serializer on
-    # the zero-2 mesh program (smoke_env.py documents the defect class);
-    # precompile "analysis" keeps the harvests.
+    # independent of ci.sh's 8-device flag. Cache-less children
+    # (smoke_env.py); precompile "analysis" keeps the harvests.
     return child_env(
         {"HYDRAGNN_COMPILE_CACHE_MIN_SECS": "0", **(extra or {})},
         device_count=2,

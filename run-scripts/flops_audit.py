@@ -3,7 +3,7 @@
 
 Round-4's verdict flagged that three FLOPs/graph figures coexisted (51.6,
 31.1, ~34.9) with no committed script behind any of them. This tool IS the
-recipe now — docs/PERFORMANCE.md and BASELINE.md cite it, and any number not
+recipe now — docs/PERFORMANCE.md and PERF.md cite it, and any number not
 produced by it is marked superseded.
 
 Recipe (definitions):

@@ -39,10 +39,6 @@ _PRELUDE = """
 import sys
 sys.path.insert(0, __REPO__)
 import jax
-if not hasattr(jax.distributed, "is_initialized"):
-    # older jax (this CPU image): run_training only uses it as an
-    # already-initialized guard, and this smoke is strictly single-process
-    jax.distributed.is_initialized = lambda: False
 """
 
 _DATA = """

@@ -43,10 +43,6 @@ _CHILD = """
 import sys
 sys.path.insert(0, {repo!r})
 import jax
-if not hasattr(jax.distributed, "is_initialized"):
-    # older jax (this CPU image): run_training/run_server only use it as an
-    # already-initialized guard, and this smoke is strictly single-process
-    jax.distributed.is_initialized = lambda: False
 
 import numpy as np
 

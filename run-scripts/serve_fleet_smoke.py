@@ -54,9 +54,6 @@ _CHILD = """
 import sys
 sys.path.insert(0, {repo!r})
 import jax
-if not hasattr(jax.distributed, "is_initialized"):
-    # older jax (this CPU image): the fleet is N single-process servers
-    jax.distributed.is_initialized = lambda: False
 
 import dataclasses
 import itertools

@@ -142,7 +142,7 @@ def pytest_lint_handles_compile_plane_keys():
         "Dataset": {"lappe_cache": True},
         "NeuralNetwork": {
             "Training": {
-                "compile_cache_dir": "/tmp/x",
+                "compile_cache_dir": False,
                 "precompile": "background",
                 "retrace_policy": "warn",
             }
@@ -163,42 +163,101 @@ def pytest_lint_handles_compile_plane_keys():
 # ---------------------------------------------------------------------------
 
 
-def pytest_setup_compile_cache_resolution(tmp_path, monkeypatch):
-    monkeypatch.chdir(tmp_path)
-    # conftest pins HYDRAGNN_COMPILE_CACHE=0 suite-wide (jaxlib serializer
-    # defect); this test exercises the resolution order itself, so start
-    # from a clean env
-    monkeypatch.delenv("HYDRAGNN_COMPILE_CACHE", raising=False)
-    # default: under the run's log dir
-    got = cp.setup_compile_cache({}, "runA")
-    assert got == os.path.abspath(os.path.join("logs", "runA", "xla_cache"))
-    assert os.path.isdir(got)
-    assert cp.cache_dir_active() == got
-    # config path wins over the default
-    got = cp.setup_compile_cache({"compile_cache_dir": str(tmp_path / "cc")}, "runA")
-    assert got == str(tmp_path / "cc")
-    # config false disables
-    assert cp.setup_compile_cache({"compile_cache_dir": False}, "runA") is None
-    # env path wins over config
-    monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE", str(tmp_path / "env_cc"))
-    got = cp.setup_compile_cache({"compile_cache_dir": False}, "runA")
-    assert got == str(tmp_path / "env_cc")
-    # env off wins over everything AND deactivates the previously active dir
-    monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE", "off")
-    assert (
-        cp.setup_compile_cache({"compile_cache_dir": str(tmp_path / "cc")}, "runA")
-        is None
+def _repo_cache_dir():
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "logs", "xla_cache",
     )
-    assert cp.cache_dir_active() is None
-    # env "1" forces the config/default resolution back on (the
-    # HYDRAGNN_LAPPE_CACHE=1 semantics), even over a config disable
+
+
+def _tree(path):
+    return sorted(
+        os.path.join(d, f) for d, _, fs in os.walk(path) for f in fs
+    )
+
+
+def pytest_compile_cache_placed_from_outside(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is there and nowhere else —
+    no jax_compilation_cache_dir update is issued, nothing lands under
+    <checkout>/logs."""
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
     monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE", "1")
-    got = cp.setup_compile_cache({"compile_cache_dir": False}, "runA")
-    assert got == os.path.abspath(os.path.join("logs", "runA", "xla_cache"))
-    # config false (no env) also deactivates an earlier run's dir
-    monkeypatch.delenv("HYDRAGNN_COMPILE_CACHE")
-    assert cp.setup_compile_cache({"compile_cache_dir": False}, "runA") is None
-    assert cp.cache_dir_active() is None
+    # what jax does with the variable at import time
+    jax.config.update("jax_compilation_cache_dir", placed)
+    cp._reset_jax_cache_object()
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda key, val: (updates.append(key), real_update(key, val))[1],
+    )
+    before = _tree(_repo_cache_dir())
+    monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE_MIN_SECS", "0")
+    try:
+        assert cp.compile_cache_dir() == placed
+        assert cp.setup_compile_cache({}) == placed
+        assert "jax_compilation_cache_dir" not in updates
+        jax.block_until_ready(jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0)))
+        assert _tree(placed), "nothing was cached where the variable points"
+        assert _tree(_repo_cache_dir()) == before
+    finally:
+        monkeypatch.undo()
+        cp.set_cache_dir(None)
+
+
+def pytest_compile_cache_default_is_anchored_on_the_checkout(
+        tmp_path, monkeypatch):
+    """Unset: <checkout>/logs/xla_cache whatever the working directory; the
+    run name cannot move it because the rule never sees it."""
+    import inspect
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("HYDRAGNN_COMPILE_CACHE", raising=False)
+    assert list(inspect.signature(cp.setup_compile_cache).parameters) == [
+        "training"
+    ]
+    try:
+        for cwd in (tmp_path, tmp_path / "elsewhere"):
+            cwd.mkdir(exist_ok=True)
+            monkeypatch.chdir(cwd)
+            assert cp.compile_cache_dir() == _repo_cache_dir()
+            assert cp.setup_compile_cache({}) == _repo_cache_dir()
+            assert cp.cache_dir_active() == _repo_cache_dir()
+            assert not os.path.exists(cwd / "logs")
+    finally:
+        cp.set_cache_dir(None)
+
+
+def pytest_compile_cache_switches(monkeypatch):
+    """The on/off values stay; a path in either channel is an error, not a
+    silently ignored placement."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("HYDRAGNN_COMPILE_CACHE", raising=False)
+    try:
+        # config false disables AND deactivates an earlier run's dir
+        assert cp.setup_compile_cache({}) == _repo_cache_dir()
+        assert cp.setup_compile_cache({"compile_cache_dir": False}) is None
+        assert cp.cache_dir_active() is None
+        # env "1" forces it back on over the config
+        monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE", "1")
+        assert (
+            cp.setup_compile_cache({"compile_cache_dir": False})
+            == _repo_cache_dir()
+        )
+        # env off wins over everything
+        for off in ("0", "off", "none", "false", ""):
+            monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE", off)
+            assert cp.setup_compile_cache({}) is None
+            assert cp.cache_dir_active() is None
+        monkeypatch.setenv("HYDRAGNN_COMPILE_CACHE", "/tmp/some/dir")
+        with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+            cp.setup_compile_cache({})
+        monkeypatch.delenv("HYDRAGNN_COMPILE_CACHE")
+        with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+            cp.setup_compile_cache({"compile_cache_dir": "/tmp/x"})
+    finally:
+        cp.set_cache_dir(None)
 
 
 def pytest_plane_degrades_to_off_without_cache_dir():

@@ -631,10 +631,16 @@ def pytest_doctor_diff_bench_rounds_consistent_with_gate(tmp_path):
     assert cell["delta_frac"] == pytest.approx(-0.2)
 
 
-def pytest_doctor_diff_committed_rounds_and_cells():
-    """The committed BENCH_r01/r05 artifacts parse through the same cell
+def pytest_doctor_diff_rounds_and_cells(tmp_path):
+    """Round artifacts of the driver's shape parse through the same cell
     keying as bench_gate (valid rounds only; invalid rounds refuse)."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    repo = str(tmp_path)
+    _bench_round(os.path.join(repo, "BENCH_r01.json"), 1, 68055.28, 68055.28)
+    _bench_round(os.path.join(repo, "BENCH_r05.json"), 5, 861.25, 183004.03)
+    with open(os.path.join(repo, "BENCH_r02.json"), "w") as fh:
+        json.dump({"rc": 0, "parsed": {
+            "metric": "synthetic throughput", "value": 0.0,
+            "error": "device unreachable"}}, fh)
     n1, cells1 = load_bench_cells(os.path.join(repo, "BENCH_r01.json"))
     n5, cells5 = load_bench_cells(os.path.join(repo, "BENCH_r05.json"))
     assert n1 == 1 and n5 == 5 and cells1 and cells5

@@ -351,7 +351,11 @@ def pytest_flash_config_completion(monkeypatch):
                         "graph_features": {"dim": [1]}},
         }
 
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # jit-target inference only
+    import hydragnn_tpu.config.config as config_mod
+
+    monkeypatch.setattr(
+        config_mod, "_jit_target_inference", lambda: (True, "test: tpu")
+    )
     done = update_config(
         cfg(global_attn_engine="GPS", global_attn_type="multihead",
             global_attn_heads=2, pe_dim=1),

@@ -454,25 +454,29 @@ def pytest_collective_census_text_parse():
     assert c["all-gather"] == {"count": 1, "bytes": 8 * 4}
     assert c["reduce-scatter"] == {"count": 1, "bytes": 1024 * 2}
     assert c["collective-permute"] == {"count": 1, "bytes": 16}
-    s = cp.summarize_comm(c, flops=1e9, device_kind="cpu")
+    s = cp.summarize_comm(c, flops=1e9, device_kind="TPU v5 lite")
     assert s["bytes_total"] == sum(e["bytes"] for e in c.values())
     assert s["ops_total"] == 4
     assert 0.0 < s["comm_fraction_est"] < 1.0
     # no flops -> decomposition unknown, bytes still real
-    s2 = cp.summarize_comm(c, flops=None, device_kind="cpu")
+    s2 = cp.summarize_comm(c, flops=None, device_kind="TPU v5 lite")
     assert s2["comm_fraction_est"] is None
+    # a device with no listed peak has no compute-time estimate either
+    s3 = cp.summarize_comm(c, flops=1e9, device_kind="cpu")
+    assert s3["compute_time_est_s"] is None
+    assert s3["comm_fraction_est"] is None
 
 
 @pytest.mark.skipif(jax.device_count() < 2, reason="needs a multi-device mesh")
 def pytest_collective_census_real_mesh_program():
-    from hydragnn_tpu.parallel.mesh import compat_shard_map, make_mesh
+    from hydragnn_tpu.parallel.mesh import make_mesh
 
     mesh = make_mesh()
 
     def f(x):
         return jax.lax.psum(x, ("branch", "data"))
 
-    sm = compat_shard_map(
+    sm = jax.shard_map(
         f, mesh=mesh, in_specs=(P(("branch", "data")),), out_specs=P(),
         check_vma=False,
     )
@@ -501,7 +505,7 @@ def pytest_precompile_analysis_mode_harvests_without_cache(monkeypatch):
     fn = jax.jit(lambda s, b, r: (s, jnp.sum(b * s), None))
     from hydragnn_tpu.train.compile_plane import setup_compile_cache
 
-    setup_compile_cache({}, "analysis_test")
+    setup_compile_cache({})
     degraded = cp.CompilePlane(mode="background", log_name="analysis_test")
     degraded.launch(fn, None, jnp.float32(2.0), _Loader(),
                     rng=jax.random.PRNGKey(0), skip_eval=True)

@@ -225,8 +225,23 @@ def pytest_step_telemetry_windows_and_stream(tmp_path):
     telem = StepTelemetry(settings, "obs_run", log_path=str(tmp_path))
     telem.attach_flops(lambda key: 1e9)  # 1 GFLOP per step, every spec
     batches = _batches()
-    for b in batches[:4]:
+
+    def mfu_samples():
+        return [
+            l for l in render_text().splitlines()
+            if l.startswith("hydragnn_mfu_estimate")
+        ]
+
+    # first window on the suite's real device, the CPU: it has no listed
+    # peak, so no MFU is computed and no gauge sample is published
+    for b in batches[:2]:
         telem.on_step(b, 0.01, real_graphs=int(np.asarray(b.graph_mask).sum()))
+    assert mfu_samples() == []
+    # second window as a listed TPU generation
+    telem._device_kind = "TPU v5 lite"
+    for b in batches[2:4]:
+        telem.on_step(b, 0.01, real_graphs=int(np.asarray(b.graph_mask).sum()))
+    assert len(mfu_samples()) == 1
     telem.on_epoch(0, {"train": 0.5, "val": 0.4, "test": 0.3, "lr": 0.01})
     telem.close()
 
@@ -242,12 +257,13 @@ def pytest_step_telemetry_windows_and_stream(tmp_path):
         padded = sum(b.num_nodes for b in pair)
         assert w["padding_waste"] == pytest.approx(1 - real / padded, abs=1e-4)
         assert w["step_time_ms"] == pytest.approx(10.0, rel=0.01)
-        # 2 steps x 1 GFLOP / 0.02 s / peak — the attach_flops contract
-        assert w["mfu_est"] == pytest.approx(
-            mfu_estimate(2e9, 0.02, "cpu"), rel=0.01
-        )
         real_g = sum(int(np.asarray(b.graph_mask).sum()) for b in pair)
         assert w["graphs_per_sec"] == pytest.approx(real_g / 0.02, rel=0.01)
+    assert windows[0]["mfu_est"] is None
+    # 2 steps x 1 GFLOP / 0.02 s / peak — the attach_flops contract
+    assert windows[1]["mfu_est"] == pytest.approx(
+        mfu_estimate(2e9, 0.02, "TPU v5 lite"), rel=0.01
+    )
     epochs = [r for r in records if r["kind"] == "epoch"]
     assert epochs == [
         {**epochs[0]}
@@ -322,9 +338,13 @@ def pytest_metrics_stream_rank_gating(tmp_path):
 def pytest_peak_flops_table():
     assert peak_flops("TPU v5p chip") == 459e12
     assert peak_flops("TPU v6e") == 918e12
-    assert peak_flops("cpu") == 197e12  # conservative fallback
-    assert mfu_estimate(197e12, 1.0, "cpu") == pytest.approx(1.0)
-    assert mfu_estimate(1.0, 0.0, "cpu") == 0.0
+    assert peak_flops("TPU v5 lite") == 197e12
+    assert mfu_estimate(197e12, 1.0, "TPU v5 lite") == pytest.approx(1.0)
+    assert mfu_estimate(1.0, 0.0, "TPU v5 lite") == 0.0
+    # no default: a device the table does not list has no peak and no MFU
+    for kind in ("cpu", "unknown", "Radeon"):
+        assert peak_flops(kind) is None
+        assert mfu_estimate(197e12, 1.0, kind) is None
 
 
 def pytest_profile_trigger_touch_file(tmp_path, monkeypatch):

@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-_STEP_BUDGET_MS = 43.0  # round-4 measured SC25 production step (BASELINE.md)
+_STEP_BUDGET_MS = 43.0  # round-4 measured SC25 production step (PERF.md)
 
 
 def _median_build_ms(loader, epochs=3):

@@ -242,9 +242,13 @@ def pytest_sorted_agg_allowed_for_grad_energy(monkeypatch):
     nn["Variables_of_interest"]["output_dim"] = [1]
     nn["Variables_of_interest"]["type"] = ["node"]
 
-    # (a) auto-default: when jitting for TPU (env-probed, no backend
-    # touch), grad-energy configs now flip sorted ON like everything else
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    # (a) auto-default: when the initialised backend is a TPU, grad-energy
+    # configs now flip sorted ON like everything else
+    import hydragnn_tpu.config.config as config_mod
+
+    monkeypatch.setattr(
+        config_mod, "_jit_target_inference", lambda: (True, "test: tpu")
+    )
     import copy
 
     nn["Architecture"].pop("use_sorted_aggregation", None)
