@@ -20,8 +20,11 @@ It refuses any backend but ``tpu`` before building data, then drives
    (``Optimizer.zero_stage: 2``) over all of them.
 
 Nothing is caught and reported as data: a failed check raises, the exit code
-is non-zero and no result line is printed. The last stdout line of a passing
-run is one JSON object starting ``{"ok": true, "device": {...}}``.
+is non-zero and no result line is printed. A passing run ends its stdout with
+two lines: ``chip_smoke report: {...}`` (each leg's result, time-to-first-step,
+compile seconds, cache hits/misses, cache directory), then, last, exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``, the
+one line the driver's chip check parses.
 
 Every timing printed here is a set-up fact (compile, first step), not a
 throughput.
@@ -559,6 +562,17 @@ def mesh_leg(hidden=HIDDEN, head_dim=HEAD_DIM, batch_size=BATCH) -> dict:
     }
 
 
+def result_line(device: dict) -> str:
+    """The last stdout line of a passing run. The driver's chip check accepts
+    exactly these keys and no other; everything else goes in the report line
+    above it."""
+    return json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]),
+        "kind": str(device["kind"]),
+        "count": int(device["count"]),
+    }})
+
+
 def main() -> int:
     t_start = time.perf_counter()
     device = require_tpu()
@@ -594,9 +608,7 @@ def main() -> int:
         os.chdir(repo)
         shutil.rmtree(workdir, ignore_errors=True)
     metrics = compile_metrics()
-    print(json.dumps({
-        "ok": True,
-        "device": device,
+    print("chip_smoke report: " + json.dumps({
         "legs": legs,
         "time_to_first_step_s": legs["main"]["time_to_first_step_s"],
         "compile_s": round(metrics["backend_compile_s"], 2),
@@ -605,6 +617,7 @@ def main() -> int:
         "cache_dir": cache_dir,
         "wall_s": round(time.perf_counter() - t_start, 1),
     }))
+    print(result_line(device), flush=True)
     return 0
 
 

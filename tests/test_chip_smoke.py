@@ -2,7 +2,9 @@
 not a TPU, and config completion must ask the backend that initialised — not
 the environment — before switching the Pallas routes on."""
 
+import importlib.util
 import inspect
+import json
 import os
 import subprocess
 import sys
@@ -25,6 +27,22 @@ def pytest_chip_smoke_refuses_a_cpu_backend():
     assert "backend 'cpu'" in proc.stderr, proc.stderr[-500:]
     # no result line, and nothing was built before the refusal
     assert '"ok"' not in proc.stdout and proc.stdout.strip() == "", proc.stdout
+
+
+def pytest_chip_smoke_result_line_holds_the_contract_keys_only():
+    # the driver rejects a last line with any key beside these (PR 21 was
+    # refused once for carrying the legs and timings there)
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(_REPO, "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    stamp = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    line = smoke.result_line({**stamp, "extra": 1})
+    assert "\n" not in line
+    assert json.loads(line) == {"ok": True, "device": stamp}
+    src = inspect.getsource(smoke.main)
+    assert src.rstrip().endswith(
+        "print(result_line(device), flush=True)\n    return 0"), src[-200:]
 
 
 def pytest_jit_target_follows_the_initialised_backend(monkeypatch):
