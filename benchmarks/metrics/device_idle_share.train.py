@@ -1,0 +1,8 @@
+"""1 - union of device-op intervals over the traced window."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t or not t["window_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
