@@ -15,6 +15,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..utils import tracer as tr
 from .graph import (
     Graph,
     GraphBatch,
@@ -809,9 +810,25 @@ class GraphLoader:
         except Exception:
             pass
 
+    def _timed_batches(self) -> Iterator[GraphBatch]:
+        """``_batches()`` with each batch's build (collate, pack or ladder
+        choice, pad; the first also plans the epoch) under a ``batch_build``
+        region on the building thread — not the wait on a full queue."""
+        it = self._batches()
+        k = max(int(self.start_batch), 0)
+        while True:
+            tr.start(tr.BATCH_BUILD, batch=k, epoch=int(self.epoch))
+            batch = next(it, None)
+            # the generator's end built nothing: closed, not counted
+            tr.stop(tr.BATCH_BUILD, discard=batch is None)
+            if batch is None:
+                return
+            yield batch
+            k += 1
+
     def __iter__(self) -> Iterator[GraphBatch]:
         if self.prefetch <= 0:
-            yield from self._batches()
+            yield from self._timed_batches()
             return
         # bounded producer thread: up to ``prefetch`` batches built ahead
         import queue
@@ -835,7 +852,7 @@ class GraphLoader:
 
         def producer():
             try:
-                for k, batch in enumerate(self._batches()):
+                for k, batch in enumerate(self._timed_batches()):
                     # chaos hooks (exact no-ops unarmed): a producer wedged
                     # in a slow build, or dead without its sentinel
                     if faultinject.maybe_loader_fault(epoch_start + k) == "die":

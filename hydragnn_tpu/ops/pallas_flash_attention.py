@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from ..utils import envflags
+from ..utils import tracer as tr
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_segment import _pad_to, mxu_precision
@@ -319,6 +320,7 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name=tr.HG_FLASH_ATTENTION,
     )(kstart, klast, gq, gk, qt, kt, vt)
     o = jnp.transpose(out[0], (1, 0, 2))[:nq, :, :d]
     if not emit_stats:
@@ -386,14 +388,15 @@ def flash_self_attention(
     residuals out of the training forward.
     """
     n = q.shape[0]
-    gid = jnp.where(node_mask, node_graph.astype(jnp.int32), -1)
-    kstart, klast, k_windows = _block_windows(
-        node_graph, n, block_q, block_k, max_nodes_per_graph
-    )
-    return _forward(
-        q, k, v, gid, gid, kstart, klast, k_windows, block_q, block_k,
-        interpret,
-    )
+    with tr.scope(tr.HG_FLASH_ATTENTION):
+        gid = jnp.where(node_mask, node_graph.astype(jnp.int32), -1)
+        kstart, klast, k_windows = _block_windows(
+            node_graph, n, block_q, block_k, max_nodes_per_graph
+        )
+        return _forward(
+            q, k, v, gid, gid, kstart, klast, k_windows, block_q, block_k,
+            interpret,
+        )
 
 
 @flash_self_attention.defjvp
@@ -414,7 +417,8 @@ def _flash_jvp(num_graphs, max_nodes_per_graph, block_q, block_k, interpret,
     fn = lambda q_, k_, v_: reference_gathered_attention(
         q_, k_, v_, node_graph, node_mask, num_graphs, max_nodes_per_graph
     )
-    _, t_out = jax.jvp(fn, (q, k, v), (t_q, t_k, t_v))
+    with tr.scope(tr.HG_FLASH_ATTENTION + tr.TANGENT):
+        _, t_out = jax.jvp(fn, (q, k, v), (t_q, t_k, t_v))
     return out, t_out
 
 
@@ -441,18 +445,22 @@ def flash_block_summary(
     dtypes either way).
     """
     nq, nk = q.shape[0], k.shape[0]
-    gid_q = jnp.zeros((nq,), jnp.int32)
-    gid_k = jnp.where(key_mask, 0, -1).astype(jnp.int32)
-    k_blocks = (nk + block_k - 1) // block_k
-    kstart = jnp.zeros((max(1, (nq + block_q - 1) // block_q),), jnp.int32)
-    klast = jnp.full_like(kstart, k_blocks - 1)
-    o, m, l = _forward(
-        q, k, v, gid_q, gid_k, kstart, klast, k_blocks, block_q, block_k,
-        interpret, emit_stats=True,
-    )
-    dt = q.dtype
-    # un-normalize: acc = o * l (exact where l > 0; both zero where l == 0)
-    return m.astype(dt), l.astype(dt), o * l[..., None].astype(dt)
+    with tr.scope(tr.HG_FLASH_ATTENTION):
+        gid_q = jnp.zeros((nq,), jnp.int32)
+        gid_k = jnp.where(key_mask, 0, -1).astype(jnp.int32)
+        k_blocks = (nk + block_k - 1) // block_k
+        kstart = jnp.zeros(
+            (max(1, (nq + block_q - 1) // block_q),), jnp.int32
+        )
+        klast = jnp.full_like(kstart, k_blocks - 1)
+        o, m, l = _forward(
+            q, k, v, gid_q, gid_k, kstart, klast, k_blocks, block_q,
+            block_k, interpret, emit_stats=True,
+        )
+        dt = q.dtype
+        # un-normalize: acc = o * l (exact where l > 0; both zero where
+        # l == 0)
+        return m.astype(dt), l.astype(dt), o * l[..., None].astype(dt)
 
 
 @flash_block_summary.defjvp
@@ -464,5 +472,6 @@ def _summary_jvp(block_q, block_k, interpret, primals, tangents):
         lambda x: x.astype(q.dtype),
         reference_block_summary(q_, k_, v_, key_mask),
     )
-    _, t_out = jax.jvp(fn, (q, k, v), (t_q, t_k, t_v))
+    with tr.scope(tr.HG_FLASH_ATTENTION + tr.TANGENT):
+        _, t_out = jax.jvp(fn, (q, k, v), (t_q, t_k, t_v))
     return out, t_out

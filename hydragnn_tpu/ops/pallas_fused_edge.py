@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from ..utils import tracer as tr
 
 from .pallas_segment import _pad_to, mxu_precision
 
@@ -243,6 +244,7 @@ def _forward(
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad, co_pad), jnp.float32),
         interpret=interpret,
+        name=tr.HG_FUSED_EDGE,
     )(estart_block, ids_col, nrecv, ein, w, b)
     return out[:num_segments, :co].astype(dtype)
 
@@ -282,10 +284,11 @@ def fused_edge_message_sum(
     f32 throughout. Differentiable to arbitrary order (custom-JVP with a
     plain-jnp tangent), so energy-force (grad-of-grad) training composes.
     """
-    return _forward(
-        node_recv, edge_in, weights, bias, segment_ids, num_segments,
-        max_degree, block_rows, block_edges, block_cols, interpret,
-    )
+    with tr.scope(tr.HG_FUSED_EDGE):
+        return _forward(
+            node_recv, edge_in, weights, bias, segment_ids, num_segments,
+            max_degree, block_rows, block_edges, block_cols, interpret,
+        )
 
 
 @fused_edge_message_sum.defjvp
@@ -307,7 +310,8 @@ def _fused_jvp(
     fn = lambda nr, ei, w, b: reference_edge_message_sum(
         nr, ei, w, b, segment_ids, num_segments
     )
-    _, t_out = jax.jvp(
-        fn, (node_recv, edge_in, weights, bias), (t_nr, t_ei, t_w, t_b)
-    )
+    with tr.scope(tr.HG_FUSED_EDGE + tr.TANGENT):
+        _, t_out = jax.jvp(
+            fn, (node_recv, edge_in, weights, bias), (t_nr, t_ei, t_w, t_b)
+        )
     return out, t_out

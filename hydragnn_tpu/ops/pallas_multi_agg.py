@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from ..utils import tracer as tr
 
 from .pallas_segment import _pad_to, mxu_precision
 
@@ -346,6 +347,7 @@ def _forward(
         ),
         out_shape=[moment] * 4,
         interpret=interpret,
+        name=tr.HG_MULTI_AGG,
     )(estart_block, *operands)
 
     # count is a [E]-read / [N]-write segment sum — negligible traffic next
@@ -394,10 +396,11 @@ def fused_multi_agg(
     reference as tangent rule, so reverse mode recomputes the edge
     messages from the gathered inputs instead of storing [E, C] residuals.
     """
-    return _forward(
-        node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
-        block_rows, block_edges, block_cols, interpret,
-    )
+    with tr.scope(tr.HG_MULTI_AGG):
+        return _forward(
+            node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
+            block_rows, block_edges, block_cols, interpret,
+        )
 
 
 @fused_multi_agg.defjvp
@@ -417,5 +420,8 @@ def _jvp(num_segments, max_degree, block_rows, block_edges, block_cols,
     fn = lambda nr, ei, g: reference_multi_agg(
         nr, ei, g, segment_ids, num_segments
     )
-    _, t_out = jax.jvp(fn, (node_recv, edge_in, gate), (t_nr, t_ei, t_g))
+    with tr.scope(tr.HG_MULTI_AGG + tr.TANGENT):
+        _, t_out = jax.jvp(
+            fn, (node_recv, edge_in, gate), (t_nr, t_ei, t_g)
+        )
     return out, t_out

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from ..utils import tracer as tr
 
 
 def mxu_precision(dtype):
@@ -113,10 +114,11 @@ def sorted_segment_sum(
     does (data/graph.py padding docs).
     Messages are [E, C] float; returns [num_segments, C].
     """
-    return _forward(
-        messages, segment_ids, num_segments, max_degree, block_rows,
-        block_edges, block_cols, interpret,
-    )
+    with tr.scope(tr.HG_SORTED_SEGMENT):
+        return _forward(
+            messages, segment_ids, num_segments, max_degree, block_rows,
+            block_edges, block_cols, interpret,
+        )
 
 
 # tuned-table key component (tune/table.py): bump on any change to the
@@ -196,6 +198,7 @@ def _forward(
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad, msg.shape[1]), jnp.float32),
         interpret=interpret,
+        name=tr.HG_SORTED_SEGMENT,
     )(estart_block, ids_col, msg)
     return out[:num_segments, :c].astype(dtype)
 
@@ -214,7 +217,8 @@ def _jvp(num_segments, max_degree, block_rows, block_edges, block_cols,
     # as the r5 custom_vjp — and it is differentiable to any order, so
     # grad-of-grad (energy-force training) composes instead of hitting
     # pallas_call's missing JVP rule.
-    t_out = jax.ops.segment_sum(
-        t_msg, segment_ids, num_segments=num_segments
-    ).astype(out.dtype)
+    with tr.scope(tr.HG_SORTED_SEGMENT + tr.TANGENT):
+        t_out = jax.ops.segment_sum(
+            t_msg, segment_ids, num_segments=num_segments
+        ).astype(out.dtype)
     return out, t_out

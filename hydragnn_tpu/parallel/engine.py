@@ -52,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models.base import HydraModel
 from ..train.loss import compute_loss
 from ..train.state import TrainState
+from ..utils import tracer as tr
 from . import rules as R
 from jax import shard_map
 
@@ -321,9 +322,10 @@ def make_mesh_train_step(
                 b_local - 1,
             )
             batch = batch.replace(dataset_id=local_ds)
-            (tot, (tasks, mutated, acts)), grads = jax.value_and_grad(
-                per_device_loss, has_aux=True
-            )(params, batch_stats, batch, rng)
+            with tr.scope(tr.HG_LOSS):
+                (tot, (tasks, mutated, acts)), grads = jax.value_and_grad(
+                    per_device_loss, has_aux=True
+                )(params, batch_stats, batch, rng)
             gm = batch.graph_mask.astype(jnp.float32)
             n = jnp.sum(gm)
             # encoder: weighted mean over every shard (DDP analog)
@@ -377,9 +379,10 @@ def make_mesh_train_step(
         # batch leaves arrive with leading axis [D_local=1, ...] inside
         # the shard; drop it to recover the per-device batch.
         batch = jax.tree_util.tree_map(lambda x: x[0], batch)
-        (tot, (tasks, mutated, acts)), grads = jax.value_and_grad(
-            per_device_loss, has_aux=True
-        )(params, batch_stats, batch, rng)
+        with tr.scope(tr.HG_LOSS):
+            (tot, (tasks, mutated, acts)), grads = jax.value_and_grad(
+                per_device_loss, has_aux=True
+            )(params, batch_stats, batch, rng)
         # weight each shard by its real-graph count so empty/remainder
         # shards neither dilute gradients nor corrupt running batch-norm
         # statistics
@@ -500,8 +503,11 @@ def make_mesh_train_step(
                     g, table, "grads", mesh, amap, sizes,
                     default_explicit=False,
                 )
-            updates, opt_state = tx.update(g, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            with tr.scope(tr.HG_OPTIMIZER):
+                updates, opt_state = tx.update(
+                    g, state.opt_state, state.params
+                )
+                params = optax.apply_updates(state.params, updates)
             return _pin_out_params(params), opt_state
 
         if use_guard:

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import optax
 from ..utils import envflags
+from ..utils import tracer as tr
 
 
 def guard_enabled(guard: Optional[bool] = None) -> bool:
@@ -50,7 +51,8 @@ def step_ok(tot, grads):
     """In-graph finiteness decision: the loss and the global gradient norm
     (one reduction over all leaves — a single NaN/inf anywhere poisons the
     norm, so one scalar check covers the whole tree)."""
-    return jnp.isfinite(tot) & jnp.isfinite(optax.global_norm(grads))
+    with tr.scope(tr.HG_GUARD):
+        return jnp.isfinite(tot) & jnp.isfinite(optax.global_norm(grads))
 
 
 def guarded_update(
@@ -83,11 +85,12 @@ def guarded_update(
         new = jnp.asarray(new)
         return jnp.where(ok, new, jnp.asarray(old, new.dtype))
 
-    params, opt_state, stats = jax.tree_util.tree_map(
-        merge,
-        (params_new, opt_new, new_stats),
-        (state.params, state.opt_state, state.batch_stats),
-    )
+    with tr.scope(tr.HG_GUARD):
+        params, opt_state, stats = jax.tree_util.tree_map(
+            merge,
+            (params_new, opt_new, new_stats),
+            (state.params, state.opt_state, state.batch_stats),
+        )
     # counter arithmetic must PRESERVE the leaves' (weak) dtype: the fresh
     # state carries python-int counters (weak int32 under jit, like `step`),
     # and an explicit int32 cast here would flip the output aval to strong

@@ -28,6 +28,7 @@ import optax
 from ..data.graph import GraphBatch
 from ..models.base import HydraModel
 from ..utils import envflags
+from ..utils import tracer as tr
 from .loss import compute_loss
 from .optimizer import ReduceLROnPlateau
 from .state import TrainState
@@ -65,18 +66,21 @@ def mp_cast(params, batch, compute_grad_energy: bool):
     """The mixed-precision input cast, shared by the single-device and mesh
     step builders so their numerics stay byte-identical: bf16 params + bf16
     input channels (f32 positions under the autograd-force objective)."""
-    return (
-        cast_floats(params, jnp.bfloat16),
-        cast_batch_bf16(batch, keep_pos=compute_grad_energy),
-    )
+    with tr.scope(tr.HG_CAST):
+        return (
+            cast_floats(params, jnp.bfloat16),
+            cast_batch_bf16(batch, keep_pos=compute_grad_energy),
+        )
 
 
 def mp_restore_stats(mutated: dict) -> dict:
     """Persist batch-norm running statistics in f32 after a bf16 forward."""
     if "batch_stats" in mutated:
-        mutated = dict(
-            mutated, batch_stats=cast_floats(mutated["batch_stats"], jnp.float32)
-        )
+        with tr.scope(tr.HG_CAST):
+            mutated = dict(
+                mutated,
+                batch_stats=cast_floats(mutated["batch_stats"], jnp.float32),
+            )
     return mutated
 
 
@@ -165,9 +169,10 @@ def make_train_step(
         # retrace sentinel: the body runs once per jit trace, so this call
         # IS the trace census (train/compile_plane.py)
         note_trace("train_step", (state, batch, rng))
-        (tot, (tasks, mutated, acts)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True
-        )(state.params, state.batch_stats, batch, rng)
+        with tr.scope(tr.HG_LOSS):
+            (tot, (tasks, mutated, acts)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(state.params, state.batch_stats, batch, rng)
         # chaos-test hook: exact no-op unless a fault is armed (trace-time)
         grads = faultinject.poison_grads(
             grads, state.step, faultinject.lr_of(state.opt_state)
@@ -183,10 +188,14 @@ def make_train_step(
         if use_guard:
 
             def do_update():
-                updates, opt_state = tx.update(
-                    grads, state.opt_state, state.params
-                )
-                return optax.apply_updates(state.params, updates), opt_state
+                with tr.scope(tr.HG_OPTIMIZER):
+                    updates, opt_state = tx.update(
+                        grads, state.opt_state, state.params
+                    )
+                    return (
+                        optax.apply_updates(state.params, updates),
+                        opt_state,
+                    )
 
             new_state = guarded_update(
                 state,
@@ -195,8 +204,11 @@ def make_train_step(
                 new_stats,
             )
         else:
-            updates, opt_state = tx.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            with tr.scope(tr.HG_OPTIMIZER):
+                updates, opt_state = tx.update(
+                    grads, state.opt_state, state.params
+                )
+                params = optax.apply_updates(state.params, updates)
             new_state = state.replace(
                 params=params,
                 opt_state=opt_state,
@@ -251,7 +263,8 @@ def _weighted_avg(entries: List[Tuple[float, Dict[str, float], int]]):
     return tot, tasks
 
 
-def device_prefetch(iterator, depth: int = 2, device=None):
+def device_prefetch(iterator, depth: int = 2, device=None, epoch: int = 0,
+                    start_batch: int = 0):
     """Double-buffered device staging: a background thread ``device_put``s
     upcoming batches so the H2D copy overlaps the current step's compute.
     The reference pays this cost inline every step (``data.to(device)``,
@@ -260,7 +273,8 @@ def device_prefetch(iterator, depth: int = 2, device=None):
     from a second thread takes it off the critical path entirely.
 
     Single-device only at the call sites (sharded stacked batches are placed
-    by the parallel step's own sharding logic)."""
+    by the parallel step's own sharding logic). ``epoch`` and ``start_batch``
+    only label the staging thread's ``h2d_stage`` regions."""
     import queue
     import threading
 
@@ -279,8 +293,13 @@ def device_prefetch(iterator, depth: int = 2, device=None):
 
     def producer():
         try:
-            for batch in iterator:
-                if not put_or_stop(jax.device_put(batch, device)):
+            for k, batch in enumerate(iterator, start_batch):
+                # the host cost of staging: device_put returns before the
+                # copy has finished, and nothing here waits for it
+                tr.start(tr.H2D_STAGE, batch=k, epoch=epoch)
+                staged = jax.device_put(batch, device)
+                tr.stop(tr.H2D_STAGE)
+                if not put_or_stop(staged):
                     return
             put_or_stop(_END)
         except BaseException as e:  # surfaced in the consumer
@@ -299,7 +318,8 @@ def device_prefetch(iterator, depth: int = 2, device=None):
         stop.set()
 
 
-def _maybe_device_prefetch(iterator, depth: Optional[int] = None):
+def _maybe_device_prefetch(iterator, depth: Optional[int] = None,
+                           epoch: int = 0, start_batch: int = 0):
     """Wrap with device_prefetch on single-device runs (multi-device batch
     placement belongs to the parallel step). ``depth`` comes from
     ``Training.double_buffer`` (true = 2, false = off, an int = that
@@ -326,7 +346,9 @@ def _maybe_device_prefetch(iterator, depth: Optional[int] = None):
         pass
     if not active:
         return iterator
-    return device_prefetch(iterator, depth=depth)
+    return device_prefetch(
+        iterator, depth=depth, epoch=epoch, start_batch=start_batch
+    )
 
 
 def train_epoch(loader, step_fn, state, rng, start_batch: int = 0,
@@ -359,7 +381,6 @@ def train_epoch(loader, step_fn, state, rng, start_batch: int = 0,
     step whose loss came back non-finite — the batch provenance the
     epoch-boundary guard policy attaches to its ``guard_skip`` event."""
     from ..utils import faultinject, preemption
-    from ..utils import tracer as tr
 
     # Device-side loss bookkeeping: the per-step (loss, tasks) scalars stay
     # on device and are read back ONCE at epoch end, so step i+1 dispatches
@@ -385,19 +406,27 @@ def train_epoch(loader, step_fn, state, rng, start_batch: int = 0,
     step0 = (
         int(jax.device_get(state.step)) if nan_watch is not None else 0
     )
-    it = _maybe_device_prefetch(iter(loader), depth=prefetch_depth)
+    # epoch_restart: the loader and the staging thread start from cold, up
+    # to the epoch's first batch in hand (encloses that first dataload)
+    epoch = int(getattr(loader, "epoch", 0) or 0)
+    tr.start(tr.EPOCH_RESTART, epoch=epoch)
+    it = _maybe_device_prefetch(
+        iter(loader), depth=prefetch_depth, epoch=epoch, start_batch=offset
+    )
     for i in range(len(loader)):
         # dataload span covers host batching + H2D staging (the reference's
         # per-step data.to(device), train_validate_test.py:506-514; here the
         # jitted step overlaps with the next host batch via async dispatch)
         t_build = time.perf_counter()
-        tr.start("dataload")
+        tr.start(tr.DATALOAD, batch=offset + consumed, epoch=epoch)
         try:
             batch = next(it)
         except StopIteration:
-            tr.stop("dataload")
+            tr.stop(tr.DATALOAD)
             break
-        tr.stop("dataload")
+        tr.stop(tr.DATALOAD)
+        if i == 0:
+            tr.stop(tr.EPOCH_RESTART)
         build_dt = time.perf_counter() - t_build
         consumed += 1
         if i < start_batch:
@@ -412,8 +441,13 @@ def train_epoch(loader, step_fn, state, rng, start_batch: int = 0,
                 build_dt,
                 parent=sp,
             )
+        # rng_split: two tiny programs a step; when the device's queue of
+        # programs is full it is here that the host waits for the device
+        idx = offset + consumed - 1
+        tr.start(tr.RNG_SPLIT, batch=idx, epoch=epoch)
         rng, sub = jax.random.split(rng)
-        tr.start("train_step")
+        tr.stop(tr.RNG_SPLIT)
+        tr.start(tr.TRAIN_STEP, batch=idx, epoch=epoch)
         t_step = time.perf_counter()
         # fleet chaos hook: host-side sleep when HYDRAGNN_FAULT_STRAGGLE
         # is armed — the slow-host model the fleet watchdog must flag
@@ -427,7 +461,9 @@ def train_epoch(loader, step_fn, state, rng, start_batch: int = 0,
         # (preemption with grace) this process before dispatching a step —
         # armed on the cumulative cross-epoch step count, not i
         faultinject.maybe_host_fault()
+        tr.start(tr.DISPATCH, batch=idx, epoch=epoch)
         out = step_fn(state, batch, sub)
+        tr.stop(tr.DISPATCH)
         # a numerics-enabled step rides its stat bundle as a 4th output
         # (obs/numerics.py); the historical 3-tuple is unchanged otherwise
         state, tot, tasks = out[0], out[1], out[2]
@@ -435,10 +471,9 @@ def train_epoch(loader, step_fn, state, rng, start_batch: int = 0,
         # graph_mask is loader data (host numpy, or an already-transferred
         # leaf under device_prefetch) — reading it never waits on compute
         n = int(np.asarray(batch.graph_mask).sum())
-        tr.stop("train_step")
+        tr.stop(tr.TRAIN_STEP)
         entries.append((tot, tasks, n))
         if step_meta is not None:
-            idx = offset + consumed - 1
             level = (
                 f"{int(batch.node_mask.shape[-1])}n/"
                 f"{int(batch.edge_mask.shape[-1])}e"
@@ -475,11 +510,15 @@ def train_epoch(loader, step_fn, state, rng, start_batch: int = 0,
         max_batches = envflags.env_int("HYDRAGNN_MAX_NUM_BATCH", 0)
         if max_batches > 0 and i + 1 >= max_batches:
             break
+    tr.stop(tr.EPOCH_RESTART)  # an epoch without a batch; closed already otherwise
+    # epoch_drain: the epoch's one host sync, a full drain of the device
+    tr.start(tr.EPOCH_DRAIN, epoch=epoch)
     if nan_watch is not None:
         # drain the watch ring at the boundary the loop syncs on anyway
         nan_watch.end_epoch(state)
     # single host sync for the whole epoch
     entries = jax.device_get(entries)
+    tr.stop(tr.EPOCH_DRAIN)
     entries = [
         (float(t), {k: float(v) for k, v in d.items()}, n)
         for t, d, n in entries
@@ -628,7 +667,6 @@ def train_validate_test(
     )
 
     from ..utils import preemption
-    from ..utils import tracer as tr
     from ..utils.profile import Profiler
     from ..utils.walltime import should_stop
     from .guard import NonFinitePolicy

@@ -4,33 +4,90 @@
 The reference fans ``tr.start/stop`` out to GPTL and Score-P C libraries with
 optional ``torch.cuda.synchronize`` + MPI barrier per span. Here the backend
 is (a) an in-process accumulator (count/total/min/max per region) and (b)
-optional ``jax.profiler.TraceAnnotation`` so regions appear in xprof/
-TensorBoard device traces. ``sync=True`` drains the async JAX dispatch queue
-(``jax.effects_barrier``) before timestamping — the device-sync analog of the
+``jax.profiler.TraceAnnotation`` so regions appear in xprof/TensorBoard
+device traces, on the device trace's own clock. ``sync=True`` drains the
+async JAX dispatch queue before timestamping — the device-sync analog of the
 reference's ``cudasync=True`` (tracer.py:106-127) — controlled globally by
 ``HYDRAGNN_TRACE_LEVEL`` exactly like the reference's train-loop spans
 (train_validate_test.py:477-498).
+
+The training iteration runs on three threads (the loader's producer, the
+H2D staging thread, the main loop), so the open-region and annotation stacks
+are per thread — a ``TraceAnnotation`` is entered and exited on the thread
+that opened it, always — and the accumulator is shared under a lock.
+
+This module also holds the one vocabulary of names that a trace of a
+training run shows (docs/OBSERVABILITY.md "Regions, scopes and kernel
+names"): the host regions below, the ``jax.named_scope`` names inside the
+compiled step, and the Pallas kernel names.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
+import threading
 import time
 from typing import Dict, Optional
 from . import envflags
 
+# -- host regions (tr.start/stop), by the thread that opens them ------------
+DATALOAD = "dataload"  # main: waiting for the next staged batch
+RNG_SPLIT = "rng_split"  # main: the key split (a full device queue holds the host here)
+TRAIN_STEP = "train_step"  # main: dispatch + the real-graph count
+DISPATCH = "dispatch"  # main, child of train_step: step_fn(state, batch, sub) alone
+EPOCH_RESTART = "epoch_restart"  # main: iter(loader) to the epoch's first batch in hand
+EPOCH_DRAIN = "epoch_drain"  # main: the epoch-end read of the per-step losses
+BATCH_BUILD = "batch_build"  # loader producer: building ONE batch
+H2D_STAGE = "h2d_stage"  # staging thread: the jax.device_put call
+
+# -- jax.named_scope names inside the compiled step (metadata only) ---------
+HG_CAST = "hg_cast"  # the bf16 casts of mixed precision
+HG_LOSS = "hg_loss"  # value_and_grad: jvp( = forward, transpose( = backward
+HG_OPTIMIZER = "hg_optimizer"  # tx.update + apply_updates
+HG_GUARD = "hg_guard"  # step_ok + the guarded select
+
+# -- Pallas kernels: pallas_call(name=...) inside a scope of the same name;
+#    the custom-JVP tangent rule runs under <name> + TANGENT ----------------
+HG_FUSED_EDGE = "hg_fused_edge"
+HG_SORTED_SEGMENT = "hg_sorted_segment"
+HG_MULTI_AGG = "hg_multi_agg"
+HG_FLASH_ATTENTION = "hg_flash_attention"
+TANGENT = "_tangent"
+
 _enabled = False
+# HYDRAGNN_TRACE_LEVEL > 0, read once at enable(): drain at every region edge
+_sync_default = False
+# jax.profiler.TraceAnnotation, resolved once at enable() (None: no profiler)
+_annotation = None
+_lock = threading.Lock()
 _regions: Dict[str, Dict[str, float]] = {}
 # span-plane bridge (obs/trace.py), resolved lazily once: a region closing
 # while a sampled span is open on this thread is emitted as a child span,
 # so the pre-existing region instrumentation lands in the trace tree
 _obs_trace = None
-# per-name stacks so re-entrant start(name) nests instead of overwriting
-_open: Dict[str, list] = {}
-# one global LIFO of (name, TraceAnnotation): xprof annotations are scoped
-# C++ objects and must exit in strict nesting order
-_ann_stack: list = []
+
+
+class _ThreadState(threading.local):
+    """One thread's open regions: per-name stacks of start times, so a
+    re-entrant start(name) nests instead of overwriting, and one LIFO of
+    (name, TraceAnnotation) — xprof annotations are scoped C++ objects and
+    must exit in strict nesting order, on the thread that entered them."""
+
+    def __init__(self):
+        self.open: Dict[str, list] = {}
+        self.anns: list = []
+
+
+_state = _ThreadState()
+
+
+def scope(name: str):
+    """``jax.named_scope(name)``: names the ops traced under it in the
+    lowered program's metadata (and so in a device trace); no arithmetic."""
+    import jax
+
+    return jax.named_scope(name)
 
 
 def _sync_devices() -> None:
@@ -47,28 +104,38 @@ def _sync_devices() -> None:
         pass
 
 
-def _trace_level() -> int:
-    return envflags.env_int("HYDRAGNN_TRACE_LEVEL", 0)
-
-
-def initialize() -> None:
-    """(reference: tracer.py:35-60 registers GPTL/Score-P if importable)"""
-    reset()
-
-
-def reset() -> None:
-    _regions.clear()
-    _open.clear()
-    while _ann_stack:
-        _, ann = _ann_stack.pop()
+def _exit_annotations(anns: list, until: Optional[str] = None) -> None:
+    """Exit this thread's annotations innermost first, down to and
+    including the newest one named ``until`` (all of them when None)."""
+    while anns:
+        top_name, ann = anns.pop()
         try:
             ann.__exit__(None, None, None)
         except Exception:
             pass
+        if top_name == until:
+            break
+
+
+def reset() -> None:
+    """Clear the accumulator and THIS thread's open regions; spans another
+    thread holds open stay open and record when that thread closes them."""
+    with _lock:
+        _regions.clear()
+    _state.open.clear()
+    _exit_annotations(_state.anns)
 
 
 def enable() -> None:
-    global _enabled
+    global _enabled, _sync_default, _annotation
+    _sync_default = envflags.env_int("HYDRAGNN_TRACE_LEVEL", 0) > 0
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _annotation = TraceAnnotation
+        except Exception:
+            pass
     _enabled = True
 
 
@@ -77,56 +144,57 @@ def disable() -> None:
     _enabled = False
 
 
-def start(name: str, sync: Optional[bool] = None) -> None:
-    """Open a region (reference: tracer.py:106-116)."""
+def start(name: str, sync: Optional[bool] = None, **attrs) -> None:
+    """Open a region on the calling thread (reference: tracer.py:106-116).
+    ``attrs`` go to the ``TraceAnnotation`` (loop spans carry ``batch=`` and
+    ``epoch=``, so one batch can be followed across threads in a trace)."""
     if not _enabled:
         return
-    if sync is None:
-        sync = _trace_level() > 0
-    if sync:
+    if _sync_default if sync is None else sync:
         _sync_devices()
-    try:
-        import jax
+    st = _state
+    if _annotation is not None:
+        try:
+            ann = _annotation(name, **attrs)
+            ann.__enter__()
+            st.anns.append((name, ann))
+        except Exception:
+            pass
+    st.open.setdefault(name, []).append(time.perf_counter())
 
-        ann = jax.profiler.TraceAnnotation(name)
-        ann.__enter__()
-        _ann_stack.append((name, ann))
-    except Exception:
-        pass
-    _open.setdefault(name, []).append(time.perf_counter())
 
-
-def stop(name: str, sync: Optional[bool] = None) -> None:
-    """Close a region and accumulate (reference: tracer.py:118-127)."""
-    if not _enabled or not _open.get(name):
+def stop(name: str, sync: Optional[bool] = None, discard: bool = False) -> None:
+    """Close the calling thread's newest open ``name`` and accumulate
+    (reference: tracer.py:118-127). ``discard`` closes without counting (a
+    region opened around something that turned out not to happen)."""
+    if not _enabled:
         return
-    if sync is None:
-        sync = _trace_level() > 0
-    if sync:
+    st = _state
+    starts = st.open.get(name)
+    if not starts:
+        return
+    if _sync_default if sync is None else sync:
         _sync_devices()
-    starts = _open[name]
     dt = time.perf_counter() - starts.pop()
     if not starts:
-        del _open[name]
+        del st.open[name]
     # unwind annotations in strict LIFO order: an out-of-nesting stop closes
     # the inner (still-open) annotations early rather than corrupting the
     # xprof span tree by exiting out of order
-    if any(n == name for n, _ in _ann_stack):
-        while _ann_stack:
-            top_name, ann = _ann_stack.pop()
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:
-                pass
-            if top_name == name:
-                break
-    rec = _regions.setdefault(
-        name, {"count": 0.0, "total": 0.0, "min": float("inf"), "max": 0.0}
-    )
-    rec["count"] += 1
-    rec["total"] += dt
-    rec["min"] = min(rec["min"], dt)
-    rec["max"] = max(rec["max"], dt)
+    if any(n == name for n, _ in st.anns):
+        _exit_annotations(st.anns, until=name)
+    if discard:
+        return
+    with _lock:
+        rec = _regions.get(name)
+        if rec is None:
+            rec = _regions[name] = {
+                "count": 0.0, "total": 0.0, "min": float("inf"), "max": 0.0
+            }
+        rec["count"] += 1
+        rec["total"] += dt
+        rec["min"] = min(rec["min"], dt)
+        rec["max"] = max(rec["max"], dt)
     _note_span(name, dt)
 
 
@@ -151,9 +219,9 @@ def _note_span(name: str, dt: float) -> None:
 
 
 @contextlib.contextmanager
-def timer(name: str, sync: Optional[bool] = None):
+def timer(name: str, sync: Optional[bool] = None, **attrs):
     """(reference: tracer.py:158-167)"""
-    start(name, sync)
+    start(name, sync, **attrs)
     try:
         yield
     finally:
@@ -175,17 +243,19 @@ def profile(name: str):
 
 
 def get_regions() -> Dict[str, Dict[str, float]]:
-    return {k: dict(v) for k, v in _regions.items()}
+    with _lock:
+        return {k: dict(v) for k, v in _regions.items()}
 
 
 def print_report(prefix: str = "") -> None:
     """Per-process region dump (the GPTL ``pr_file`` analog,
     reference: examples/multibranch/train.py:507-514)."""
-    if not _regions:
+    regions = get_regions()
+    if not regions:
         return
-    width = max(len(k) for k in _regions)
+    width = max(len(k) for k in regions)
     print(f"{prefix}{'region'.ljust(width)}  count     total(s)    avg(s)      max(s)")
-    for name, r in sorted(_regions.items()):
+    for name, r in sorted(regions.items()):
         avg = r["total"] / max(r["count"], 1)
         print(
             f"{prefix}{name.ljust(width)}  {int(r['count']):<8d}"

@@ -20,6 +20,10 @@ REPO_DIR=${REPO_DIR:-\$HOME/hydragnn_tpu}
 
 echo "strong scaling: EBS=${EFFECTIVE_BATCH_SIZE} hosts=${NUM_HOSTS} per-host bs=${PER_HOST_BS}"
 
+# HYDRAGNN_TRACE_LEVEL stays 0: at 1 every region edge of the training loop
+# drains the device first (utils/tracer.py), eight drains a step, so the
+# loop runs one step at a time with no host/device overlap and the timed
+# batches measure that. Set it to 1 only for a device-accurate region report.
 # printf %q re-quotes driver args so spaces/quotes survive the remote shell
 # (guarded: printf with zero operands would emit a spurious '' argument)
 ARGS=""
@@ -34,5 +38,5 @@ gcloud compute tpus tpu-vm ssh "${TPU_NAME}" \
     ${HYDRAGNN_COORDINATOR:+HYDRAGNN_COORDINATOR=${HYDRAGNN_COORDINATOR}} \
     HYDRAGNN_VALTEST=0 \
     HYDRAGNN_MAX_NUM_BATCH=${HYDRAGNN_MAX_NUM_BATCH:-5} \
-    HYDRAGNN_TRACE_LEVEL=${HYDRAGNN_TRACE_LEVEL:-1} \
+    HYDRAGNN_TRACE_LEVEL=${HYDRAGNN_TRACE_LEVEL:-0} \
     python ${DRIVER} --batch_size ${PER_HOST_BS} ${ARGS}"

@@ -25,6 +25,10 @@ ZONE=${2:?gce zone}
 DRIVER=${3:?training driver .py}
 shift 3
 
+# HYDRAGNN_TRACE_LEVEL stays 0: at 1 every region edge of the training loop
+# drains the device first (utils/tracer.py), eight drains a step, so the
+# loop runs one step at a time with no host/device overlap and the timed
+# batches measure that. Set it to 1 only for a device-accurate region report.
 REPO_DIR=${REPO_DIR:-\$HOME/hydragnn_tpu}
 SAMPLE_SECS=${SAMPLE_SECS:-5}
 
@@ -39,7 +43,7 @@ gcloud compute tpus tpu-vm ssh "${TPU_NAME}" \
   --command "cd ${REPO_DIR} && \
     (vmstat -t ${SAMPLE_SECS} > telemetry_host_\$(hostname).log 2>&1 &) && \
     HYDRAGNN_TELEMETRY=${HYDRAGNN_TELEMETRY:-1} \
-    HYDRAGNN_TRACE_LEVEL=${HYDRAGNN_TRACE_LEVEL:-1} \
+    HYDRAGNN_TRACE_LEVEL=${HYDRAGNN_TRACE_LEVEL:-0} \
     python ${DRIVER} ${ARGS}; \
     pkill vmstat || true"
 

@@ -70,9 +70,8 @@ def pytest_tracer_strict_annotation_lifo():
     """An out-of-nesting stop must unwind the xprof annotation stack in
     strict LIFO order — inner (still-open) annotations are closed early
     rather than exited out of order (scoped C++ objects)."""
-    from hydragnn_tpu.utils.tracer import _ann_stack
-
     tr.reset()
+    _ann_stack = tr._state.anns  # this thread's own stack
     tr.enable()
     tr.start("a")
     tr.start("b")
