@@ -189,11 +189,20 @@ def pair_message_factored(dim, inv, batch, name_recv, name_send, edge_terms=()):
     what keeps their parameter trees checkpoint-interchangeable. Keeping
     ``node_recv`` un-gathered is what lets the fused kernels run the
     receiver gather in-register (ops/pallas_fused_edge.py,
-    ops/pallas_multi_agg.py)."""
+    ops/pallas_multi_agg.py).
+
+    An edge term joins the feature stream in the feature stream's dtype
+    (``inv.dtype``): geometry stays float32 under mixed precision (the
+    coordinate update's ``segment_mean`` promotes), and one f32 ``[E, 1]``
+    length would otherwise promote every ``[E, C]`` array downstream of
+    it, the Pallas kernels' streams included. The identity in a float32
+    step."""
     node_recv = nn.Dense(dim, name=name_recv)(inv)
     edge_in = nn.Dense(dim, use_bias=False, name=name_send)(inv)[batch.senders]
     for name, arr in edge_terms:
-        edge_in = edge_in + nn.Dense(dim, use_bias=False, name=name)(arr)
+        edge_in = edge_in + nn.Dense(dim, use_bias=False, name=name)(
+            arr.astype(inv.dtype)
+        )
     return node_recv, edge_in
 
 

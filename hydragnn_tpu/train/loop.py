@@ -114,7 +114,11 @@ def make_train_step(
     to bf16 inside the differentiated function, so gradients flow back
     through the cast and land in f32 for the optimizer; running batch-norm
     statistics are re-cast to f32 before being stored. Targets stay f32, so
-    residuals and the loss accumulate in f32 by dtype promotion.
+    residuals and the loss accumulate in f32 by dtype promotion. The rule:
+    features run in the compute dtype; coordinates and the loss accumulation
+    stay float32. A geometric edge term (an EGNN layer's edge length) is cast
+    to the feature stream's dtype where it joins it (models/layers.py
+    ``pair_message_factored``), so f32 coordinates promote no ``[E, C]`` array.
 
     ``guard`` (default: on, env HYDRAGNN_STEP_GUARD=0 disables): in-graph
     non-finite step guard — loss/global-grad-norm finiteness is computed in

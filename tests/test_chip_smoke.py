@@ -9,9 +9,19 @@ import os
 import subprocess
 import sys
 
+import numpy as np
+
 import hydragnn_tpu.config.config as config_mod
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_smoke():
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(_REPO, "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    return smoke
 
 
 def pytest_chip_smoke_refuses_a_cpu_backend():
@@ -32,10 +42,7 @@ def pytest_chip_smoke_refuses_a_cpu_backend():
 def pytest_chip_smoke_result_line_holds_the_contract_keys_only():
     # the driver rejects a last line with any key beside these (PR 21 was
     # refused once for carrying the legs and timings there)
-    spec = importlib.util.spec_from_file_location(
-        "chip_smoke", os.path.join(_REPO, "chip_smoke.py"))
-    smoke = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(smoke)
+    smoke = _load_smoke()
     stamp = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
     line = smoke.result_line({**stamp, "extra": 1})
     assert "\n" not in line
@@ -54,3 +61,24 @@ def pytest_jit_target_follows_the_initialised_backend(monkeypatch):
     # read the environment at all
     src = inspect.getsource(config_mod._jit_target_inference)
     assert not any(t in src for t in ("os.environ", "getenv", "find_spec"))
+
+
+def pytest_chip_smoke_cell_shape_case_rehearsed():
+    """``chip_smoke.kernel_cases`` yields the cell-shape bf16 fused-edge case
+    FIRST (forward by max norm, tangent by L2); at a tiny size in interpret
+    mode it holds the tolerances it is held to on the chip."""
+    smoke = _load_smoke()
+    assert smoke.CELL_SHAPE == {"n_nodes": 12136, "edges": 196608,
+                                "max_degree": 36, "mean_degree": 15.6}
+    tiny = {"n_nodes": 90, "edges": 700, "max_degree": 9, "mean_degree": 5.0}
+    name, dt, check = next(iter(smoke.kernel_cases(
+        channels=(24,), n_nodes=70, max_degree=8, interpret=True,
+        cell_shape=tiny)))
+    assert name.startswith("fused_edge cell") and dt == "bfloat16", name
+    (fwd, fwd_err), (tan, tan_err, tan_tol) = check()
+    assert fwd.startswith("forward") and fwd_err <= smoke.TOL[dt], fwd_err
+    assert tan.startswith("tangent L2") and tan_tol == smoke.TOL_TANGENT_L2
+    assert tan_err <= tan_tol, tan_err
+    ids = smoke._cell_ids(np.random.default_rng(0), **smoke.CELL_SHAPE)
+    assert ids.shape == (196608,) and (np.diff(ids) >= 0).all()
+    assert np.bincount(ids[ids < 12135]).max() <= 36

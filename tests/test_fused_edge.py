@@ -382,3 +382,63 @@ def pytest_energy_force_step_fused_equals_dense(monkeypatch):
     assert abs(losses["1"] - losses["0"]) <= 1e-4 * max(
         1.0, abs(losses["0"])
     ), losses
+
+
+# ---------------------------------------------------------------------------
+# the shape the bf16 training step runs (benchmarks' EGNN-866 cells), compiled
+# for a described v5e at the real size (tests/test_chip_smoke.py rehearses
+# the chip check's case of it in interpret mode)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One device of a DESCRIBED v5e (the TPU compiler is installed; no chip
+    is attached). Only this file's worker loads the TPU library."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("tangent", [False, True])
+def pytest_bf16_kernel_compiles_for_v5e_at_the_cell_shape(v5e_chip, tangent):
+    """Mosaic accepts the bf16 call the training step makes since the edge
+    length joins the feature stream in bf16: ``[12160, 896]`` rows,
+    196608 edges, in-degree bound 36, the default tile plan."""
+    from hydragnn_tpu.tune.runtime import tile_plan
+
+    n, e, c, deg = 12136, 196608, 866, 36
+    plan = tile_plan("fused_edge", {
+        "edges": e, "num_segments": n, "max_degree": deg, "ci": c, "co": c,
+        "dtype": "bfloat16"}, jnp.bfloat16)
+    assert plan["block_rows"] % 16 == 0 and plan["block_edges"] % 16 == 0, plan
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip)
+    operands = (shaped((n, c)), shaped((e, c)), shaped((c, c)), shaped((c,)))
+    ids = shaped((e,), jnp.int32)
+
+    def kernel(ids, *ops):
+        return fused_edge_message_sum(
+            *ops, ids, n, deg, plan["block_rows"], plan["block_edges"],
+            plan["block_cols"], False)
+
+    if tangent:
+        fn = lambda ids, p, t: jax.jvp(lambda *o: kernel(ids, *o), p, t)
+        compiled = jax.jit(fn).lower(ids, operands, operands).compile()
+    else:
+        compiled = jax.jit(kernel).lower(ids, *operands).compile()
+    text = compiled.as_text()
+    call = [line for line in text.splitlines()
+            if "tpu_custom_call" in line and "hg_fused_edge" in line]
+    assert len(call) == 1 and "f32[12160,896]" in call[0], call
+    # its streams are the padded bf16 operands: rows, edges (+ 10 windows
+    # of 512), weights
+    for stream in ("bf16[12160,896]", "bf16[201728,896]", "bf16[896,896]"):
+        assert stream in text, stream

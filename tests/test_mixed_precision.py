@@ -260,3 +260,136 @@ def pytest_dimenet_bf16_jitted_grads_finite():
         assert bool(jnp.isfinite(leaf).all()), (
             f"non-finite params after bf16 steps: {jax.tree_util.keystr(path)}"
         )
+
+
+# ---------------------------------------------------------------------------
+# dtype discipline of the conv stack under mixed precision: features in the
+# compute dtype, coordinates in float32 (models/layers.py
+# pair_message_factored casts an edge term to the feature stream's dtype)
+# ---------------------------------------------------------------------------
+
+
+def _edge_stack(mpnn_type, equivariance, grad_energy, edge_lengths=False):
+    """A 4-layer ``pair_message_factored`` stack on receiver-sorted
+    OC20-shaped batches (tests/test_fused_edge.py's fixtures), kernels routed
+    as config completion routes them. ``edge_lengths`` stores each edge's
+    length as a one-column ``edge_attr`` (CGCNN's only edge term)."""
+    import copy
+    import dataclasses
+
+    import test_fused_edge as tfe
+
+    tr, va, te = tfe._shaped_graphs()
+    config = copy.deepcopy(tfe._egnn_config(equivariance, grad_energy))
+    arch = config["NeuralNetwork"]["Architecture"]
+    arch.update(mpnn_type=mpnn_type, num_conv_layers=4)
+    if edge_lengths:
+        config["Dataset"]["edge_features"] = ["lengths"]
+        tr, va, te = (
+            [dataclasses.replace(g, edge_attr=np.linalg.norm(
+                g.pos[g.senders] - g.pos[g.receivers], axis=1,
+                keepdims=True).astype(np.float32)) for g in part]
+            for part in (tr, va, te))
+    config = update_config(config, tr, va, te)
+    arch = config["NeuralNetwork"]["Architecture"]
+    loader = GraphLoader(tr, 8, seed=0, drop_last=True, sort_edges=True,
+                         max_in_degree=arch.get("max_in_degree", 0))
+    batch = next(iter(loader))
+    model = create_model(config)
+    return config, model, init_model(model, batch, seed=0), batch
+
+
+@pytest.mark.parametrize(
+    "mpnn_type,equivariance,grad_energy",
+    [
+        # the benchmark's model: layers 0-2 displace coordinates (f32 after
+        # layer 0), layer 3 takes the fused-edge route
+        ("EGNN", True, False),
+        # every layer fused; positions arrive in bf16 and stay
+        ("EGNN", False, False),
+        # autograd forces: mp_cast keeps f32 positions, so the length is f32
+        # from layer 0 on
+        ("EGNN", False, True),
+        # the other ``pair_message_factored`` stack that holds the rule: its
+        # edge term is a stored ``edge_attr``, which mp_cast casts
+        ("CGCNN", False, False),
+    ],
+)
+def pytest_mp_conv_stack_features_bf16_coordinates_f32(
+        mpnn_type, equivariance, grad_energy):
+    from flax.traverse_util import flatten_dict
+
+    from hydragnn_tpu.train.loop import mp_cast
+
+    config, model, variables, batch = _edge_stack(
+        mpnn_type, equivariance, grad_energy,
+        edge_lengths=mpnn_type == "CGCNN")
+    # the fused-edge route is on: a non-equivariant EGCL layer takes it
+    assert config["NeuralNetwork"]["Architecture"]["use_fused_edge_kernel"]
+
+    def forward(variables, batch):
+        params, batch = mp_cast(variables["params"], batch, grad_energy)
+        _, mutated = model.apply(
+            {"params": params,
+             "batch_stats": variables.get("batch_stats", {})},
+            batch, train=True, capture_intermediates=True,
+            mutable=["intermediates", "batch_stats"],
+        )
+        return mutated["intermediates"]
+
+    captured = flatten_dict(jax.eval_shape(forward, variables, batch))
+    n, e = batch.x.shape[0], batch.senders.shape[0]
+    coords, features, sized = {}, {}, set()
+    for path, outs in captured.items():
+        if not path[0].startswith(("graph_convs_", "feature_layers_")):
+            continue
+        outs = jax.tree_util.tree_leaves(outs)
+        if path[0].startswith("graph_convs_") and path[1:] == ("__call__",):
+            # a conv returns (features, coordinates)
+            coords[path[0]] = outs.pop(1).dtype
+        for out in outs:
+            features["/".join(path)] = out.dtype
+            sized.add(out.shape[0])
+    assert sized == {n, e}, sized  # node- and edge-sized arrays were seen
+    promoted = {k: str(v) for k, v in features.items() if v != jnp.bfloat16}
+    assert not promoted, promoted
+    assert len(coords) == 4
+    if equivariance or grad_energy:
+        # displaced (or kept) coordinates are float32: a gate gain of 0.001
+        # on a bf16 position would round away
+        assert set(coords.values()) == {jnp.dtype(jnp.float32)}, coords
+
+
+def pytest_f32_step_jaxpr_unchanged_by_the_edge_term_cast(monkeypatch):
+    """With ``mixed_precision`` off the cast is the identity: the training
+    step's jaxpr is the one the spelling without ``astype`` gives."""
+    import re
+
+    from flax import linen as nn
+
+    import hydragnn_tpu.models.layers as layers
+
+    def uncast(dim, inv, batch, name_recv, name_send, edge_terms=()):
+        node_recv = nn.Dense(dim, name=name_recv)(inv)
+        edge_in = nn.Dense(dim, use_bias=False, name=name_send)(inv)[
+            batch.senders]
+        for name, arr in edge_terms:
+            edge_in = edge_in + nn.Dense(dim, use_bias=False, name=name)(arr)
+        return node_recv, edge_in
+
+    config, model, variables, batch = _edge_stack("EGNN", True, False)
+    tx = make_optimizer(config["NeuralNetwork"]["Training"]["Optimizer"])
+    state = TrainState.create(variables, tx)
+    rng = jax.random.PRNGKey(0)
+
+    def step_jaxpr():
+        step = make_train_step(model, tx, mixed_precision=False)
+        text = str(jax.make_jaxpr(step)(state, batch, rng))
+        return re.sub(r" at 0x[0-9a-f]+", "", text)
+
+    with_cast = step_jaxpr()
+    monkeypatch.setattr(layers, "pair_message_factored", uncast)
+    without = step_jaxpr()
+    assert with_cast == without
+    # and it is a real step: the edge products of all four layers are in it
+    assert with_cast.count("dot_general") > 20
