@@ -1,0 +1,8 @@
+"""Device time of the Mosaic ops named `%hg_flash_attention*` (forward and
+the tiled backward) over device busy time, in the traced span."""
+
+import span_reads
+
+
+def read(ctx):
+    return span_reads.kernel_share_of_busy(ctx, "flash_attention")

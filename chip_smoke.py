@@ -7,7 +7,10 @@ One process, no arguments, run from the repo root:
 
 It refuses any backend but ``tpu`` before building data, then drives
 
-1. the kernel leg: each of the four Pallas kernels compiled
+1. the kernel legs: the decoder's two kernels (causal grouped-query flash
+   attention, forward and tiled backward; the grouped expert product and its
+   two backward products) at the ZAYA cell's shapes against plain ``jnp``;
+   each of the four older Pallas kernels compiled
    (``interpret=False``) at the widths the repo's cells use and compared with
    its plain-``jnp`` reference, first the bf16 fused-edge call at the
    benchmark cells' own shape (forward and tangent);
@@ -119,6 +122,11 @@ TOL = {"float32": 2e-5, "bfloat16": 1.6e-2}
 # kernel does. L2 reads 1.2e-2 .. 1.3e-2 (CPU, widths 128 and 866); 3e-2
 # still fails on one lost edge window of 512 in 196608 (5e-2).
 TOL_TANGENT_L2 = 3e-2
+# The causal flash kernel's backward rebuilds each probability from the saved
+# log-sum-exp and subtracts two sums of 8192 terms (dp - delta): measured on a
+# v5e 5.9e-5 (float32 streams; 50x under one bf16-rounded operand, 3e-3) and
+# 3.0e-3 .. 3.4e-3 (bf16 streams).
+TOL_DECODER_BWD = {"float32": 3e-4, "bfloat16": 3.2e-2}
 
 
 def _sorted_ids(rng, n_nodes: int, max_degree: int, n_padding: int):
@@ -298,6 +306,120 @@ def kernel_leg(**shape) -> None:
     for name, dt, check in kernel_cases(**shape):
         for label, err, *tol in check():
             _check(f"{name} {label}", err, tol[0] if tol else TOL[dt])
+
+
+def _blocked_causal_reference(q, k, v, node_graph, node_mask, block=1024):
+    """Causal same-graph attention in plain jnp, one block of queries at a
+    time (``[H, block, N]`` scores), so that 32768 nodes fit."""
+    import jax
+    import jax.numpy as jnp
+
+    n, hq, d = q.shape
+    kf = jnp.repeat(k, hq // k.shape[1], axis=1)
+    vf = jnp.repeat(v, hq // v.shape[1], axis=1)
+    idx = jnp.arange(n, dtype=jnp.int32)
+
+    def one(args):
+        qb, ib, gb, rb = args
+        s = jnp.einsum("ihd,jhd->hij", qb, kf) / np.sqrt(d)
+        ok = ((gb[:, None] == node_graph[None, :]) & (rb[:, None] & node_mask[None, :])
+              & (idx[None, :] <= ib[:, None]))
+        p = jnp.where(ok[None], jax.nn.softmax(jnp.where(ok[None], s, -1e30), axis=-1), 0.0)
+        return jnp.einsum("hij,jhd->ihd", p, vf)
+
+    blocks = lambda a: a.reshape((-1, block) + a.shape[1:])
+    out = jax.lax.map(jax.checkpoint(one), (blocks(q), blocks(idx), blocks(node_graph), blocks(node_mask)))
+    return out.reshape(n, hq, d)
+
+
+def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
+                       longest=8192, groups=8, width=2048, interpret=False,
+                       tiles=((512, 512),), dtypes=("bfloat16", "float32")) -> dict:
+    """The decoder's two kernels alone at the ZAYA cell's shapes, forward and
+    backward, against plain jnp: causal grouped-query flash attention over
+    ``[tokens, heads x head_dim]`` with a longest graph of ``longest`` nodes
+    and a graph boundary inside a tile; the grouped product over ``groups``
+    experts of ragged size, ``width -> width``. Prints each launch's time
+    (a set-up fact, not a throughput)."""
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.ops import pallas_grouped_matmul as gm
+    from hydragnn_tpu.ops.pallas_flash_attention import flash_causal_attention
+
+    rng = np.random.default_rng(0)
+    sizes, left = [longest], tokens - longest - 37
+    while left > 0:
+        sizes.append(int(min(left, rng.integers(max(longest // 64, 1), max(longest // 2, 2)))))
+        left -= sizes[-1]
+    n_real = sum(sizes)
+    node_graph = jnp.asarray(np.concatenate(
+        [np.full(s, i) for i, s in enumerate(sizes)] + [np.full(tokens - n_real, len(sizes))]).astype(np.int32))
+    node_mask = jnp.asarray(np.arange(tokens) < n_real)
+    times = {}
+
+    def timed(name, fn, *args):
+        out = jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*args))
+        times[name] = round(1e3 * (time.perf_counter() - t0), 2)
+        return out
+
+    for dt in dtypes:
+        dtype = jnp.dtype(dt)
+        arr = lambda shape, scale=1.0: jnp.asarray(rng.normal(size=shape) * scale, jnp.float32).astype(dtype)
+        q, k, v = arr((tokens, heads, head_dim)), arr((tokens, kv_heads, head_dim)), arr((tokens, kv_heads, head_dim))
+        w = arr((tokens, heads, head_dim)) * node_mask[:, None, None].astype(dtype)
+        f32 = lambda a: a.astype(jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            ref_loss = lambda q_, k_, v_: jnp.sum(
+                _blocked_causal_reference(q_, k_, v_, node_graph, node_mask) * f32(w))
+            ref_out = jax.jit(_blocked_causal_reference)(f32(q), f32(k), f32(v), node_graph, node_mask)
+            ref_grads = jax.jit(jax.grad(ref_loss, (0, 1, 2)))(f32(q), f32(k), f32(v))
+        for bq, bk in tiles:
+            fwd = jax.jit(lambda q_, k_, v_: flash_causal_attention(
+                q_, k_, v_, node_graph, node_mask, longest, bq, bk, interpret))
+            bwd = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(f32(flash_causal_attention(
+                q_, k_, v_, node_graph, node_mask, longest, bq, bk, interpret)) * f32(w)), (0, 1, 2)))
+            tag = f"flash_causal {dt} tiles {bq}x{bk}"
+            out = timed(tag + " fwd_ms", fwd, q, k, v)
+            _check(tag + " forward", _rel_err(out[:n_real], ref_out[:n_real]), TOL[dt])
+            grads = timed(tag + " fwd+bwd_ms", bwd, q, k, v)
+            for name, got, want in zip("qkv", grads, ref_grads):
+                _check(f"{tag} d{name}", _rel_err(got, want), TOL_DECODER_BWD[dt])
+
+        # ---- grouped product: ragged groups, one of them empty
+        share = rng.dirichlet(np.ones(groups - 1) * 2.0)
+        slot_np = rng.choice(groups - 1, size=tokens, p=share)
+        slot_np[rng.random(tokens) < 0.05] = groups  # not held here
+        slot = jnp.asarray(slot_np.astype(np.int32))
+        x, wts = arr((tokens, width)), arr((groups, width, width), 1.0 / np.sqrt(width))
+        cot = arr((tokens, width))
+        bm, bn, bk_ = gm.normalize_tiles(tokens, width, width, dtype=dt)
+
+        def through(kernel):
+            def f(x_, w_):
+                lay = gm.aligned_layout(slot, groups, bm)
+                rows = gm.permute_rows(x_, lay["src"], lay["dest"])
+                if kernel:
+                    y = gm.grouped_matmul(rows, w_, lay["tile_group"], lay["n_tiles"], bm, bn, bk_, interpret)
+                else:
+                    y = gm.reference_grouped_matmul(rows, w_, lay["tile_group"], bm)
+                return gm.permute_rows(y, lay["dest"], lay["src"])
+            return f
+
+        tag = f"grouped_expert {dt} groups={groups} {width}->{width} tiles {bm}x{bn}x{bk_}"
+        out = timed(tag + " fwd_ms", jax.jit(through(True)), x, wts)
+        grad_of = lambda f: jax.jit(jax.grad(lambda x_, w_: jnp.sum(f32(f(x_, w_)) * f32(cot)), (0, 1)))
+        grads = timed(tag + " fwd+bwd_ms", grad_of(through(True)), x, wts)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(through(False))(f32(x), f32(wts))
+            ref_g = grad_of(through(False))(f32(x), f32(wts))
+        _check(tag + " forward", _rel_err(out, ref), TOL[dt])
+        for name, got, want in zip(("dx", "dw"), grads, ref_g):
+            _check(f"{tag} {name}", _rel_err(got, want), TOL[dt])
+    print("  launch times (ms): " + json.dumps(times), flush=True)
+    return {"launch_ms": times}
 
 
 # ---------------------------------------------------------------------------
@@ -653,7 +775,8 @@ def main() -> int:
     # cache (train/compile_plane.py compile_cache_dir) outlives it
     workdir = tempfile.mkdtemp(prefix="chip_smoke_")
     os.chdir(workdir)
-    todo = [("kernels", kernel_leg), ("main", main_leg),
+    todo = [("kernels", kernel_leg), ("decoder_kernels", decoder_kernel_leg),
+            ("main", main_leg),
             ("second_order", second_order_leg)]
     if jax.local_device_count() > 1:
         todo.append(("mesh", mesh_leg))

@@ -396,6 +396,8 @@ def prepare_data(
             trainset + valset + testset,
             max(batch_size // num_shards, 1),
             with_triplets=arch["mpnn_type"] == "DimeNet",
+            node_slots=training.get("pack_node_slots"),
+            graph_slots=training.get("pack_graph_slots"),
         )
     else:
         spec = SpecLadder.for_dataset(

@@ -155,6 +155,23 @@ def update_config(
     arch["graph_size_variable"] = graph_size_variable
     arch["max_nodes_per_graph"] = max(sizes, default=0)
 
+    # ---- the decoder stack (models/zaya.py): its keys are validated here,
+    # at once; there are no edges to aggregate, so the sorted-aggregation
+    # route stays off unless asked for
+    if arch["mpnn_type"] == "ZAYA":
+        from ..models.zaya import ZayaConfig
+
+        arch.setdefault("cca_time0", 2)
+        arch.setdefault("cca_time1", 2)
+        arch.setdefault("partial_rotary_factor", 0.5)
+        arch.setdefault("rope_theta", 5.0e6)
+        arch.setdefault("rms_norm_eps", 1.0e-5)
+        arch.setdefault("loss_chunk_rows", 4096)
+        if arch.get("num_experts") is not None:
+            arch.setdefault("experts_held", list(range(int(arch["num_experts"]))))
+        ZayaConfig.from_arch(arch)
+        arch.setdefault("use_sorted_aggregation", False)
+
     # GPS defaults (reference: config_utils.py:40-47)
     arch.setdefault("global_attn_engine", None)
     arch.setdefault("global_attn_type", None)

@@ -115,6 +115,21 @@ _HANDLED = {
     "NeuralNetwork.Architecture.max_in_degree",
     "NeuralNetwork.Architecture.use_fused_edge_kernel",
     "NeuralNetwork.Architecture.use_flash_attention",
+    # the decoder stack (mpnn_type ZAYA, models/zaya.py)
+    "NeuralNetwork.Architecture.num_attention_heads",
+    "NeuralNetwork.Architecture.num_key_value_heads",
+    "NeuralNetwork.Architecture.head_dim",
+    "NeuralNetwork.Architecture.cca_time0",
+    "NeuralNetwork.Architecture.cca_time1",
+    "NeuralNetwork.Architecture.partial_rotary_factor",
+    "NeuralNetwork.Architecture.rope_theta",
+    "NeuralNetwork.Architecture.num_experts",
+    "NeuralNetwork.Architecture.experts_held",
+    "NeuralNetwork.Architecture.moe_intermediate_size",
+    "NeuralNetwork.Architecture.router_hidden_size",
+    "NeuralNetwork.Architecture.vocab_size",
+    "NeuralNetwork.Architecture.rms_norm_eps",
+    "NeuralNetwork.Architecture.loss_chunk_rows",
     "NeuralNetwork.Architecture.branch_loss_weights",
     "NeuralNetwork.Architecture.branch_loss_metrics",
     "NeuralNetwork.Architecture.dropout",
@@ -161,6 +176,8 @@ _HANDLED = {
     "NeuralNetwork.Training.elastic",
     "NeuralNetwork.Training.mixed_precision",
     "NeuralNetwork.Training.pack_batches",
+    "NeuralNetwork.Training.pack_node_slots",
+    "NeuralNetwork.Training.pack_graph_slots",
     "NeuralNetwork.Training.num_pad_buckets",
     "NeuralNetwork.Training.size_bucketed_batching",
     "NeuralNetwork.Training.branch_parallel",

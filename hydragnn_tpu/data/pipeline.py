@@ -45,16 +45,29 @@ class LoaderStallError(RuntimeError):
 
 
 def _pack_spec(
-    graphs: Sequence[Graph], per_shard: int, with_triplets: bool = False
+    graphs: Sequence[Graph], per_shard: int, with_triplets: bool = False,
+    node_slots: Optional[int] = None, graph_slots: Optional[int] = None,
 ) -> PadSpec:
     """Budget spec for packed batching: mean-size * per_shard (+5% headroom),
     never below the largest single graph, with 2x graph slots so bins of
     small graphs aren't cut short by the slot cap. ``with_triplets`` also
-    budgets the DimeNet triplet channel (counted per graph, O(E) each)."""
+    budgets the DimeNet triplet channel (counted per graph, O(E) each).
+    ``node_slots`` / ``graph_slots`` (``Training.pack_node_slots`` /
+    ``pack_graph_slots``) state the budget outright, as a job that counts its
+    batch in tokens does: that many node slots (one is the dummy node) with
+    the edge budget in the data's edges-per-node proportion, that many graph
+    slots (one is the dummy graph)."""
     ns = np.asarray([g.num_nodes for g in graphs])
     es = np.asarray([g.num_edges for g in graphs])
     budget_n = max(int(ns.mean() * per_shard * 1.05) + 2, int(ns.max()) + 2)
     budget_e = max(int(es.mean() * per_shard * 1.05) + 1, int(es.max()) + 1)
+    if node_slots:
+        if int(node_slots) < int(ns.max()) + 1:
+            raise ValueError(
+                f"Training.pack_node_slots {node_slots} cannot hold the largest "
+                f"graph ({int(ns.max())} nodes) and the dummy node")
+        budget_n = int(node_slots)
+        budget_e = max(int(es.sum() / max(ns.sum(), 1) * budget_n * 1.05) + 1, int(es.max()) + 1)
     n_triplets = 0
     if with_triplets:
         ts = np.asarray([_triplet_count(g) for g in graphs])
@@ -64,7 +77,7 @@ def _pack_spec(
     return PadSpec(
         n_nodes=_round_up(budget_n, 8),
         n_edges=_round_up(budget_e, 128),
-        n_graphs=2 * per_shard + 1,
+        n_graphs=int(graph_slots) if graph_slots else 2 * per_shard + 1,
         n_triplets=n_triplets,
     )
 

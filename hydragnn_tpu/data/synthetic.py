@@ -492,3 +492,41 @@ def lennard_jones_dataset(
                 g.graph_targets["energy"] - e_per_atom * g.num_nodes
             ).astype(np.float32)
     return graphs
+
+
+def packed_documents_dataset(
+    number_configurations: int,
+    median_tokens: float,
+    sigma: float,
+    min_tokens: int,
+    max_tokens: int,
+    vocab_size: int,
+    zipf_exponent: float = 1.1,
+    seed: int = 0,
+) -> List[Graph]:
+    """Documents as graphs for the decoder stack (models/zaya.py): lognormal
+    lengths clipped to ``[min_tokens, max_tokens]``, ids Zipf over
+    ``vocab_size``, p(rank) ~ rank ** -exponent (repeated ids route alike:
+    uneven expert load). Node id in
+    ``z`` (int32) and ``x`` (float32 column); ``pos = [index in document,
+    document number, 0]``; chain edges ``t-1 -> t``. The targets are carried
+    for the loader's sake and read by no head."""
+    rng = np.random.default_rng(seed)
+    ranks = np.arange(1, vocab_size + 1, dtype=np.float64)
+    cdf = np.cumsum(ranks ** -float(zipf_exponent))
+    cdf /= cdf[-1]
+    graphs = []
+    for doc in range(int(number_configurations)):
+        n = int(np.clip(rng.lognormal(np.log(median_tokens), sigma), min_tokens, max_tokens))
+        ids = np.searchsorted(cdf, rng.random(n)).astype(np.int32)
+        idx = np.arange(n, dtype=np.float32)
+        graphs.append(Graph(
+            x=ids[:, None].astype(np.float32),
+            pos=np.stack([idx, np.full(n, doc, np.float32), np.zeros(n, np.float32)], axis=1),
+            senders=np.arange(0, n - 1, dtype=np.int32),
+            receivers=np.arange(1, n, dtype=np.int32),
+            z=ids,
+            graph_targets={"energy": np.zeros((1,), np.float32)},
+            node_targets={"forces": np.zeros((n, 3), np.float32)},
+        ))
+    return graphs

@@ -153,6 +153,10 @@ class ModelConfig:
     # 692-752). See layers.MLP and docs/MIGRATION.md.
     decoder_mirror_init: bool = True
     decoder_recovery_slope: float = 0.1
+    # --- the decoder stack (mpnn_type "ZAYA", models/zaya.py): its own keys,
+    # and with them the token head (train/loss.py token_loss) in place of the
+    # regression heads
+    zaya: Optional[Any] = None
 
     @property
     def num_heads(self) -> int:

@@ -161,7 +161,16 @@ def model_config_from(config: Dict[str, Any]) -> ModelConfig:
         initial_bias=arch.get("initial_bias"),
         periodic_boundary_conditions=bool(arch.get("periodic_boundary_conditions", False)),
         max_neighbours=arch.get("max_neighbours"),
+        zaya=_zaya_config(arch),
     )
+
+
+def _zaya_config(arch: Dict[str, Any]):
+    if arch["mpnn_type"] != "ZAYA":
+        return None
+    from .zaya import ZayaConfig
+
+    return ZayaConfig.from_arch(arch)
 
 
 def create_model(config: Dict[str, Any]):
@@ -181,6 +190,12 @@ def create_model(config: Dict[str, Any]):
             "GPS global attention is not supported with MACE"
         )
         return MACEModel(cfg=cfg)
+    if cfg.mpnn_type == "ZAYA":
+        # a decoder stack: no message passing, its own embedding and the
+        # token head (models/zaya.py, train/loss.py token_loss)
+        from .zaya import ZayaModel
+
+        return ZayaModel(cfg=cfg)
     return HydraModel(cfg=cfg)
 
 
@@ -194,4 +209,4 @@ def init_model(
 
 
 def available_models() -> Tuple[str, ...]:
-    return conv_registry() + ("MACE",)
+    return conv_registry() + ("MACE", "ZAYA")

@@ -420,9 +420,9 @@ def make_nan_diagnostic(model, compute_grad_energy: bool = False,
 
         def loss_probe(params, batch_stats, batch, rng):
             if mixed_precision:
-                from ..train.loop import mp_cast
+                from ..train.loop import mp_cast, mp_keep
 
-                params, batch = mp_cast(params, batch, compute_grad_energy)
+                params, batch = mp_cast(params, batch, compute_grad_energy, mp_keep(model))
             rec = ProbeRecord()
             with collecting(rec):
                 tot, _, _, _ = compute_loss(

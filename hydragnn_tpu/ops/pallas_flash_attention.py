@@ -39,7 +39,20 @@ queries against one K/V block, and ``parallel/ring_attention.py`` merges
 those partials across ring steps in plain jnp — the per-chip block of
 ring attention rides the same inner loop instead of a dense einsum.
 
-Differentiation is the house custom-JVP: only the primal runs Pallas; the
+``flash_causal_attention`` is the decoder's entry (models/zaya.py): the same
+tiling with a CAUSAL mask inside the segment mask (key index <= query index
+within a graph: nodes of a graph are contiguous and ordered, so it is one
+more in-register compare of two iotas and a window that ends at the query
+block's own last row), grouped-query heads (the key/value index map divides
+the head index by the group size) and a TILED backward: two more Pallas
+launches under the forward's own schedule (``hg_flash_attention_bwd``), one
+holding a query block and streaming its keys for ``dq``, one holding a
+key/value block and streaming its queries for ``dk``/``dv``, both rebuilding
+the probabilities from the forward's saved log-sum-exp. No ``[*, N, N]``
+array exists in either direction, so a graph of 8192 nodes trains. It is a
+first-order ``custom_vjp`` (the token loss needs no more).
+
+GPS differentiation is the house custom-JVP: only the primal runs Pallas; the
 tangent rule is the plain-jnp per-graph gathered reference pushed through
 ``jax.jvp`` (G·Nmax² work, not N²), so reverse mode transposes to the
 dense-recompute backward and the op composes under ``jax.grad`` to ANY
@@ -71,7 +84,7 @@ _NEG = -1.0e30
 # tuned-table key component (tune/table.py): bump on any change to the
 # kernel's schedule, block layout, or semantics — stale tuned entries must
 # miss, not steer a different program
-KERNEL_VERSION = 1
+KERNEL_VERSION = 2
 
 
 def normalize_tiles(block_q=128, block_k=128):
@@ -180,12 +193,29 @@ def reference_block_summary(q, k, v, key_mask):
 # ---------------------------------------------------------------------------
 
 
+def _pair_mask(gid_rows, gid_cols, row0, col0, causal, rows_are_queries=True):
+    """Same-graph mask of one tile from the streamed graph-id column
+    ``[R, 1]`` and row ``[1, C]`` (padding nodes carry -1 on the key side
+    and never match); under ``causal`` also key index <= query index, from
+    the tile's first flat row/column index."""
+    keys = gid_cols if rows_are_queries else gid_rows
+    mask = (gid_rows == gid_cols) & (keys >= 0)
+    if causal:
+        shape = (gid_rows.shape[0], gid_cols.shape[1])
+        r = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        c = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        mask = mask & ((c <= r) if rows_are_queries else (r <= c))
+    return mask
+
+
 def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
-            *refs, scale, emit_stats):
+            *refs, scale, emit_stats, causal=False):
     # stats outputs exist only for the block-summary (ring) launch: the
     # self-attention launch would have to WRITE two [H, N, 128] f32 arrays
     # to HBM just to discard them (pallas outputs cannot be DCE'd)
-    if emit_stats:
+    if emit_stats == "lse":  # one array: each row's log-sum-exp
+        o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    elif emit_stats:
         o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = refs
     else:
         o_ref, m_scr, l_scr, acc_scr = refs
@@ -210,9 +240,10 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             precision=mxu_precision(q.dtype),
             preferred_element_type=jnp.float32,
         ) * scale  # [Bq, Bk] f32
-        # same-graph mask from the streamed graph-id column/row; padding
-        # nodes carry id -1 on the KEY side and never match
-        mask = (gidq_ref[:] == gidk_ref[:]) & (gidk_ref[:] >= 0)
+        mask = _pair_mask(
+            gidq_ref[:], gidk_ref[:], j * q.shape[0],
+            (kstart_ref[j] + kk) * k_ref.shape[1], causal,
+        )
         s = jnp.where(mask, s, _NEG)
         m_prev = m_scr[:, 0:1]
         l_prev = l_scr[:, 0:1]
@@ -238,32 +269,42 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
         l = l_scr[:, 0:1]
         # rows with no valid key (padding queries): l == 0, acc == 0 -> 0
         o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        if emit_stats:
+        if emit_stats == "lse":
+            # rows with no key keep a finite value (and a zero output)
+            lse_ref[0] = m_scr[:] + jnp.log(jnp.maximum(l_scr[:], 1e-30))
+        elif emit_stats:
             m_ref[0] = m_scr[:]
             l_ref[0] = l_scr[:]
 
 
+def _heads_first(x, block):
+    x = _pad_to(_pad_to(x, block, 0), 128, 2)
+    return jnp.transpose(x, (1, 0, 2))  # [H, N_pad, d_pad]
+
+
 def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
-             block_q, block_k, interpret, emit_stats=False):
+             block_q, block_k, interpret, emit_stats=False, causal=False,
+             padded=False):
     """Shared launch: q ``[Nq, H, d]`` against k/v ``[Nk, H, d]`` with
     per-q-block key-window schedule (kstart/klast in k-block units) and
     per-node graph ids (-1 = never a valid key). Returns the normalized
     ``o [Nq, H, d]`` (operand dtype); with ``emit_stats`` also the f32
     running statistics ``(m [Nq, H], l [Nq, H])`` as extra HBM outputs —
-    only the block-summary launch pays for them."""
+    only the block-summary launch pays for them. ``k``/``v`` may carry fewer
+    heads than ``q`` (grouped-query: head ``h`` reads key/value head
+    ``h // group``). ``padded`` returns the launch's own arrays instead
+    (``o [H, Nq_pad, d_pad]``, ``m``/``l [H, Nq_pad, 128]`` lane-broadcast):
+    what the tiled backward streams."""
     nq, h, d = q.shape
     nk = k.shape[0]
+    group = h // k.shape[1]
     bq, bk = block_q, block_k
     d_pad = d + (-d) % 128
     scale = 1.0 / float(d) ** 0.5
 
-    def _prep(x, blk):
-        x = _pad_to(_pad_to(x, blk, 0), 128, 2)
-        return jnp.transpose(x, (1, 0, 2))  # [H, N_pad, d_pad]
-
-    qt = _prep(q, bq)
-    kt = _prep(k, bk)
-    vt = _prep(v, bk)
+    qt = _heads_first(q, bq)
+    kt = _heads_first(k, bk)
+    vt = _heads_first(v, bk)
     nq_pad, nk_pad = qt.shape[1], kt.shape[1]
     j_blocks = nq_pad // bq
     k_blocks = nk_pad // bk
@@ -282,7 +323,7 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
         return (h_i, j, 0)
 
     def kv_index(h_i, j, kk, ks, kl):
-        return (h_i, jnp.minimum(ks[j] + kk, kl[j]), 0)
+        return (h_i // group, jnp.minimum(ks[j] + kk, kl[j]), 0)
 
     def gidq_index(h_i, j, kk, ks, kl):
         return (j, 0)
@@ -297,10 +338,12 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
     out_specs = [pl.BlockSpec((1, bq, d_pad), out_index)]
     out_shape = [jax.ShapeDtypeStruct((h, nq_pad, d_pad), q.dtype)]
     if emit_stats:
-        out_specs += [pl.BlockSpec((1, bq, 128), out_index)] * 2
-        out_shape += [jax.ShapeDtypeStruct((h, nq_pad, 128), jnp.float32)] * 2
+        stats = 1 if emit_stats == "lse" else 2
+        out_specs += [pl.BlockSpec((1, bq, 128), out_index)] * stats
+        out_shape += [jax.ShapeDtypeStruct((h, nq_pad, 128), jnp.float32)] * stats
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, emit_stats=emit_stats),
+        functools.partial(_kernel, scale=scale, emit_stats=emit_stats,
+                          causal=causal),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -322,6 +365,8 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
         interpret=interpret,
         name=tr.HG_FLASH_ATTENTION,
     )(kstart, klast, gq, gk, qt, kt, vt)
+    if padded:
+        return out
     o = jnp.transpose(out[0], (1, 0, 2))[:nq, :, :d]
     if not emit_stats:
         return o
@@ -475,3 +520,300 @@ def _summary_jvp(block_q, block_k, interpret, primals, tangents):
     with tr.scope(tr.HG_FLASH_ATTENTION + tr.TANGENT):
         _, t_out = jax.jvp(fn, (q, k, v), (t_q, t_k, t_v))
     return out, t_out
+
+
+# ---------------------------------------------------------------------------
+# causal grouped-query attention with a tiled backward (the decoder stack)
+# ---------------------------------------------------------------------------
+
+
+def reference_causal_attention(q, k, v, node_graph, node_mask):
+    """Flat ``[N, N]``-masked causal grouped-query attention in plain jnp:
+    node ``i`` attends node ``j`` iff both are real, share a graph and
+    ``j <= i``. ``q [N, Hq, d]``, ``k``/``v [N, Hk, d]`` with ``Hq`` a
+    multiple of ``Hk``. The oracle of the kernel and the route off the TPU;
+    scores and softmax in float32."""
+    n, hq, d = q.shape
+    group = hq // k.shape[1]
+    kf = jnp.repeat(k, group, axis=1)
+    vf = jnp.repeat(v, group, axis=1)
+    idx = jnp.arange(n)
+    allowed = (
+        (node_graph[:, None] == node_graph[None, :])
+        & (node_mask[:, None] & node_mask[None, :])
+        & (idx[None, :] <= idx[:, None])
+    )
+    logits = jnp.einsum(
+        "ihd,jhd->hij", q, kf, preferred_element_type=jnp.float32
+    ) * (1.0 / float(d) ** 0.5)
+    logits = jnp.where(allowed[None], logits, _NEG)
+    probs = jnp.where(allowed[None], jax.nn.softmax(logits, axis=-1), 0.0)
+    return jnp.einsum("hij,jhd->ihd", probs.astype(v.dtype), vf)
+
+
+def _causal_windows(node_graph, n, block_q, block_k, max_nodes_per_graph):
+    """The causal schedule, both ways round. A query block's keys run from
+    the first node of the graph owning its first row to its own last row; a
+    key block's queries run from its own first row to the last node of the
+    graph owning its last row. -> (kstart, klast, k_windows) in k-block
+    units per q block, (qstart, qlast, q_windows) in q-block units per k
+    block. Static step counts cover the worst legal window."""
+    ng = node_graph.astype(jnp.int32)
+    nmax = max(max_nodes_per_graph, 1)
+
+    def rows(block):
+        blocks = (n + block - 1) // block
+        row0 = jnp.minimum(jnp.arange(blocks, dtype=jnp.int32) * block, n - 1)
+        return row0, jnp.minimum(row0 + block - 1, n - 1)
+
+    q0, q1 = rows(block_q)
+    first = jnp.searchsorted(ng, ng[q0], side="left").astype(jnp.int32)
+    k_windows = (block_q + nmax - 1 + block_k - 1) // block_k + 1
+    k0, k1 = rows(block_k)
+    last = jnp.searchsorted(ng, ng[k1], side="right").astype(jnp.int32) - 1
+    q_windows = (block_k + nmax - 1 + block_q - 1) // block_q + 1
+    return (first // block_k, q1 // block_k, k_windows,
+            k0 // block_q, last // block_q, q_windows)
+
+
+def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
+               do_ref, o_ref, lse_ref, dq_ref, delta_scr, acc_scr, *, scale):
+    j = pl.program_id(1)
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _init():
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+        delta = jnp.sum(
+            do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+            axis=1, keepdims=True,
+        )
+        delta_scr[:] = jnp.broadcast_to(delta, delta_scr.shape)
+
+    @pl.when(kstart_ref[j] + kk <= klast_ref[j])
+    def _step():
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        nt = (((1,), (1,)), ((), ()))
+        prec = mxu_precision(q.dtype)
+        s = jax.lax.dot_general(
+            q, k, nt, precision=prec, preferred_element_type=jnp.float32
+        ) * scale
+        mask = _pair_mask(
+            gidq_ref[:], gidk_ref[:], j * q.shape[0],
+            (kstart_ref[j] + kk) * k.shape[0], True,
+        )
+        p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, 0:1]), 0.0)
+        dp = jax.lax.dot_general(
+            do, v, nt, precision=prec, preferred_element_type=jnp.float32
+        )
+        ds = p * (dp - delta_scr[:, 0:1]) * scale
+        acc_scr[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            precision=prec, preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(qstart_ref, qlast_ref, gidk_ref, gidq_ref, k_ref, v_ref,
+                q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+                dk_scr, dv_scr, *, scale):
+    """One key/value block held, its query blocks streamed; every product is
+    written transposed (``s.T = k @ q.T``) so that no tile is transposed in
+    the kernel: the statistics arrive as lane-major rows ``[8, Bq]``."""
+    i = pl.program_id(1)
+    qq = pl.program_id(2)
+
+    @pl.when(qq == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    @pl.when(qstart_ref[i] + qq <= qlast_ref[i])
+    def _step():
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        nt = (((1,), (1,)), ((), ()))
+        nn = (((1,), (0,)), ((), ()))
+        prec = mxu_precision(q.dtype)
+        st = jax.lax.dot_general(
+            k, q, nt, precision=prec, preferred_element_type=jnp.float32
+        ) * scale  # [Bk, Bq]
+        mask = _pair_mask(
+            gidk_ref[:], gidq_ref[:], i * k.shape[0],
+            (qstart_ref[i] + qq) * q.shape[0], True, rows_are_queries=False,
+        )
+        pt = jnp.where(mask, jnp.exp(st - lse_ref[0][0:1, :]), 0.0)
+        dv_scr[:] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, nn, precision=prec,
+            preferred_element_type=jnp.float32,
+        )
+        dpt = jax.lax.dot_general(
+            v, do, nt, precision=prec, preferred_element_type=jnp.float32
+        )
+        dst = pt * (dpt - delta_ref[0][0:1, :]) * scale
+        dk_scr[:] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, nn, precision=prec,
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(qq == pl.num_programs(2) - 1)
+    def _finalize():
+        dk_ref[0] = dk_scr[:]
+        dv_ref[0] = dv_scr[:]
+
+
+def _causal_prep(node_graph, node_mask, max_nodes_per_graph, block_q, block_k):
+    gid = jnp.where(node_mask, node_graph.astype(jnp.int32), -1)
+    return gid, _causal_windows(
+        node_graph, node_graph.shape[0], block_q, block_k, max_nodes_per_graph
+    )
+
+
+def _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
+                block_q, block_k, interpret):
+    gid, (ks, kl, kw, _, _, _) = _causal_prep(
+        node_graph, node_mask, max_nodes_per_graph, block_q, block_k
+    )
+    with tr.scope(tr.HG_FLASH_ATTENTION):
+        o_pad, lse = _forward(
+            q, k, v, gid, gid, ks, kl, kw, block_q, block_k, interpret,
+            emit_stats="lse", causal=True, padded=True,
+        )
+        n, _, d = q.shape
+        o = jnp.transpose(o_pad, (1, 0, 2))[:n, :, :d]
+    return o, lse  # lse [H, Nq_pad, 128], lane-broadcast
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def flash_causal_attention(
+    q,
+    k,
+    v,
+    node_graph,
+    node_mask,
+    max_nodes_per_graph: int,
+    block_q: int = 512,
+    block_k: int = 512,
+    interpret: bool = False,
+):
+    """Causal grouped-query flash attention over the flat node array.
+
+    ``q [N, Hq, d]``, ``k``/``v [N, Hk, d]`` (``Hq`` a multiple of ``Hk``);
+    node ``i`` attends the real nodes ``j <= i`` of its own graph. Same
+    layout contract as :func:`flash_self_attention` (graphs contiguous,
+    ``node_graph`` non-decreasing, padding last); a graph past
+    ``max_nodes_per_graph`` is under-covered and the caller poisons it.
+    Forward and backward are Pallas launches under one schedule; reverse
+    mode only, first order."""
+    return _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
+                       block_q, block_k, interpret)[0]
+
+
+def _causal_vjp_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
+                    block_q, block_k, interpret):
+    o, lse = _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
+                         block_q, block_k, interpret)
+    return o, (q, k, v, o, lse, node_graph, node_mask)
+
+
+def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
+    q, k, v, o, lse, node_graph, node_mask = res
+    n, hq, d = q.shape
+    hk = k.shape[1]
+    group = hq // hk
+    bq, bk = block_q, block_k
+    scale = 1.0 / float(d) ** 0.5
+    gid, (ks, kl, kw, qs, ql, qw) = _causal_prep(
+        node_graph, node_mask, max_nodes_per_graph, bq, bk
+    )
+    with tr.scope(tr.HG_FLASH_ATTENTION + tr.BWD):
+        qt, dot, ot = (_heads_first(x, bq) for x in (q, do.astype(q.dtype), o))
+        kt, vt = _heads_first(k, bk), _heads_first(v, bk)
+        nq_pad, nk_pad, d_pad = qt.shape[1], kt.shape[1], qt.shape[2]
+        j_blocks, k_blocks = nq_pad // bq, nk_pad // bk
+        gcol = lambda npad: jnp.full((npad, 1), -1, jnp.int32).at[:n, 0].set(gid)
+        grow = lambda npad: jnp.full((1, npad), -1, jnp.int32).at[0, :n].set(gid)
+        clip = lambda a, hi: jnp.clip(a.astype(jnp.int32), 0, hi - 1)
+        ks, kl = clip(ks, k_blocks), clip(kl, k_blocks)
+        qs, ql = clip(qs, j_blocks), clip(ql, j_blocks)
+        kw, qw = max(1, min(kw, k_blocks)), max(1, min(qw, j_blocks))
+
+        # ---- dq: the forward's schedule
+        held = lambda h_i, j, kk, s_, l_: (h_i, j, 0)
+        kv = lambda h_i, j, kk, s_, l_: (
+            h_i // group, jnp.minimum(s_[j] + kk, l_[j]), 0)
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, scale=scale),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(hq, j_blocks, kw),
+                in_specs=[
+                    pl.BlockSpec((bq, 1), lambda h_i, j, kk, s_, l_: (j, 0)),
+                    pl.BlockSpec((1, bk), lambda h_i, j, kk, s_, l_: (
+                        0, jnp.minimum(s_[j] + kk, l_[j]))),
+                    pl.BlockSpec((1, bq, d_pad), held),
+                    pl.BlockSpec((1, bk, d_pad), kv),
+                    pl.BlockSpec((1, bk, d_pad), kv),
+                    pl.BlockSpec((1, bq, d_pad), held),
+                    pl.BlockSpec((1, bq, d_pad), held),
+                    pl.BlockSpec((1, bq, 128), held),
+                ],
+                out_specs=pl.BlockSpec((1, bq, d_pad), held),
+                scratch_shapes=[
+                    pltpu.VMEM((bq, 128), jnp.float32),
+                    pltpu.VMEM((bq, d_pad), jnp.float32),
+                ],
+            ),
+            out_shape=jax.ShapeDtypeStruct((hq, nq_pad, d_pad), q.dtype),
+            interpret=interpret,
+            name=tr.HG_FLASH_ATTENTION + tr.BWD,
+        )(ks, kl, gcol(nq_pad), grow(nk_pad), qt, kt, vt, dot, ot, lse)
+
+        # ---- dk, dv: one key/value block held, per QUERY head; the group's
+        # heads are summed after (float32 partials)
+        rows8 = lambda x: jnp.broadcast_to(x[:, None, :], (hq, 8, nq_pad))
+        lse_row = rows8(lse[:, :, 0])
+        delta_row = rows8(jnp.sum(
+            dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1))
+        kheld = lambda h_i, i, qq, s_, l_: (h_i // group, i, 0)
+        out_held = lambda h_i, i, qq, s_, l_: (h_i, i, 0)
+        qv = lambda h_i, i, qq, s_, l_: (
+            h_i, jnp.minimum(s_[i] + qq, l_[i]), 0)
+        stat = lambda h_i, i, qq, s_, l_: (
+            h_i, 0, jnp.minimum(s_[i] + qq, l_[i]))
+        dk_h, dv_h = pl.pallas_call(
+            functools.partial(_dkv_kernel, scale=scale),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(hq, k_blocks, qw),
+                in_specs=[
+                    pl.BlockSpec((bk, 1), lambda h_i, i, qq, s_, l_: (i, 0)),
+                    pl.BlockSpec((1, bq), lambda h_i, i, qq, s_, l_: (
+                        0, jnp.minimum(s_[i] + qq, l_[i]))),
+                    pl.BlockSpec((1, bk, d_pad), kheld),
+                    pl.BlockSpec((1, bk, d_pad), kheld),
+                    pl.BlockSpec((1, bq, d_pad), qv),
+                    pl.BlockSpec((1, bq, d_pad), qv),
+                    pl.BlockSpec((1, 8, bq), stat),
+                    pl.BlockSpec((1, 8, bq), stat),
+                ],
+                out_specs=[pl.BlockSpec((1, bk, d_pad), out_held)] * 2,
+                scratch_shapes=[pltpu.VMEM((bk, d_pad), jnp.float32)] * 2,
+            ),
+            out_shape=[jax.ShapeDtypeStruct((hq, nk_pad, d_pad), jnp.float32)] * 2,
+            interpret=interpret,
+            name=tr.HG_FLASH_ATTENTION + tr.BWD,
+        )(qs, ql, gcol(nk_pad), grow(nq_pad), kt, vt, qt, dot, lse_row, delta_row)
+
+        def per_kv_head(x):
+            x = x.reshape(hk, group, nk_pad, d_pad).sum(axis=1)
+            return jnp.transpose(x, (1, 0, 2))[:n, :, :d]
+
+        dq = jnp.transpose(dq, (1, 0, 2))[:n, :, :d]
+        return (dq, per_kv_head(dk_h).astype(k.dtype),
+                per_kv_head(dv_h).astype(v.dtype), None, None)
+
+
+flash_causal_attention.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)

@@ -263,9 +263,9 @@ def make_mesh_train_step(
 
     def per_device_loss(params, batch_stats, batch, rng):
         if mixed_precision:
-            from ..train.loop import mp_cast, mp_restore_stats
+            from ..train.loop import mp_cast, mp_keep, mp_restore_stats
 
-            params, batch = mp_cast(params, batch, compute_grad_energy)
+            params, batch = mp_cast(params, batch, compute_grad_energy, mp_keep(model))
         variables = {"params": params, "batch_stats": batch_stats}
         (tot, tasks, mutated, _), acts = obs_numerics.run_probed(
             use_numerics, meta,
@@ -628,10 +628,10 @@ def make_mesh_eval_step(objective: Objective, table: R.RuleTable, mesh: Mesh):
         variables = state.variables()
         if mixed_precision:
             # keep eval numerics identical to the single-host eval step
-            from ..train.loop import mp_cast_eval
+            from ..train.loop import mp_cast_eval, mp_keep
 
             variables, batch = mp_cast_eval(
-                variables, batch, compute_grad_energy
+                variables, batch, compute_grad_energy, mp_keep(model)
             )
         tot, tasks, _, _ = compute_loss(
             model, variables, batch, cfg, False, None, compute_grad_energy

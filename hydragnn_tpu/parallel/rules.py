@@ -53,6 +53,11 @@ PLACED_SCOPES = ("params", "opt_state", "batch_stats")
 # one model-family fact the branch/mp presets encode
 DECODER_PATTERN = r"(^|/)(graph_shared|heads_NN|readout)"
 
+# expert banks of the decoder stack (models/zaya.py ``experts_gate`` /
+# ``experts_up`` / ``experts_down``, ``[experts held, ...]``): the leading
+# axis is the expert axis
+EXPERT_PATTERN = r"(^|/)experts_(gate|up|down)$"
+
 # default ZeRO eligibility threshold (parallel/mesh.py historical default)
 DEFAULT_MIN_SIZE = 1024
 
@@ -354,6 +359,23 @@ def _branch_rules(num_branches: int) -> Tuple[Rule, ...]:
             ),
         ),
         _replicated_default(),
+    )
+
+
+def expert_rule(experts_held: int) -> Rule:
+    """The expert axis of the decoder stack's expert banks over the model
+    axis, for inline tables. Which experts a chip holds is a statement of the
+    configuration (``Architecture.experts_held``): the layer computes the
+    tokens routed to those and adds nothing for the rest. With a model axis of
+    one chip the bank is whole on it and the layer runs without an exchange;
+    the exchange of tokens between the chips of a wider model axis does not
+    exist yet (ROADMAP "Reach"), and no table here asks for it."""
+    return Rule(
+        pattern=EXPERT_PATTERN,
+        axes=(MODEL,),
+        scope=PLACED_SCOPES,
+        leading_eq=int(experts_held),
+        reason="expert banks [experts held, ...] over the model axis",
     )
 
 

@@ -402,7 +402,7 @@ class GraphServer:
         import jax
 
         from ..train.compile_plane import note_trace
-        from ..train.loop import mp_cast_eval
+        from ..train.loop import mp_cast_eval, mp_keep
 
         model = self.model
         quantized = self.cfg.weights_dtype == "int8"
@@ -426,7 +426,7 @@ class GraphServer:
             note_trace("serve_predict", (state, batch))
             variables = state.variables()
             if mixed_precision:
-                variables, batch = mp_cast_eval(variables, batch, False)
+                variables, batch = mp_cast_eval(variables, batch, False, mp_keep(model))
             if w8a8:
                 with nn.intercept_methods(w8a8_interceptor):
                     return model.apply(variables, batch, train=False)

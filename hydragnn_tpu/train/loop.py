@@ -53,22 +53,32 @@ def cast_batch_bf16(batch: GraphBatch, keep_pos: bool = False) -> GraphBatch:
     return batch.replace(**upd)
 
 
-def cast_floats(tree, dtype):
-    return jax.tree_util.tree_map(
-        lambda p: p.astype(dtype)
-        if isinstance(p, jnp.ndarray) and jnp.issubdtype(p.dtype, jnp.floating)
-        else p,
-        tree,
-    )
+def cast_floats(tree, dtype, keep=None):
+    """Every floating leaf cast to ``dtype``; ``keep(leaf name)`` true leaves
+    one as it is (a model's ``float32_leaves``, see ``mp_keep``)."""
+    def cast(path, p):
+        if not (isinstance(p, jnp.ndarray) and jnp.issubdtype(p.dtype, jnp.floating)):
+            return p
+        if keep is not None and path and keep(str(getattr(path[-1], "key", ""))):
+            return p
+        return p.astype(dtype)
+
+    return jax.tree_util.tree_map_with_path(cast, tree)
 
 
-def mp_cast(params, batch, compute_grad_energy: bool):
+def mp_keep(model):
+    """The model's own statement of which parameter leaves the mixed-precision
+    cast leaves in float32 (``float32_leaves(leaf name) -> bool``), or None."""
+    return getattr(model, "float32_leaves", None)
+
+
+def mp_cast(params, batch, compute_grad_energy: bool, keep=None):
     """The mixed-precision input cast, shared by the single-device and mesh
     step builders so their numerics stay byte-identical: bf16 params + bf16
     input channels (f32 positions under the autograd-force objective)."""
     with tr.scope(tr.HG_CAST):
         return (
-            cast_floats(params, jnp.bfloat16),
+            cast_floats(params, jnp.bfloat16, keep),
             cast_batch_bf16(batch, keep_pos=compute_grad_energy),
         )
 
@@ -84,11 +94,11 @@ def mp_restore_stats(mutated: dict) -> dict:
     return mutated
 
 
-def mp_cast_eval(variables, batch, compute_grad_energy: bool):
+def mp_cast_eval(variables, batch, compute_grad_energy: bool, keep=None):
     """Eval-side cast: bf16 params AND running stats (eval normalizes with
     the running statistics, unlike training)."""
     variables = {
-        "params": cast_floats(variables["params"], jnp.bfloat16),
+        "params": cast_floats(variables["params"], jnp.bfloat16, keep),
         "batch_stats": cast_floats(
             variables.get("batch_stats", {}), jnp.bfloat16
         ),
@@ -145,7 +155,7 @@ def make_train_step(
 
     def loss_fn(params, batch_stats, batch, rng):
         if mixed_precision:
-            params, batch = mp_cast(params, batch, compute_grad_energy)
+            params, batch = mp_cast(params, batch, compute_grad_energy, mp_keep(model))
         variables = {"params": params, "batch_stats": batch_stats}
         (tot, tasks, mutated, _), acts = obs_numerics.run_probed(
             use_numerics, meta,
@@ -247,7 +257,7 @@ def make_eval_step(
         variables = state.variables()
         if mixed_precision:
             variables, batch = mp_cast_eval(
-                variables, batch, compute_grad_energy
+                variables, batch, compute_grad_energy, mp_keep(model)
             )
         tot, tasks, _, outputs = compute_loss(
             model, variables, batch, cfg, False, None, compute_grad_energy
@@ -527,6 +537,12 @@ def train_epoch(loader, step_fn, state, rng, start_batch: int = 0,
         (float(t), {k: float(v) for k, v in d.items()}, n)
         for t, d, n in entries
     ]
+    # the steps' counters (tr.COUNTER_PREFIX entries) go to the tracer's
+    # table, summed; they stay in the tasks too
+    for _, d, _ in entries:
+        for k, v in d.items():
+            if k.startswith(tr.COUNTER_PREFIX):
+                tr.count(k, v)
     if guard_log is not None and step_meta is not None:
         # non-finite loss census -> batch provenance for the guard-skip
         # event (grad-only NaNs keep a finite loss; the NaN watch covers

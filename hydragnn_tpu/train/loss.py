@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from ..data.graph import GraphBatch
 from ..models.base import ModelConfig
+from ..utils import tracer as tr
 
 
 def _elementwise(loss_type: str, err: jnp.ndarray) -> jnp.ndarray:
@@ -102,6 +103,73 @@ def _per_branch_head_loss(
     return out
 
 
+def chunked_cross_entropy(hidden, head, targets, weights, chunk_rows: int):
+    """Sum over rows of ``weights * (logsumexp(hidden @ head) - logit[target])``
+    with the logits in float32 and only ``chunk_rows`` rows of them alive at
+    a time, forward and backward (each chunk is recomputed in the backward):
+    ``[32768, 32784]`` float32 logits whole would be 4.3 GB. ``hidden [T, D]``,
+    ``head [D, V]``, ``targets [T]`` int, ``weights [T]`` float32."""
+    t = hidden.shape[0]
+    chunk = max(1, min(int(chunk_rows), t))
+    pad = (-t) % chunk
+    if pad:
+        hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
+        targets = jnp.pad(targets, (0, pad))
+        weights = jnp.pad(weights, (0, pad))
+    n_chunks = (t + pad) // chunk
+
+    @jax.checkpoint
+    def one(h, tgt, w):
+        logits = jnp.dot(h, head.astype(h.dtype), preferred_element_type=jnp.float32,
+                         precision="highest" if h.dtype == jnp.float32 else None)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+        return jnp.sum(w * (lse - picked))
+
+    def body(total, xs):
+        return total + one(*xs), None
+
+    total, _ = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        (hidden.reshape(n_chunks, chunk, -1), targets.reshape(n_chunks, chunk),
+         weights.reshape(n_chunks, chunk)))
+    return total
+
+
+def token_loss(hidden, head, batch: GraphBatch, chunk_rows: int):
+    """The token head: mean cross-entropy of the NEXT node's id over the real
+    nodes whose next node is in the same graph, through the tied head
+    ``head [D, V]``. Ids ride in ``batch.z``; float32 throughout."""
+    with tr.scope(tr.HG_TOKEN_LOSS):
+        ids = jnp.clip(batch.z.astype(jnp.int32), 0, head.shape[1] - 1)
+        nxt = jnp.roll(ids, -1)
+        same = (jnp.roll(batch.node_graph, -1) == batch.node_graph) & (
+            jnp.roll(batch.node_mask, -1) & batch.node_mask)
+        same = same.at[-1].set(False)
+        w = same.astype(jnp.float32)
+        total = chunked_cross_entropy(hidden, head, nxt, w, chunk_rows)
+        return total / jnp.maximum(jnp.sum(w), 1.0)
+
+
+def _apply(model, variables, batch, train, rng):
+    """-> (outputs, mutated collections): batch statistics are mutable in
+    training only."""
+    if train:
+        return model.apply(variables, batch, train=True, mutable=["batch_stats"],
+                           rngs={"dropout": rng})
+    return model.apply(variables, batch, train=False), {}
+
+
+def _token_head_loss(model, variables, batch, cfg, train, rng):
+    outputs, mutated = _apply(model, variables, batch, train, rng)
+    name = cfg.output_names[0]
+    loss = token_loss(outputs[name], variables["params"]["embedding"], batch,
+                      cfg.zaya.loss_chunk_rows)
+    tasks = {name: loss}
+    tasks.update({k: v for k, v in outputs.items() if k.startswith(tr.COUNTER_PREFIX)})
+    return loss, tasks, mutated, {name: outputs[name]}
+
+
 def compute_loss(
     model,
     variables: Dict,
@@ -114,6 +182,8 @@ def compute_loss(
     """Single entry point for both objectives, shared by the single-device and
     mesh-parallel step builders: returns (total, per-task losses, mutated
     collections, outputs)."""
+    if cfg.zaya is not None:
+        return _token_head_loss(model, variables, batch, cfg, train, rng)
     if compute_grad_energy:
         def apply_outputs(b):
             if train:
@@ -128,16 +198,7 @@ def compute_loss(
 
         tot, tasks, aux, preds = energy_force_loss(apply_outputs, batch, cfg)
         return tot, tasks, aux or {}, preds
-    if train:
-        outputs, mutated = model.apply(
-            variables,
-            batch,
-            train=True,
-            mutable=["batch_stats"],
-            rngs={"dropout": rng},
-        )
-    else:
-        outputs, mutated = model.apply(variables, batch, train=False), {}
+    outputs, mutated = _apply(model, variables, batch, train, rng)
     tot, tasks = multitask_loss(outputs, batch, cfg)
     return tot, tasks, mutated, outputs
 

@@ -51,10 +51,16 @@ def make_optimizer(
     ``Optimizer.clip_grad_norm`` (off by default) prepends global-norm
     gradient clipping — the stability guard for deep multiplicative stacks
     (e.g. PaiNN-update chains in conv node heads), where a single outlier
-    step can blow the scalar/vector product streams past float range."""
+    step can blow the scalar/vector product streams past float range.
+
+    ``Optimizer.warmup_steps`` (off by default) ramps the rate linearly over
+    the first N optimizer steps: a decoder with a top-1 router does not
+    survive AdamW's first unit-sized updates at the full rate
+    (models/zaya.py)."""
     kind = opt_config.get("type", "AdamW")
     lr = float(opt_config.get("learning_rate", 1e-3))
     clip = float(opt_config.get("clip_grad_norm", 0.0) or 0.0)
+    warmup = int(opt_config.get("warmup_steps", 0) or 0)
     if kind not in _OPT_TABLE:
         raise ValueError(f"unknown optimizer {kind!r}; known: {sorted(_OPT_TABLE)}")
 
@@ -70,9 +76,19 @@ def make_optimizer(
 
     # inject_hyperparams makes learning_rate runtime-adjustable so the
     # plateau scheduler can scale it between epochs without recompiling.
+    if warmup <= 0:
+        return optax.inject_hyperparams(
+            lambda learning_rate: build(learning_rate)
+        )(learning_rate=lr)
+    # Optimizer.warmup_steps: a linear ramp counted in optimizer steps (step k
+    # of 1..W runs at k/W of the rate), a factor beside learning_rate, so the
+    # epoch ramp and the plateau scheduler keep scaling the rate itself
     return optax.inject_hyperparams(
-        lambda learning_rate: build(learning_rate)
-    )(learning_rate=lr)
+        lambda learning_rate, warmup_factor: build(learning_rate * warmup_factor)
+    )(
+        learning_rate=lr,
+        warmup_factor=lambda count: jax.numpy.minimum((count + 1.0) / warmup, 1.0),
+    )
 
 
 _OPT_TABLE = {

@@ -37,6 +37,8 @@ SEGMENT = "segment_sum"
 FUSED_EDGE = "fused_edge"
 MULTI_AGG = "multi_agg"
 FLASH = "flash_attention"
+FLASH_CAUSAL = "flash_attention_causal"
+GROUPED_EXPERT = "grouped_expert"
 INT8_DOT = "int8_dot"
 
 
@@ -96,6 +98,30 @@ KERNELS: Dict[str, KernelSpec] = {
             "block_k": (128, 256, 512),
         },
     ),
+    # the decoder's causal grouped-query launch of the same kernel, forward
+    # and tiled backward (flash_causal_attention): graphs of thousands of
+    # nodes, so its pinned tiles are larger than the GPS launch's
+    FLASH_CAUSAL: KernelSpec(
+        kernel=FLASH_CAUSAL,
+        params=("block_q", "block_k"),
+        defaults={"block_q": 512, "block_k": 512},
+        grid={
+            "block_q": (256, 512, 1024),
+            "block_k": (256, 512, 1024),
+        },
+    ),
+    # grouped product over the experts held (ops/pallas_grouped_matmul.py):
+    # block_m is also the alignment of each expert's rows
+    GROUPED_EXPERT: KernelSpec(
+        kernel=GROUPED_EXPERT,
+        params=("block_m", "block_n", "block_k"),
+        defaults={"block_m": 512, "block_n": 1024, "block_k": 512},
+        grid={
+            "block_m": (256, 512, 1024),
+            "block_n": (512, 1024, 2048),
+            "block_k": (256, 512, 1024),
+        },
+    ),
     # int8 inference matmul (ops/quant.py int8_matmul): its own table axis
     # keyed under dtype="int8" so quantized executables are tuned and
     # looked up separately from the f32/bf16 plans for the same shapes
@@ -121,8 +147,10 @@ def kernel_version(kernel: str) -> int:
         from ..ops import pallas_fused_edge as m
     elif kernel == MULTI_AGG:
         from ..ops import pallas_multi_agg as m
-    elif kernel == FLASH:
+    elif kernel in (FLASH, FLASH_CAUSAL):
         from ..ops import pallas_flash_attention as m
+    elif kernel == GROUPED_EXPERT:
+        from ..ops import pallas_grouped_matmul as m
     elif kernel == INT8_DOT:
         from ..ops import quant as m
     else:
@@ -169,11 +197,21 @@ def normalize(kernel: str, plan: Dict[str, int],
             p["block_rows"], p["block_edges"], p["block_cols"],
         )
         return {"block_rows": nb, "block_edges": eb, "block_cols": cb}
-    if kernel == FLASH:
+    if kernel in (FLASH, FLASH_CAUSAL):
         from ..ops.pallas_flash_attention import normalize_tiles
 
         bq, bk = normalize_tiles(p["block_q"], p["block_k"])
         return {"block_q": bq, "block_k": bk}
+    if kernel == GROUPED_EXPERT:
+        from ..ops.pallas_grouped_matmul import normalize_tiles
+
+        bm, bn, bk = normalize_tiles(
+            int(shapes.get("rows", 0)), int(shapes.get("k", 0)),
+            int(shapes.get("n", 0)),
+            p["block_m"], p["block_n"], p["block_k"],
+            shapes.get("dtype", "bfloat16"),
+        )
+        return {"block_m": bm, "block_n": bn, "block_k": bk}
     if kernel == INT8_DOT:
         from ..ops.quant import normalize_tiles
 

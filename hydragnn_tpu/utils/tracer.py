@@ -46,6 +46,19 @@ HG_CAST = "hg_cast"  # the bf16 casts of mixed precision
 HG_LOSS = "hg_loss"  # value_and_grad: jvp( = forward, transpose( = backward
 HG_OPTIMIZER = "hg_optimizer"  # tx.update + apply_updates
 HG_GUARD = "hg_guard"  # step_ok + the guarded select
+HG_CCA_CONV = "hg_cca_conv"  # ZAYA: the two causal convolutions + q-k mean of a CCA sublayer
+HG_ROUTER = "hg_router"  # ZAYA: the float32 router MLP, top-1 choice and expert layout
+HG_MOE = "hg_moe"  # ZAYA: gather to expert rows, the grouped products, scatter back
+HG_TOKEN_LOSS = "hg_token_loss"  # the chunked next-node cross-entropy
+
+# -- counters: per-step scalars a step carries among its per-task entries
+#    under COUNTER_PREFIX, summed here at the epoch drain (train/loop.py) -----
+COUNTER_PREFIX = "count:"
+CT_TOKENS = "count:tokens"  # real nodes x expert layers
+CT_TOKENS_ROUTED_HERE = "count:tokens_routed_here"  # tokens whose expert is held, over layers
+CT_EXPERT_LOAD_MAX = "count:expert_load_max"  # largest load of a held expert, summed over layers
+CT_EXPERT_LOAD_MEAN = "count:expert_load_mean"  # mean load of the held experts, summed over layers
+CT_CAUSAL_PAIRS = "count:causal_pairs"  # (query, key) pairs within graphs, one layer's
 
 # -- Pallas kernels: pallas_call(name=...) inside a scope of the same name;
 #    the custom-JVP tangent rule runs under <name> + TANGENT ----------------
@@ -53,7 +66,9 @@ HG_FUSED_EDGE = "hg_fused_edge"
 HG_SORTED_SEGMENT = "hg_sorted_segment"
 HG_MULTI_AGG = "hg_multi_agg"
 HG_FLASH_ATTENTION = "hg_flash_attention"
+HG_GROUPED_EXPERT = "hg_grouped_expert"
 TANGENT = "_tangent"
+BWD = "_bwd"  # a kernel's own backward launches (custom-VJP kernels)
 
 _enabled = False
 # HYDRAGNN_TRACE_LEVEL > 0, read once at enable(): drain at every region edge
@@ -240,6 +255,23 @@ def profile(name: str):
         return wrapped
 
     return deco
+
+
+def count(name: str, value: float) -> None:
+    """Add ``value`` to counter ``name`` in the region table (``total`` is
+    the running sum, ``count`` the number of additions)."""
+    if not _enabled:
+        return
+    with _lock:
+        rec = _regions.get(name)
+        if rec is None:
+            rec = _regions[name] = {
+                "count": 0.0, "total": 0.0, "min": float("inf"), "max": 0.0
+            }
+        rec["count"] += 1
+        rec["total"] += value
+        rec["min"] = min(rec["min"], value)
+        rec["max"] = max(rec["max"], value)
 
 
 def get_regions() -> Dict[str, Dict[str, float]]:
