@@ -19,7 +19,9 @@ It refuses any backend but ``tpu`` before building data, then drives
    with the lowered programs checked for Mosaic custom calls and the kernel
    route compared with the dense route on one real batch;
 3. the second-order leg: one ``compute_grad_energy`` epoch at the
-   ``examples/md17`` widths through the sorted-segment kernel;
+   ``examples/md17`` widths through the sorted-segment kernel, then an EGNN's
+   energy-force gradient through the receiver gather's transposed kernel call
+   against the same gradient through the plain gather;
 4. with more than one device, the same EGNN through the mesh step
    (``Optimizer.zero_stage: 2``) over all of them.
 
@@ -92,6 +94,20 @@ def _rel_err(out, ref, norm=lambda x: np.max(np.abs(x))) -> float:
     assert out.shape == ref.shape, (out.shape, ref.shape)
     assert np.isfinite(out).all(), "non-finite kernel output"
     return float(norm(out - ref) / (norm(ref) + 1e-30))
+
+
+def _timed_ms(fn, *args, repeats: int = 5):
+    """``fn(*args)`` once to compile, then the median wall time of
+    ``repeats`` calls in milliseconds, each waited for (a set-up fact)."""
+    import jax
+
+    out = jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        out = jax.block_until_ready(fn(*args))
+        times.append(1e3 * (time.perf_counter() - t0))
+    return out, float(np.median(times))
 
 
 def _check(name: str, err: float, tol: float) -> None:
@@ -276,6 +292,31 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
                 f32(q), f32(k), f32(v), node_graph, node_mask, g, nmax)
         return [(str(plan), _rel_err(out[:n_real], ref[:n_real]))]
 
+    def gather_transpose(c, dtype, ids, n_nodes, max_degree):
+        """The VJP of ``ops/segment.py gather(sorted_ids=True)`` (the
+        sorted-segment kernel, routed as the step routes it) against the
+        scatter-add JAX derives from ``x[ids]``, both read against a float32
+        sum of the same cotangents; padding edges carry a zero cotangent, as
+        in a step, so the dummy node's row is compared too."""
+        from hydragnn_tpu.ops.segment import gather
+
+        e = ids.shape[0]
+        x = arr((n_nodes, c), dtype)
+        ct = jnp.where((ids < n_nodes - 1)[:, None], arr((e, c), dtype), 0)
+        vjp_of = lambda take: jax.jit(
+            lambda v, t: jax.vjp(take, v)[1](t)[0])
+        routed = vjp_of(lambda v: gather(v, ids, True, max_degree))
+        scatter = vjp_of(lambda v: v[ids])
+        assert "hg_sorted_segment" in str(jax.make_jaxpr(routed)(x, ct)), (
+            "the sorted gather did not take the kernel route")
+        ref = jax.ops.segment_sum(f32(ct), ids, num_segments=n_nodes)
+        out, ms = _timed_ms(routed, x, ct)
+        out_scatter, ms_scatter = _timed_ms(scatter, x, ct)
+        return [(f"vjp {ms:.2f} ms", _rel_err(out, ref)),
+                # read, not held: XLA accumulates in the operand dtype
+                (f"the scatter-add it replaces {ms_scatter:.2f} ms",
+                 _rel_err(out_scatter, ref), 1.0)]
+
     if cell_shape:
         # FIRST, the shape the bf16 training step runs since the edge length
         # joins the feature stream in bf16 (models/layers.py
@@ -288,6 +329,13 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
                lambda: fused_edge(channels[0], jnp.bfloat16, cell_ids,
                                   cell["n_nodes"], cell["max_degree"],
                                   tangent=True))
+        # the receiver gather's transpose at the same shape: four of a
+        # step's eight edge-sized scatter-adds are this call
+        yield (f"gather_transpose cell c={channels[0]} n={cell['n_nodes']} "
+               f"e={cell['edges']} deg<={cell['max_degree']} bfloat16",
+               "bfloat16",
+               lambda: gather_transpose(channels[0], jnp.bfloat16, cell_ids,
+                                        cell["n_nodes"], cell["max_degree"]))
     for dtype in (jnp.bfloat16, jnp.float32):
         dt = jnp.dtype(dtype).name
         for c in channels:
@@ -359,10 +407,8 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
     times = {}
 
     def timed(name, fn, *args):
-        out = jax.block_until_ready(fn(*args))
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))
-        times[name] = round(1e3 * (time.perf_counter() - t0), 2)
+        out, ms = _timed_ms(fn, *args, repeats=1)
+        times[name] = round(ms, 2)
         return out
 
     for dt in dtypes:
@@ -639,7 +685,85 @@ def second_order_leg(check_lowering=True) -> dict:
         print(f"  tpu_custom_call sites in the energy-force train step: {n_calls}")
         n_layers = config["NeuralNetwork"]["Architecture"]["num_conv_layers"]
         assert n_calls >= n_layers, n_calls
-    return {"losses": {"train": [float(x) for x in hist["train"]]}}
+    return {"losses": {"train": [float(x) for x in hist["train"]]},
+            "egnn_force_gradient_gap": egnn_force_gradient_gap()}
+
+
+def egnn_force_gradient_gap(hidden=64, tol=5e-3) -> float:
+    """Grad-of-grad through the receiver gather's transpose (ops/segment.py
+    ``gather(sorted_ids=True)``: a linear call whose JVP is itself on the
+    tangent): the energy-force training gradient of a two-layer EGNN (the
+    fused call's tangent rule in both layers) on a packed, padded
+    Lennard-Jones batch, every parameter leaf against the same step with the
+    plain gather. Returns the largest gap, by each leaf's largest entry."""
+    import jax
+
+    import hydragnn_tpu.models.layers as layers
+    import hydragnn_tpu.ops.pallas_fused_edge as fused
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.data import GraphLoader, lennard_jones_dataset
+    from hydragnn_tpu.data.pipeline import split_dataset
+    from hydragnn_tpu.models import create_model, init_model
+    from hydragnn_tpu.train.loss import compute_loss
+
+    datasets = split_dataset(lennard_jones_dataset(64), 0.75, seed=0)
+    config = update_config({
+        "Verbosity": {"level": 0},
+        "Dataset": {"node_features": {"name": ["type"], "dim": [1]}},
+        "NeuralNetwork": {
+            "Architecture": {
+                "mpnn_type": "EGNN", "radius": 2.5, "max_neighbours": 32,
+                "hidden_dim": hidden, "num_conv_layers": 2,
+                "task_weights": [1.0],
+                "output_heads": {"node": {
+                    "num_headlayers": 2, "dim_headlayers": [hidden, hidden],
+                    "type": "mlp"}},
+            },
+            "Variables_of_interest": {
+                "input_node_features": [0], "output_names": ["graph_energy"],
+                "output_index": [0], "output_dim": [1], "type": ["node"],
+            },
+            "Training": {
+                "num_epoch": 1, "batch_size": 16, "compute_grad_energy": True,
+                "Optimizer": {"type": "AdamW", "learning_rate": 1e-3},
+            },
+        },
+    }, *datasets)
+    _assert_kernel_routes_on(config)
+    batch = next(iter(GraphLoader(
+        datasets[0], 16, seed=0, drop_last=True, sort_edges=True, pack=True,
+        max_in_degree=config["NeuralNetwork"]["Architecture"]["max_in_degree"])))
+    assert not np.asarray(batch.edge_mask).all(), "no padding edge in the batch"
+    model = create_model(config)
+    variables = init_model(model, batch, seed=0)
+
+    def loss(params):
+        return compute_loss(
+            model, {"params": params, "batch_stats": variables.get("batch_stats", {})},
+            batch, model.cfg, True, jax.random.PRNGKey(0), True)[0]
+
+    def leaves():
+        grad = jax.jit(jax.grad(loss))
+        text = str(jax.make_jaxpr(grad)(variables["params"]))
+        return text.count("= linear_call["), [
+            np.asarray(g, np.float64) for g in jax.tree_util.tree_leaves(
+                grad(variables["params"]))]
+
+    calls, routed = leaves()
+    assert calls > 0, "the energy-force gradient holds no transposed gather"
+    plain_gather = lambda values, index, *a, **k: values[index]
+    with mock.patch.object(layers, "gather", plain_gather), \
+            mock.patch.object(fused, "gather", plain_gather):
+        plain_calls, plain = leaves()
+    assert plain_calls == 0, plain_calls
+    floor = 1e-3 * max(np.abs(g).max() for g in plain)
+    gap = max(np.abs(a - b).max() / max(np.abs(b).max(), floor)
+              for a, b in zip(routed, plain))
+    print(f"  EGNN energy-force gradient, transposed gather against plain: "
+          f"{calls} linear calls, largest leaf gap {gap:.3e} (tol {tol:.0e})",
+          flush=True)
+    assert np.isfinite(gap) and gap <= tol, gap
+    return float(gap)
 
 
 # ---------------------------------------------------------------------------

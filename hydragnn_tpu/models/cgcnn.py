@@ -37,7 +37,8 @@ class CGConv(nn.Module):
             )
             return hoisted_pair_dense(
                 self.output_dim, inv, batch, f"{name}_recv", f"{name}_send",
-                terms,
+                terms, sorted_ids=self.sorted_agg,
+                max_degree=self.max_in_degree,
             )
 
         gate = nn.sigmoid(z_proj("gate"))

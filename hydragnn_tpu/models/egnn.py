@@ -93,7 +93,8 @@ class EGCL(nn.Module):
             # post-concat, EGCLStack.py:238-247)
             pre = hoisted_pair_dense(
                 self.hidden_dim, inv, batch, "edge_lin_recv",
-                "edge_lin_send", terms
+                "edge_lin_send", terms, sorted_ids=self.sorted_agg,
+                max_degree=self.max_in_degree,
             )
             act = nn.relu
             edge_feat = act(

@@ -10,6 +10,7 @@ from flax import linen as nn
 
 from ..ops.remat import kernel_remat, tag as remat_tag
 from ..ops.segment import fused_edge_message_sum as _fused_edge_message_sum
+from ..ops.segment import gather
 
 
 def mirrored_lecun_normal():
@@ -206,7 +207,8 @@ def pair_message_factored(dim, inv, batch, name_recv, name_send, edge_terms=()):
     return node_recv, edge_in
 
 
-def hoisted_pair_dense(dim, inv, batch, name_recv, name_send, edge_terms=()):
+def hoisted_pair_dense(dim, inv, batch, name_recv, name_send, edge_terms=(),
+                       sorted_ids=False, max_degree=0):
     """First edge-MLP layer distributed over its concat inputs and computed
     on node-sized operands BEFORE the edge gather:
 
@@ -222,6 +224,11 @@ def hoisted_pair_dense(dim, inv, batch, name_recv, name_send, edge_terms=()):
     ``edge_terms`` is an iterable of (name, [E, d] array) extra edge-aligned
     operands, each getting its own bias-free projection.
 
+    ``sorted_ids`` / ``max_degree`` are the flags the caller's module hands
+    ``segment_sum``: with them the RECEIVER gather's transpose runs as a
+    sorted segment sum (ops/segment.py ``gather``) instead of XLA's
+    scatter-add. The sender gather has no such order and stays as it is.
+
     When the downstream consumer is relu -> Dense -> relu -> segment_sum and
     nothing else reads the per-edge messages, prefer
     ``fused_pair_dense_sum`` below: same parameters, but the whole chain
@@ -230,7 +237,7 @@ def hoisted_pair_dense(dim, inv, batch, name_recv, name_send, edge_terms=()):
     node_recv, edge_in = pair_message_factored(
         dim, inv, batch, name_recv, name_send, edge_terms
     )
-    return node_recv[batch.receivers] + edge_in
+    return gather(node_recv, batch.receivers, sorted_ids, max_degree) + edge_in
 
 
 class _FusedEdgeDense(nn.Module):

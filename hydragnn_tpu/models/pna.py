@@ -28,6 +28,7 @@ from flax import linen as nn
 
 from ..ops.remat import kernel_remat, tag as remat_tag
 from ..ops.segment import (
+    gather,
     multi_moment_agg,
     segment_count,
     segment_max,
@@ -101,7 +102,8 @@ def pna_aggregate(msg, batch, deg_hist, sorted_agg=False, max_in_degree=0,
         deg = cnt[:, None]
     else:
         if node_recv is not None:
-            msg = node_recv[batch.receivers] + msg
+            msg = gather(node_recv, batch.receivers, sorted_agg,
+                         max_in_degree) + msg
         if gate is not None:
             msg = msg * gate
         aggs = [

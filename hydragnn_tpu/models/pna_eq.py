@@ -58,7 +58,8 @@ class PNAEqConv(nn.Module):
         if self.edge_dim and batch.edge_attr is not None:
             terms.append(("pre_attr", nn.Dense(self.node_size)(batch.edge_attr)))
         msg = hoisted_pair_dense(
-            self.node_size, x, batch, "pre_recv", "pre_send", terms
+            self.node_size, x, batch, "pre_recv", "pre_send", terms,
+            sorted_ids=self.sorted_agg, max_degree=self.max_in_degree,
         )
         msg = MLP((self.node_size, self.node_size, 3 * self.node_size),
                   "silu")(nn.tanh(msg))

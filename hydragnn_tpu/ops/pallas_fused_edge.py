@@ -52,6 +52,7 @@ the forward, keeping the training forward VMEM-resident too.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,17 +61,25 @@ from jax.experimental.pallas import tpu as pltpu
 from ..utils import tracer as tr
 
 from .pallas_segment import _pad_to, mxu_precision
+from .segment import gather
 
 
 def reference_edge_message_sum(
-    node_recv, edge_in, weights, bias, segment_ids, num_segments
+    node_recv, edge_in, weights, bias, segment_ids, num_segments,
+    max_degree: Optional[int] = None,
 ):
     """Dense (plain-jnp) statement of the fused computation — the off-TPU
     fallback, the tangent rule, and the identity oracle for tests:
 
         segment_sum(relu(relu(node_recv[ids] + edge_in) @ weights + bias))
+
+    ``max_degree`` is the tangent rule's alone: it holds the kernel's own
+    contract (ascending ids, the in-degree bound), so the transpose of its
+    ``node_recv[ids]`` gather is a sorted segment sum (ops/segment.py
+    ``gather``). The fallback and the oracle leave it out and get the plain
+    gather, as every route does where the Pallas route is off.
     """
-    pre = node_recv[segment_ids] + edge_in
+    pre = gather(node_recv, segment_ids, True, max_degree) + edge_in
     msg = jax.nn.relu(jnp.dot(jax.nn.relu(pre), weights) + bias)
     return jax.ops.segment_sum(msg, segment_ids, num_segments=num_segments)
 
@@ -308,7 +317,7 @@ def _fused_jvp(
     # this rule again. The primal-dependent residuals (relu masks, pre) are
     # what jax.checkpoint at the call site pushes into the backward.
     fn = lambda nr, ei, w, b: reference_edge_message_sum(
-        nr, ei, w, b, segment_ids, num_segments
+        nr, ei, w, b, segment_ids, num_segments, max_degree
     )
     with tr.scope(tr.HG_FUSED_EDGE + tr.TANGENT):
         _, t_out = jax.jvp(

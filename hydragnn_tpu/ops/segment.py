@@ -16,7 +16,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils import envflags
+from jax.custom_derivatives import linear_call
+
+from ..utils import envflags, tracer as tr
 
 
 def _pallas_route_enabled() -> bool:
@@ -290,9 +292,53 @@ def segment_softmax(logits, segment_ids, num_segments, mask=None):
     return exp / jnp.maximum(denom[segment_ids], 1e-16)
 
 
-def gather(values, index):
-    """Row gather: values[index] — spelled out for symmetry with scatter."""
-    return jnp.take(values, index, axis=0)
+def gather(
+    values,
+    index,
+    sorted_ids: bool = False,
+    max_degree: Optional[int] = None,
+):
+    """Row gather ``values[index]``, the read side of ``segment_sum``.
+
+    JAX transposes a gather into a scatter-add, which XLA runs a row at a
+    time. Over receiver-sorted ``index`` that transpose IS a sorted segment
+    sum, so under the predicate ``segment_sum`` itself routes on
+    (``sorted_ids`` + a static in-degree bound ``max_degree`` + 2-D values +
+    the Pallas route) the gather becomes a linear call whose transpose is
+    ``segment_sum(ct, index, rows, sorted_ids=True, max_degree=...)``: the
+    ``hg_sorted_segment`` kernel under the scope ``hg_gather_transpose``.
+    The forward is the same ``values[index]`` on every route.
+
+    ``jax.custom_derivatives.linear_call`` is the tool: it is transposable
+    (a ``custom_vjp`` on a tangent path is not, and the fused-edge tangent
+    rule pushes this gather through ``jax.jvp``), its JVP is itself on the
+    tangent, and its transpose is again a ``linear_call`` with the roles
+    swapped, so grad-of-grad (energy-force training), ``jax.checkpoint`` and
+    ``shard_map`` compose. It has no batching rule: do not ``vmap`` it (the
+    conv stacks never are).
+
+    The transpose inherits the kernel's contract: a row gathered more than
+    ``max_degree`` times gets an UNSPECIFIED cotangent. That is the dummy
+    node, which receives every padding edge; its row is exact only while
+    every padding edge's cotangent is zero, which holds where each consumer
+    of the gathered rows masks with ``edge_mask`` or lands on the dummy
+    node's masked output (tests/test_fused_edge.py holds every stack that
+    passes its flags to it).
+    """
+    if sorted_ids and max_degree and values.ndim == 2 and _pallas_route_enabled():
+        num_rows = values.shape[0]
+
+        def take(ids, x):
+            return x[ids]
+
+        def take_transpose(ids, ct):
+            with tr.scope(tr.HG_GATHER_TRANSPOSE):
+                return segment_sum(
+                    ct, ids, num_rows, sorted_ids=True, max_degree=max_degree
+                )
+
+        return linear_call(take, take_transpose, index, values)
+    return values[index]
 
 
 def masked_global_mean_pool(x, node_graph, num_graphs, node_mask):

@@ -68,6 +68,9 @@ HG_MULTI_AGG = "hg_multi_agg"
 HG_FLASH_ATTENTION = "hg_flash_attention"
 HG_GROUPED_EXPERT = "hg_grouped_expert"
 TANGENT = "_tangent"
+# the transpose of ops/segment.py gather(sorted_ids=True): a scope AROUND the
+# hg_sorted_segment scope and call, which tells a transposed sum from a forward one
+HG_GATHER_TRANSPOSE = "hg_gather_transpose"
 BWD = "_bwd"  # a kernel's own backward launches (custom-VJP kernels)
 
 _enabled = False

@@ -10,6 +10,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 import hydragnn_tpu.config.config as config_mod
 
@@ -82,3 +83,28 @@ def pytest_chip_smoke_cell_shape_case_rehearsed():
     ids = smoke._cell_ids(np.random.default_rng(0), **smoke.CELL_SHAPE)
     assert ids.shape == (196608,) and (np.diff(ids) >= 0).all()
     assert np.bincount(ids[ids < 12135]).max() <= 36
+
+
+def pytest_chip_smoke_gather_transpose_case_rehearsed(monkeypatch):
+    """The cell-shape case of the receiver gather's transpose comes SECOND
+    (after the fused-edge call whose tangent rule uses it); at a tiny size,
+    with the route forced on in interpret mode, the kernel's VJP holds the
+    bf16 tolerance with the dummy node's row compared, and the scatter-add
+    it replaces is read beside it."""
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    smoke = _load_smoke()
+    tiny = {"n_nodes": 90, "edges": 700, "max_degree": 9, "mean_degree": 5.0}
+    cases = iter(smoke.kernel_cases(
+        channels=(24,), n_nodes=70, max_degree=8, interpret=True,
+        cell_shape=tiny))
+    next(cases)
+    name, dt, check = next(cases)
+    assert name.startswith("gather_transpose cell") and dt == "bfloat16", name
+    (vjp, vjp_err), (scatter, scatter_err, scatter_tol) = check()
+    assert vjp.startswith("vjp ") and vjp.endswith(" ms"), vjp
+    assert vjp_err <= smoke.TOL[dt], vjp_err
+    assert "scatter-add" in scatter and scatter_err <= scatter_tol == 1.0
+    # with the route off the case refuses to time a plain gather as the kernel
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "0")
+    with pytest.raises(AssertionError, match="kernel route"):
+        check()

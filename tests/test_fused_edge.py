@@ -385,6 +385,192 @@ def pytest_energy_force_step_fused_equals_dense(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the receiver gather's transpose (ops/segment.py gather(sorted_ids=True)):
+# on a packed, PADDED batch every gradient leaf equals the plain gather's.
+# The hazard is the dummy node's row of d(node_recv): the kernel leaves it
+# unspecified, no consumer masks it, and it enters the receiver projection's
+# weight gradient through inv[dummy]. It is exact only while every padding
+# edge's cotangent is zero, which each stack below has to hold.
+# ---------------------------------------------------------------------------
+
+
+def _plain_gather(monkeypatch):
+    """Every call site's ``gather`` back to the bare ``values[index]``: the
+    route the transposed one is compared with, all kernels left on."""
+    import hydragnn_tpu.models.layers as layers
+    import hydragnn_tpu.models.pna as pna
+    import hydragnn_tpu.ops.pallas_fused_edge as fused
+
+    for module in (layers, pna, fused):
+        monkeypatch.setattr(
+            module, "gather", lambda values, index, *a, **k: values[index])
+
+
+def _padded_stack(mpnn_type, equivariance, num_layers=4):
+    """A conv stack with its kernel routes on, and one packed batch whose
+    node and edge slots both end in padding."""
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.data import GraphLoader
+    from hydragnn_tpu.models import create_model, init_model
+
+    tr, va, te = _shaped_graphs()
+    config = copy.deepcopy(_egnn_config(equivariance))
+    config["NeuralNetwork"]["Architecture"].update(
+        mpnn_type=mpnn_type, num_conv_layers=num_layers)
+    config = update_config(config, tr, va, te)
+    arch = config["NeuralNetwork"]["Architecture"]
+    assert arch["use_sorted_aggregation"] and arch["max_in_degree"] > 0
+    loader = GraphLoader(tr, 8, seed=0, drop_last=True, sort_edges=True,
+                         pack=True, max_in_degree=arch["max_in_degree"])
+    batch = next(iter(loader))
+    n_pad = int((~np.asarray(batch.node_mask)).sum())
+    e_pad = int((~np.asarray(batch.edge_mask)).sum())
+    assert n_pad >= 1 and e_pad > arch["max_in_degree"], (n_pad, e_pad)
+    # the layout the hazard is about: every padding edge on the LAST row
+    assert (np.asarray(batch.receivers)[~np.asarray(batch.edge_mask)]
+            == batch.x.shape[0] - 1).all()
+    model = create_model(config)
+    return model, init_model(model, batch, seed=0), batch
+
+
+def _grad_leaves(model, variables, batch, grad_energy=False,
+                 mixed_precision=False):
+    """The training gradient of every parameter leaf, as the step takes it."""
+    from hydragnn_tpu.train.loop import mp_cast, mp_restore_stats
+    from hydragnn_tpu.train.loss import compute_loss
+
+    def loss(params):
+        b = batch
+        if mixed_precision:
+            params, b = mp_cast(params, b, grad_energy)
+        tot, _, _, _ = compute_loss(
+            model,
+            {"params": params, "batch_stats": variables.get("batch_stats", {})},
+            b, model.cfg, True, jax.random.PRNGKey(0), grad_energy)
+        return tot.astype(jnp.float32)
+
+    grads = jax.jit(jax.grad(loss))(variables["params"])
+    return {jax.tree_util.keystr(path): np.asarray(leaf, np.float32)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(grads)}
+
+
+def _absmax(x):
+    return np.abs(x).max()
+
+
+def _assert_leaves_equal(got, ref, tol, norm=_absmax, floor_share=1e-3):
+    """Leaf by leaf, by the leaf's own norm (its largest entry unless told
+    otherwise); a leaf whose gradient is rounding alone (a bias in front of
+    a batch norm: up to 1.8e-3 of the largest leaf's norm in bf16) is read
+    against ``floor_share`` of the largest leaf's."""
+    assert got.keys() == ref.keys()
+    floor = floor_share * max(float(norm(r)) for r in ref.values())
+    for name, r in ref.items():
+        assert np.isfinite(got[name]).all(), name
+        gap = float(norm(got[name] - r)) / max(float(norm(r)), floor)
+        assert gap <= tol, (name, gap, tol)
+
+
+@pytest.mark.parametrize(
+    "mpnn_type,equivariance,mixed_precision,tol",
+    [
+        # the benchmark's model: layers 0-2 equivariant (the unfused gather),
+        # layer 3 fused (the tangent rule's gather)
+        ("EGNN", True, False, 1e-5),
+        # as the cells run it: the forward is the same bit for bit, but XLA's
+        # scatter-add accumulates in bf16 and the kernel in float32, and the
+        # difference passes through up to three more bf16 layers. Read by L2
+        # (measured 0 .. 7.4e-2 a leaf; either route lies 0.2 .. 0.6 from the
+        # float32 gradient on most conv leaves at this width)
+        ("EGNN", True, True, 0.15),
+        # every layer through the fused call's tangent rule
+        ("EGNN", False, False, 1e-5),
+        # the other stacks that hand ``gather`` their flags
+        ("CGCNN", False, False, 1e-5),
+        ("PNAEq", False, False, 1e-5),
+        ("PNA", False, False, 1e-5),
+    ],
+)
+def pytest_transposed_gather_gradients_equal_plain_on_a_padded_batch(
+        monkeypatch, mpnn_type, equivariance, mixed_precision, tol):
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    model, variables, batch = _padded_stack(mpnn_type, equivariance)
+    got = _grad_leaves(model, variables, batch,
+                       mixed_precision=mixed_precision)
+    recv = [k for k in got if "_recv" in k and "kernel" in k]
+    assert recv, sorted(got)  # the leaves the dummy row would reach
+    with monkeypatch.context() as plain:
+        _plain_gather(plain)
+        ref = _grad_leaves(model, variables, batch,
+                           mixed_precision=mixed_precision)
+    if mixed_precision:
+        _assert_leaves_equal(got, ref, tol, np.linalg.norm, floor_share=1e-2)
+    else:
+        _assert_leaves_equal(got, ref, tol)
+    assert any(np.abs(ref[k]).max() > 0 for k in recv)
+
+
+def pytest_transposed_gather_is_in_the_egnn_step(monkeypatch):
+    """The equivariant EGNN's gradient program: one linear call a gather
+    (three unfused layers + the fused layer's tangent rule), each transposed
+    into a sorted-segment kernel call; none with the route off."""
+    model, variables, batch = _padded_stack("EGNN", True)
+
+    def jaxpr():
+        from hydragnn_tpu.train.loss import compute_loss
+
+        loss = lambda p: compute_loss(
+            model, {"params": p, "batch_stats": {}}, batch, model.cfg, True,
+            jax.random.PRNGKey(0), False)[0]
+        return str(jax.make_jaxpr(jax.grad(loss))(variables["params"]))
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    on = jaxpr()
+    with monkeypatch.context() as plain:
+        _plain_gather(plain)
+        off = jaxpr()
+    assert "= linear_call[" not in off
+    # forward + transposed, four gathers
+    assert on.count("= linear_call[") == 8, on.count("= linear_call[")
+    assert on.count("name=hg_sorted_segment") - off.count(
+        "name=hg_sorted_segment") == 4
+    assert off.count("scatter-add") - on.count("scatter-add") == 4
+
+
+def pytest_energy_force_gradients_transposed_equal_plain(monkeypatch):
+    """``compute_grad_energy``: the force is a gradient through the gather,
+    the training gradient differentiates it again (a linear call's JVP is
+    itself on the tangent, its transpose the call with the roles swapped).
+    Every leaf's second-order gradient equals the plain gather's."""
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.data import GraphLoader, lennard_jones_dataset
+    from hydragnn_tpu.data.pipeline import split_dataset
+    from hydragnn_tpu.models import create_model, init_model
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    tr, va, te = split_dataset(lennard_jones_dataset(24), 0.75, seed=0)
+    config = _egnn_config(grad_energy=True)
+    config["NeuralNetwork"]["Architecture"].update(radius=2.5,
+                                                   max_neighbours=32)
+    config["Dataset"] = {"node_features": {"name": ["type"], "dim": [1]}}
+    config = update_config(config, tr, va, te)
+    arch = config["NeuralNetwork"]["Architecture"]
+    loader = GraphLoader(tr, 8, seed=0, drop_last=True, sort_edges=True,
+                         pack=True, max_in_degree=arch["max_in_degree"])
+    batch = next(iter(loader))
+    assert not np.asarray(batch.edge_mask).all()
+    model = create_model(config)
+    variables = init_model(model, batch, seed=0)
+    got = _grad_leaves(model, variables, batch, grad_energy=True)
+    with monkeypatch.context() as plain:
+        _plain_gather(plain)
+        ref = _grad_leaves(model, variables, batch, grad_energy=True)
+    # float32 second order: summation order alone (measured 2.6e-4)
+    _assert_leaves_equal(got, ref, 2e-3)
+    assert any(np.abs(v).max() > 0 for v in ref.values())
+
+
+# ---------------------------------------------------------------------------
 # the shape the bf16 training step runs (benchmarks' EGNN-866 cells), compiled
 # for a described v5e at the real size (tests/test_chip_smoke.py rehearses
 # the chip check's case of it in interpret mode)
@@ -442,3 +628,81 @@ def pytest_bf16_kernel_compiles_for_v5e_at_the_cell_shape(v5e_chip, tangent):
     # of 512), weights
     for stream in ("bf16[12160,896]", "bf16[201728,896]", "bf16[896,896]"):
         assert stream in text, stream
+
+
+def _cell_train_step_text(monkeypatch, v5e_chip):
+    """The EGNN-866 cells' own train step (benchmarks/configs/
+    egnn866_sc25.json, bf16), lowered at the packed cell's batch shape and
+    compiled for the described v5e: its optimised program's text. The kernel
+    routes ask ``jax.default_backend()`` at trace time, so the test answers
+    for it while the step is traced."""
+    import json
+
+    from hydragnn_tpu.config import update_config
+    from hydragnn_tpu.data import GraphLoader, oc20_shaped_dataset
+    from hydragnn_tpu.data.pipeline import split_dataset
+    from hydragnn_tpu.models import create_model, init_model
+    from hydragnn_tpu.train import TrainState, make_optimizer, make_train_step
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmarks", "configs",
+                           "egnn866_sc25.json")) as f:
+        config = json.load(f)["program_config"]
+    config["NeuralNetwork"]["Training"]["batch_size"] = 8
+    config["NeuralNetwork"]["Architecture"].update(
+        use_sorted_aggregation=True, use_fused_edge_kernel=True)
+    datasets = split_dataset(oc20_shaped_dataset(
+        24, mean_atoms=20, min_atoms=10, max_atoms=40, max_neighbours=10),
+        0.8, seed=0)
+    config = update_config(config, *datasets)
+    config["NeuralNetwork"]["Architecture"]["max_in_degree"] = 36
+    batch = next(iter(GraphLoader(
+        datasets[0], 8, seed=0, drop_last=True, sort_edges=True, pack=True,
+        max_in_degree=36)))
+    model = create_model(config)
+    tx = make_optimizer(config["NeuralNetwork"]["Training"]["Optimizer"])
+    state = TrainState.create(init_model(model, batch, seed=0), tx)
+    cell = {batch.x.shape[0]: 12136, batch.senders.shape[0]: 196608}
+    assert len(cell) == 2 and batch.graph_mask.shape[0] not in cell
+
+    def shaped(x, grow=False):
+        aval = jax.api_util.shaped_abstractify(x)
+        shape = tuple(cell.get(d, d) if grow and i == 0 else d
+                      for i, d in enumerate(aval.shape))
+        return jax.ShapeDtypeStruct(shape, aval.dtype, sharding=v5e_chip,
+                                    weak_type=aval.weak_type)
+
+    args = (jax.tree_util.tree_map(shaped, state),
+            jax.tree_util.tree_map(lambda x: shaped(x, True), batch),
+            shaped(jax.random.PRNGKey(0)))
+    with monkeypatch.context() as on_tpu:
+        on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = make_train_step(model, tx, mixed_precision=True).lower(*args)
+    return lowered.compile().as_text()
+
+
+def pytest_cell_train_step_compiles_for_v5e_with_transposed_gathers(
+        monkeypatch, v5e_chip):
+    """The whole bf16 train step of ``egnn866_oc20_train``'s shape
+    (``[12136, 866]`` rows, 196608 edges, in-degree bound 36): Mosaic takes
+    the four transposed calls, ten ``hg_sorted_segment`` calls for six, and
+    four of the eight edge-sized ``bf16[12136,866]`` scatter-adds are gone
+    (the four left transpose the SENDER gathers)."""
+    import re
+
+    def counts(text):
+        # a Mosaic call is an instruction named after its ``pallas_call``
+        mosaic = re.findall(r"^\s*%(hg_[a-z_]+)[.\d]* = .*custom-call\(", text,
+                            re.MULTILINE)
+        return (
+            mosaic.count("hg_sorted_segment"),
+            mosaic.count("hg_fused_edge"),
+            len(re.findall(r"= bf16\[12136,866\]\S* scatter\(", text)),
+        )
+
+    transposed = counts(_cell_train_step_text(monkeypatch, v5e_chip))
+    with monkeypatch.context() as plain:
+        _plain_gather(plain)
+        before = counts(_cell_train_step_text(plain, v5e_chip))
+    assert before == (6, 1, 8), before
+    assert transposed == (10, 1, 4), transposed

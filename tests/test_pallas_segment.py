@@ -163,3 +163,175 @@ def pytest_bf16_messages_stream_without_upcast():
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref), rtol=2e-2, atol=2e-2
     )
+
+
+# ---------------------------------------------------------------------------
+# ops/segment.py gather(sorted_ids=True): a row gather over receiver-sorted
+# ids whose TRANSPOSE is the kernel above (HYDRAGNN_PALLAS_SEGMENT=1 routes
+# it off the TPU, in interpret mode)
+# ---------------------------------------------------------------------------
+
+
+def _padded_ids(kind, rng, n, max_degree):
+    """Receiver-sorted ids of ``n`` rows, the last the dummy node:
+    ``capped`` every row within the bound; ``empty_trailing`` edge-less rows
+    inside and a trailing run of them; ``dummy`` a padded batch's layout,
+    the last row holding several edge windows' worth of padding edges."""
+    if kind == "empty_trailing":
+        return np.array([2, 2, 5, 5, 5, 9], np.int32)
+    deg = rng.integers(0, max_degree + 1, n - 1)
+    deg[3] = 0
+    ids = np.repeat(np.arange(n - 1), deg)
+    if kind == "dummy":
+        ids = np.concatenate([ids, np.full(1500, n - 1)])
+    return ids.astype(np.int32)
+
+
+def _gather_case(kind, dtype, seed=0, n=40, c=6, max_degree=8):
+    rng = np.random.default_rng(seed)
+    ids = _padded_ids(kind, rng, n, max_degree)
+    x = jnp.asarray(rng.normal(size=(n, c)), dtype)
+    ct = rng.normal(size=(ids.shape[0], c))
+    # a padded batch's own rule: a padding edge's cotangent is exactly zero
+    ct[ids == n - 1] = 0.0
+    return jnp.asarray(ids), x, jnp.asarray(ct, dtype), max_degree
+
+
+_GATHER_DTYPES = [(jnp.float32, 1e-6), (jnp.bfloat16, 2e-2)]
+
+
+@pytest.mark.parametrize("dtype,tol", _GATHER_DTYPES)
+@pytest.mark.parametrize("kind", ["capped", "empty_trailing", "dummy"])
+def pytest_sorted_gather_forward_and_vjp(monkeypatch, kind, dtype, tol):
+    """Forward bit for bit ``x[ids]``; the VJP is the scatter-add's (float32
+    accumulation against XLA's in the operand dtype, so bf16 to the kernel's
+    tolerance), with edge-less, trailing and over-cap dummy-node rows."""
+    from hydragnn_tpu.ops.segment import gather
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    ids, x, ct, deg = _gather_case(kind, dtype)
+    out, vjp = jax.vjp(lambda v: gather(v, ids, True, deg), x)
+    assert out.dtype == x.dtype
+    np.testing.assert_array_equal(
+        np.asarray(out, np.float32), np.asarray(x[ids], np.float32))
+    (got,) = vjp(ct)
+    assert got.dtype == x.dtype and got.shape == x.shape
+    ref = jax.ops.segment_sum(
+        ct.astype(jnp.float32), ids, num_segments=x.shape[0])
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(ref), rtol=tol, atol=tol)
+    # the dummy row too: any partial sum of zero cotangents is zero
+    assert not np.asarray(got, np.float32)[-1].any()
+
+
+def pytest_sorted_gather_nonzero_padding_cotangents_stay_in_the_dummy_row(
+        monkeypatch):
+    """The contract's edge: were padding edges to carry a cotangent, only
+    the dummy node's row would be unspecified; every real row stays exact."""
+    from hydragnn_tpu.ops.segment import gather
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    ids, x, _, deg = _gather_case("dummy", jnp.float32)
+    ct = jnp.asarray(
+        np.random.default_rng(1).normal(size=(ids.shape[0], x.shape[1])),
+        jnp.float32)
+    (got,) = jax.vjp(lambda v: gather(v, ids, True, deg), x)[1](ct)
+    ref = jax.ops.segment_sum(ct, ids, num_segments=x.shape[0])
+    np.testing.assert_allclose(
+        np.asarray(got)[:-1], np.asarray(ref)[:-1], rtol=1e-6, atol=1e-6)
+
+
+def _gather_loss(take, ids, w):
+    """A loss with curvature through the gathered rows, masked the way a
+    conv stack masks its padding edges."""
+    real = (ids < ids.max())[:, None]
+
+    def loss(x):
+        return jnp.sum(jnp.where(real, jnp.sin(take(x)) * w, 0.0))
+
+    return loss
+
+
+@pytest.mark.parametrize(
+    "transform",
+    ["jvp", "grad_of_grad", "checkpoint_jit", "jit_grad"],
+)
+def pytest_sorted_gather_composes(monkeypatch, transform):
+    """``jax.jvp`` (the fused-edge tangent rule's use), grad-of-grad
+    (energy-force training), ``jax.checkpoint`` under ``jit`` (the kernels'
+    remat) and a jitted grad: each equals the plain gather's."""
+    from hydragnn_tpu.ops.segment import gather
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    ids, x, w, deg = _gather_case("dummy", jnp.float32, seed=2)
+    t = jnp.asarray(np.random.default_rng(3).normal(size=x.shape), jnp.float32)
+    routed = _gather_loss(lambda v: gather(v, ids, True, deg), ids, w)
+    plain = _gather_loss(lambda v: v[ids], ids, w)
+
+    def apply(loss):
+        if transform == "jvp":
+            return jax.jvp(loss, (x,), (t,))
+        if transform == "grad_of_grad":
+            return jax.grad(lambda v: jnp.sum(jax.grad(loss)(v) ** 2))(x)
+        if transform == "checkpoint_jit":
+            return jax.jit(jax.grad(jax.checkpoint(loss)))(x)
+        return jax.jit(jax.grad(loss))(x)
+
+    for got, ref in zip(jax.tree_util.tree_leaves(apply(routed)),
+                        jax.tree_util.tree_leaves(apply(plain))):
+        scale = max(float(jnp.abs(ref).max()), 1.0)
+        np.testing.assert_allclose(np.asarray(got) / scale,
+                                   np.asarray(ref) / scale,
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "case,route_env,sorted_ids,max_degree,ndim",
+    [
+        ("unsorted", "1", False, 8, 2),
+        ("no_bound", "1", True, None, 2),
+        ("zero_bound", "1", True, 0, 2),
+        ("one_dimensional", "1", True, 8, 1),
+        ("route_off", "0", True, 8, 2),
+        ("cpu_default", None, True, 8, 2),
+    ],
+)
+def pytest_sorted_gather_stays_a_bare_gather(
+        monkeypatch, case, route_env, sorted_ids, max_degree, ndim):
+    """Outside the predicate ``segment_sum`` routes on, the gather and its
+    gradient are the jaxprs ``x[ids]`` gives: no linear call, no kernel."""
+    import re
+
+    from hydragnn_tpu.ops.segment import gather
+
+    if route_env is None:
+        monkeypatch.delenv("HYDRAGNN_PALLAS_SEGMENT", raising=False)
+    else:
+        monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", route_env)
+    ids, x, _, _ = _gather_case("capped", jnp.float32)
+    if ndim == 1:
+        x = x[:, 0]
+    strip = lambda fn: re.sub(
+        r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(jax.value_and_grad(
+            lambda v: jnp.sum(fn(v) ** 2)))(x)))
+    routed = strip(lambda v: gather(v, ids, sorted_ids, max_degree))
+    assert routed == strip(lambda v: v[ids]), case
+    assert "linear_call" not in routed and "pallas_call" not in routed
+
+
+def pytest_sorted_gather_transpose_is_the_named_kernel(monkeypatch):
+    """With the route on, the gradient's jaxpr holds the gather as a linear
+    call and its transpose as the sorted-segment kernel, and the lowered
+    program names the transposed call's scope around the kernel's own."""
+    from hydragnn_tpu.ops.segment import gather
+    from hydragnn_tpu.utils import tracer as tr
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    ids, x, _, deg = _gather_case("capped", jnp.float32)
+    grad = jax.grad(lambda v: jnp.sum(gather(v, ids, True, deg) ** 2))
+    text = str(jax.make_jaxpr(grad)(x))
+    assert text.count("= linear_call[") == 2 and "pallas_call" in text
+    assert "scatter-add" not in text and "scatter_add" not in text
+    lowered = jax.jit(grad).lower(x).as_text(debug_info=True)
+    assert (f"{tr.HG_GATHER_TRANSPOSE}/{tr.HG_SORTED_SEGMENT}" in lowered
+            ), "the transposed call's scope is missing from the program"
