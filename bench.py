@@ -1477,155 +1477,6 @@ def main_serve():
     }))
 
 
-def main_tune():
-    """BENCH_TUNE=1: kernel-autotuning A/B cells (ROADMAP item 4;
-    docs/TUNING.md "Guard rails") — per-Pallas-kernel dispatch medians,
-    pinned default plan vs swept winner, at the OC20/SC25 production
-    shape by default (BENCH_TUNE_* envs shrink it; the ci.sh smoke runs
-    tiny shapes, where off-TPU interpret-mode medians prove the cells
-    build, not tile guidance). Sweeps publish into a tuned table
-    (BENCH_TUNE_CACHE_DIR, else a fresh temp dir) through tune/sweep.py —
-    the same timing discipline as every other cell here (warm-up,
-    median-of-k, block_until_ready).
-
-    On TPU a second leg A/Bs the full production train step (the r5
-    headline cell) with the table deactivated vs installed, after
-    sweeping the workload's real ladder slots — the hardware-round
-    waypoint item 4 pins: 0.205 MFU measured on default tiles at r5,
-    target 0.40+ with the tuned table. One JSON record appends to
-    logs/tune_cells.jsonl."""
-    import tempfile
-
-    import jax
-
-    from hydragnn_tpu.tune import plans
-    from hydragnn_tpu.tune.sweep import build_call, measure, sweep_kernel
-    from hydragnn_tpu.tune.table import TunedTable
-
-    on_tpu = jax.default_backend() == "tpu"
-    interpret = not on_tpu
-    nodes = int(os.getenv("BENCH_TUNE_NODES", "2560"))
-    edges = int(os.getenv("BENCH_TUNE_EDGES", "51200"))
-    hidden = int(os.getenv("BENCH_TUNE_HIDDEN", "866"))
-    max_deg = int(os.getenv("BENCH_TUNE_MAX_DEGREE", "20"))
-    heads = int(os.getenv("BENCH_TUNE_HEADS", "8"))
-    nmax = int(os.getenv("BENCH_TUNE_NMAX", "80"))
-    dtype = os.getenv("BENCH_TUNE_DTYPE", "bfloat16" if on_tpu else "float32")
-    budget = int(os.getenv("BENCH_TUNE_BUDGET", "32"))
-    trials = int(os.getenv("BENCH_TUNE_TRIALS", "5"))
-    cache_dir = os.getenv("BENCH_TUNE_CACHE_DIR") or tempfile.mkdtemp(
-        prefix="bench_tune_"
-    )
-    table = TunedTable(cache_dir)
-
-    slots = [
-        (plans.SEGMENT, {"edges": edges, "channels": hidden,
-                         "num_segments": nodes, "max_degree": max_deg}),
-        (plans.FUSED_EDGE, {"edges": edges, "ci": hidden, "co": hidden,
-                            "num_segments": nodes, "max_degree": max_deg,
-                            "dtype": dtype}),
-        (plans.MULTI_AGG, {"edges": edges, "channels": hidden,
-                           "num_segments": nodes, "max_degree": max_deg,
-                           "has_recv": True, "has_gate": False,
-                           "dtype": dtype}),
-        (plans.FLASH, {"nodes": nodes, "heads": heads,
-                       "head_dim": max(hidden // heads, 1),
-                       "max_nodes_per_graph": nmax}),
-    ]
-    cells = {}
-    for kernel, shapes in slots:
-        default = plans.default_plan(kernel, shapes)
-        default_s = measure(
-            build_call(kernel, shapes, dtype, default, interpret),
-            n_trials=trials,
-        )
-        res = sweep_kernel(kernel, shapes, dtype, table, budget=budget,
-                           trials=trials, interpret=interpret)
-        tuned = res["plan"]
-        tuned_s = measure(
-            build_call(kernel, shapes, dtype, tuned, interpret),
-            n_trials=trials,
-        )
-        assert default_s > 0 and tuned_s > 0, (kernel, default_s, tuned_s)
-        cells[kernel] = {
-            "default_plan": default,
-            "tuned_plan": tuned,
-            "default_us": round(default_s * 1e6, 1),
-            "tuned_us": round(tuned_s * 1e6, 1),
-            "tuned_vs_default": round(default_s / tuned_s, 3),
-            "cached": bool(res.get("cached")),
-        }
-        print(f"BENCH_TUNE {kernel}: default {default_s * 1e6:.1f}us -> "
-              f"tuned {tuned_s * 1e6:.1f}us "
-              f"({default_s / tuned_s:.2f}x) plan={tuned}", flush=True)
-    if on_tpu:
-        # the default plan is always candidate #0 (plans.candidates), so
-        # on hardware the winner cannot lose to it beyond run-to-run noise
-        for k, c in cells.items():
-            assert c["tuned_vs_default"] >= 0.9, (k, c)
-    record = {
-        "metric": "BENCH_TUNE kernel tile A/B (pinned default plan vs "
-                  "swept winner, per Pallas kernel, OC20 production shape)",
-        "unit": "x (default_us / tuned_us)",
-        "value": round(min(c["tuned_vs_default"] for c in cells.values()), 3),
-        "device_kind": _device_kind(),
-        "dtype": dtype,
-        "interpret": interpret,
-        "budget": budget,
-        "trials": trials,
-        "shape": {"nodes": nodes, "edges": edges, "hidden": hidden,
-                  "max_degree": max_deg, "heads": heads,
-                  "max_nodes_per_graph": nmax},
-        "cells": cells,
-        # hardware-round waypoint (ROADMAP item 4, pinned by ISSUE 16):
-        # r5 measured the production cell at 0.205 MFU on default tiles
-        "mfu_baseline_default_tiles": 0.205,
-        "mfu_target_tuned": 0.40,
-        "tuned_table": cache_dir,
-        "ok": True,
-    }
-
-    if on_tpu and os.getenv("BENCH_TUNE_PROD", "1") == "1":
-        # full-step leg: sweep the production workload's REAL ladder
-        # slots (the per-kernel cells above use a fixed shape signature;
-        # the step consults whatever the loader's pad levels imply), then
-        # run the r5 headline cell with the table off vs installed
-        from hydragnn_tpu.tune import runtime as tune_runtime
-        from hydragnn_tpu.tune.sweep import config_slots, sweep_slots
-
-        config, loader = _production_workload()
-        real_slots = config_slots(config, loader.ladder)
-        if real_slots:
-            sweep_slots(real_slots, table, budget=budget, trials=trials,
-                        log=lambda m: print(m, flush=True))
-        prod_ab = {}
-        for tag, installed in (("default_tiles", False), ("tuned_tiles", True)):
-            if installed:
-                tune_runtime.install(table, "cached")
-            else:
-                tune_runtime.deactivate()
-            try:
-                r = _bench_production()
-            finally:
-                tune_runtime.deactivate()
-            prod_ab[tag] = {
-                "graphs_per_sec": round(r["graphs_per_sec"], 2),
-                "mfu": round(r["mfu"], 4),
-            }
-        record["production_step_ab"] = prod_ab
-        record["production_mfu_gain"] = round(
-            prod_ab["tuned_tiles"]["mfu"]
-            / max(prod_ab["default_tiles"]["mfu"], 1e-9),
-            3,
-        )
-
-    os.makedirs("logs", exist_ok=True)
-    line = json.dumps(record)
-    print(line, flush=True)
-    with open(os.path.join("logs", "tune_cells.jsonl"), "a") as fh:
-        fh.write(line + "\n")
-
-
 def main_mix():
     """BENCH_MIX=1: GFM mixture-plane cells (docs/GFM.md "Benchmarks").
 
@@ -1797,9 +1648,6 @@ def main():
         return
     if os.getenv("BENCH_MIX", "0") == "1":
         main_mix()
-        return
-    if os.getenv("BENCH_TUNE", "0") == "1":
-        main_tune()
         return
     if os.getenv("BENCH_AB", "0") == "1":
         main_ab()

@@ -175,8 +175,8 @@ def _cell_ids(rng, n_nodes: int, edges: int, max_degree: int,
 def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
                  interpret=False, cell_shape=CELL_SHAPE):
     """Yield ``(name, dtype_name, check)``: ``check()`` compiles one kernel
-    at one width and dtype, with the tile plan ``tune.runtime.tile_plan``
-    returns on this device, and returns ``[(label, rel_err[, tol]), ...]``
+    at one width and dtype, with the tiles its entry point runs when given
+    none (the training step's), and returns ``[(label, rel_err[, tol]), ...]``
     against the kernel's plain-jnp reference (``tol`` where the comparison is
     not the dtype's ``TOL``)."""
     import jax
@@ -195,14 +195,12 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
         reference_multi_agg,
     )
     from hydragnn_tpu.ops.pallas_segment import sorted_segment_sum
-    from hydragnn_tpu.tune.runtime import tile_plan
 
     rng = np.random.default_rng(0)
     ids_np = _sorted_ids(rng, n_nodes, max_degree, n_padding=300)
     ids = jnp.asarray(ids_np)
     e = ids_np.shape[0]
     real = slice(0, n_nodes - 1)  # the dummy node's row is unspecified
-    shape_key = {"edges": e, "num_segments": n_nodes, "max_degree": max_degree}
     f32 = lambda x: x.astype(jnp.float32)
 
     def arr(shape, dtype, scale=1.0):
@@ -210,12 +208,10 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
 
     def segment(c, dtype):
         msg = arr((e, c), dtype)
-        plan = tile_plan("segment_sum", {**shape_key, "channels": c}, dtype)
         out = jax.jit(lambda m: sorted_segment_sum(
-            m, ids, n_nodes, max_degree, plan["block_rows"],
-            plan["block_edges"], plan["block_cols"], interpret))(msg)
+            m, ids, n_nodes, max_degree, interpret=interpret))(msg)
         ref = jax.ops.segment_sum(f32(msg), ids, num_segments=n_nodes)
-        return [(str(plan), _rel_err(out[real], ref[real]))]
+        return [("forward", _rel_err(out[real], ref[real]))]
 
     def fused_edge(c, dtype, ids=ids, n_nodes=n_nodes, max_degree=max_degree,
                    tangent=False):
@@ -223,13 +219,8 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
         real = slice(0, n_nodes - 1)
         nrecv, ein = arr((n_nodes, c), dtype), arr((e, c), dtype)
         w, b = arr((c, c), dtype, c ** -0.5), arr((c,), dtype)
-        plan = tile_plan("fused_edge", {
-            "edges": e, "num_segments": n_nodes, "max_degree": max_degree,
-            "ci": c, "co": c, "dtype": jnp.dtype(dtype).name,
-        }, dtype)
         kernel = lambda nr, x, w_, b_: fused_edge_message_sum(
-            nr, x, w_, b_, ids, n_nodes, max_degree, plan["block_rows"],
-            plan["block_edges"], plan["block_cols"], interpret)
+            nr, x, w_, b_, ids, n_nodes, max_degree, interpret=interpret)
         dense = lambda nr, x, w_, b_: reference_edge_message_sum(
             nr, x, w_, b_, ids, n_nodes)
         primals = (nrecv, ein, w, b)
@@ -237,7 +228,7 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
             out = jax.jit(kernel)(*primals)
             with jax.default_matmul_precision("highest"):
                 ref = dense(*map(f32, primals))
-            return [(str(plan), _rel_err(out[real], ref[real]))]
+            return [("forward", _rel_err(out[real], ref[real]))]
         # the training step's use: the primal through the kernel, the tangent
         # through the custom-JVP rule, both in the stream dtype
         tangents = (arr((n_nodes, c), dtype), arr((e, c), dtype),
@@ -247,8 +238,8 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
         with jax.default_matmul_precision("highest"):
             ref, t_ref = jax.jit(lambda p, t: jax.jvp(dense, p, t))(
                 tuple(map(f32, primals)), tuple(map(f32, tangents)))
-        return [(f"forward {plan}", _rel_err(out[real], ref[real])),
-                (f"tangent L2 {plan}",
+        return [("forward", _rel_err(out[real], ref[real])),
+                ("tangent L2",
                  _rel_err(t_out[real], t_ref[real], np.linalg.norm),
                  TOL_TANGENT_L2)]
 
@@ -256,19 +247,14 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
         nrecv, ein = arr((n_nodes, c), dtype), arr((e, c), dtype)
         # the Hadamard gate operand (PNAPlus) rides the 256-wide case only
         gate = arr((e, c), dtype) if c == 256 else None
-        plan = tile_plan("multi_agg", {
-            **shape_key, "channels": c, "has_recv": True,
-            "has_gate": gate is not None, "dtype": jnp.dtype(dtype).name,
-        }, dtype)
         outs = jax.jit(lambda nr, x, g: fused_multi_agg(
-            nr, x, g, ids, n_nodes, max_degree, plan["block_rows"],
-            plan["block_edges"], plan["block_cols"], interpret,
+            nr, x, g, ids, n_nodes, max_degree, interpret=interpret,
         ))(nrecv, ein, gate)
         # the reference forms the message in the stream dtype exactly like
         # the kernel, then takes f32 moments
         refs = reference_multi_agg(nrecv, ein, gate, ids, n_nodes)
         return [
-            (f"{moment} {plan}", _rel_err(o[real], r[real]))
+            (moment, _rel_err(o[real], r[real]))
             for o, r, moment in zip(
                 outs, refs, ("sum", "count", "min", "max", "sumsq"))
         ]
@@ -281,16 +267,13 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
         node_mask = jnp.asarray(np.arange(n_real + pad) < n_real)
         n, g = n_real + pad, len(sizes) + 1
         q, k, v = (arr((n, 8, 32), dtype) for _ in range(3))
-        plan = tile_plan("flash_attention", {
-            "nodes": n, "heads": 8, "head_dim": 32,
-            "max_nodes_per_graph": nmax}, dtype)
         out = jax.jit(lambda q_, k_, v_: flash_self_attention(
             q_, k_, v_, node_graph, node_mask, g, nmax,
-            plan["block_q"], plan["block_k"], interpret))(q, k, v)
+            interpret=interpret))(q, k, v)
         with jax.default_matmul_precision("highest"):
             ref = reference_gathered_attention(
                 f32(q), f32(k), f32(v), node_graph, node_mask, g, nmax)
-        return [(str(plan), _rel_err(out[:n_real], ref[:n_real]))]
+        return [("forward", _rel_err(out[:n_real], ref[:n_real]))]
 
     def gather_transpose(c, dtype, ids, n_nodes, max_degree):
         """The VJP of ``ops/segment.py gather(sorted_ids=True)`` (the
@@ -382,7 +365,7 @@ def _blocked_causal_reference(q, k, v, node_graph, node_mask, block=1024):
 
 def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
                        longest=8192, groups=8, width=2048, interpret=False,
-                       tiles=((512, 512),), dtypes=("bfloat16", "float32")) -> dict:
+                       dtypes=("bfloat16", "float32")) -> dict:
     """The decoder's two kernels alone at the ZAYA cell's shapes, forward and
     backward, against plain jnp: causal grouped-query flash attention over
     ``[tokens, heads x head_dim]`` with a longest graph of ``longest`` nodes
@@ -422,17 +405,15 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
                 _blocked_causal_reference(q_, k_, v_, node_graph, node_mask) * f32(w))
             ref_out = jax.jit(_blocked_causal_reference)(f32(q), f32(k), f32(v), node_graph, node_mask)
             ref_grads = jax.jit(jax.grad(ref_loss, (0, 1, 2)))(f32(q), f32(k), f32(v))
-        for bq, bk in tiles:
-            fwd = jax.jit(lambda q_, k_, v_: flash_causal_attention(
-                q_, k_, v_, node_graph, node_mask, longest, bq, bk, interpret))
-            bwd = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(f32(flash_causal_attention(
-                q_, k_, v_, node_graph, node_mask, longest, bq, bk, interpret)) * f32(w)), (0, 1, 2)))
-            tag = f"flash_causal {dt} tiles {bq}x{bk}"
-            out = timed(tag + " fwd_ms", fwd, q, k, v)
-            _check(tag + " forward", _rel_err(out[:n_real], ref_out[:n_real]), TOL[dt])
-            grads = timed(tag + " fwd+bwd_ms", bwd, q, k, v)
-            for name, got, want in zip("qkv", grads, ref_grads):
-                _check(f"{tag} d{name}", _rel_err(got, want), TOL_DECODER_BWD[dt])
+        causal = lambda q_, k_, v_: flash_causal_attention(
+            q_, k_, v_, node_graph, node_mask, longest, interpret=interpret)
+        bwd = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(f32(causal(q_, k_, v_)) * f32(w)), (0, 1, 2)))
+        tag = f"flash_causal {dt}"
+        out = timed(tag + " fwd_ms", jax.jit(causal), q, k, v)
+        _check(tag + " forward", _rel_err(out[:n_real], ref_out[:n_real]), TOL[dt])
+        grads = timed(tag + " fwd+bwd_ms", bwd, q, k, v)
+        for name, got, want in zip("qkv", grads, ref_grads):
+            _check(f"{tag} d{name}", _rel_err(got, want), TOL_DECODER_BWD[dt])
 
         # ---- grouped product: ragged groups, one of them empty
         share = rng.dirichlet(np.ones(groups - 1) * 2.0)
@@ -441,20 +422,20 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
         slot = jnp.asarray(slot_np.astype(np.int32))
         x, wts = arr((tokens, width)), arr((groups, width, width), 1.0 / np.sqrt(width))
         cot = arr((tokens, width))
-        bm, bn, bk_ = gm.normalize_tiles(tokens, width, width, dtype=dt)
+        bm = gm.normalize_tiles(tokens, width, width, dtype=dt)[0]
 
         def through(kernel):
             def f(x_, w_):
                 lay = gm.aligned_layout(slot, groups, bm)
                 rows = gm.permute_rows(x_, lay["src"], lay["dest"])
                 if kernel:
-                    y = gm.grouped_matmul(rows, w_, lay["tile_group"], lay["n_tiles"], bm, bn, bk_, interpret)
+                    y = gm.grouped_matmul(rows, w_, lay["tile_group"], lay["n_tiles"], bm, interpret=interpret)
                 else:
                     y = gm.reference_grouped_matmul(rows, w_, lay["tile_group"], bm)
                 return gm.permute_rows(y, lay["dest"], lay["src"])
             return f
 
-        tag = f"grouped_expert {dt} groups={groups} {width}->{width} tiles {bm}x{bn}x{bk_}"
+        tag = f"grouped_expert {dt} groups={groups} {width}->{width}"
         out = timed(tag + " fwd_ms", jax.jit(through(True)), x, wts)
         grad_of = lambda f: jax.jit(jax.grad(lambda x_, w_: jnp.sum(f32(f(x_, w_)) * f32(cot)), (0, 1)))
         grads = timed(tag + " fwd+bwd_ms", grad_of(through(True)), x, wts)

@@ -223,7 +223,6 @@ def checkers() -> List[Checker]:
         obs_contract,
         sharding_rules,
         threads,
-        tile_constants,
         trace_hazard,
     )
 

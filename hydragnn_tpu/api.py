@@ -1348,12 +1348,6 @@ def _(config: dict, datasets=None, install_sigterm: bool = False):
         from .obs.events import attach_stream as _attach_events
 
         _attach_events(run_dir)
-    # kernel autotuning plane (tune/; docs/TUNING.md): install the run's
-    # tuned table BEFORE the server's ladder warm-up, so the serve-side
-    # Pallas routes consult it (same wiring as run_training's warm-up)
-    from .tune.runtime import setup_autotune
-
-    setup_autotune(config, test_loader, log_name)
     server = GraphServer(
         model,
         state,

@@ -430,26 +430,6 @@ def update_config(
             f"Training.retrace_policy {training['retrace_policy']!r} must "
             f"be one of {RETRACE_POLICIES}"
         )
-    # ---- kernel autotuning plane (docs/TUNING.md): whether warm-up
-    # consults the tuned tile table (cached), fills it first (sweep), or
-    # rides pinned defaults (off); the per-kernel candidate budget; and the
-    # table directory (None = next to the compile cache under the run's log
-    # dir; false disables; HYDRAGNN_TUNE_CACHE overrides)
-    training.setdefault("autotune", "cached")
-    from ..tune.runtime import MODES as AUTOTUNE_MODES
-
-    if training["autotune"] not in AUTOTUNE_MODES:
-        raise ValueError(
-            f"Training.autotune {training['autotune']!r} must be one of "
-            f"{AUTOTUNE_MODES}"
-        )
-    training.setdefault("autotune_budget", 32)
-    if int(training["autotune_budget"] or 0) < 0:
-        raise ValueError(
-            "Training.autotune_budget must be >= 0 (candidate plans per "
-            f"kernel slot; 0 = defaults only), got {training['autotune_budget']!r}"
-        )
-    training.setdefault("autotune_cache_dir", None)
     # ---- data plane (docs/ROBUSTNESS.md "Data plane"): what a sample that
     # fails validation (non-finite features, degenerate edges, budget
     # overflow, corrupt bytes) means, and how long the loader's prefetch

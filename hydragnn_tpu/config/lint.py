@@ -166,9 +166,6 @@ _HANDLED = {
     "NeuralNetwork.Training.compile_cache_dir",
     "NeuralNetwork.Training.precompile",
     "NeuralNetwork.Training.retrace_policy",
-    "NeuralNetwork.Training.autotune",
-    "NeuralNetwork.Training.autotune_budget",
-    "NeuralNetwork.Training.autotune_cache_dir",
     "NeuralNetwork.Training.compute_grad_energy",
     "NeuralNetwork.Training.conv_checkpointing",
     "NeuralNetwork.Training.remat_policy",

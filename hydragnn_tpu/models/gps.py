@@ -89,15 +89,6 @@ class MultiheadSelfAttention(nn.Module):
             Nmax = self.max_nodes_per_graph
             interpret = jax.default_backend() != "tpu"
 
-            # block constants via the tuned-table lookup (tuned entry ->
-            # swept winner, none -> pinned defaults; tune/runtime.py)
-            from ..tune.runtime import tile_plan
-
-            plan = tile_plan("flash_attention", {
-                "nodes": N, "heads": H, "head_dim": d,
-                "max_nodes_per_graph": Nmax,
-            }, x.dtype)
-
             # remat per Training.remat_policy (ops/remat.py; default =
             # bare jax.checkpoint) keeps the tangent rule's residuals
             # (per-graph probability blocks) out of the training forward:
@@ -108,8 +99,7 @@ class MultiheadSelfAttention(nn.Module):
             def attend(qf, kf, vf):
                 return remat_tag(flash_self_attention(
                     qf, kf, vf, batch.node_graph, batch.node_mask,
-                    batch.num_graphs, Nmax, block_q=plan["block_q"],
-                    block_k=plan["block_k"], interpret=interpret,
+                    batch.num_graphs, Nmax, interpret=interpret,
                 ), "flash_attention_out")
 
             out = kernel_remat(attend, self.remat_policy)(

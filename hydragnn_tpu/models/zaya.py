@@ -164,33 +164,24 @@ def causal_attention(q, k, v, batch_aux, max_nodes: int):
     node_graph, node_mask = batch_aux["node_graph"], batch_aux["node_mask"]
     if not _flash_route_enabled():
         return reference_causal_attention(q, k, v, node_graph, node_mask)
-    from ..tune.runtime import tile_plan
-
-    n, hq, d = q.shape
-    plan = tile_plan("flash_attention_causal", {
-        "nodes": n, "heads": hq, "kv_heads": k.shape[1], "head_dim": d,
-        "max_nodes_per_graph": max_nodes,
-    }, q.dtype)
     return flash_causal_attention(
-        q, k, v, node_graph, node_mask, max_nodes, plan["block_q"],
-        plan["block_k"], jax.default_backend() != "tpu",
+        q, k, v, node_graph, node_mask, max_nodes,
+        interpret=jax.default_backend() != "tpu",
     )
 
 
-def expert_products(x_rows, w_gate, w_up, w_down, layout, tiles, kernel: bool):
-    """SiLU-gated expert MLP on the group-aligned rows."""
-    from ..ops.pallas_grouped_matmul import grouped_matmul, normalize_tiles, reference_grouped_matmul
+def expert_products(x_rows, w_gate, w_up, w_down, layout, block_m: int, kernel: bool):
+    """SiLU-gated expert MLP on the group-aligned rows (``block_m`` the
+    layout's row tile)."""
+    from ..ops.pallas_grouped_matmul import grouped_matmul, reference_grouped_matmul
 
     tg, nt = layout["tile_group"], layout["n_tiles"]
-    bm = tiles["block_m"]
 
     def gmm(a, w):
         if not kernel:
-            return reference_grouped_matmul(a, w.astype(a.dtype), tg, bm)
-        _, bn, bk = normalize_tiles(a.shape[0], w.shape[1], w.shape[2], bm,
-                                    tiles["block_n"], tiles["block_k"], a.dtype)
-        return grouped_matmul(a, w.astype(a.dtype), tg, nt, bm, bn, bk,
-                              jax.default_backend() != "tpu")
+            return reference_grouped_matmul(a, w.astype(a.dtype), tg, block_m)
+        return grouped_matmul(a, w.astype(a.dtype), tg, nt, block_m,
+                              interpret=jax.default_backend() != "tpu")
 
     h = jax.nn.silu(gmm(x_rows, w_gate)) * gmm(x_rows, w_up)
     return gmm(h, w_down)
@@ -258,8 +249,7 @@ def expert_sublayer(p: Dict, beta, u, s_prev, node_mask, z: ZayaConfig, first: b
     -> (y [T, D] before the residual add, router state, the held experts'
     loads [held], every expert's load [num_experts]). ``choice`` overrides the
     router's (tests)."""
-    from ..ops.pallas_grouped_matmul import aligned_layout, permute_rows
-    from ..tune.runtime import tile_plan
+    from ..ops.pallas_grouped_matmul import aligned_layout, normalize_tiles, permute_rows
 
     t, d_model = u.shape
     with tr.scope(tr.HG_ROUTER):
@@ -270,14 +260,13 @@ def expert_sublayer(p: Dict, beta, u, s_prev, node_mask, z: ZayaConfig, first: b
             jnp.arange(held, dtype=jnp.int32))
         slot = jnp.where(node_mask, table[choice], held)
         kernel = jax.default_backend() == "tpu"
-        tiles = tile_plan("grouped_expert", {
-            "rows": t, "groups": held, "k": d_model, "n": z.moe_intermediate_size,
-            "dtype": jnp.dtype(u.dtype).name}, u.dtype)
-        layout = aligned_layout(slot, held, tiles["block_m"])
+        # each expert's rows start at a multiple of the kernel's row tile
+        block_m = normalize_tiles(t, d_model, z.moe_intermediate_size, dtype=u.dtype)[0]
+        layout = aligned_layout(slot, held, block_m)
     with tr.scope(tr.HG_MOE):
         rows = permute_rows(u, layout["src"], layout["dest"])
         out_rows = expert_products(rows, p["experts_gate"], p["experts_up"], p["experts_down"],
-                                   layout, tiles, kernel)
+                                   layout, block_m, kernel)
         y = permute_rows(out_rows, layout["dest"], layout["src"]) * gate[:, None].astype(u.dtype)
     every = jnp.zeros((z.num_experts,), jnp.float32).at[choice].add(node_mask.astype(jnp.float32))
     return y, s, layout["counts"], every

@@ -69,7 +69,6 @@ from .events import (
     EV_REPLICA_RESTART,
     EV_RETRACE_VIOLATION,
     EV_SHED,
-    EV_TILE_PLAN,
     EV_WEDGE,
     severity_rank,
 )
@@ -100,7 +99,6 @@ F_QUARANTINE_ROT = "quarantine_rot"      # data rot: quarantine/demotions
 F_LOADER_STALL = "loader_stall"          # loader watchdog fired
 F_WEDGED_STEP = "wedged_step"            # serving device step wedged
 F_COLD_START = "compile_cold_start"      # warm path regressed to recompiles
-F_UNTUNED_KERNEL = "untuned_kernel"      # TPU run rode default tile plans
 F_CRASH = "crash"                        # unexplained crash dump
 F_ELASTIC_SHRINK = "elastic_shrink"      # fleet re-laid-out onto fewer hosts
 F_ELASTIC_GROW = "elastic_grow"          # fleet re-grew to more hosts
@@ -115,7 +113,7 @@ FINDING_KINDS = (
     F_LR_ROLLBACK_LOOP, F_STRAGGLER, F_DESYNC, F_STALE_HOST,
     F_HBM_PRESSURE, F_COMM_DOMINANT, F_SHED_SPIRAL, F_QUEUE_SATURATION,
     F_QUARANTINE_ROT, F_LOADER_STALL, F_WEDGED_STEP, F_COLD_START,
-    F_UNTUNED_KERNEL, F_CRASH, F_ELASTIC_SHRINK, F_ELASTIC_GROW,
+    F_CRASH, F_ELASTIC_SHRINK, F_ELASTIC_GROW,
     F_REPLICA_FLAP, F_BREAKER_OPEN, F_RELOAD_ROLLBACK,
     F_QUANT_DRIFT, F_CACHE_INEFFECTIVE,
 )
@@ -1262,37 +1260,6 @@ def r_wedged_step(s: RunStreams, cfg: DoctorConfig) -> List[Finding]:
         "index) and warm that level explicitly",
         evidence=evs,
         data={"wedges": len(evs)},
-    )]
-
-
-@rule
-def r_untuned_kernel(s: RunStreams, cfg: DoctorConfig) -> List[Finding]:
-    """A TPU run whose Pallas kernels rode pinned default tile plans —
-    free MFU left on the table. Fires only for real accelerator device
-    kinds: CPU/interpret runs (CI, doctor_smoke's clean leg) legitimately
-    ride defaults and stay silent."""
-    evs = s.events_of(EV_TILE_PLAN)
-    defaults = [
-        e for e in evs
-        if e.get("source") == "default"
-        and "tpu" in str(e.get("device", "")).lower()
-    ]
-    if not defaults:
-        return []
-    kernels = sorted({str(e.get("kernel")) for e in defaults})
-    return [Finding(
-        F_UNTUNED_KERNEL, "info",
-        f"untuned kernel(s) on {defaults[0].get('device')}: "
-        f"{', '.join(kernels)} ran {len(defaults)} specialization(s) on "
-        "pinned default tile plans — no tuned-table entry matched this "
-        "(kernel version, device, dtype, shape)",
-        "run `python -m hydragnn_tpu.tune <config.json>` on this device "
-        "to sweep and persist winners, then point "
-        "Training.autotune_cache_dir (or HYDRAGNN_TUNE_CACHE) at the "
-        "table; Training.autotune: sweep does it inline at warm-up "
-        "(docs/TUNING.md)",
-        evidence=defaults,
-        data={"kernels": kernels, "default_lookups": len(defaults)},
     )]
 
 

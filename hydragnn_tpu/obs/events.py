@@ -56,7 +56,6 @@ EV_FLEET_STRAGGLER = "fleet_straggler"    # fleet watchdog flagged a slow host
 EV_FLEET_DESYNC = "fleet_desync"          # step progress skewed past the bound
 EV_FLEET_HOST_STALE = "fleet_host_stale"  # host heartbeat missing past timeout
 EV_SHARDING_AUDIT = "sharding_audit"      # inspector flagged an over-replicated leaf
-EV_TILE_PLAN = "tile_plan"                # kernel tile-plan choice (tune/runtime.py)
 EV_ELASTIC_SHRINK = "elastic_shrink"      # fleet re-laid-out onto fewer hosts
 EV_ELASTIC_GROW = "elastic_grow"          # fleet re-laid-out back onto more hosts
 EV_REPLICA_EXIT = "replica_exit"          # serving replica process died
@@ -75,7 +74,7 @@ EVENT_KINDS = (
     EV_MIX_SOURCE_ADD, EV_MIX_SOURCE_REMOVE, EV_MIX_DEMOTE, EV_MIX_DRIFT,
     EV_NUMERICS_PROVENANCE,
     EV_FLEET_STRAGGLER, EV_FLEET_DESYNC, EV_FLEET_HOST_STALE,
-    EV_SHARDING_AUDIT, EV_TILE_PLAN,
+    EV_SHARDING_AUDIT,
     EV_ELASTIC_SHRINK, EV_ELASTIC_GROW,
     EV_REPLICA_EXIT, EV_REPLICA_RESTART, EV_REPLICA_BENCHED,
     EV_BREAKER_OPEN, EV_BREAKER_CLOSE, EV_RELOAD_ROLLBACK,
@@ -116,7 +115,6 @@ DEFAULT_SEVERITY: Dict[str, str] = {
     EV_FLEET_DESYNC: "error",
     EV_FLEET_HOST_STALE: "warn",
     EV_SHARDING_AUDIT: "warn",
-    EV_TILE_PLAN: "info",
     # a shrink is progress lost + degraded capacity; a re-grow is recovery
     EV_ELASTIC_SHRINK: "warn",
     EV_ELASTIC_GROW: "info",

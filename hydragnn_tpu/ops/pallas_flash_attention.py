@@ -81,18 +81,20 @@ from .pallas_segment import _pad_to, mxu_precision
 # the kernel and the jnp references so their masked maxima agree exactly
 _NEG = -1.0e30
 
-# tuned-table key component (tune/table.py): bump on any change to the
-# kernel's schedule, block layout, or semantics — stale tuned entries must
-# miss, not steer a different program
-KERNEL_VERSION = 2
+# the launches' tiles, queries by keys: the self-attention and block-summary
+# launches (graphs of tens of nodes), and the decoder's causal launch with its
+# tiled backward (graphs of thousands). Stated here and nowhere else; a change
+# to them is an edit of these lines, claimed in a benchmark cell
+BLOCK_Q, BLOCK_K = 128, 128
+CAUSAL_BLOCK_Q, CAUSAL_BLOCK_K = 512, 512
 
 
-def normalize_tiles(block_q=128, block_k=128):
-    """Snap a candidate tile plan to the kernel's alignment contract —
-    ``block_q`` to the 16-row sublane tile (covers bf16), ``block_k`` to
-    the 128-lane tile. The one clamp site shared by the routing layer and
-    the tune plane's table keys (tune/plans.py); the kernel itself requires
-    already-aligned blocks."""
+def normalize_tiles(block_q=BLOCK_Q, block_k=BLOCK_K):
+    """Snap requested tiles to the kernel's alignment contract: ``block_q``
+    to the 16-row sublane tile (covers bf16), ``block_k`` to the 128-lane
+    tile. The one clamp, applied by the three entry points before the tiles
+    become ``custom_jvp`` / ``custom_vjp`` non-differentiable arguments; the
+    launches themselves require aligned blocks."""
     bq = max(16, block_q - block_q % 16)
     bk = max(128, block_k - block_k % 128)
     return bq, bk
@@ -398,7 +400,6 @@ def _block_windows(node_graph, n, block_q, block_k, max_nodes_per_graph):
     return first // block_k, last // block_k, k_windows
 
 
-@functools.partial(jax.custom_jvp, nondiff_argnums=(5, 6, 7, 8, 9))
 def flash_self_attention(
     q,
     k,
@@ -407,8 +408,8 @@ def flash_self_attention(
     node_mask,
     num_graphs: int,
     max_nodes_per_graph: int,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int = BLOCK_Q,
+    block_k: int = BLOCK_K,
     interpret: bool = False,
 ):
     """Segment-masked flash self-attention over the flat node array.
@@ -424,14 +425,25 @@ def flash_self_attention(
     Padding rows come out 0 (the dense oracle leaves softmax garbage
     there; both are masked downstream).
 
-    ``block_q`` must be a multiple of the sublane tile (16 covers bf16),
-    ``block_k`` of the 128-lane tile. Returns ``[N, H, d]`` in the operand
-    dtype; logits/softmax accumulate in f32 and never touch HBM.
+    ``block_q`` is snapped to the sublane tile (16 covers bf16), ``block_k``
+    to the 128-lane tile (``normalize_tiles``). Returns ``[N, H, d]`` in the
+    operand dtype; logits/softmax accumulate in f32 and never touch HBM.
     Differentiable to arbitrary order (custom-JVP whose tangent is the
     plain-jnp gathered-dense reference), so energy-force training
     composes; wrap call sites in ``jax.checkpoint`` to keep the tangent
     residuals out of the training forward.
     """
+    return _flash_self_attention(
+        q, k, v, node_graph, node_mask, num_graphs, max_nodes_per_graph,
+        *normalize_tiles(block_q, block_k), interpret,
+    )
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_self_attention(
+    q, k, v, node_graph, node_mask, num_graphs, max_nodes_per_graph,
+    block_q, block_k, interpret,
+):
     n = q.shape[0]
     with tr.scope(tr.HG_FLASH_ATTENTION):
         gid = jnp.where(node_mask, node_graph.astype(jnp.int32), -1)
@@ -444,12 +456,12 @@ def flash_self_attention(
         )
 
 
-@flash_self_attention.defjvp
+@_flash_self_attention.defjvp
 def _flash_jvp(num_graphs, max_nodes_per_graph, block_q, block_k, interpret,
                primals, tangents):
     q, k, v, node_graph, node_mask = primals
     t_q, t_k, t_v, _, _ = tangents
-    out = flash_self_attention(
+    out = _flash_self_attention(
         q, k, v, node_graph, node_mask, num_graphs, max_nodes_per_graph,
         block_q, block_k, interpret,
     )
@@ -467,14 +479,13 @@ def _flash_jvp(num_graphs, max_nodes_per_graph, block_q, block_k, interpret,
     return out, t_out
 
 
-@functools.partial(jax.custom_jvp, nondiff_argnums=(4, 5, 6))
 def flash_block_summary(
     q,
     k,
     v,
     key_mask,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int = BLOCK_Q,
+    block_k: int = BLOCK_K,
     interpret: bool = False,
 ):
     """Online-softmax partial of local queries against ONE key/value block
@@ -489,6 +500,13 @@ def flash_block_summary(
     the operand dtype on return (the ring carries match the dense route's
     dtypes either way).
     """
+    return _flash_block_summary(
+        q, k, v, key_mask, *normalize_tiles(block_q, block_k), interpret
+    )
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(4, 5, 6))
+def _flash_block_summary(q, k, v, key_mask, block_q, block_k, interpret):
     nq, nk = q.shape[0], k.shape[0]
     with tr.scope(tr.HG_FLASH_ATTENTION):
         gid_q = jnp.zeros((nq,), jnp.int32)
@@ -508,11 +526,11 @@ def flash_block_summary(
         return m.astype(dt), l.astype(dt), o * l[..., None].astype(dt)
 
 
-@flash_block_summary.defjvp
+@_flash_block_summary.defjvp
 def _summary_jvp(block_q, block_k, interpret, primals, tangents):
     q, k, v, key_mask = primals
     t_q, t_k, t_v, _ = tangents
-    out = flash_block_summary(q, k, v, key_mask, block_q, block_k, interpret)
+    out = _flash_block_summary(q, k, v, key_mask, block_q, block_k, interpret)
     fn = lambda q_, k_, v_: jax.tree_util.tree_map(
         lambda x: x.astype(q.dtype),
         reference_block_summary(q_, k_, v_, key_mask),
@@ -686,7 +704,6 @@ def _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
     return o, lse  # lse [H, Nq_pad, 128], lane-broadcast
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def flash_causal_attention(
     q,
     k,
@@ -694,8 +711,8 @@ def flash_causal_attention(
     node_graph,
     node_mask,
     max_nodes_per_graph: int,
-    block_q: int = 512,
-    block_k: int = 512,
+    block_q: int = CAUSAL_BLOCK_Q,
+    block_k: int = CAUSAL_BLOCK_K,
     interpret: bool = False,
 ):
     """Causal grouped-query flash attention over the flat node array.
@@ -707,6 +724,15 @@ def flash_causal_attention(
     ``max_nodes_per_graph`` is under-covered and the caller poisons it.
     Forward and backward are Pallas launches under one schedule; reverse
     mode only, first order."""
+    return _flash_causal_attention(
+        q, k, v, node_graph, node_mask, max_nodes_per_graph,
+        *normalize_tiles(block_q, block_k), interpret,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_causal_attention(q, k, v, node_graph, node_mask,
+                            max_nodes_per_graph, block_q, block_k, interpret):
     return _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
                        block_q, block_k, interpret)[0]
 
@@ -816,4 +842,4 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
                 per_kv_head(dv_h).astype(v.dtype), None, None)
 
 
-flash_causal_attention.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)
+_flash_causal_attention.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)

@@ -131,20 +131,19 @@ def _kernel(estart_ref, ids_ref, nrecv_ref, ein_ref, w_ref, b_ref, out_ref):
     )
 
 
-# tuned-table key component (tune/table.py): bump on any change to the
-# kernel's schedule, block layout, or semantics — stale tuned entries must
-# miss, not steer a different program
-KERNEL_VERSION = 1
+# the launch's tiles: output rows a block, edges a streamed window, output
+# channels a block. Stated here and nowhere else; a change to them is an edit
+# of this line, claimed in a benchmark cell
+BLOCK_ROWS, BLOCK_EDGES, BLOCK_COLS = 128, 512, 512
 
 
 def normalize_tiles(
     ci, co, dtype,
-    block_rows=128, block_edges=512, block_cols=512,
+    block_rows=BLOCK_ROWS, block_edges=BLOCK_EDGES, block_cols=BLOCK_COLS,
 ):
-    """Clamp a candidate tile plan to what ``_forward`` will actually run —
-    the one clamp site, shared by the kernel, the routing layer (so nondiff
-    specialization args are pre-clamped) and the tune plane's table keys
-    (tune/plans.py).
+    """Clamp requested tiles to what the launch runs for these widths and
+    this stream dtype: the one clamp, applied by ``fused_edge_message_sum``
+    before the tiles become ``custom_jvp`` non-differentiable arguments.
 
     Channel padding: input width streams whole (the dense contracts over
     it). Output width: ONE block when it fits a lane-aligned <=1024 tile
@@ -181,7 +180,7 @@ def normalize_tiles(
 
 def _forward(
     node_recv, edge_in, weights, bias, segment_ids, num_segments, max_degree,
-    block_rows, block_edges, block_cols, interpret,
+    nb, eb, cb, interpret,
 ):
     e, ci = edge_in.shape
     ci_w, co = weights.shape
@@ -189,9 +188,6 @@ def _forward(
     assert node_recv.shape[1] == ci, (node_recv.shape, ci)
     dtype = edge_in.dtype
     ci_pad = ci + (-ci) % 128
-    nb, eb, cb = normalize_tiles(
-        ci, co, dtype, block_rows, block_edges, block_cols,
-    )
     ids = segment_ids.astype(jnp.int32)
     ein = _pad_to(_pad_to(edge_in, eb, 0), 128, 1)
     nrecv = _pad_to(_pad_to(node_recv, nb, 0), 128, 1)
@@ -258,7 +254,6 @@ def _forward(
     return out[:num_segments, :co].astype(dtype)
 
 
-@functools.partial(jax.custom_jvp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def fused_edge_message_sum(
     node_recv,
     edge_in,
@@ -267,9 +262,9 @@ def fused_edge_message_sum(
     segment_ids,
     num_segments: int,
     max_degree: int = 32,
-    block_rows: int = 128,
-    block_edges: int = 512,
-    block_cols: int = 512,
+    block_rows: int = BLOCK_ROWS,
+    block_edges: int = BLOCK_EDGES,
+    block_cols: int = BLOCK_COLS,
     interpret: bool = False,
 ):
     """Fused ``segment_sum(relu(relu(node_recv[ids] + edge_in) @ W + b))``
@@ -292,7 +287,24 @@ def fused_edge_message_sum(
     Returns ``[num_segments, co]`` in the operand dtype; accumulation is
     f32 throughout. Differentiable to arbitrary order (custom-JVP with a
     plain-jnp tangent), so energy-force (grad-of-grad) training composes.
+    Tiles past the clamp (``normalize_tiles``) run, and compile, as the
+    clamped ones.
     """
+    tiles = normalize_tiles(
+        edge_in.shape[1], weights.shape[1], edge_in.dtype,
+        block_rows, block_edges, block_cols,
+    )
+    return _fused_edge_message_sum(
+        node_recv, edge_in, weights, bias, segment_ids, num_segments,
+        max_degree, *tiles, interpret,
+    )
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _fused_edge_message_sum(
+    node_recv, edge_in, weights, bias, segment_ids, num_segments, max_degree,
+    block_rows, block_edges, block_cols, interpret,
+):
     with tr.scope(tr.HG_FUSED_EDGE):
         return _forward(
             node_recv, edge_in, weights, bias, segment_ids, num_segments,
@@ -300,14 +312,14 @@ def fused_edge_message_sum(
         )
 
 
-@fused_edge_message_sum.defjvp
+@_fused_edge_message_sum.defjvp
 def _fused_jvp(
     num_segments, max_degree, block_rows, block_edges, block_cols, interpret,
     primals, tangents,
 ):
     node_recv, edge_in, weights, bias, segment_ids = primals
     t_nr, t_ei, t_w, t_b, _ = tangents
-    out = fused_edge_message_sum(
+    out = _fused_edge_message_sum(
         node_recv, edge_in, weights, bias, segment_ids, num_segments,
         max_degree, block_rows, block_edges, block_cols, interpret,
     )

@@ -38,10 +38,6 @@ from jax.experimental.pallas import tpu as pltpu
 from ..utils import tracer as tr
 from .pallas_segment import _pad_to, mxu_precision
 
-# tuned-table key component (tune/table.py): bump on any schedule change
-KERNEL_VERSION = 1
-
-
 def _round_up(x: int, m: int) -> int:
     return ((int(x) + m - 1) // m) * m
 
@@ -51,15 +47,22 @@ def _round_up(x: int, m: int) -> int:
 _VMEM_BUDGET = 10 * 2**20
 
 
-def normalize_tiles(rows, k, n, block_m=512, block_n=1024, block_k=512,
-                    dtype="bfloat16"):
-    """Snap a tile plan to the kernel's alignment contract: ``block_m`` to
-    the 16-row sublane tile (covers bf16) and no taller than the rows there
+# the launch's tiles: rows (also the alignment of each group's rows), output
+# columns, contraction. Stated here and nowhere else; a change to them is an
+# edit of this line, claimed in a benchmark cell
+BLOCK_M, BLOCK_N, BLOCK_K = 512, 1024, 512
+
+
+def normalize_tiles(rows, k, n, block_m=BLOCK_M, block_n=BLOCK_N,
+                    block_k=BLOCK_K, dtype="bfloat16"):
+    """Snap requested tiles to the kernel's alignment contract: ``block_m``
+    to the 16-row sublane tile (covers bf16) and no taller than the rows there
     are, ``block_n``/``block_k`` to the 128-lane tile and no wider than the
     lane-padded operand; then ``block_n``, then ``block_k`` halved until the
     blocks of the streaming ``dtype`` fit the VMEM budget (float32 streams
-    take half the tile bf16 streams do). The one clamp site shared with
-    tune/plans.py."""
+    take half the tile bf16 streams do). The one clamp, applied by
+    ``grouped_matmul`` before the tiles become ``custom_vjp``
+    non-differentiable arguments."""
     bm = max(16, min(block_m - block_m % 16, _round_up(max(rows, 1), 16)))
     bn = max(128, min(block_n - block_n % 128, _round_up(n, 128)))
     bk = max(128, min(block_k - block_k % 128, _round_up(k, 128)))
@@ -257,23 +260,33 @@ def _launch_dw(x, dy, groups, tile_group, n_tiles, bm, bn, bk, out_dtype,
     return out[:, :k, :n]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def grouped_matmul(x, w, tile_group, n_tiles, block_m: int = 512,
-                   block_n: int = 1024, block_k: int = 512,
+def grouped_matmul(x, w, tile_group, n_tiles, block_m: int = BLOCK_M,
+                   block_n: int = BLOCK_N, block_k: int = BLOCK_K,
                    interpret: bool = False):
     """``y[r] = x[r] @ w[tile_group[r // block_m]]`` for a group-aligned
     ``x [R, K]`` (``aligned_layout``) and ``w [G, K, N]``; ``n_tiles`` row
-    tiles are in use, the rest are zero. Blocks must come from
-    ``normalize_tiles``; ``R`` is a multiple of ``block_m``. Reverse mode
-    only, first order: both backward products are launches of this kernel."""
+    tiles are in use, the rest are zero. ``block_m`` is the one the layout
+    was built with (``normalize_tiles`` of the tokens' rows: already
+    clamped), ``R`` a multiple of it; ``block_n``/``block_k`` are clamped
+    here to this product's widths. Reverse mode only, first order: both
+    backward products are launches of this kernel."""
+    _, bn, bk = normalize_tiles(x.shape[0], w.shape[1], w.shape[2], block_m,
+                                block_n, block_k, x.dtype)
+    return _grouped_matmul(x, w, tile_group, n_tiles, block_m, bn, bk,
+                           interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _grouped_matmul(x, w, tile_group, n_tiles, block_m, block_n, block_k,
+                    interpret):
     with tr.scope(tr.HG_GROUPED_EXPERT):
         return _launch_rows(x, w, tile_group, n_tiles, block_m, block_n,
                             block_k, False, interpret)
 
 
 def _gmm_fwd(x, w, tile_group, n_tiles, block_m, block_n, block_k, interpret):
-    y = grouped_matmul(x, w, tile_group, n_tiles, block_m, block_n, block_k,
-                       interpret)
+    y = _grouped_matmul(x, w, tile_group, n_tiles, block_m, block_n, block_k,
+                        interpret)
     return y, (x, w, tile_group, n_tiles)
 
 
@@ -295,4 +308,4 @@ def _gmm_bwd(block_m, block_n, block_k, interpret, res, dy):
     return dx, dw, None, None
 
 
-grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+_grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)

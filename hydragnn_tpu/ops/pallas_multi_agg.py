@@ -219,25 +219,23 @@ def _make_kernel(has_recv: bool, has_gate: bool, max_degree: int):
     return kernel
 
 
-# tuned-table key component (tune/table.py): bump on any change to the
-# kernel's schedule, block layout, or semantics — stale tuned entries must
-# miss, not steer a different program
-KERNEL_VERSION = 2
+# the launch's tiles: output rows a block, edges a streamed window, channels
+# a block. Stated here and nowhere else; a change to them is an edit of this
+# line, claimed in a benchmark cell
+BLOCK_ROWS, BLOCK_EDGES, BLOCK_COLS = 128, 512, 128
 
 
 def normalize_tiles(
     c, dtype, has_recv, has_gate,
-    block_rows=128, block_edges=512, block_cols=128,
+    block_rows=BLOCK_ROWS, block_edges=BLOCK_EDGES, block_cols=BLOCK_COLS,
 ):
-    """Clamp a candidate tile plan to what ``_forward`` will actually run:
-    ``block_cols`` to the lane-padded channel width, ``block_edges`` by the
-    VMEM-fit shrink loop.
+    """Clamp requested tiles to what the launch runs: ``block_cols`` to the
+    lane-padded channel width, ``block_edges`` by the VMEM-fit shrink loop.
 
-    This is the one clamp site — ``_forward`` consumes its result, and the
-    routing layer (ops/segment.py) normalizes BEFORE the values become
-    ``custom_jvp`` nondiff args, so equivalent plans share one jit
-    specialization instead of keying the executable cache on the unclamped
-    request (tune/plans.py builds tuned-table keys from the same values).
+    The one clamp, applied by ``fused_multi_agg`` BEFORE the tiles become
+    ``custom_jvp`` non-differentiable arguments, so two requests that clamp
+    to one program share one executable instead of keying the jit cache on
+    the unclamped request.
     """
     nb, eb = block_rows, block_edges
     c128 = c + (-c) % 128
@@ -267,7 +265,7 @@ def normalize_tiles(
 
 def _forward(
     node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
-    block_rows, block_edges, block_cols, interpret,
+    nb, eb, cb, interpret,
 ):
     e, c = edge_in.shape
     dtype = edge_in.dtype
@@ -277,10 +275,6 @@ def _forward(
         assert node_recv.shape[1] == c, (node_recv.shape, c)
     if has_gate:
         assert gate.shape == edge_in.shape, (gate.shape, edge_in.shape)
-
-    nb, eb, cb = normalize_tiles(
-        c, dtype, has_recv, has_gate, block_rows, block_edges, block_cols,
-    )
 
     ids = segment_ids.astype(jnp.int32)
     ein = _pad_to(_pad_to(edge_in, eb, 0), cb, 1)
@@ -364,7 +358,6 @@ def _forward(
     return s, cnt, mn, mx, ssq
 
 
-@functools.partial(jax.custom_jvp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def fused_multi_agg(
     node_recv,
     edge_in,
@@ -372,9 +365,9 @@ def fused_multi_agg(
     segment_ids,
     num_segments: int,
     max_degree: int = 32,
-    block_rows: int = 128,
-    block_edges: int = 512,
-    block_cols: int = 128,
+    block_rows: int = BLOCK_ROWS,
+    block_edges: int = BLOCK_EDGES,
+    block_cols: int = BLOCK_COLS,
     interpret: bool = False,
 ):
     """Fused multi-moment aggregation of ``(node_recv[ids] + edge_in) *
@@ -395,7 +388,24 @@ def fused_multi_agg(
     Differentiable to arbitrary order: custom-JVP with the plain-jnp dense
     reference as tangent rule, so reverse mode recomputes the edge
     messages from the gathered inputs instead of storing [E, C] residuals.
+    Tiles past the clamp (``normalize_tiles``) run, and compile, as the
+    clamped ones.
     """
+    tiles = normalize_tiles(
+        edge_in.shape[1], edge_in.dtype, node_recv is not None,
+        gate is not None, block_rows, block_edges, block_cols,
+    )
+    return _fused_multi_agg(
+        node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
+        *tiles, interpret,
+    )
+
+
+@functools.partial(jax.custom_jvp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _fused_multi_agg(
+    node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
+    block_rows, block_edges, block_cols, interpret,
+):
     with tr.scope(tr.HG_MULTI_AGG):
         return _forward(
             node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
@@ -403,12 +413,12 @@ def fused_multi_agg(
         )
 
 
-@fused_multi_agg.defjvp
+@_fused_multi_agg.defjvp
 def _jvp(num_segments, max_degree, block_rows, block_edges, block_cols,
          interpret, primals, tangents):
     node_recv, edge_in, gate, segment_ids = primals
     t_nr, t_ei, t_g, _ = tangents
-    out = fused_multi_agg(
+    out = _fused_multi_agg(
         node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
         block_rows, block_edges, block_cols, interpret,
     )

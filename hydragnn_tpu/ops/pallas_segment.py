@@ -85,17 +85,29 @@ def _pad_to(x, multiple, axis):
     return jnp.pad(x, widths)
 
 
-@functools.partial(
-    jax.custom_jvp, nondiff_argnums=(2, 3, 4, 5, 6, 7)
-)
+# the launch's tiles: output rows a block, edges a streamed window, channels
+# a block. Stated here and nowhere else; a change to them is an edit of this
+# line, claimed in a benchmark cell
+BLOCK_ROWS, BLOCK_EDGES, BLOCK_COLS = 128, 512, 512
+
+
+def normalize_tiles(c, block_rows=BLOCK_ROWS, block_edges=BLOCK_EDGES,
+                    block_cols=BLOCK_COLS):
+    """Clamp requested tiles to what the launch runs for ``c`` channels
+    (``block_cols`` never exceeds the lane-padded channel width): the one
+    clamp, applied by ``sorted_segment_sum`` before the tiles become
+    ``custom_jvp`` non-differentiable arguments."""
+    return block_rows, block_edges, min(block_cols, max(c, 128))
+
+
 def sorted_segment_sum(
     messages,
     segment_ids,
     num_segments: int,
     max_degree: int = 32,
-    block_rows: int = 128,
-    block_edges: int = 512,
-    block_cols: int = 512,
+    block_rows: int = BLOCK_ROWS,
+    block_edges: int = BLOCK_EDGES,
+    block_cols: int = BLOCK_COLS,
     interpret: bool = False,
 ):
     """``segment_sum`` for receiver-sorted edges via the Pallas kernel.
@@ -112,8 +124,24 @@ def sorted_segment_sum(
     receives every padding edge and will exceed it: its slot must be masked
     downstream, which every consumer of the dummy-node convention already
     does (data/graph.py padding docs).
-    Messages are [E, C] float; returns [num_segments, C].
+    Messages are [E, C] float; returns [num_segments, C]. Tiles past the
+    clamp (``normalize_tiles``) run, and compile, as the clamped ones.
     """
+    tiles = normalize_tiles(
+        messages.shape[1], block_rows, block_edges, block_cols
+    )
+    return _sorted_segment_sum(
+        messages, segment_ids, num_segments, max_degree, *tiles, interpret
+    )
+
+
+@functools.partial(
+    jax.custom_jvp, nondiff_argnums=(2, 3, 4, 5, 6, 7)
+)
+def _sorted_segment_sum(
+    messages, segment_ids, num_segments, max_degree, block_rows, block_edges,
+    block_cols, interpret,
+):
     with tr.scope(tr.HG_SORTED_SEGMENT):
         return _forward(
             messages, segment_ids, num_segments, max_degree, block_rows,
@@ -121,27 +149,10 @@ def sorted_segment_sum(
         )
 
 
-# tuned-table key component (tune/table.py): bump on any change to the
-# kernel's schedule, block layout, or semantics — stale tuned entries must
-# miss, not steer a different program
-KERNEL_VERSION = 1
-
-
-def normalize_tiles(c, block_rows=128, block_edges=512, block_cols=512):
-    """Clamp a candidate tile plan to what ``_forward`` will actually run
-    (``block_cols`` never exceeds the lane-padded channel width) — the one
-    clamp site, shared by the kernel, the routing layer (so nondiff
-    specialization args are pre-clamped) and the tune plane's table keys
-    (tune/plans.py)."""
-    return block_rows, block_edges, min(block_cols, max(c, 128))
-
-
 def _forward(
-    messages, segment_ids, num_segments, max_degree, block_rows, block_edges,
-    block_cols, interpret,
+    messages, segment_ids, num_segments, max_degree, nb, eb, cb, interpret,
 ):
     e, c = messages.shape
-    nb, eb, cb = normalize_tiles(c, block_rows, block_edges, block_cols)
     dtype = messages.dtype
 
     ids = segment_ids.astype(jnp.int32)
@@ -203,12 +214,12 @@ def _forward(
     return out[:num_segments, :c].astype(dtype)
 
 
-@sorted_segment_sum.defjvp
+@_sorted_segment_sum.defjvp
 def _jvp(num_segments, max_degree, block_rows, block_edges, block_cols,
          interpret, primals, tangents):
     messages, segment_ids = primals
     t_msg, _ = tangents  # integer ids get a float0 tangent — no gradient
-    out = sorted_segment_sum(
+    out = _sorted_segment_sum(
         messages, segment_ids, num_segments, max_degree, block_rows,
         block_edges, block_cols, interpret,
     )

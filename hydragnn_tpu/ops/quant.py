@@ -16,11 +16,6 @@ Two consumers (serve/quantize.py):
   (max-abs over template batches / 127 — no per-batch reduction in the
   serving path), then ``int8_matmul`` accumulates int8 x int8 in int32 and
   one ``a_scale * w_scale`` multiply rescales the product.
-
-The block-plan surface (``normalize_tiles`` + the ``int8_dot`` entry in
-tune/plans.py) keys int8 executions as their own axis of the tuned table:
-an int8 plan can never be confused with (or silently reuse) an f32/bf16
-entry for the same shapes.
 """
 
 from __future__ import annotations
@@ -30,28 +25,9 @@ from typing import Tuple
 import jax.numpy as jnp
 from jax import lax
 
-#: bumping this invalidates tuned-table entries for the int8_dot plan
-#: (tune/plans.py kernel_version contract)
-KERNEL_VERSION = 1
-
 #: symmetric int8 range: +-127 (the -128 slot is unused so negation is
 #: closed and the scale math stays symmetric)
 INT8_MAX = 127.0
-
-
-def normalize_tiles(rows: int, cols: int, k: int, block_m: int,
-                    block_n: int, block_k: int) -> Tuple[int, int, int]:
-    """Clamp an int8_dot block plan to the operand extents (lane-padded to
-    the 128 MXU lane width), the same normalize-before-key contract as the
-    Pallas kernels: equivalent plans collapse to one tuned-table entry."""
-
-    def _clamp(block: int, extent: int) -> int:
-        block = max(int(block), 8)
-        if extent > 0:
-            block = min(block, max(-(-int(extent) // 128) * 128, 8))
-        return block
-
-    return (_clamp(block_m, rows), _clamp(block_n, cols), _clamp(block_k, k))
 
 
 def quantize_per_channel(w) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -91,26 +67,8 @@ def int8_matmul(x_q, w_q) -> jnp.ndarray:
     last axis of ``x_q`` against the first of ``w_q`` (the dense-layer
     layout). ``preferred_element_type=int32`` is the whole point — an int8
     accumulator would overflow at K > ~2, and f32 accumulation would
-    forfeit the integer MXU path this mode exists for.
-
-    Consults the ``int8_dot`` tile plan (tune/runtime.py) at trace time so
-    int8 executions are announced and tuned under their own dtype axis;
-    the plan is advisory for the XLA lowering but is the tuned-table key
-    a Pallas int8 kernel will consume verbatim."""
-    try:  # keying/announcement only — never allowed to fail the matmul
-        from ..tune.runtime import tile_plan
-
-        tile_plan(
-            "int8_dot",
-            {
-                "rows": int(x_q.shape[0]) if x_q.ndim > 1 else 1,
-                "cols": int(w_q.shape[-1]),
-                "k": int(w_q.shape[0]),
-            },
-            dtype="int8",
-        )
-    except Exception:  # noqa: BLE001 — advisory plane
-        pass
+    forfeit the integer MXU path this mode exists for. XLA's own product:
+    there is no kernel of this repo, and no tile, behind it."""
     return lax.dot_general(
         x_q,
         w_q,

@@ -84,23 +84,13 @@ def segment_sum(
         _debug_check_sorted(segment_ids)
     if sorted_ids and max_degree and msg.ndim == 2 and _pallas_route_enabled():
         from .pallas_segment import sorted_segment_sum
-        from ..tune.runtime import tile_plan
 
-        # block constants come from the tuned-table lookup (tuned entry for
-        # this (kernel, device, shape, dtype) if one exists, else the pinned
-        # defaults, normalized either way so equivalent plans share one jit
-        # specialization — tune/runtime.py)
-        plan = tile_plan("segment_sum", {
-            "edges": msg.shape[0], "channels": msg.shape[1],
-            "num_segments": num_segments, "max_degree": max_degree,
-        }, msg.dtype)
-        # forcing the route on a non-TPU backend (HYDRAGNN_PALLAS_SEGMENT=1,
+        # the kernel runs with its own tiles (ops/pallas_segment.py).
+        # Forcing the route on a non-TPU backend (HYDRAGNN_PALLAS_SEGMENT=1,
         # e.g. the CPU-mesh dryrun) runs the kernel in interpret mode —
         # same program, Python-evaluated blocks
         return sorted_segment_sum(
             msg, segment_ids, num_segments, max_degree,
-            block_rows=plan["block_rows"], block_edges=plan["block_edges"],
-            block_cols=plan["block_cols"],
             interpret=jax.default_backend() != "tpu",
         )
     return jax.ops.segment_sum(msg, segment_ids, num_segments=num_segments)
@@ -132,18 +122,10 @@ def fused_edge_message_sum(
         _debug_check_sorted(segment_ids)
     if max_degree and _pallas_route_enabled():
         from .pallas_fused_edge import fused_edge_message_sum as _pallas_fused
-        from ..tune.runtime import tile_plan
 
-        plan = tile_plan("fused_edge", {
-            "edges": edge_in.shape[0], "ci": edge_in.shape[1],
-            "co": weights.shape[1], "num_segments": num_segments,
-            "max_degree": max_degree, "dtype": str(edge_in.dtype),
-        }, edge_in.dtype)
         return _pallas_fused(
             node_recv, edge_in, weights, bias, segment_ids, num_segments,
-            max_degree, block_rows=plan["block_rows"],
-            block_edges=plan["block_edges"], block_cols=plan["block_cols"],
-            interpret=jax.default_backend() != "tpu",
+            max_degree, interpret=jax.default_backend() != "tpu",
         )
     from .pallas_fused_edge import reference_edge_message_sum
 
@@ -196,23 +178,8 @@ def multi_moment_agg(
 
     if (sorted_ids and max_degree and edge_in.ndim == 2
             and _multiagg_route_enabled()):
-        from ..tune.runtime import tile_plan
-
-        # normalizing HERE (tile_plan always returns a clamped plan) is
-        # also the fix for the specialization-key bug: the kernel clamps
-        # block_cols to the lane-padded channel width internally, but the
-        # custom_jvp nondiff args — and hence the jit executable cache —
-        # used to key on the caller's unclamped value
-        plan = tile_plan("multi_agg", {
-            "edges": edge_in.shape[0], "channels": edge_in.shape[1],
-            "num_segments": num_segments, "max_degree": max_degree,
-            "has_recv": node_recv is not None, "has_gate": gate is not None,
-            "dtype": str(edge_in.dtype),
-        }, edge_in.dtype)
         return fused_multi_agg(
             node_recv, edge_in, gate, segment_ids, num_segments, max_degree,
-            block_rows=plan["block_rows"], block_edges=plan["block_edges"],
-            block_cols=plan["block_cols"],
             interpret=jax.default_backend() != "tpu",
         )
     return reference_multi_agg(

@@ -51,21 +51,9 @@ def _block_attend(q, k, v, kmask, m, denom, acc, scale, use_flash=False):
     """
     if use_flash:
         from ..ops.pallas_flash_attention import flash_block_summary
-        from ..tune.runtime import tile_plan
 
-        # ring blocks are their own ladder slot: same kernel inner loop,
-        # different shape regime (local queries vs one rotating K/V block),
-        # so the key carries a role marker and never collides with the
-        # GPS batch slots (tune/runtime.py)
-        plan = tile_plan("flash_attention", {
-            "nodes": q.shape[0], "heads": q.shape[1],
-            "head_dim": q.shape[2], "max_nodes_per_graph": 0,
-            "role": "block_summary",
-        }, q.dtype)
         m_b, l_b, acc_b = flash_block_summary(
-            q, k, v, kmask, block_q=plan["block_q"],
-            block_k=plan["block_k"],
-            interpret=jax.default_backend() != "tpu",
+            q, k, v, kmask, interpret=jax.default_backend() != "tpu",
         )
         new_m = jnp.maximum(m, m_b)
         corr = jnp.exp(m - new_m)

@@ -808,14 +808,6 @@ def train_validate_test(
 
         events_armed = _attach_events(run_dir) is not None
 
-    # kernel autotuning plane (tune/; docs/TUNING.md): install the run's
-    # tuned table BEFORE the warm-up traces below, so every Pallas route's
-    # tile_plan lookup consults it (autotune=cached) or a budgeted sweep
-    # fills it first (autotune=sweep); off/no-table keeps pinned defaults
-    from ..tune.runtime import setup_autotune
-
-    setup_autotune(config, train_loader, log_name)
-
     # compile plane (train/compile_plane.py): AOT warm-up of every
     # (train, eval) x pad-bucket specialization against the persistent
     # compilation cache, plus the retrace sentinel. Degrades to off when no

@@ -36,9 +36,6 @@ python -m hydragnn_tpu.analysis --json > logs/graftlint_ci.json 2>/dev/null || {
 }
 echo "graftlint gate green ($(python -c "import json;print(json.load(open('logs/graftlint_ci.json'))['summary']['waived'])") waived)"
 
-echo "== kernel-autotune smoke (interpret-mode sweep over all 4 Pallas kernels -> atomic table write -> 100% cache-hit second run -> runtime lookup serves the winner) =="
-python run-scripts/tune_smoke.py
-
 echo "== $TIER suite (8-device CPU mesh) =="
 python -m pytest tests/ -x -q --deselect tests/test_multihost.py "$@"
 
@@ -53,11 +50,6 @@ BENCH_GUARD_SMOKE=1 python bench.py
 
 echo "== BENCH_PNA smoke (PNA multi-agg bench cells build + train on CPU; fused==dense) =="
 BENCH_PNA_SMOKE=1 python bench.py
-
-echo "== BENCH_TUNE smoke (per-kernel default-vs-tuned tile A/B cells build on CPU; interpret mode, tiny shapes) =="
-BENCH_TUNE=1 BENCH_TUNE_NODES=64 BENCH_TUNE_EDGES=256 BENCH_TUNE_HIDDEN=16 \
-  BENCH_TUNE_MAX_DEGREE=8 BENCH_TUNE_HEADS=2 BENCH_TUNE_NMAX=16 \
-  BENCH_TUNE_BUDGET=2 BENCH_TUNE_TRIALS=1 python bench.py
 
 echo "== compile-plane smoke (background precompile + error-mode retrace sentinel; cold -> warm cache) =="
 python run-scripts/compile_smoke.py

@@ -381,57 +381,6 @@ def pytest_atomic_write_module_level_write_fires(tmp_path):
     assert "module scope" in got[0].message and got[0].line == 3
 
 
-# ---------------------------------------------------------------------------
-# tile_constants
-# ---------------------------------------------------------------------------
-
-def pytest_tile_constants_fires_on_pinned_literal_call_sites(tmp_path):
-    repo = mini_repo(tmp_path, {
-        "hydragnn_tpu/ops/segment.py": """
-            def route(msg):
-                return segment_sum_pallas(msg, block_rows=128, block_cols=512)
-        """,
-        "hydragnn_tpu/models/gps.py": """
-            def attend(q):
-                return flash_self_attention(q, block_q=64, block_k=plan["block_k"])
-        """,
-    })
-    got = findings_of(repo, "tile_constants")
-    assert len(got) == 3, got  # block_rows, block_cols, block_q — NOT plan[...]
-    msgs = "\n".join(f.message for f in got)
-    assert "block_rows=128" in msgs and "block_cols=512" in msgs
-    assert "block_q=64" in msgs
-    assert all("tile_plan" in f.hint for f in got)
-
-
-def pytest_tile_constants_exempts_kernel_modules_and_tune_plane(tmp_path):
-    repo = mini_repo(tmp_path, {
-        # the kernel module owns its pinned defaults (incl. internal calls)
-        "hydragnn_tpu/ops/pallas_segment.py": """
-            def _forward(msg):
-                return _kernel(msg, block_rows=128, block_edges=512)
-        """,
-        # plans.py owns the candidate grids and default plans
-        "hydragnn_tpu/tune/plans.py": """
-            DEFAULTS = dict(segment=make_plan(block_rows=128, block_cols=512))
-        """,
-    })
-    assert findings_of(repo, "tile_constants") == []
-
-
-def pytest_tile_constants_waiver_with_reason_waives(tmp_path):
-    repo = mini_repo(tmp_path, {
-        "hydragnn_tpu/models/gps.py": """
-            def attend(q):
-                # graftlint: disable=tile_constants -- fixed tile is load-bearing here
-                return flash_self_attention(q, block_q=16)
-        """,
-    })
-    got = findings_of(repo, "tile_constants")
-    assert len(got) == 1 and got[0].waived
-    assert "load-bearing" in got[0].waive_reason
-
-
 def pytest_env_census_stale_row_not_kept_alive_by_linter_prose(tmp_path):
     # a flag named ONLY in the analysis plane's / envflags' own docstrings
     # is dead: the docs row for it must still be flagged stale
@@ -549,12 +498,12 @@ def pytest_baseline_roundtrip_is_local_only_suppression(tmp_path, capsys):
     capsys.readouterr()
 
 
-def pytest_checker_catalog_lists_all_ten():
+def pytest_checker_catalog_lists_all_nine():
     ids = {c.id for c in analysis.checkers()}
     assert ids == {
         "env_census", "config_keys", "obs_contract", "trace_hazard",
         "threads", "atomic_write", "error_codes", "fault_coverage",
-        "tile_constants", "sharding_rules",
+        "sharding_rules",
     }
     for c in analysis.checkers():
         assert c.rationale, c.id  # every checker cites its incident

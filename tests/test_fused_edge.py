@@ -597,23 +597,19 @@ def v5e_chip():
 def pytest_bf16_kernel_compiles_for_v5e_at_the_cell_shape(v5e_chip, tangent):
     """Mosaic accepts the bf16 call the training step makes since the edge
     length joins the feature stream in bf16: ``[12160, 896]`` rows,
-    196608 edges, in-degree bound 36, the default tile plan."""
-    from hydragnn_tpu.tune.runtime import tile_plan
+    196608 edges, in-degree bound 36, the kernel's own tiles."""
+    from hydragnn_tpu.ops.pallas_fused_edge import normalize_tiles
 
     n, e, c, deg = 12136, 196608, 866, 36
-    plan = tile_plan("fused_edge", {
-        "edges": e, "num_segments": n, "max_degree": deg, "ci": c, "co": c,
-        "dtype": "bfloat16"}, jnp.bfloat16)
-    assert plan["block_rows"] % 16 == 0 and plan["block_edges"] % 16 == 0, plan
+    plan = normalize_tiles(c, c, jnp.bfloat16)
+    assert plan[0] % 16 == 0 and plan[1] % 16 == 0, plan
     shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         shape, dtype, sharding=v5e_chip)
     operands = (shaped((n, c)), shaped((e, c)), shaped((c, c)), shaped((c,)))
     ids = shaped((e,), jnp.int32)
 
     def kernel(ids, *ops):
-        return fused_edge_message_sum(
-            *ops, ids, n, deg, plan["block_rows"], plan["block_edges"],
-            plan["block_cols"], False)
+        return fused_edge_message_sum(*ops, ids, n, deg, *plan, False)
 
     if tangent:
         fn = lambda ids, p, t: jax.jvp(lambda *o: kernel(ids, *o), p, t)
