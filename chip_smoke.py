@@ -13,7 +13,9 @@ It refuses any backend but ``tpu`` before building data, then drives
    each of the four older Pallas kernels compiled
    (``interpret=False``) at the widths the repo's cells use and compared with
    its plain-``jnp`` reference, first the bf16 fused-edge call at the
-   benchmark cells' own shape (forward and tangent);
+   benchmark cells' own shape (forward and tangent), then the receiver
+   gather's transposed kernel call and a message layer's pair of row
+   gathers (ordered against plain, bit for bit) at that shape;
 2. the main leg: SC25-shaped EGNN (hidden 866, 4 conv layers) through
    ``run_training`` -> ``run_prediction`` -> ``run_server`` in this process,
    with the lowered programs checked for Mosaic custom calls and the kernel
@@ -300,6 +302,46 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
                 (f"the scatter-add it replaces {ms_scatter:.2f} ms",
                  _rel_err(out_scatter, ref), 1.0)]
 
+    def row_gather(c, dtype, ids, n_nodes, max_degree):
+        """A message layer's two row gathers, ``(x W_r + b)[recv]`` and
+        ``(x W_s)[send]``, as ``models/layers.py hoisted_pair_dense`` orders
+        them (product, gather, product, gather) against the same sum spelled
+        plainly, which XLA schedules products first: the rows equal to the
+        bit, and both wall times read (alone a pair may schedule either way;
+        the step's own trace is what PERF.md holds)."""
+        import types
+
+        from flax import linen as nn
+        from hydragnn_tpu.models.layers import hoisted_pair_dense
+
+        e = ids.shape[0]
+        send = jnp.asarray(rng.integers(0, n_nodes, e).astype(np.int32))
+        batch = types.SimpleNamespace(senders=send, receivers=ids)
+
+        class Ordered(nn.Module):
+            @nn.compact
+            def __call__(self, v):
+                return hoisted_pair_dense(c, v, batch, "recv", "send",
+                                          sorted_ids=True, max_degree=max_degree)
+
+        class Plain(nn.Module):
+            @nn.compact
+            def __call__(self, v):
+                return (nn.Dense(c, name="recv")(v)[ids]
+                        + nn.Dense(c, use_bias=False, name="send")(v)[send])
+
+        x = arr((n_nodes, c), dtype)
+        params = {"params": {
+            "recv": {"kernel": arr((c, c), dtype, c ** -0.5),
+                     "bias": arr((c,), dtype)},
+            "send": {"kernel": arr((c, c), dtype, c ** -0.5)}}}
+        out, ms = _timed_ms(jax.jit(Ordered().apply), params, x)
+        ref, ms_plain = _timed_ms(jax.jit(Plain().apply), params, x)
+        assert out.dtype == ref.dtype == jnp.dtype(dtype), (out.dtype, ref.dtype)
+        differing = float(jnp.sum(f32(out) != f32(ref))) / out.size
+        return [(f"ordered {ms:.2f} ms, plain {ms_plain:.2f} ms, "
+                 "share of entries that differ", differing, 0.0)]
+
     if cell_shape:
         # FIRST, the shape the bf16 training step runs since the edge length
         # joins the feature stream in bf16 (models/layers.py
@@ -319,6 +361,13 @@ def kernel_cases(channels=(866, 256), n_nodes=2400, max_degree=20,
                "bfloat16",
                lambda: gather_transpose(channels[0], jnp.bfloat16, cell_ids,
                                         cell["n_nodes"], cell["max_degree"]))
+        # a layer's pair of row gathers at the same shape, in the order the
+        # step gives them: twelve such gathers a step
+        yield (f"row_gather cell c={channels[0]} n={cell['n_nodes']} "
+               f"e={cell['edges']} deg<={cell['max_degree']} bfloat16",
+               "bfloat16",
+               lambda: row_gather(channels[0], jnp.bfloat16, cell_ids,
+                                  cell["n_nodes"], cell["max_degree"]))
     for dtype in (jnp.bfloat16, jnp.float32):
         dt = jnp.dtype(dtype).name
         for c in channels:
@@ -736,7 +785,8 @@ def egnn_force_gradient_gap(hidden=64, tol=5e-3) -> float:
     with mock.patch.object(layers, "gather", plain_gather), \
             mock.patch.object(fused, "gather", plain_gather):
         plain_calls, plain = leaves()
-    assert plain_calls == 0, plain_calls
+    # the fused rule's closing sums stay linear calls under the plain gather
+    assert 0 < plain_calls < calls, (plain_calls, calls)
     floor = 1e-3 * max(np.abs(g).max() for g in plain)
     gap = max(np.abs(a - b).max() / max(np.abs(b).max(), floor)
               for a, b in zip(routed, plain))

@@ -198,8 +198,19 @@ def pair_message_factored(dim, inv, batch, name_recv, name_send, edge_terms=()):
     length would otherwise promote every ``[E, C]`` array downstream of
     it, the Pallas kernels' streams included. The identity in a float32
     step."""
+    edge_in = gather(
+        nn.Dense(dim, use_bias=False, name=name_send)(inv), batch.senders)
+    # ORDER, not arithmetic: the receiver projection waits for the sender
+    # gather, so a layer runs product, gather, product, gather. On TPU a row
+    # gather writes at the speed of HBM only while its node-sized operand is
+    # still in VMEM, and XLA keeps it there when the op that made it is
+    # scheduled directly before the gather; with both products hoisted ahead
+    # of both gathers one operand is left in HBM and its gather fetches it a
+    # row at a time, six times slower. The barrier's transpose orders the
+    # backward the same way (PERF.md section 6, PR 32;
+    # tests/test_fused_edge.py holds the compiled step to it).
+    inv, edge_in = jax.lax.optimization_barrier((inv, edge_in))
     node_recv = nn.Dense(dim, name=name_recv)(inv)
-    edge_in = nn.Dense(dim, use_bias=False, name=name_send)(inv)[batch.senders]
     for name, arr in edge_terms:
         edge_in = edge_in + nn.Dense(dim, use_bias=False, name=name)(
             arr.astype(inv.dtype)

@@ -56,6 +56,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.custom_derivatives import linear_call
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from ..utils import tracer as tr
@@ -78,10 +79,34 @@ def reference_edge_message_sum(
     ``node_recv[ids]`` gather is a sorted segment sum (ops/segment.py
     ``gather``). The fallback and the oracle leave it out and get the plain
     gather, as every route does where the Pallas route is off.
+
+    With it the closing sum is a ``linear_call`` too, for the ORDER of the
+    backward: its transpose, the ``dout[ids]`` gather, bars ``dout`` behind
+    the recomputed ``node_recv[ids]`` rows, so XLA relays ``dout`` directly
+    before its gather and the gather reads it from VMEM. Left to itself the
+    scheduler relays ``dout`` first, recomputes the rows in between, and the
+    gather fetches ``dout`` from HBM a row at a time, six times slower
+    (models/layers.py ``pair_message_factored`` has the forward's half;
+    PERF.md section 6, PR 32). The rows are a residual of the sum, which is
+    linear in ``msg`` alone, hence the ``stop_gradient``.
     """
-    pre = gather(node_recv, segment_ids, True, max_degree) + edge_in
-    msg = jax.nn.relu(jnp.dot(jax.nn.relu(pre), weights) + bias)
-    return jax.ops.segment_sum(msg, segment_ids, num_segments=num_segments)
+    rows = gather(node_recv, segment_ids, True, max_degree)
+    msg = jax.nn.relu(jnp.dot(jax.nn.relu(rows + edge_in), weights) + bias)
+    if not max_degree:
+        return jax.ops.segment_sum(msg, segment_ids, num_segments=num_segments)
+
+    def total(res, m):
+        with tr.scope(tr.HG_ROW_GATHER):
+            return jax.ops.segment_sum(m, res[0], num_segments=num_segments)
+
+    def total_transpose(res, ct):
+        ids, after = res
+        ct, _ = jax.lax.optimization_barrier((ct, after))
+        with tr.scope(tr.HG_ROW_GATHER):
+            return ct[ids]
+
+    return linear_call(total, total_transpose,
+                       (segment_ids, jax.lax.stop_gradient(rows)), msg)
 
 
 def _kernel(estart_ref, ids_ref, nrecv_ref, ein_ref, w_ref, b_ref, out_ref):

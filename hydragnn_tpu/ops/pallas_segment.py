@@ -228,7 +228,8 @@ def _jvp(num_segments, max_degree, block_rows, block_edges, block_cols,
     # as the r5 custom_vjp — and it is differentiable to any order, so
     # grad-of-grad (energy-force training) composes instead of hitting
     # pallas_call's missing JVP rule.
-    with tr.scope(tr.HG_SORTED_SEGMENT + tr.TANGENT):
+    with tr.scope(tr.HG_SORTED_SEGMENT + tr.TANGENT), \
+            tr.scope(tr.HG_ROW_GATHER):
         t_out = jax.ops.segment_sum(
             t_msg, segment_ids, num_segments=num_segments
         ).astype(out.dtype)

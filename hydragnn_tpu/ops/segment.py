@@ -274,7 +274,8 @@ def gather(
     the Pallas route) the gather becomes a linear call whose transpose is
     ``segment_sum(ct, index, rows, sorted_ids=True, max_degree=...)``: the
     ``hg_sorted_segment`` kernel under the scope ``hg_gather_transpose``.
-    The forward is the same ``values[index]`` on every route.
+    The forward is the same ``values[index]`` on every route, under the
+    scope ``hg_row_gather``.
 
     ``jax.custom_derivatives.linear_call`` is the tool: it is transposable
     (a ``custom_vjp`` on a tangent path is not, and the fused-edge tangent
@@ -296,7 +297,8 @@ def gather(
         num_rows = values.shape[0]
 
         def take(ids, x):
-            return x[ids]
+            with tr.scope(tr.HG_ROW_GATHER):
+                return x[ids]
 
         def take_transpose(ids, ct):
             with tr.scope(tr.HG_GATHER_TRANSPOSE):
@@ -305,7 +307,8 @@ def gather(
                 )
 
         return linear_call(take, take_transpose, index, values)
-    return values[index]
+    with tr.scope(tr.HG_ROW_GATHER):
+        return values[index]
 
 
 def masked_global_mean_pool(x, node_graph, num_graphs, node_mask):

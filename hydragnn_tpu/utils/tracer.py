@@ -71,6 +71,10 @@ TANGENT = "_tangent"
 # the transpose of ops/segment.py gather(sorted_ids=True): a scope AROUND the
 # hg_sorted_segment scope and call, which tells a transposed sum from a forward one
 HG_GATHER_TRANSPOSE = "hg_gather_transpose"
+# an edge-sized feature gather ``[N, C] -> [E, C]`` of the message path, and
+# the tangent sums whose transposes are such gathers: an op_name that holds
+# it and ends in ``/gather`` is one of a step's row gathers
+HG_ROW_GATHER = "hg_row_gather"
 BWD = "_bwd"  # a kernel's own backward launches (custom-VJP kernels)
 
 _enabled = False

@@ -108,3 +108,46 @@ def pytest_chip_smoke_gather_transpose_case_rehearsed(monkeypatch):
     monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "0")
     with pytest.raises(AssertionError, match="kernel route"):
         check()
+
+
+@pytest.mark.parametrize("route", ["0", "1"])
+def pytest_chip_smoke_row_gather_case_rehearsed(monkeypatch, route):
+    """The cell-shape case of a layer's pair of row gathers comes THIRD; at
+    a tiny size, on either route, the ordered spelling's rows equal the plain
+    one's to the bit and both wall times are printed."""
+    import re
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", route)
+    smoke = _load_smoke()
+    tiny = {"n_nodes": 90, "edges": 700, "max_degree": 9, "mean_degree": 5.0}
+    cases = iter(smoke.kernel_cases(
+        channels=(24,), n_nodes=70, max_degree=8, interpret=True,
+        cell_shape=tiny))
+    next(cases), next(cases)
+    name, dt, check = next(cases)
+    assert name.startswith("row_gather cell") and dt == "bfloat16", name
+    ((label, differing, tol),) = check()
+    assert re.match(r"ordered \d+\.\d\d ms, plain \d+\.\d\d ms, ", label), label
+    assert differing == 0.0 and tol == 0.0
+
+
+def pytest_chip_smoke_force_gradient_gap_rehearsed(monkeypatch):
+    """The second-order leg's comparison at a tiny width, the kernel routes
+    forced on in interpret mode (on the chip config completion turns them
+    on): the energy-force gradient through the transposed gathers equals
+    the plain gather's, and the plain side keeps only the fused rule's
+    closing linear calls."""
+    import hydragnn_tpu.config as config_pkg
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    complete = config_pkg.update_config
+
+    def routed(config, *datasets):
+        config["NeuralNetwork"]["Architecture"].update(
+            use_sorted_aggregation=True, use_fused_edge_kernel=True)
+        return complete(config, *datasets)
+
+    monkeypatch.setattr(config_pkg, "update_config", routed)
+    smoke = _load_smoke()
+    assert smoke.egnn_force_gradient_gap(hidden=16) <= 5e-3
+

@@ -19,7 +19,11 @@ from hydragnn_tpu.ops.pallas_fused_edge import (
     fused_edge_message_sum,
     reference_edge_message_sum,
 )
-from test_pallas_segment import _sorted_capped_receivers
+from test_pallas_segment import (
+    _bits,
+    _row_gather_case,
+    _sorted_capped_receivers,
+)
 
 
 def _operands(rng, e, n, ci, co, dtype=np.float32):
@@ -529,9 +533,12 @@ def pytest_transposed_gather_is_in_the_egnn_step(monkeypatch):
     with monkeypatch.context() as plain:
         _plain_gather(plain)
         off = jaxpr()
-    assert "= linear_call[" not in off
-    # forward + transposed, four gathers
-    assert on.count("= linear_call[") == 8, on.count("= linear_call[")
+    # the fused layer's rule closes on a linear call of its own, a sum of
+    # tangents alone: the gradient holds its transpose, the ordered
+    # ``dout[ids]`` gather
+    assert off.count("= linear_call[") == 1, off.count("= linear_call[")
+    # forward + transposed, four gathers more
+    assert on.count("= linear_call[") == 9, on.count("= linear_call[")
     assert on.count("name=hg_sorted_segment") - off.count(
         "name=hg_sorted_segment") == 4
     assert off.count("scatter-add") - on.count("scatter-add") == 4
@@ -568,6 +575,160 @@ def pytest_energy_force_gradients_transposed_equal_plain(monkeypatch):
     # float32 second order: summation order alone (measured 2.6e-4)
     _assert_leaves_equal(got, ref, 2e-3)
     assert any(np.abs(v).max() > 0 for v in ref.values())
+
+
+# ---------------------------------------------------------------------------
+# ORDER for the row gathers (PERF.md section 6, PR 32): the barrier in
+# ``pair_message_factored`` and the fused rule's closing linear call change
+# the schedule and nothing else, so each is held to the unordered spelling
+# bit for bit, under the transforms a step puts them through
+# ---------------------------------------------------------------------------
+
+
+def _order_case(dtype, c):
+    """A padded batch's receiver-sorted ids (edge-less rows, a padding run to
+    the dummy node), senders anywhere, ``[n, c]`` features."""
+    recv, x, _, max_degree = _row_gather_case(dtype, c)
+    send = np.random.default_rng(c + 1).integers(
+        0, x.shape[0], recv.shape[0]).astype(np.int32)
+    return recv, jnp.asarray(send), x, max_degree
+
+
+def _assert_bit_equal(got, ref):
+    got, ref = (jax.tree_util.tree_leaves(t) for t in (got, ref))
+    assert len(got) == len(ref)
+    for g, r in zip(got, ref):
+        np.testing.assert_array_equal(_bits(g), _bits(r))
+
+
+@pytest.mark.parametrize("c", [3, 128, 866])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def pytest_pair_message_order_changes_no_bit(dtype, c):
+    """``hoisted_pair_dense`` (sender gather, barrier, receiver projection,
+    receiver gather) against ``(x W_r + b)[recv] + (x W_s)[send]`` spelled
+    plainly: rows, parameter gradients and input gradient equal to the bit."""
+    import types
+
+    from flax import linen as nn
+    from hydragnn_tpu.models.layers import hoisted_pair_dense
+
+    recv, send, x, deg = _order_case(dtype, c)
+    batch = types.SimpleNamespace(senders=send, receivers=recv)
+
+    class Ordered(nn.Module):
+        @nn.compact
+        def __call__(self, v):
+            return hoisted_pair_dense(c, v, batch, "recv", "send",
+                                      sorted_ids=True, max_degree=deg)
+
+    class Plain(nn.Module):
+        @nn.compact
+        def __call__(self, v):
+            return (nn.Dense(c, name="recv")(v)[recv]
+                    + nn.Dense(c, use_bias=False, name="send")(v)[send])
+
+    params = jax.tree_util.tree_map(
+        lambda p: p.astype(dtype),
+        Ordered().init(jax.random.PRNGKey(0), x))
+    w = jnp.asarray(np.random.default_rng(1).normal(
+        size=(recv.shape[0], c)), dtype)
+
+    def run(module):
+        loss = lambda p, v: jnp.sum(
+            (module.apply(p, v) * w).astype(jnp.float32))
+        return module.apply(params, x), jax.jit(
+            jax.grad(loss, argnums=(0, 1)))(params, x)
+
+    _assert_bit_equal(run(Ordered()), run(Plain()))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "transform", ["grad", "checkpoint", "grad_of_grad", "shard_map"])
+def pytest_tangent_rule_closing_sum_composes(transform, dtype):
+    """The tangent rule's statement (``max_degree`` given: the closing sum a
+    linear call whose transpose bars ``dout`` behind the rows) against the
+    oracle's (the plain ``segment_sum``): value and every gradient equal to
+    the bit under ``jit(grad)``, ``jax.checkpoint``, grad-of-grad (energy-force
+    training) and ``shard_map`` (the mesh step)."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    recv, _, nr, deg = _order_case(dtype, 16)
+    n, e = nr.shape[0], recv.shape[0]
+    _, ei, w, b = _operands(np.random.default_rng(2), e, n, 16, 16)
+    ops = tuple(o.astype(dtype) for o in (nr, ei, w, b))
+
+    def apply(max_degree):
+        fn = lambda *o: reference_edge_message_sum(
+            *o, recv, n, max_degree)
+        loss = lambda *o: jnp.sum(jnp.sin(fn(*o).astype(jnp.float32)))
+        every = tuple(range(4))
+        if transform == "grad":
+            return fn(*ops), jax.jit(jax.grad(loss, every))(*ops)
+        if transform == "checkpoint":
+            return jax.jit(jax.grad(jax.checkpoint(loss), every))(*ops)
+        if transform == "grad_of_grad":
+            inner = lambda *o: jnp.sum(
+                jax.grad(loss, 1)(*o).astype(jnp.float32) ** 2)
+            return jax.jit(jax.grad(inner, every))(*ops)
+        mesh = Mesh(np.asarray(jax.devices()[:2]), ("d",))
+        two = tuple(jnp.stack([o, 2 * o]) for o in ops)
+        per_shard = lambda *o: jax.lax.psum(jax.tree_util.tree_map(
+            lambda g: g[None], jax.grad(loss, every)(*(a[0] for a in o))), "d")
+        return jax.jit(jax.shard_map(
+            per_shard, mesh=mesh, in_specs=P("d"), out_specs=P()))(*two)
+
+    _assert_bit_equal(apply(deg), apply(None))
+
+
+def pytest_ordered_egnn_gradients_under_shard_map_equal_unsharded(monkeypatch):
+    """The mesh step's composition, in the model: the equivariant EGNN's
+    training gradient (three ordered layers, the fused layer's rule, four
+    transposed gathers) taken per shard under ``shard_map`` and averaged
+    equals the unsharded one."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from hydragnn_tpu.train.loss import compute_loss
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    model, variables, batch = _padded_stack("EGNN", True)
+    ref = _grad_leaves(model, variables, batch)
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("d",))
+    twice = jax.tree_util.tree_map(lambda x: jnp.stack([x, x]), batch)
+
+    def per_shard(params, shard):
+        mine = jax.tree_util.tree_map(lambda x: x[0], shard)
+        loss = lambda p: compute_loss(
+            model, {"params": p, "batch_stats": {}}, mine, model.cfg, True,
+            jax.random.PRNGKey(0), False)[0]
+        return jax.lax.pmean(jax.grad(loss)(params), "d")
+
+    grads = jax.jit(jax.shard_map(
+        per_shard, mesh=mesh, in_specs=(P(), P("d")), out_specs=P(),
+        check_vma=False))(variables["params"], twice)
+    got = {jax.tree_util.keystr(path): np.asarray(leaf, np.float32)
+           for path, leaf in jax.tree_util.tree_leaves_with_path(grads)}
+    _assert_leaves_equal(got, ref, 1e-5)
+
+
+def pytest_row_gather_order_is_in_the_egnn_step(monkeypatch):
+    """The equivariant EGNN's gradient program holds the order at every
+    edge-sized feature gather: one barrier a message layer forward and its
+    transpose backward (``pair_message_factored``), and the fused layer's
+    rule bars ``dout`` behind the recomputed rows (one more, backward only)."""
+    from hydragnn_tpu.train.loss import compute_loss
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", "1")
+    model, variables, batch = _padded_stack("EGNN", True)
+    loss = lambda p: compute_loss(
+        model, {"params": p, "batch_stats": {}}, batch, model.cfg, True,
+        jax.random.PRNGKey(0), False)[0]
+    forward = str(jax.make_jaxpr(loss)(variables["params"]))
+    assert forward.count("optimization_barrier") == 4, forward.count(
+        "optimization_barrier")
+    grad = str(jax.make_jaxpr(jax.grad(loss))(variables["params"]))
+    assert grad.count("optimization_barrier") == 9, grad.count(
+        "optimization_barrier")
 
 
 # ---------------------------------------------------------------------------
@@ -677,8 +838,15 @@ def _cell_train_step_text(monkeypatch, v5e_chip):
     return lowered.compile().as_text()
 
 
+@pytest.fixture(scope="module")
+def cell_step_text(v5e_chip):
+    """The packed cell's step as the cells run it, compiled once a module."""
+    with pytest.MonkeyPatch.context() as patch:
+        return _cell_train_step_text(patch, v5e_chip)
+
+
 def pytest_cell_train_step_compiles_for_v5e_with_transposed_gathers(
-        monkeypatch, v5e_chip):
+        monkeypatch, v5e_chip, cell_step_text):
     """The whole bf16 train step of ``egnn866_oc20_train``'s shape
     (``[12136, 866]`` rows, 196608 edges, in-degree bound 36): Mosaic takes
     the four transposed calls, ten ``hg_sorted_segment`` calls for six, and
@@ -696,9 +864,55 @@ def pytest_cell_train_step_compiles_for_v5e_with_transposed_gathers(
             len(re.findall(r"= bf16\[12136,866\]\S* scatter\(", text)),
         )
 
-    transposed = counts(_cell_train_step_text(monkeypatch, v5e_chip))
+    transposed = counts(cell_step_text)
     with monkeypatch.context() as plain:
         _plain_gather(plain)
         before = counts(_cell_train_step_text(plain, v5e_chip))
     assert before == (6, 1, 8), before
     assert transposed == (10, 1, 4), transposed
+
+
+def _row_gathers(text, shape):
+    """The scheduled step's gather fusions that write ``shape``: (fusion,
+    op_name, whether the gathered operand is assigned to VMEM, memory space
+    ``S(1)`` in its layout), in schedule order."""
+    import re
+
+    gathering = set()
+    name = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+        elif name and " gather(" in line:
+            gathering.add(name)
+    entry = re.search(r"^ENTRY .*?^\}", text, re.M | re.S).group(0)
+    types = dict(re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (\S+) ", entry, re.M))
+    out = []
+    for m in re.finditer(
+            r"^\s*%([\w.\-]+) = " + re.escape(shape) + r"\S* fusion\(%([\w.\-]+), "
+            r".*calls=%([\w.\-]+).*op_name=\"([^\"]*)\"", entry, re.M):
+        fusion, operand, callee, op_name = m.groups()
+        if callee in gathering and op_name.endswith("/gather"):
+            out.append((fusion, op_name, "S(1)" in types[operand]))
+    return out
+
+
+def pytest_cell_train_step_reads_every_row_gather_from_vmem(cell_step_text):
+    """Twelve ``bf16[196608,866]`` row gathers a step (seven forward, the
+    fused rule's recomputed rows, four ``dout[receivers]``), every one
+    under ``hg_row_gather`` and every one reading its ``[12136,866]``
+    operand from VMEM: XLA leaves the operand there when its producer is
+    scheduled directly before the gather, and such a gather writes at the
+    speed of HBM, six times the one that fetches its rows from HBM (4.35 ms
+    for 0.74 on the chip; PERF.md section 6, PR 32). The order that
+    ``models/layers.py pair_message_factored`` and the fused rule's closing
+    sum set is what this holds; without it six of the twelve read HBM."""
+    from hydragnn_tpu.utils import tracer as tr
+
+    rows = _row_gathers(cell_step_text, "bf16[196608,866]")
+    assert len(rows) == 12, rows
+    assert all(tr.HG_ROW_GATHER in op_name for _, op_name, _ in rows), rows
+    from_hbm = [(fusion, op_name) for fusion, op_name, vmem in rows if not vmem]
+    assert not from_hbm, from_hbm
+

@@ -370,9 +370,11 @@ def pytest_f32_step_jaxpr_unchanged_by_the_edge_term_cast(monkeypatch):
     import hydragnn_tpu.models.layers as layers
 
     def uncast(dim, inv, batch, name_recv, name_send, edge_terms=()):
-        node_recv = nn.Dense(dim, name=name_recv)(inv)
         edge_in = nn.Dense(dim, use_bias=False, name=name_send)(inv)[
             batch.senders]
+        # the order ``pair_message_factored`` sets for the row gathers
+        inv, edge_in = jax.lax.optimization_barrier((inv, edge_in))
+        node_recv = nn.Dense(dim, name=name_recv)(inv)
         for name, arr in edge_terms:
             edge_in = edge_in + nn.Dense(dim, use_bias=False, name=name)(arr)
         return node_recv, edge_in

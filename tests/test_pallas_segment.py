@@ -335,3 +335,89 @@ def pytest_sorted_gather_transpose_is_the_named_kernel(monkeypatch):
     lowered = jax.jit(grad).lower(x).as_text(debug_info=True)
     assert (f"{tr.HG_GATHER_TRANSPOSE}/{tr.HG_SORTED_SEGMENT}" in lowered
             ), "the transposed call's scope is missing from the program"
+
+
+# ---------------------------------------------------------------------------
+# the edge-sized row gathers of a step (scope ``hg_row_gather``): ``gather``
+# forward and the sorted-segment tangent, whose transpose is ``dout[ids]``.
+# A gather copies rows, so both are held bit for bit to ``x[ids]`` /
+# ``jax.ops.segment_sum`` at the widths a step has: 3 (coordinates), 128
+# (one lane block) and 866 (the EGNN-866 cells' features)
+# ---------------------------------------------------------------------------
+
+
+def _bits(x):
+    return np.asarray(jnp.asarray(x, jnp.float32))
+
+
+def _row_gather_case(dtype, c):
+    """``_gather_case``'s padded-batch layout (edge-less rows inside, a
+    padding run to the dummy node with zero cotangents) at width ``c``."""
+    return _gather_case("dummy", dtype, seed=c, n=24, c=c, max_degree=6)
+
+
+_ROW_WIDTHS = [3, 128, 866]
+_ROW_DTYPES = [jnp.float32, jnp.bfloat16]
+
+
+@pytest.mark.parametrize("c", _ROW_WIDTHS)
+@pytest.mark.parametrize("dtype", _ROW_DTYPES)
+@pytest.mark.parametrize("route", ["0", "1"])
+def pytest_row_gather_equals_indexing_bit_for_bit(monkeypatch, route, dtype, c):
+    """``gather`` on either route: the rows are ``x[ids]`` to the bit, its
+    tangent too; off the Pallas route the gradient is the scatter-add's to
+    the bit (on it, the kernel's: ``pytest_sorted_gather_forward_and_vjp``)."""
+    from hydragnn_tpu.ops.segment import gather
+
+    monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", route)
+    ids, x, ct, deg = _row_gather_case(dtype, c)
+    take = lambda v: gather(v, ids, True, deg)
+    out, t_out = jax.jvp(take, (x,), (2 * x,))
+    assert out.dtype == x.dtype and out.shape == (ids.shape[0], c)
+    np.testing.assert_array_equal(_bits(out), _bits(x[ids]))
+    np.testing.assert_array_equal(_bits(t_out), _bits((2 * x)[ids]))
+    if route == "0":
+        (got,) = jax.vjp(take, x)[1](ct)
+        (ref,) = jax.vjp(lambda v: v[ids], x)[1](ct)
+        np.testing.assert_array_equal(_bits(got), _bits(ref))
+
+
+@pytest.mark.parametrize("c", _ROW_WIDTHS)
+@pytest.mark.parametrize("dtype", _ROW_DTYPES)
+def pytest_sorted_segment_tangent_equals_segment_sum_bit_for_bit(dtype, c):
+    """The kernel's tangent rule is the plain sum and its transpose the
+    plain ``dout[ids]`` gather, dummy row and edge-less rows included (the
+    PRIMAL of the over-cap dummy row is the kernel's and unspecified)."""
+    ids, x, ct, deg = _row_gather_case(dtype, c)
+    n = x.shape[0]
+    total = lambda m: sorted_segment_sum(m, ids, n, deg, interpret=True)
+    _, t_out = jax.jvp(total, (ct,), (ct,))
+    ref = jax.ops.segment_sum(ct, ids, num_segments=n)
+    assert t_out.dtype == ct.dtype
+    np.testing.assert_array_equal(_bits(t_out), _bits(ref))
+    (got,) = jax.vjp(total, ct)[1](x)
+    np.testing.assert_array_equal(_bits(got), _bits(x[ids]))
+
+
+def pytest_row_gathers_carry_their_scope(monkeypatch):
+    """Every spelling of an edge-sized feature gather names the scope, on
+    both routes, and the sorted-segment tangent hands it to its transpose."""
+    import re
+
+    from hydragnn_tpu.ops.segment import gather
+    from hydragnn_tpu.utils import tracer as tr
+
+    ids, x, ct, deg = _row_gather_case(jnp.float32, 3)
+    n = x.shape[0]
+    for route in ("0", "1"):
+        monkeypatch.setenv("HYDRAGNN_PALLAS_SEGMENT", route)
+        text = jax.jit(lambda v: gather(v, ids, True, deg)).lower(x).as_text(
+            debug_info=True)
+        assert f"{tr.HG_ROW_GATHER}/gather" in text, route
+    grad = jax.grad(lambda m: jnp.sum(
+        sorted_segment_sum(m, ids, n, deg, interpret=True) ** 2))
+    text = jax.jit(grad).lower(ct).as_text(debug_info=True)
+    assert re.search(
+        rf"transpose\(jvp\({tr.HG_SORTED_SEGMENT}{tr.TANGENT}\)\)/"
+        rf"{tr.HG_ROW_GATHER}/gather", text
+    ), "the transposed tangent sum's gather does not carry the scope"
