@@ -409,18 +409,25 @@ def _blocked_causal_reference(q, k, v, node_graph, node_mask, block=1024):
 
     blocks = lambda a: a.reshape((-1, block) + a.shape[1:])
     out = jax.lax.map(jax.checkpoint(one), (blocks(q), blocks(idx), blocks(node_graph), blocks(node_mask)))
-    return out.reshape(n, hq, d)
+    return out.reshape(n, hq, v.shape[2])
 
 
 def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
                        longest=8192, groups=8, width=2048, interpret=False,
-                       dtypes=("bfloat16", "float32")) -> dict:
-    """The decoder's two kernels alone at the ZAYA cell's shapes, forward and
+                       dtypes=("bfloat16", "float32"), value_dim=None,
+                       width_out=None, topk=0, experts=0, capacity=2.0,
+                       block=1024) -> dict:
+    """The decoder's two kernels alone at a cell's shapes, forward and
     backward, against plain jnp: causal grouped-query flash attention over
-    ``[tokens, heads x head_dim]`` with a longest graph of ``longest`` nodes
-    and a graph boundary inside a tile; the grouped product over ``groups``
-    experts of ragged size, ``width -> width``. Prints each launch's time
-    (a set-up fact, not a throughput)."""
+    ``[tokens, heads x head_dim]`` (values ``value_dim`` wide, ``head_dim``
+    unless given) with a longest graph of ``longest`` nodes and a graph
+    boundary inside a tile; the grouped product over ``groups`` experts of
+    ragged size, ``width -> width_out`` (``width`` unless given). The
+    defaults are the ZAYA cell's (one slot a token); ``topk`` > 0 takes the
+    JOYAI cell's layout instead: every token chooses ``topk`` of ``experts``,
+    ``groups`` of them held, rows within ``capacity`` times the balanced
+    count, dispatch and combine around the product (``joyai_kernel_leg``).
+    Prints each launch's time (a set-up fact, not a throughput)."""
     import jax
     import jax.numpy as jnp
 
@@ -446,18 +453,19 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
     for dt in dtypes:
         dtype = jnp.dtype(dt)
         arr = lambda shape, scale=1.0: jnp.asarray(rng.normal(size=shape) * scale, jnp.float32).astype(dtype)
-        q, k, v = arr((tokens, heads, head_dim)), arr((tokens, kv_heads, head_dim)), arr((tokens, kv_heads, head_dim))
-        w = arr((tokens, heads, head_dim)) * node_mask[:, None, None].astype(dtype)
+        dv = value_dim or head_dim
+        q, k, v = arr((tokens, heads, head_dim)), arr((tokens, kv_heads, head_dim)), arr((tokens, kv_heads, dv))
+        w = arr((tokens, heads, dv)) * node_mask[:, None, None].astype(dtype)
         f32 = lambda a: a.astype(jnp.float32)
+        blocked = lambda q_, k_, v_, g_, m_: _blocked_causal_reference(q_, k_, v_, g_, m_, block)
         with jax.default_matmul_precision("highest"):
-            ref_loss = lambda q_, k_, v_: jnp.sum(
-                _blocked_causal_reference(q_, k_, v_, node_graph, node_mask) * f32(w))
-            ref_out = jax.jit(_blocked_causal_reference)(f32(q), f32(k), f32(v), node_graph, node_mask)
+            ref_loss = lambda q_, k_, v_: jnp.sum(blocked(q_, k_, v_, node_graph, node_mask) * f32(w))
+            ref_out = jax.jit(blocked)(f32(q), f32(k), f32(v), node_graph, node_mask)
             ref_grads = jax.jit(jax.grad(ref_loss, (0, 1, 2)))(f32(q), f32(k), f32(v))
         causal = lambda q_, k_, v_: flash_causal_attention(
             q_, k_, v_, node_graph, node_mask, longest, interpret=interpret)
         bwd = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(f32(causal(q_, k_, v_)) * f32(w)), (0, 1, 2)))
-        tag = f"flash_causal {dt}"
+        tag = f"flash_causal {dt}" + (f" {heads}x{head_dim}/{dv}" if value_dim else "")
         out = timed(tag + " fwd_ms", jax.jit(causal), q, k, v)
         _check(tag + " forward", _rel_err(out[:n_real], ref_out[:n_real]), TOL[dt])
         grads = timed(tag + " fwd+bwd_ms", bwd, q, k, v)
@@ -465,26 +473,55 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
             _check(f"{tag} d{name}", _rel_err(got, want), TOL_DECODER_BWD[dt])
 
         # ---- grouped product: ragged groups, one of them empty
-        share = rng.dirichlet(np.ones(groups - 1) * 2.0)
-        slot_np = rng.choice(groups - 1, size=tokens, p=share)
-        slot_np[rng.random(tokens) < 0.05] = groups  # not held here
-        slot = jnp.asarray(slot_np.astype(np.int32))
-        x, wts = arr((tokens, width)), arr((groups, width, width), 1.0 / np.sqrt(width))
-        cot = arr((tokens, width))
-        bm = gm.normalize_tiles(tokens, width, width, dtype=dt)[0]
+        wide = width_out or width
+        x, wts = arr((tokens, width)), arr((groups, width, wide), 1.0 / np.sqrt(width))
+        cot = arr((tokens, wide))
+        if topk:
+            # every token chooses ``topk`` of ``experts``; the first ``groups`` are held, the first of them by nobody
+            from hydragnn_tpu.models import decoder as dc
 
-        def through(kernel):
-            def f(x_, w_):
-                lay = gm.aligned_layout(slot, groups, bm)
-                rows = gm.permute_rows(x_, lay["src"], lay["dest"])
-                if kernel:
-                    y = gm.grouped_matmul(rows, w_, lay["tile_group"], lay["n_tiles"], bm, interpret=interpret)
-                else:
-                    y = gm.reference_grouped_matmul(rows, w_, lay["tile_group"], bm)
-                return gm.permute_rows(y, lay["dest"], lay["src"])
-            return f
+            choice = jnp.asarray(np.stack(
+                [rng.choice(np.arange(1, experts), size=topk, replace=False) for _ in range(tokens)]).astype(np.int32))
+            gate = arr((tokens, topk)).astype(jnp.float32)
+            bm = gm.normalize_tiles(tokens * topk, width, wide, dtype=dt)[0]
+            balanced = int(np.ceil(capacity * tokens * topk * groups / experts))
+            rows_budget = -(-balanced // bm) * bm + groups * bm
 
-        tag = f"grouped_expert {dt} groups={groups} {width}->{width}"
+            def through(kernel):
+                def f(x_, w_):
+                    lay = dc.topk_layout(choice, node_mask, tuple(range(groups)), experts, bm, rows_budget)
+                    rows = dc.dispatch_rows(x_, lay["token"])
+                    if kernel:
+                        y = gm.grouped_matmul(rows, w_, lay["tile_group"], lay["n_tiles"], bm, interpret=interpret)
+                    else:
+                        y = gm.reference_grouped_matmul(rows, w_, lay["tile_group"], bm)
+                    gate_row = jnp.concatenate([gate.reshape(-1), jnp.zeros((1,))])[lay["src"]]
+                    return dc.combine_rows(y, gate_row, lay["token"], tokens)
+                return f
+
+            lay = jax.jit(lambda: dc.topk_layout(choice, node_mask, tuple(range(groups)), experts, bm, rows_budget))()
+            assert int(lay["overrun"]) == 0 and int(lay["counts"][0]) == 0 and int(lay["counts"].sum()) > 0
+            print(f"  top-{topk} of {experts}, {groups} held: {int(lay['counts'].sum())} rows of {tokens} tokens in "
+                  f"{lay['src'].shape[0]} row slots, {int(lay['n_tiles'])} tiles of {bm} in use", flush=True)
+        else:
+            share = rng.dirichlet(np.ones(groups - 1) * 2.0)
+            slot_np = rng.choice(groups - 1, size=tokens, p=share)
+            slot_np[rng.random(tokens) < 0.05] = groups  # not held here
+            slot = jnp.asarray(slot_np.astype(np.int32))
+            bm = gm.normalize_tiles(tokens, width, wide, dtype=dt)[0]
+
+            def through(kernel):
+                def f(x_, w_):
+                    lay = gm.aligned_layout(slot, groups, bm)
+                    rows = gm.permute_rows(x_, lay["src"], lay["dest"])
+                    if kernel:
+                        y = gm.grouped_matmul(rows, w_, lay["tile_group"], lay["n_tiles"], bm, interpret=interpret)
+                    else:
+                        y = gm.reference_grouped_matmul(rows, w_, lay["tile_group"], bm)
+                    return gm.permute_rows(y, lay["dest"], lay["src"])
+                return f
+
+        tag = f"grouped_expert {dt} groups={groups} {width}->{wide}" + (f" top-{topk}" if topk else "")
         out = timed(tag + " fwd_ms", jax.jit(through(True)), x, wts)
         grad_of = lambda f: jax.jit(jax.grad(lambda x_, w_: jnp.sum(f32(f(x_, w_)) * f32(cot)), (0, 1)))
         grads = timed(tag + " fwd+bwd_ms", grad_of(through(True)), x, wts)
@@ -496,6 +533,16 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
             _check(f"{tag} {name}", _rel_err(got, want), TOL[dt])
     print("  launch times (ms): " + json.dumps(times), flush=True)
     return {"launch_ms": times}
+
+
+def joyai_kernel_leg(interpret=False, **small) -> dict:
+    """``decoder_kernel_leg`` at the JOYAI cell's shapes: latent attention's
+    32 heads of 192-wide queries and keys beside 128-wide values over 16,384
+    tokens, and the top-8 layout (16 of 256 experts held, 2048 -> 768) with
+    dispatch and combine; bfloat16, the cell's precision."""
+    shapes = dict(tokens=16384, heads=32, kv_heads=32, head_dim=192, value_dim=128, longest=8192,
+                  groups=16, width=2048, width_out=768, topk=8, experts=256, dtypes=("bfloat16",), block=256)
+    return decoder_kernel_leg(interpret=interpret, **{**shapes, **small})
 
 
 # ---------------------------------------------------------------------------
@@ -931,7 +978,7 @@ def main() -> int:
     workdir = tempfile.mkdtemp(prefix="chip_smoke_")
     os.chdir(workdir)
     todo = [("kernels", kernel_leg), ("decoder_kernels", decoder_kernel_leg),
-            ("main", main_leg),
+            ("joyai_kernels", joyai_kernel_leg), ("main", main_leg),
             ("second_order", second_order_leg)]
     if jax.local_device_count() > 1:
         todo.append(("mesh", mesh_leg))

@@ -171,6 +171,19 @@ def update_config(
             arch.setdefault("experts_held", list(range(int(arch["num_experts"]))))
         ZayaConfig.from_arch(arch)
         arch.setdefault("use_sorted_aggregation", False)
+    if arch["mpnn_type"] == "JOYAI":
+        from ..models.joyai import JoyaiConfig
+
+        for key, default in (
+                ("rope_theta", 1.0e4), ("rope_interleave", True), ("n_shared_experts", 1),
+                ("first_k_dense_replace", 1), ("routed_scaling_factor", 1.0), ("norm_topk_prob", True),
+                ("num_nextn_predict_layers", 0), ("mtp_loss_weight", 0.3), ("expert_row_capacity", None),
+                ("rms_norm_eps", 1.0e-6), ("loss_chunk_rows", 4096)):
+            arch.setdefault(key, default)
+        if arch.get("n_routed_experts") is not None:
+            arch.setdefault("experts_held", list(range(int(arch["n_routed_experts"]))))
+        JoyaiConfig.from_arch(arch)
+        arch.setdefault("use_sorted_aggregation", False)
 
     # GPS defaults (reference: config_utils.py:40-47)
     arch.setdefault("global_attn_engine", None)

@@ -130,6 +130,23 @@ _HANDLED = {
     "NeuralNetwork.Architecture.vocab_size",
     "NeuralNetwork.Architecture.rms_norm_eps",
     "NeuralNetwork.Architecture.loss_chunk_rows",
+    # the second decoder stack (mpnn_type JOYAI, models/joyai.py)
+    "NeuralNetwork.Architecture.q_lora_rank",
+    "NeuralNetwork.Architecture.kv_lora_rank",
+    "NeuralNetwork.Architecture.qk_nope_head_dim",
+    "NeuralNetwork.Architecture.qk_rope_head_dim",
+    "NeuralNetwork.Architecture.v_head_dim",
+    "NeuralNetwork.Architecture.rope_interleave",
+    "NeuralNetwork.Architecture.intermediate_size",
+    "NeuralNetwork.Architecture.n_routed_experts",
+    "NeuralNetwork.Architecture.num_experts_per_tok",
+    "NeuralNetwork.Architecture.n_shared_experts",
+    "NeuralNetwork.Architecture.first_k_dense_replace",
+    "NeuralNetwork.Architecture.routed_scaling_factor",
+    "NeuralNetwork.Architecture.norm_topk_prob",
+    "NeuralNetwork.Architecture.num_nextn_predict_layers",
+    "NeuralNetwork.Architecture.mtp_loss_weight",
+    "NeuralNetwork.Architecture.expert_row_capacity",
     "NeuralNetwork.Architecture.branch_loss_weights",
     "NeuralNetwork.Architecture.branch_loss_metrics",
     "NeuralNetwork.Architecture.dropout",

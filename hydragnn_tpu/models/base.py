@@ -157,6 +157,13 @@ class ModelConfig:
     # and with them the token head (train/loss.py token_loss) in place of the
     # regression heads
     zaya: Optional[Any] = None
+    # --- the second decoder stack (mpnn_type "JOYAI", models/joyai.py)
+    joyai: Optional[Any] = None
+
+    @property
+    def decoder(self) -> Optional[Any]:
+        """The keys of whichever decoder stack this is, or None."""
+        return self.zaya or self.joyai
 
     @property
     def num_heads(self) -> int:

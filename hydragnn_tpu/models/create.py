@@ -162,6 +162,7 @@ def model_config_from(config: Dict[str, Any]) -> ModelConfig:
         periodic_boundary_conditions=bool(arch.get("periodic_boundary_conditions", False)),
         max_neighbours=arch.get("max_neighbours"),
         zaya=_zaya_config(arch),
+        joyai=_joyai_config(arch),
     )
 
 
@@ -171,6 +172,14 @@ def _zaya_config(arch: Dict[str, Any]):
     from .zaya import ZayaConfig
 
     return ZayaConfig.from_arch(arch)
+
+
+def _joyai_config(arch: Dict[str, Any]):
+    if arch["mpnn_type"] != "JOYAI":
+        return None
+    from .joyai import JoyaiConfig
+
+    return JoyaiConfig.from_arch(arch)
 
 
 def create_model(config: Dict[str, Any]):
@@ -196,6 +205,12 @@ def create_model(config: Dict[str, Any]):
         from .zaya import ZayaModel
 
         return ZayaModel(cfg=cfg)
+    if cfg.mpnn_type == "JOYAI":
+        # the second decoder stack: latent attention, top-k experts beside a
+        # shared one, an untied head and a second token loss (models/joyai.py)
+        from .joyai import JoyaiModel
+
+        return JoyaiModel(cfg=cfg)
     return HydraModel(cfg=cfg)
 
 
@@ -209,4 +224,4 @@ def init_model(
 
 
 def available_models() -> Tuple[str, ...]:
-    return conv_registry() + ("MACE", "ZAYA")
+    return conv_registry() + ("MACE", "ZAYA", "JOYAI")

@@ -1,0 +1,239 @@
+"""What the decoder stacks share (models/zaya.py, models/joyai.py).
+
+Token = node, document = graph, packed sequence = packed batch: the batcher
+lays graphs out contiguously along the flat node axis, so ``node_graph`` is a
+packed sequence's segment ids and a node's index within its graph is its
+position. Here, once: RMSNorm, RoPE from that index (rotate-half and
+interleaved), the token embedding, the route to the causal flash kernel, the
+SiLU-gated expert products on group-aligned rows, dispatch and combine around
+them for a token of several assignments (top-k), the balancing rule of a
+router's bias buffer, the initial scales, the per-layer rematerialisation and
+the poison of a step that cannot stand.
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from ..utils import tracer as tr
+
+# the key of a multi-token-prediction module's hidden state among a model's
+# outputs (models/joyai.py returns it, train/loss.py reads it)
+MTP_HIDDEN = "mtp_hidden"
+
+# the gain of the router bias's balancing rule (``balanced_bias``); a constant
+# of the stacks, not a key
+ROUTER_BIAS_GAIN = 0.01
+
+
+def rms_norm(x, gain, eps: float):
+    """RMSNorm in float32, returned in the input's dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope(x, pos, rot: int, theta: float, interleaved: bool = False):
+    """RoPE on the first ``rot`` channels of each head of ``x [T, H, d]``; the
+    angle from the in-graph index, in float32. Rotate-half pairs channel ``i``
+    with ``i + rot / 2``; ``interleaved`` pairs ``2 i`` with ``2 i + 1``."""
+    half = rot // 2
+    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / rot))
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        pairs = xf[..., :rot].reshape(xf.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).reshape(xf.shape[:-1] + (rot,))
+        out = jnp.concatenate([turned, xf[..., rot:]], axis=-1)
+    else:
+        x1, x2, rest = xf[..., :half], xf[..., half:rot], xf[..., rot:]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+    return out.astype(x.dtype)
+
+
+def dense(x, w):
+    return jnp.dot(x, w.astype(x.dtype))
+
+
+def embed_tokens(emb, ids_raw, vocab_size: int):
+    """Rows of the embedding for the node ids of ``batch.z``. ``emb`` is
+    stored as a head reads it, ``[hidden, vocabulary]``: the lookup takes rows
+    of the transpose. -> (x [T, hidden], ids [T] clipped)."""
+    ids = jnp.clip(ids_raw.astype(jnp.int32), 0, vocab_size - 1)
+    return emb.T[ids], ids
+
+
+def batch_aux(batch) -> Dict:
+    """What a layer reads of the batch: each node's index in its graph, the
+    segment ids and the mask."""
+    from .base import _node_position_in_graph
+
+    return {"pos": _node_position_in_graph(batch), "node_graph": batch.node_graph,
+            "node_mask": batch.node_mask}
+
+
+def follows(node_graph, node_mask, ahead: int):
+    """Real nodes whose node ``ahead`` places on is real and in their graph
+    (bool ``[T]``): the terms of a token loss ``ahead`` places ahead."""
+    same = (jnp.roll(node_graph, -ahead) == node_graph) & jnp.roll(node_mask, -ahead) & node_mask
+    return same.at[-ahead:].set(False)
+
+
+def causal_attention(q, k, v, aux, max_nodes: int):
+    """Route: the Pallas flash kernel on the TPU (or where
+    ``HYDRAGNN_PALLAS_FLASH`` forces it, interpreted), else the flat masked
+    reference. ``v`` may be narrower than ``q`` and ``k``."""
+    from ..ops.pallas_flash_attention import (
+        _flash_route_enabled, flash_causal_attention, reference_causal_attention)
+
+    node_graph, node_mask = aux["node_graph"], aux["node_mask"]
+    if not _flash_route_enabled():
+        return reference_causal_attention(q, k, v, node_graph, node_mask)
+    return flash_causal_attention(
+        q, k, v, node_graph, node_mask, max_nodes,
+        interpret=jax.default_backend() != "tpu",
+    )
+
+
+def expert_products(x_rows, w_gate, w_up, w_down, layout, block_m: int, kernel: bool):
+    """SiLU-gated expert MLP on the group-aligned rows (``block_m`` the
+    layout's row tile)."""
+    from ..ops.pallas_grouped_matmul import grouped_matmul, reference_grouped_matmul
+
+    tg, nt = layout["tile_group"], layout["n_tiles"]
+
+    def gmm(a, w):
+        if not kernel:
+            return reference_grouped_matmul(a, w.astype(a.dtype), tg, block_m)
+        return grouped_matmul(a, w.astype(a.dtype), tg, nt, block_m,
+                              interpret=jax.default_backend() != "tpu")
+
+    h = jax.nn.silu(gmm(x_rows, w_gate)) * gmm(x_rows, w_up)
+    return gmm(h, w_down)
+
+
+def gated_mlp(u, w_gate, w_up, w_down):
+    """``W_down (silu(W_gate u) * W_up u)`` on every row."""
+    return dense(jax.nn.silu(dense(u, w_gate)) * dense(u, w_up), w_down)
+
+
+def held_table(experts_held, num_experts: int):
+    """expert id -> its place among the experts held, or ``held`` (none)."""
+    held = len(experts_held)
+    return jnp.full((num_experts,), held, jnp.int32).at[jnp.asarray(experts_held)].set(
+        jnp.arange(held, dtype=jnp.int32))
+
+
+def topk_layout(choice, node_mask, experts_held, num_experts: int, block_m: int, rows: int = 0):
+    """The group-aligned layout of a top-k choice ``[T, k]``: a token is ``k``
+    assignments (assignment ``t k + j``), each a row of its own where its
+    expert is held, so a token has 0 to ``k`` rows here. ``rows`` is the row
+    budget (0: the worst case, every assignment of every token). Adds to
+    ``aligned_layout``'s keys ``token [R]``, the token of each row (``T`` for
+    a row that holds none), and ``tokens_here []``, the tokens with a row."""
+    from ..ops.pallas_grouped_matmul import aligned_layout
+
+    t, k = choice.shape
+    slot = jnp.where(node_mask[:, None], held_table(experts_held, num_experts)[choice], len(experts_held))
+    layout = aligned_layout(slot.reshape(-1), len(experts_held), block_m, rows)
+    layout["token"] = jnp.where(layout["src"] < t * k, layout["src"] // k, t)
+    layout["tokens_here"] = jnp.sum(jnp.any(slot < len(experts_held), axis=1).astype(jnp.int32))
+    return layout
+
+
+@jax.custom_vjp
+def dispatch_rows(u, token):
+    """``concat(u, 0)[token]``: a gather WITH REPEATS of the tokens' rows into
+    the aligned buffer (a token with several experts here is read several
+    times, one with none not at all). Its cotangent is the sum over a token's
+    rows, taken in float32."""
+    zero = jnp.zeros((1,) + u.shape[1:], u.dtype)
+    return jnp.concatenate([u, zero], axis=0)[token]
+
+
+def _dispatch_fwd(u, token):
+    # the residual carries the token count and the dtype as an empty array
+    return dispatch_rows(u, token), (token, jnp.zeros((u.shape[0], 0), u.dtype))
+
+
+def _dispatch_bwd(res, g):
+    token, like = res
+    t = like.shape[0]
+    du = jnp.zeros((t + 1,) + g.shape[1:], jnp.float32).at[token].add(g.astype(jnp.float32))
+    return du[:t].astype(like.dtype), None
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+def combine_rows(out_rows, gate_row, token, tokens: int):
+    """``y[t] = sum over t's rows r of gate_row[r] * out_rows[r]`` in float32
+    ``[tokens, D]``: zero for a token with no row here."""
+    w = out_rows.astype(jnp.float32) * gate_row.astype(jnp.float32)[:, None]
+    return jnp.zeros((tokens + 1, out_rows.shape[1]), jnp.float32).at[token].add(w)[:tokens]
+
+
+def balanced_bias(beta, loads):
+    """The balancing rule of the router's bias buffer (loss-free balancing,
+    arXiv:2408.15664, its proportional variant), applied once a training step
+    outside the gradient: each expert's bias moves by ``ROUTER_BIAS_GAIN``
+    times its load's shortfall against the mean load, as a share of the mean.
+    A top-1 router trained without it sends every token of a batch to one
+    expert a layer within tens of steps."""
+    loads = jax.lax.stop_gradient(loads)
+    mean = jnp.mean(loads)
+    return beta + ROUTER_BIAS_GAIN * (mean - loads) / jnp.maximum(mean, 1.0)
+
+
+def _lecun(key, shape, dtype=jnp.float32):
+    return jax.random.normal(key, shape, dtype) / (shape[-2] ** 0.5)
+
+
+def _small(key, shape, dtype=jnp.float32):  # variance_scaling(0.001, fan_avg, uniform)
+    lim = (3.0 * 0.001 / ((shape[-2] + shape[-1]) / 2.0)) ** 0.5
+    return jax.random.uniform(key, shape, dtype, -lim, lim)
+
+
+# the init kinds of a layer's ``layer_param_shapes``: ``lecun`` (normal, fan-in
+# the second-to-last axis), ``small`` (the projections that write into the
+# residual stream start near zero, so that the stream, and with it the router,
+# sees the token and not the mean of its prefix: with every matrix at LeCun
+# scale the attention's average drowns the embedding and every token of a batch
+# picks one expert, read on the chip in PR 29), ``ones`` and ``zeros``
+INIT = {"lecun": _lecun, "small": _small, "ones": nn.initializers.ones, "zeros": nn.initializers.zeros}
+
+
+def layer_params(module: nn.Module, shapes: Dict) -> Dict:
+    """The leaves of ``shapes`` (name -> (shape, init kind)) as ``module``'s
+    parameters."""
+    return {name: module.param(name, INIT[kind], shape) for name, (shape, kind) in shapes.items()}
+
+
+def remat_in_training(layer_cls, train: bool):
+    """Every layer is rematerialised in training: one saved residual stream a
+    layer."""
+    return nn.remat(layer_cls) if train else layer_cls
+
+
+def poison(x, bad):
+    """A step that cannot stand (a graph past the static bound under-covers
+    its key window in the flash kernel; a routing past the row budget leaves
+    rows out) surfaces as NaN, never as wrong numbers: the guard
+    (``hg_guard``) skips and counts it."""
+    return jnp.where(bad, jnp.nan, x)
+
+
+def graphs_overflow(batch, max_nodes: int):
+    return jnp.any((batch.nodes_per_graph > max_nodes) & batch.graph_mask)
+
+
+def causal_pairs(batch):
+    """(query, key) pairs within graphs, one layer's."""
+    n_g = batch.nodes_per_graph.astype(jnp.float32) * batch.graph_mask.astype(jnp.float32)
+    return jnp.sum(n_g * (n_g + 1.0) * 0.5)

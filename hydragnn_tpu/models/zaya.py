@@ -26,6 +26,9 @@ mixed-precision cast leaves alone, ``ZayaModel.float32_leaves``. The model retur
 hidden state; the tied head and the next-node cross-entropy are
 train/loss.py ``token_loss`` (the ``[T, V]`` logits never exist whole).
 Every layer is rematerialised in training: one saved residual stream a layer.
+What this stack shares with models/joyai.py (norm, RoPE, embedding, the
+routes to the two kernels, the balancing rule, initial scales) is
+models/decoder.py.
 
 The plain reference of these equations, item by item, is
 benchmarks/reference/zaya.py.
@@ -42,6 +45,12 @@ from flax import linen as nn
 
 from ..data.graph import GraphBatch
 from ..utils import tracer as tr
+# what the decoder stacks share lives in models/decoder.py; the names stay
+# importable from here
+from .decoder import (  # noqa: F401
+    INIT as _INIT, ROUTER_BIAS_GAIN, balanced_bias, batch_aux, causal_attention, causal_pairs,
+    dense as _dense, embed_tokens, expert_products, graphs_overflow, held_table, layer_params,
+    poison, remat_in_training, rms_norm, rope)
 
 ARCH_KEYS = (
     "num_attention_heads", "num_key_value_heads", "head_dim", "cca_time0",
@@ -49,11 +58,6 @@ ARCH_KEYS = (
     "experts_held", "moe_intermediate_size", "router_hidden_size",
     "vocab_size", "rms_norm_eps", "loss_chunk_rows",
 )
-
-# the gain of the router bias's balancing rule (``balanced_bias``); a constant
-# of the stack, not a key
-ROUTER_BIAS_GAIN = 0.01
-
 
 @dataclasses.dataclass(frozen=True)
 class ZayaConfig:
@@ -122,69 +126,12 @@ class ZayaConfig:
         return self.num_attention_heads // self.num_key_value_heads
 
 
-def rms_norm(x, gain, eps: float):
-    """RMSNorm in float32, returned in the input's dtype."""
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * gain.astype(jnp.float32)).astype(x.dtype)
-
-
 def shift_in_graph(a, pos, j: int):
     """``a[t - j]`` where node ``t - j`` is in ``t``'s graph, else zero."""
     if j == 0:
         return a
     keep = (pos >= j).reshape((-1,) + (1,) * (a.ndim - 1))
     return jnp.where(keep, jnp.roll(a, j, axis=0), jnp.zeros((), a.dtype))
-
-
-def rope(x, pos, rot: int, theta: float):
-    """Rotate-half RoPE on the first ``rot`` channels of each head of
-    ``x [T, H, d]``; the angle from the in-graph index, in float32."""
-    half = rot // 2
-    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / rot))
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2, rest = xf[..., :half], xf[..., half:rot], xf[..., rot:]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
-    return out.astype(x.dtype)
-
-
-def _dense(x, w):
-    return jnp.dot(x, w.astype(x.dtype))
-
-
-def causal_attention(q, k, v, batch_aux, max_nodes: int):
-    """Route: the Pallas flash kernel on the TPU (or where
-    ``HYDRAGNN_PALLAS_FLASH`` forces it, interpreted), else the flat masked
-    reference."""
-    from ..ops.pallas_flash_attention import (
-        _flash_route_enabled, flash_causal_attention, reference_causal_attention)
-
-    node_graph, node_mask = batch_aux["node_graph"], batch_aux["node_mask"]
-    if not _flash_route_enabled():
-        return reference_causal_attention(q, k, v, node_graph, node_mask)
-    return flash_causal_attention(
-        q, k, v, node_graph, node_mask, max_nodes,
-        interpret=jax.default_backend() != "tpu",
-    )
-
-
-def expert_products(x_rows, w_gate, w_up, w_down, layout, block_m: int, kernel: bool):
-    """SiLU-gated expert MLP on the group-aligned rows (``block_m`` the
-    layout's row tile)."""
-    from ..ops.pallas_grouped_matmul import grouped_matmul, reference_grouped_matmul
-
-    tg, nt = layout["tile_group"], layout["n_tiles"]
-
-    def gmm(a, w):
-        if not kernel:
-            return reference_grouped_matmul(a, w.astype(a.dtype), tg, block_m)
-        return grouped_matmul(a, w.astype(a.dtype), tg, nt, block_m,
-                              interpret=jax.default_backend() != "tpu")
-
-    h = jax.nn.silu(gmm(x_rows, w_gate)) * gmm(x_rows, w_up)
-    return gmm(h, w_down)
 
 
 def cca_sublayer(p: Dict, u, aux, z: ZayaConfig, max_nodes: int):
@@ -256,9 +203,7 @@ def expert_sublayer(p: Dict, beta, u, s_prev, node_mask, z: ZayaConfig, first: b
         routed, gate, s = route(p, beta, u, s_prev, z, first)
         choice = routed if choice is None else choice
         held = len(z.experts_held)
-        table = jnp.full((z.num_experts,), held, jnp.int32).at[jnp.asarray(z.experts_held)].set(
-            jnp.arange(held, dtype=jnp.int32))
-        slot = jnp.where(node_mask, table[choice], held)
+        slot = jnp.where(node_mask, held_table(z.experts_held, z.num_experts)[choice], held)
         kernel = jax.default_backend() == "tpu"
         # each expert's rows start at a multiple of the kernel's row tile
         block_m = normalize_tiles(t, d_model, z.moe_intermediate_size, dtype=u.dtype)[0]
@@ -272,26 +217,10 @@ def expert_sublayer(p: Dict, beta, u, s_prev, node_mask, z: ZayaConfig, first: b
     return y, s, layout["counts"], every
 
 
-def balanced_bias(beta, loads):
-    """The balancing rule of the router's bias buffer (loss-free balancing,
-    arXiv:2408.15664, its proportional variant), applied once a training step
-    outside the gradient: each expert's bias moves by ``ROUTER_BIAS_GAIN``
-    times its load's shortfall against the mean load, as a share of the mean.
-    A top-1 router trained without it sends every token of a batch to one
-    expert a layer within tens of steps."""
-    loads = jax.lax.stop_gradient(loads)
-    mean = jnp.mean(loads)
-    return beta + ROUTER_BIAS_GAIN * (mean - loads) / jnp.maximum(mean, 1.0)
-
-
 def layer_param_shapes(hidden: int, z: ZayaConfig, first: bool) -> Dict[str, Tuple[Tuple[int, ...], str]]:
-    """name -> (shape, init kind) of one layer's parameter leaves; the kinds
-    are ``lecun`` (normal, fan-in the second-to-last axis), ``small`` (the two
-    projections that write into the residual stream start near zero, so that
-    the stream, and with it the router, sees the token and not the mean of
-    its prefix: with every matrix at LeCun scale the attention's average
-    drowns the embedding and every token of a batch picks one expert, read on
-    the chip in PR 29), ``ones`` and ``zeros``."""
+    """name -> (shape, init kind) of one layer's parameter leaves (the kinds:
+    ``models/decoder.py INIT``; ``small`` for the two projections that write
+    into the residual stream)."""
     d, lq, lk = hidden, z.latent_q, z.latent_k
     r, e, f, held = z.router_hidden_size, z.num_experts, z.moe_intermediate_size, len(z.experts_held)
     c, heads = lq + lk, z.num_attention_heads + z.num_key_value_heads
@@ -316,18 +245,6 @@ def layer_param_shapes(hidden: int, z: ZayaConfig, first: bool) -> Dict[str, Tup
     return shapes
 
 
-def _lecun(key, shape, dtype=jnp.float32):
-    return jax.random.normal(key, shape, dtype) / (shape[-2] ** 0.5)
-
-
-def _small(key, shape, dtype=jnp.float32):  # variance_scaling(0.001, fan_avg, uniform)
-    lim = (3.0 * 0.001 / ((shape[-2] + shape[-1]) / 2.0)) ** 0.5
-    return jax.random.uniform(key, shape, dtype, -lim, lim)
-
-
-_INIT = {"lecun": _lecun, "small": _small, "ones": nn.initializers.ones, "zeros": nn.initializers.zeros}
-
-
 def residual_add(p: Dict, sub: str, x, y):
     """x <- (a_r x + b_r) + (a_y y + b_y), four learned vectors a sublayer."""
     v = lambda name: p[f"{sub}_{name}"].astype(x.dtype)
@@ -345,8 +262,7 @@ class ZayaLayer(nn.Module):
     @nn.compact
     def __call__(self, x, s_prev, aux, beta):
         z = self.z
-        p = {name: self.param(name, _INIT[kind], shape)
-             for name, (shape, kind) in layer_param_shapes(self.hidden, z, self.first).items()}
+        p = layer_params(self, layer_param_shapes(self.hidden, z, self.first))
         u = rms_norm(x, p["attn_norm"], z.rms_norm_eps)
         x = residual_add(p, "attn", x, cca_sublayer(p, u, aux, z, self.max_nodes))
         u = rms_norm(x, p["moe_norm"], z.rms_norm_eps)
@@ -377,14 +293,10 @@ class ZayaModel(nn.Module):
             raise ValueError("mpnn_type ZAYA reads node ids from batch.z (int32)")
         # stored as the head reads it, [hidden, vocabulary]: the lookup takes
         # rows of the transpose
-        emb = self.param("embedding", _lecun, (d_model, z.vocab_size))
-        ids = jnp.clip(batch.z.astype(jnp.int32), 0, z.vocab_size - 1)
-        x = emb.T[ids]
-        from .base import _node_position_in_graph
-
-        aux = {"pos": _node_position_in_graph(batch), "node_graph": batch.node_graph,
-               "node_mask": batch.node_mask}
-        layer_cls = nn.remat(ZayaLayer) if train else ZayaLayer
+        x, _ = embed_tokens(self.param("embedding", _INIT["lecun"], (d_model, z.vocab_size)),
+                            batch.z, z.vocab_size)
+        aux = batch_aux(batch)
+        layer_cls = remat_in_training(ZayaLayer, train)
         s = jnp.zeros((x.shape[0], z.router_hidden_size), jnp.float32)
         counters = jnp.zeros((3,), jnp.float32)
         for i in range(cfg.num_conv_layers):
@@ -399,16 +311,12 @@ class ZayaModel(nn.Module):
             counters = counters + c
         x = rms_norm(x, self.param("final_norm", nn.initializers.ones, (d_model,)), z.rms_norm_eps)
         real = jnp.sum(batch.node_mask.astype(jnp.float32))
-        n_g = batch.nodes_per_graph.astype(jnp.float32) * batch.graph_mask.astype(jnp.float32)
-        # a graph past the static bound under-covers its key window in the
-        # flash kernel: surface as NaN, never as wrong numbers
-        overflow = jnp.any((batch.nodes_per_graph > cfg.max_nodes_per_graph) & batch.graph_mask)
-        x = jnp.where(overflow, jnp.nan, x)
+        x = poison(x, graphs_overflow(batch, cfg.max_nodes_per_graph))
         return {
             cfg.output_names[0]: x,
             tr.CT_TOKENS: real * cfg.num_conv_layers,
             tr.CT_TOKENS_ROUTED_HERE: counters[0],
             tr.CT_EXPERT_LOAD_MAX: counters[1],
             tr.CT_EXPERT_LOAD_MEAN: counters[2],
-            tr.CT_CAUSAL_PAIRS: jnp.sum(n_g * (n_g + 1.0) * 0.5),
+            tr.CT_CAUSAL_PAIRS: causal_pairs(batch),
         }

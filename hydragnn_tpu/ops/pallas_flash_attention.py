@@ -279,8 +279,17 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             l_ref[0] = l_scr[:]
 
 
+def _lane_multiple(d: int) -> int:
+    """What a head width is padded to a multiple of. The lane tile, 128;
+    a width that is a whole number of HALF tiles and wider than one tile
+    (MLA's 192-wide queries and keys) streams as it is: the block spans the
+    array's whole minor axis, and neither the score product's contraction
+    nor the operand's bytes grow to 256."""
+    return 64 if d > 128 and d % 64 == 0 else 128
+
+
 def _heads_first(x, block):
-    x = _pad_to(_pad_to(x, block, 0), 128, 2)
+    x = _pad_to(_pad_to(x, block, 0), _lane_multiple(x.shape[2]), 2)
     return jnp.transpose(x, (1, 0, 2))  # [H, N_pad, d_pad]
 
 
@@ -301,12 +310,13 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
     nk = k.shape[0]
     group = h // k.shape[1]
     bq, bk = block_q, block_k
-    d_pad = d + (-d) % 128
     scale = 1.0 / float(d) ** 0.5
 
     qt = _heads_first(q, bq)
     kt = _heads_first(k, bk)
     vt = _heads_first(v, bk)
+    # queries and keys share a width, values (and the output) have their own
+    d_pad, dv_pad = qt.shape[2], vt.shape[2]
     nq_pad, nk_pad = qt.shape[1], kt.shape[1]
     j_blocks = nq_pad // bq
     k_blocks = nk_pad // bk
@@ -337,8 +347,8 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
         return (h_i, j, 0)
 
     grid = (h, j_blocks, k_windows)
-    out_specs = [pl.BlockSpec((1, bq, d_pad), out_index)]
-    out_shape = [jax.ShapeDtypeStruct((h, nq_pad, d_pad), q.dtype)]
+    out_specs = [pl.BlockSpec((1, bq, dv_pad), out_index)]
+    out_shape = [jax.ShapeDtypeStruct((h, nq_pad, dv_pad), q.dtype)]
     if emit_stats:
         stats = 1 if emit_stats == "lse" else 2
         out_specs += [pl.BlockSpec((1, bq, 128), out_index)] * stats
@@ -354,13 +364,13 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
                 pl.BlockSpec((1, bk), gidk_index),
                 pl.BlockSpec((1, bq, d_pad), q_index),
                 pl.BlockSpec((1, bk, d_pad), kv_index),
-                pl.BlockSpec((1, bk, d_pad), kv_index),
+                pl.BlockSpec((1, bk, dv_pad), kv_index),
             ],
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),
                 pltpu.VMEM((bq, 128), jnp.float32),
-                pltpu.VMEM((bq, d_pad), jnp.float32),
+                pltpu.VMEM((bq, dv_pad), jnp.float32),
             ],
         ),
         out_shape=out_shape,
@@ -369,7 +379,7 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
     )(kstart, klast, gq, gk, qt, kt, vt)
     if padded:
         return out
-    o = jnp.transpose(out[0], (1, 0, 2))[:nq, :, :d]
+    o = jnp.transpose(out[0], (1, 0, 2))[:nq, :, :v.shape[2]]
     if not emit_stats:
         return o
     m = jnp.transpose(out[1][:, :, 0])[:nq]  # [Nq, H]
@@ -548,9 +558,9 @@ def _summary_jvp(block_q, block_k, interpret, primals, tangents):
 def reference_causal_attention(q, k, v, node_graph, node_mask):
     """Flat ``[N, N]``-masked causal grouped-query attention in plain jnp:
     node ``i`` attends node ``j`` iff both are real, share a graph and
-    ``j <= i``. ``q [N, Hq, d]``, ``k``/``v [N, Hk, d]`` with ``Hq`` a
-    multiple of ``Hk``. The oracle of the kernel and the route off the TPU;
-    scores and softmax in float32."""
+    ``j <= i``. ``q [N, Hq, d]``, ``k [N, Hk, d]``, ``v [N, Hk, dv]`` with
+    ``Hq`` a multiple of ``Hk``. The oracle of the kernel and the route off
+    the TPU; scores and softmax in float32."""
     n, hq, d = q.shape
     group = hq // k.shape[1]
     kf = jnp.repeat(k, group, axis=1)
@@ -699,8 +709,7 @@ def _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
             q, k, v, gid, gid, ks, kl, kw, block_q, block_k, interpret,
             emit_stats="lse", causal=True, padded=True,
         )
-        n, _, d = q.shape
-        o = jnp.transpose(o_pad, (1, 0, 2))[:n, :, :d]
+        o = jnp.transpose(o_pad, (1, 0, 2))[:q.shape[0], :, :v.shape[2]]
     return o, lse  # lse [H, Nq_pad, 128], lane-broadcast
 
 
@@ -717,8 +726,11 @@ def flash_causal_attention(
 ):
     """Causal grouped-query flash attention over the flat node array.
 
-    ``q [N, Hq, d]``, ``k``/``v [N, Hk, d]`` (``Hq`` a multiple of ``Hk``);
-    node ``i`` attends the real nodes ``j <= i`` of its own graph. Same
+    ``q [N, Hq, d]``, ``k [N, Hk, d]``, ``v [N, Hk, dv]`` (``Hq`` a multiple
+    of ``Hk``; ``dv`` may differ from ``d``, as latent attention's 192-wide
+    queries and keys beside 128-wide values do: the scale is ``1/sqrt(d)``,
+    the output ``[N, Hq, dv]``); node ``i`` attends the real nodes ``j <= i``
+    of its own graph. Same
     layout contract as :func:`flash_self_attention` (graphs contiguous,
     ``node_graph`` non-decreasing, padding last); a graph past
     ``max_nodes_per_graph`` is under-covered and the caller poisons it.
@@ -757,7 +769,8 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
     with tr.scope(tr.HG_FLASH_ATTENTION + tr.BWD):
         qt, dot, ot = (_heads_first(x, bq) for x in (q, do.astype(q.dtype), o))
         kt, vt = _heads_first(k, bk), _heads_first(v, bk)
-        nq_pad, nk_pad, d_pad = qt.shape[1], kt.shape[1], qt.shape[2]
+        nq_pad, nk_pad = qt.shape[1], kt.shape[1]
+        d_pad, dv_pad, dv = qt.shape[2], vt.shape[2], v.shape[2]
         j_blocks, k_blocks = nq_pad // bq, nk_pad // bk
         gcol = lambda npad: jnp.full((npad, 1), -1, jnp.int32).at[:n, 0].set(gid)
         grow = lambda npad: jnp.full((1, npad), -1, jnp.int32).at[0, :n].set(gid)
@@ -781,9 +794,9 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
                         0, jnp.minimum(s_[j] + kk, l_[j]))),
                     pl.BlockSpec((1, bq, d_pad), held),
                     pl.BlockSpec((1, bk, d_pad), kv),
-                    pl.BlockSpec((1, bk, d_pad), kv),
-                    pl.BlockSpec((1, bq, d_pad), held),
-                    pl.BlockSpec((1, bq, d_pad), held),
+                    pl.BlockSpec((1, bk, dv_pad), kv),
+                    pl.BlockSpec((1, bq, dv_pad), held),
+                    pl.BlockSpec((1, bq, dv_pad), held),
                     pl.BlockSpec((1, bq, 128), held),
                 ],
                 out_specs=pl.BlockSpec((1, bq, d_pad), held),
@@ -819,27 +832,30 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
                     pl.BlockSpec((1, bq), lambda h_i, i, qq, s_, l_: (
                         0, jnp.minimum(s_[i] + qq, l_[i]))),
                     pl.BlockSpec((1, bk, d_pad), kheld),
-                    pl.BlockSpec((1, bk, d_pad), kheld),
+                    pl.BlockSpec((1, bk, dv_pad), kheld),
                     pl.BlockSpec((1, bq, d_pad), qv),
-                    pl.BlockSpec((1, bq, d_pad), qv),
+                    pl.BlockSpec((1, bq, dv_pad), qv),
                     pl.BlockSpec((1, 8, bq), stat),
                     pl.BlockSpec((1, 8, bq), stat),
                 ],
-                out_specs=[pl.BlockSpec((1, bk, d_pad), out_held)] * 2,
-                scratch_shapes=[pltpu.VMEM((bk, d_pad), jnp.float32)] * 2,
+                out_specs=[pl.BlockSpec((1, bk, d_pad), out_held),
+                           pl.BlockSpec((1, bk, dv_pad), out_held)],
+                scratch_shapes=[pltpu.VMEM((bk, d_pad), jnp.float32),
+                                pltpu.VMEM((bk, dv_pad), jnp.float32)],
             ),
-            out_shape=[jax.ShapeDtypeStruct((hq, nk_pad, d_pad), jnp.float32)] * 2,
+            out_shape=[jax.ShapeDtypeStruct((hq, nk_pad, d_pad), jnp.float32),
+                       jax.ShapeDtypeStruct((hq, nk_pad, dv_pad), jnp.float32)],
             interpret=interpret,
             name=tr.HG_FLASH_ATTENTION + tr.BWD,
         )(qs, ql, gcol(nk_pad), grow(nq_pad), kt, vt, qt, dot, lse_row, delta_row)
 
-        def per_kv_head(x):
-            x = x.reshape(hk, group, nk_pad, d_pad).sum(axis=1)
-            return jnp.transpose(x, (1, 0, 2))[:n, :, :d]
+        def per_kv_head(x, width):
+            x = x.reshape(hk, group, nk_pad, x.shape[2]).sum(axis=1)
+            return jnp.transpose(x, (1, 0, 2))[:n, :, :width]
 
         dq = jnp.transpose(dq, (1, 0, 2))[:n, :, :d]
-        return (dq, per_kv_head(dk_h).astype(k.dtype),
-                per_kv_head(dv_h).astype(v.dtype), None, None)
+        return (dq, per_kv_head(dk_h, d).astype(k.dtype),
+                per_kv_head(dv_h, dv).astype(v.dtype), None, None)
 
 
 _flash_causal_attention.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)

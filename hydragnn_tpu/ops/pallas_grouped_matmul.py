@@ -1,12 +1,17 @@
 """Pallas TPU kernel: grouped matrix product over the experts held.
 
-A top-1 expert layer (models/zaya.py) sends every token to one expert; the
-chip holds some of the experts. The rows routed to the experts held are laid
-out SORTED BY EXPERT in a group-aligned buffer: group ``g`` starts at a
-multiple of ``block_m`` and is padded with zero rows to the next multiple
-(``aligned_layout``). Group sizes are ragged and known only on the device;
-the buffer's static length ``T + G * block_m`` holds any routing, so no token
-is ever dropped, not even when every token picks one expert.
+An expert layer sends every token to one expert (top-1, models/zaya.py) or to
+several (top-k, models/joyai.py: a token is then ``k`` ASSIGNMENTS, each its
+own row); the chip holds some of the experts. The rows routed to the experts
+held are laid out SORTED BY EXPERT in a group-aligned buffer: group ``g``
+starts at a multiple of ``block_m`` and is padded with zero rows to the next
+multiple (``aligned_layout``). Group sizes are ragged and known only on the
+device; the buffer's static length ``A + G * block_m`` for ``A`` assignments
+holds any routing, so no token is ever dropped, not even when every token
+picks one expert. A top-k layer may state a budget of rows below that worst
+case (``aligned_layout(..., rows=)``): the layout then reports how many rows
+did not fit (``overrun``), and the caller poisons the step; it never cuts in
+silence.
 
 Because a row tile then belongs to exactly one group, the kernel is a plain
 tiled matmul whose weight block index comes from a scalar-prefetched
@@ -80,18 +85,24 @@ def aligned_rows(tokens: int, groups: int, block_m: int) -> int:
     return _round_up(tokens, block_m) + groups * block_m
 
 
-def aligned_layout(slot, groups: int, block_m: int):
-    """Where each token goes. ``slot [T]`` is the token's local expert in
-    ``[0, groups)``, or ``groups`` for a token that is not computed here
-    (its expert lives elsewhere, or it is padding).
+def aligned_layout(slot, groups: int, block_m: int, rows: int = 0):
+    """Where each assignment goes. ``slot [T]`` is the assignment's local
+    expert in ``[0, groups)``, or ``groups`` for one that is not computed here
+    (its expert lives elsewhere, or it is padding). ``rows`` > 0 is a budget:
+    the buffer's length ``R`` (a multiple of ``block_m``) in place of the
+    worst case ``aligned_rows(T, groups, block_m)``.
 
-    -> dict of ``dest [T]`` (the token's row in the aligned buffer, or ``R``,
-    the appended zero row, when it is not computed here), ``src [R]`` (the
-    token of each aligned row, or ``T``, the appended zero row), ``tile_group
-    [R / block_m]``, ``n_tiles []`` (tiles in use) and ``counts [groups]``.
+    -> dict of ``dest [T]`` (the assignment's row in the aligned buffer, or
+    ``R``, the appended zero row, when it is not computed here), ``src [R]``
+    (the assignment of each aligned row, or ``T``, the appended zero row),
+    ``tile_group [R / block_m]``, ``n_tiles []`` (tiles in use), ``counts
+    [groups]`` and ``overrun []`` (rows past a budget: they are not computed,
+    and the caller must not let the step stand).
     """
     t = slot.shape[0]
     r = aligned_rows(t, groups, block_m)
+    if rows:
+        r = min(r, _round_up(rows, block_m))
     slot = slot.astype(jnp.int32)
     counts = jnp.zeros((groups + 1,), jnp.int32).at[slot].add(1)[:groups]
     tiles = jnp.maximum((counts + block_m - 1) // block_m, 1)
@@ -103,7 +114,12 @@ def aligned_layout(slot, groups: int, block_m: int):
     held = sorted_slot < groups
     safe = jnp.minimum(sorted_slot, groups - 1)
     rank = jnp.arange(t, dtype=jnp.int32) - group_first[safe]
-    dest_sorted = jnp.where(held, row_start[safe] + rank, r)
+    dest_sorted = row_start[safe] + rank
+    # a row past a budget is not computed (and counted): the worst-case
+    # buffer has none
+    fits = dest_sorted < r
+    overrun = jnp.sum((held & ~fits).astype(jnp.int32))
+    dest_sorted = jnp.where(held & fits, dest_sorted, r)
     dest = jnp.zeros((t,), jnp.int32).at[order].set(dest_sorted)
     src = jnp.full((r,), t, jnp.int32).at[dest_sorted].set(order, mode="drop")
     tile_group = jnp.minimum(
@@ -112,7 +128,8 @@ def aligned_layout(slot, groups: int, block_m: int):
         groups - 1,
     ).astype(jnp.int32)
     return {"dest": dest, "src": src, "tile_group": tile_group,
-            "n_tiles": tile_end[-1].astype(jnp.int32), "counts": counts}
+            "n_tiles": jnp.minimum(tile_end[-1], r // block_m).astype(jnp.int32),
+            "counts": counts, "overrun": overrun}
 
 
 @jax.custom_vjp

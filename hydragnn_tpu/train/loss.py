@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from ..data.graph import GraphBatch
 from ..models.base import ModelConfig
+from ..models.decoder import MTP_HIDDEN, follows
 from ..utils import tracer as tr
 
 
@@ -136,19 +137,24 @@ def chunked_cross_entropy(hidden, head, targets, weights, chunk_rows: int):
     return total
 
 
-def token_loss(hidden, head, batch: GraphBatch, chunk_rows: int):
-    """The token head: mean cross-entropy of the NEXT node's id over the real
-    nodes whose next node is in the same graph, through the tied head
-    ``head [D, V]``. Ids ride in ``batch.z``; float32 throughout."""
+def _follows(batch: GraphBatch, ahead: int):
+    """Weight 1.0 on the real nodes whose node ``ahead`` places on is real and
+    in their graph."""
+    return follows(batch.node_graph, batch.node_mask, ahead).astype(jnp.float32)
+
+
+def token_loss(hidden, head, batch: GraphBatch, chunk_rows: int, ahead: int = 1):
+    """The token head: cross-entropy of the id of the node ``ahead`` places on
+    (the NEXT node; 2 for a multi-token-prediction module), summed over the
+    real nodes for which that node is in the same graph and divided by the
+    count of (node, next node) pairs: the mean next-node loss for ``ahead`` 1,
+    and a second loss on the same count beside it (DeepSeek-V3 divides both by
+    the sequence length). Through ``head [D, V]`` (the embedding where it is
+    tied). Ids ride in ``batch.z``; float32 throughout."""
     with tr.scope(tr.HG_TOKEN_LOSS):
         ids = jnp.clip(batch.z.astype(jnp.int32), 0, head.shape[1] - 1)
-        nxt = jnp.roll(ids, -1)
-        same = (jnp.roll(batch.node_graph, -1) == batch.node_graph) & (
-            jnp.roll(batch.node_mask, -1) & batch.node_mask)
-        same = same.at[-1].set(False)
-        w = same.astype(jnp.float32)
-        total = chunked_cross_entropy(hidden, head, nxt, w, chunk_rows)
-        return total / jnp.maximum(jnp.sum(w), 1.0)
+        total = chunked_cross_entropy(hidden, head, jnp.roll(ids, -ahead), _follows(batch, ahead), chunk_rows)
+        return total / jnp.maximum(jnp.sum(_follows(batch, 1)), 1.0)
 
 
 def _apply(model, variables, batch, train, rng):
@@ -162,10 +168,15 @@ def _apply(model, variables, batch, train, rng):
 
 def _token_head_loss(model, variables, batch, cfg, train, rng):
     outputs, mutated = _apply(model, variables, batch, train, rng)
-    name = cfg.output_names[0]
-    loss = token_loss(outputs[name], variables["params"]["embedding"], batch,
-                      cfg.zaya.loss_chunk_rows)
+    name, params = cfg.output_names[0], variables["params"]
+    # an untied head where the stack has one (models/joyai.py), else the embedding
+    head, chunk = params.get("head", params["embedding"]), cfg.decoder.loss_chunk_rows
+    loss = token_loss(outputs[name], head, batch, chunk)
     tasks = {name: loss}
+    if MTP_HIDDEN in outputs:
+        # the multi-token-prediction module's loss, through the same head
+        tasks["mtp"] = token_loss(outputs[MTP_HIDDEN], head, batch, chunk, ahead=2)
+        loss = loss + cfg.joyai.mtp_loss_weight * tasks["mtp"]
     tasks.update({k: v for k, v in outputs.items() if k.startswith(tr.COUNTER_PREFIX)})
     return loss, tasks, mutated, {name: outputs[name]}
 
@@ -182,7 +193,7 @@ def compute_loss(
     """Single entry point for both objectives, shared by the single-device and
     mesh-parallel step builders: returns (total, per-task losses, mutated
     collections, outputs)."""
-    if cfg.zaya is not None:
+    if cfg.decoder is not None:
         return _token_head_loss(model, variables, batch, cfg, train, rng)
     if compute_grad_energy:
         def apply_outputs(b):

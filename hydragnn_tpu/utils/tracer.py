@@ -47,8 +47,13 @@ HG_LOSS = "hg_loss"  # value_and_grad: jvp( = forward, transpose( = backward
 HG_OPTIMIZER = "hg_optimizer"  # tx.update + apply_updates
 HG_GUARD = "hg_guard"  # step_ok + the guarded select
 HG_CCA_CONV = "hg_cca_conv"  # ZAYA: the two causal convolutions + q-k mean of a CCA sublayer
-HG_ROUTER = "hg_router"  # ZAYA: the float32 router MLP, top-1 choice and expert layout
+HG_ROUTER = "hg_router"  # ZAYA, JOYAI: the float32 router, the choice and the expert layout
 HG_MOE = "hg_moe"  # ZAYA: gather to expert rows, the grouped products, scatter back
+HG_MLA_PROJ = "hg_mla_proj"  # JOYAI: the four latent projections of an MLA block, their norms and RoPE
+HG_MOE_DISPATCH = "hg_moe_dispatch"  # JOYAI: the gather with repeats of tokens into expert rows
+HG_MOE_COMBINE = "hg_moe_combine"  # JOYAI: the gate-weighted sum over a token's rows
+HG_SHARED_EXPERT = "hg_shared_expert"  # JOYAI: the shared expert's MLP on every token
+HG_MTP = "hg_mtp"  # JOYAI: the multi-token-prediction module (join, its own layer, its norm)
 HG_TOKEN_LOSS = "hg_token_loss"  # the chunked next-node cross-entropy
 
 # -- counters: per-step scalars a step carries among its per-task entries
@@ -59,6 +64,9 @@ CT_TOKENS_ROUTED_HERE = "count:tokens_routed_here"  # tokens whose expert is hel
 CT_EXPERT_LOAD_MAX = "count:expert_load_max"  # largest load of a held expert, summed over layers
 CT_EXPERT_LOAD_MEAN = "count:expert_load_mean"  # mean load of the held experts, summed over layers
 CT_CAUSAL_PAIRS = "count:causal_pairs"  # (query, key) pairs within graphs, one layer's
+CT_EXPERT_ROWS_HERE = "count:expert_rows_here"  # JOYAI: rows computed on this chip (a token is 0..k), over layers
+CT_EXPERT_ROWS_OVERRUN = "count:expert_rows_overrun"  # JOYAI: rows past the row budget (the step is poisoned)
+CT_MTP_PAIRS = "count:mtp_pairs"  # JOYAI: nodes whose two successors lie in their document
 
 # -- Pallas kernels: pallas_call(name=...) inside a scope of the same name;
 #    the custom-JVP tangent rule runs under <name> + TANGENT ----------------

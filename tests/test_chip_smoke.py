@@ -151,3 +151,29 @@ def pytest_chip_smoke_force_gradient_gap_rehearsed(monkeypatch):
     smoke = _load_smoke()
     assert smoke.egnn_force_gradient_gap(hidden=16) <= 5e-3
 
+
+
+@pytest.mark.parametrize("leg,shapes,tags", [
+    # the ZAYA cell's shapes: one head width, one slot a token
+    ("decoder_kernel_leg", dict(tokens=512, heads=4, kv_heads=2, head_dim=32, longest=160, groups=4, width=64),
+     ("flash_causal float32 fwd_ms", "grouped_expert float32 groups=4 64->64 fwd+bwd_ms")),
+    # the JOYAI cell's: 192-wide queries and keys beside 128-wide values, top-k rows within a budget
+    ("joyai_kernel_leg", dict(tokens=512, heads=2, kv_heads=2, longest=160, groups=4, width=64, width_out=48,
+                              experts=32, topk=4),
+     ("flash_causal float32 2x192/128 fwd+bwd_ms", "grouped_expert float32 groups=4 64->48 top-4 fwd+bwd_ms")),
+])
+def pytest_chip_smoke_decoder_kernel_legs_rehearsed(leg, shapes, tags):
+    """Both decoder kernel legs at a tiny size in interpret mode: every check
+    a leg makes on the chip (forward, dq / dk / dv, dx / dw against blocked
+    jnp references) holds its float32 tolerance, and the JOYAI leg's defaults
+    are the cell's shapes."""
+    import inspect
+
+    smoke = _load_smoke()
+    got = getattr(smoke, leg)(interpret=True, dtypes=("float32",), block=128, **shapes)
+    for tag in tags:
+        assert tag in got["launch_ms"], (tag, list(got["launch_ms"]))
+    src = inspect.getsource(smoke.joyai_kernel_leg)
+    for shape in ("tokens=16384", "heads=32", "head_dim=192", "value_dim=128", "groups=16", "width_out=768",
+                  "topk=8", "experts=256"):
+        assert shape in src, shape

@@ -441,3 +441,11 @@ def pytest_example_multibranch_branch_parallel(tmp_path):
         cwd=str(tmp_path), timeout=600,
     )
     assert "epoch 2:" in out
+
+
+@pytest.mark.slow  # full example subprocess: exceeds the capped fast tier; runs in the ci.sh suite
+def pytest_example_joyai_flash():
+    """The second decoder stack's preset (examples/joyai_flash): latent
+    attention, top-4 of 16 experts beside a shared one, the module on."""
+    out = _run_example("examples/joyai_flash/joyai_flash.py", "--num_docs", "48", "--num_epoch", "2")
+    assert "train loss by epoch" in out

@@ -787,6 +787,35 @@ def pytest_bf16_kernel_compiles_for_v5e_at_the_cell_shape(v5e_chip, tangent):
         assert stream in text, stream
 
 
+def pytest_causal_flash_launches_compile_for_v5e_at_latent_attention_widths(v5e_chip):
+    """Mosaic accepts the causal flash kernel's three launches (forward,
+    ``dq``, ``dk``/``dv``) at the JOYAI cell's shape: 16384 tokens, 32 heads,
+    queries and keys 192 wide beside values 128 wide, bf16. The 192 streams
+    as it is: no operand grows to 256 lanes (the heads-first copies are
+    ``[32, 16384, 192]`` and ``[32, 16384, 128]``)."""
+    import re
+
+    from hydragnn_tpu.ops.pallas_flash_attention import flash_causal_attention
+
+    n, h, d_qk, d_v = 16384, 32, 192, 128
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def loss(q, k, v, node_graph, node_mask):
+        out = flash_causal_attention(q, k, v, node_graph, node_mask, 8192)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shaped((n, h, d_qk)), shaped((n, h, d_qk)), shaped((n, h, d_v)),
+        shaped((n,), jnp.int32), shaped((n,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"^\s*%(hg_flash_attention[a-z_]*)[.\d]* = .*custom-call\(", text, re.MULTILINE)
+    assert sorted(calls) == ["hg_flash_attention", "hg_flash_attention_bwd", "hg_flash_attention_bwd"], calls
+    assert "bf16[32,16384,192]" in text and "bf16[32,16384,128]" in text
+    assert "bf16[32,16384,256]" not in text
+    # dq, dk (192 wide) and dv (128 wide) in the operands' shapes (and a tuple's few bytes)
+    assert 0 <= compiled.memory_analysis().output_size_in_bytes - 2 * n * h * (2 * d_qk + d_v) < 4096
+
+
 def _cell_train_step_text(monkeypatch, v5e_chip):
     """The EGNN-866 cells' own train step (benchmarks/configs/
     egnn866_sc25.json, bf16), lowered at the packed cell's batch shape and
