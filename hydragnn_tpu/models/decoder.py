@@ -237,3 +237,14 @@ def causal_pairs(batch):
     """(query, key) pairs within graphs, one layer's."""
     n_g = batch.nodes_per_graph.astype(jnp.float32) * batch.graph_mask.astype(jnp.float32)
     return jnp.sum(n_g * (n_g + 1.0) * 0.5)
+
+
+def flash_steps(batch, max_nodes: int, d: int, dv: int, dtype) -> Dict:
+    """The causal flash launch's schedule on this batch, one block's forward
+    launch, one head (queries and keys ``d`` wide, values ``dv``, streamed as
+    ``dtype``): the tiles its windows hold and the steps the schedule runs for
+    them, as the step's two ``count:flash_*`` entries."""
+    from ..ops.pallas_flash_attention import causal_schedule_steps
+
+    visited, scheduled = causal_schedule_steps(batch.node_graph, batch.node_mask, max_nodes, d, dv, dtype)
+    return {tr.CT_FLASH_TILES_VISITED: visited, tr.CT_FLASH_STEPS_SCHEDULED: scheduled}

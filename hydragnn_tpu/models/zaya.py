@@ -49,7 +49,7 @@ from ..utils import tracer as tr
 # importable from here
 from .decoder import (  # noqa: F401
     INIT as _INIT, ROUTER_BIAS_GAIN, balanced_bias, batch_aux, causal_attention, causal_pairs,
-    dense as _dense, embed_tokens, expert_products, graphs_overflow, held_table, layer_params,
+    dense as _dense, embed_tokens, expert_products, flash_steps, graphs_overflow, held_table, layer_params,
     poison, remat_in_training, rms_norm, rope)
 
 ARCH_KEYS = (
@@ -319,4 +319,5 @@ class ZayaModel(nn.Module):
             tr.CT_EXPERT_LOAD_MAX: counters[1],
             tr.CT_EXPERT_LOAD_MEAN: counters[2],
             tr.CT_CAUSAL_PAIRS: causal_pairs(batch),
+            **flash_steps(batch, cfg.max_nodes_per_graph, z.head_dim, z.head_dim, x.dtype),
         }

@@ -17,10 +17,11 @@ data/graph.py):
   scheduled like the sorted-segment kernels' ``estart`` scheme
   (ops/pallas_segment.py): ``node_graph`` ascends along the flat layout,
   so a searchsorted over it gives each q-block's first/last k-block as
-  scalar-prefetch arrays. Cross-graph tiles are never visited — the block
+  scalar-prefetch arrays. Cross-graph tiles are never visited: the block
   index map CLAMPS to the window's last block and ``pl.when`` skips the
-  recompute, so an out-of-window step is a zero-cost revisit of an
-  already-resident block, not a DMA;
+  recompute, so an out-of-window step moves no data, but it is still a grid
+  step (0.1-0.3 us each on a v5e, PERF.md section 6, PR 34), and K is the
+  worst legal window's length for every query block;
 - per visited tile: ``s = q @ k.T`` on the MXU (f32 accumulation),
   same-graph masking by an in-register compare of the streamed per-node
   graph-id column/row (padding nodes carry id -1 and never match), and
@@ -39,7 +40,7 @@ queries against one K/V block, and ``parallel/ring_attention.py`` merges
 those partials across ring steps in plain jnp — the per-chip block of
 ring attention rides the same inner loop instead of a dense einsum.
 
-``flash_causal_attention`` is the decoder's entry (models/zaya.py): the same
+``flash_causal_attention`` is the decoder's entry (models/decoder.py): the same
 tiling with a CAUSAL mask inside the segment mask (key index <= query index
 within a graph: nodes of a graph are contiguous and ordered, so it is one
 more in-register compare of two iotas and a window that ends at the query
@@ -51,6 +52,23 @@ key/value block and streaming its queries for ``dk``/``dv``, both rebuilding
 the probabilities from the forward's saved log-sum-exp. No ``[*, N, N]``
 array exists in either direction, so a graph of 8192 nodes trains. It is a
 first-order ``custom_vjp`` (the token loss needs no more).
+
+Where each launch's window loop runs. The self-attention and block-summary
+launches (GPS, the ring: windows of 2-4 tiles of 128) run it as the grid's
+third axis, above. The three causal launches run it INSIDE the kernel when a
+head's streamed operands fit VMEM whole: grid ``(H, q_blocks)`` (``(Hq,
+k_blocks)`` for ``dk``/``dv``), the head's keys and values (for ``dk``/``dv``
+its queries, their cotangents and the two statistics rows) one block each,
+fetched once a key/value head and prefetched during the previous head's last
+block, and a ``fori_loop`` from the block's first tile to its last slicing
+tile ``b`` out of the resident arrays: the same tile body in the same order,
+at the window's own length (documents of a median 1,024 tokens under a bound
+of 8,192 fill a quarter of the worst window). The rule is by shape, at trace
+time: rows x (query/key width + value width) x itemsize of one head, one copy,
+against ``CAUSAL_RESIDENT_BYTES`` (``_resident``); both decoder cells and
+every pack up to 64k slots at 128-wide bf16 heads take the loop, a longer pack
+keeps the grid schedule. The resident launches raise Mosaic's scoped VMEM
+limit (``_compiler_params``); no other kernel here sets compiler parameters.
 
 GPS differentiation is the house custom-JVP: only the primal runs Pallas; the
 tangent rule is the plain-jnp per-graph gathered reference pushed through
@@ -87,6 +105,11 @@ _NEG = -1.0e30
 # to them is an edit of these lines, claimed in a benchmark cell
 BLOCK_Q, BLOCK_K = 128, 128
 CAUSAL_BLOCK_Q, CAUSAL_BLOCK_K = 512, 512
+# the causal launches' schedule: a head's streamed operands (one copy: its
+# keys and values, for the ``dk``/``dv`` launch its queries and their
+# cotangents) up to this size stay in VMEM whole and the window's loop runs
+# inside the kernel; a longer pack streams tiles under the grid
+CAUSAL_RESIDENT_BYTES = 32 * 2 ** 20
 
 
 def normalize_tiles(block_q=BLOCK_Q, block_k=BLOCK_K):
@@ -210,8 +233,48 @@ def _pair_mask(gid_rows, gid_cols, row0, col0, causal, rows_are_queries=True):
     return mask
 
 
+def _softmax_tile(q, k_ref, v_ref, at, mask_tile, m_scr, l_scr, acc_scr, scale):
+    """One tile of the online softmax, into the running statistics: ``q``
+    ``[Bq, d]`` against the key and value tile at index ``at`` of their refs
+    (the whole block under the grid, one tile's rows of the resident head
+    under the in-kernel loop), masked by ``mask_tile()``. One body, in one
+    order, for both schedules."""
+    s = jax.lax.dot_general(
+        q,
+        k_ref[at],
+        (((1,), (1,)), ((), ())),  # contract the head dim: q @ k.T
+        precision=mxu_precision(q.dtype),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [Bq, Bk] f32
+    mask = mask_tile()
+    s = jnp.where(mask, s, _NEG)
+    m_prev = m_scr[:, 0:1]
+    l_prev = l_scr[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    # fully-masked tiles keep m_new == m_prev == _NEG: exp(0) == 1 on
+    # the correction, so the explicit where() is what zeroes them
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    l_scr[:] = jnp.broadcast_to(
+        l_prev * corr + jnp.sum(p, axis=1, keepdims=True), l_scr.shape
+    )
+    acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+        p.astype(v_ref.dtype),  # bf16 streams hit the MXU fast path
+        v_ref[at],
+        (((1,), (0,)), ((), ())),
+        precision=mxu_precision(v_ref.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+
+def _tile_rows(b, block):
+    """Rows of tile ``b`` of a head's resident array."""
+    return pl.ds(pl.multiple_of(b * block, block), block)
+
+
 def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
-            *refs, scale, emit_stats, causal=False):
+            *refs, scale, emit_stats, causal=False, resident_block_k=0):
     # stats outputs exist only for the block-summary (ring) launch: the
     # self-attention launch would have to WRITE two [H, N, 128] f32 arrays
     # to HBM just to discard them (pallas outputs cannot be DCE'd)
@@ -222,51 +285,12 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
     else:
         o_ref, m_scr, l_scr, acc_scr = refs
     j = pl.program_id(1)
-    kk = pl.program_id(2)
 
-    @pl.when(kk == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # out-of-window steps clamp their block index to the window's last
-    # block (no DMA — the block is already resident) and skip the update
-    @pl.when(kstart_ref[j] + kk <= klast_ref[j])
-    def _step():
-        q = q_ref[0]  # [Bq, d_pad]
-        s = jax.lax.dot_general(
-            q,
-            k_ref[0],
-            (((1,), (1,)), ((), ())),  # contract the head dim: q @ k.T
-            precision=mxu_precision(q.dtype),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [Bq, Bk] f32
-        mask = _pair_mask(
-            gidq_ref[:], gidk_ref[:], j * q.shape[0],
-            (kstart_ref[j] + kk) * k_ref.shape[1], causal,
-        )
-        s = jnp.where(mask, s, _NEG)
-        m_prev = m_scr[:, 0:1]
-        l_prev = l_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        # fully-masked tiles keep m_new == m_prev == _NEG: exp(0) == 1 on
-        # the correction, so the explicit where() is what zeroes them
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        l_scr[:] = jnp.broadcast_to(
-            l_prev * corr + jnp.sum(p, axis=1, keepdims=True), l_scr.shape
-        )
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(v_ref.dtype),  # bf16 streams hit the MXU fast path
-            v_ref[0],
-            (((1,), (0,)), ((), ())),
-            precision=mxu_precision(v_ref.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-
-    @pl.when(kk == pl.num_programs(2) - 1)
     def _finalize():
         l = l_scr[:, 0:1]
         # rows with no valid key (padding queries): l == 0, acc == 0 -> 0
@@ -277,6 +301,45 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
         elif emit_stats:
             m_ref[0] = m_scr[:]
             l_ref[0] = l_scr[:]
+
+    if resident_block_k:
+        # the head's keys, values and key ids are resident: walk this query
+        # block's own window, first tile to last, and no step more
+        bk = resident_block_k
+
+        def _tile(kb, _):
+            q = q_ref[0]
+            _softmax_tile(
+                q, k_ref, v_ref, (0, _tile_rows(kb, bk)),
+                lambda: _pair_mask(gidq_ref[:], gidk_ref[kb], j * q.shape[0],
+                                   kb * bk, causal),
+                m_scr, l_scr, acc_scr, scale,
+            )
+
+        _init()
+        jax.lax.fori_loop(kstart_ref[j], klast_ref[j] + 1, _tile, None)
+        _finalize()
+        return
+
+    kk = pl.program_id(2)
+    pl.when(kk == 0)(_init)
+
+    # out-of-window steps clamp their block index to the window's last
+    # block (no DMA: the block is already resident) and skip the update;
+    # each is still a grid step
+    @pl.when(kstart_ref[j] + kk <= klast_ref[j])
+    def _step():
+        q = q_ref[0]  # [Bq, d_pad]
+        _softmax_tile(
+            q, k_ref, v_ref, (0,),
+            lambda: _pair_mask(
+                gidq_ref[:], gidk_ref[:], j * q.shape[0],
+                (kstart_ref[j] + kk) * k_ref.shape[1], causal,
+            ),
+            m_scr, l_scr, acc_scr, scale,
+        )
+
+    pl.when(kk == pl.num_programs(2) - 1)(_finalize)
 
 
 def _lane_multiple(d: int) -> int:
@@ -293,6 +356,50 @@ def _heads_first(x, block):
     return jnp.transpose(x, (1, 0, 2))  # [H, N_pad, d_pad]
 
 
+def _resident(n_pad, width, dtype) -> bool:
+    """The causal launches' schedule, by shape: whether a head's streamed
+    operands (``n_pad`` rows, ``width`` lanes in all, one copy) stay in VMEM
+    whole, so that the window's loop runs inside the kernel."""
+    return n_pad * width * jnp.dtype(dtype).itemsize <= CAUSAL_RESIDENT_BYTES
+
+
+def _compiler_params(resident: bool):
+    """Mosaic's scoped VMEM limit for a launch that holds a head resident:
+    two copies of it (Pallas double-buffers every block: the next head's
+    arrives during this head's last block) and room for the tiles."""
+    if not resident:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=2 * CAUSAL_RESIDENT_BYTES + 32 * 2 ** 20)
+
+
+def _walked(resident: bool, id_row, block, widths, windows, head):
+    """The side of a launch that its window walks (keys and values of the
+    forward and ``dq`` launches, queries and their cotangents of the
+    ``dk``/``dv`` launch), under either schedule: -> (the grid's axes after
+    (head, held block), the id row ``[1, n_pad]`` as the kernel reads it, its
+    spec, one spec per operand width of ``widths``). Resident: no inner axis,
+    a head's arrays one block each (their block index moves with ``head(h)``
+    only, so they are fetched once a key/value head) and the ids as
+    ``[blocks, 1, block]`` (a tile's ids are an index of the leading axis,
+    not a dynamic lane slice). Streamed: ``windows`` inner steps whose block
+    index clamps to the window's last block."""
+    n_pad = id_row.shape[1]
+    if resident:
+        return ((), id_row.reshape(n_pad // block, 1, block),
+                pl.BlockSpec((n_pad // block, 1, block), lambda *_: (0, 0, 0)),
+                [pl.BlockSpec((1, n_pad, w), lambda h_i, *_: (head(h_i), 0, 0))
+                 for w in widths])
+
+    def tile(j, kk, first, last):
+        return jnp.minimum(first[j] + kk, last[j])
+
+    return ((windows,), id_row,
+            pl.BlockSpec((1, block), lambda h_i, *a: (0, tile(*a))),
+            [pl.BlockSpec((1, block, w), lambda h_i, *a: (head(h_i), tile(*a), 0))
+             for w in widths])
+
+
 def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
              block_q, block_k, interpret, emit_stats=False, causal=False,
              padded=False):
@@ -305,7 +412,10 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
     heads than ``q`` (grouped-query: head ``h`` reads key/value head
     ``h // group``). ``padded`` returns the launch's own arrays instead
     (``o [H, Nq_pad, d_pad]``, ``m``/``l [H, Nq_pad, 128]`` lane-broadcast):
-    what the tiled backward streams."""
+    what the tiled backward streams. A ``causal`` launch whose head fits
+    (``_resident``) takes the grid ``(H, q_blocks)``: a head's keys, values
+    and key ids are one block each and each query block's window is a loop
+    inside the kernel, at its own length."""
     nq, h, d = q.shape
     nk = k.shape[0]
     group = h // k.shape[1]
@@ -321,6 +431,7 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
     j_blocks = nq_pad // bq
     k_blocks = nk_pad // bk
     k_windows = max(1, min(k_windows, k_blocks))
+    resident = causal and _resident(nk_pad, d_pad + dv_pad, kt.dtype)
 
     gq = jnp.full((nq_pad, 1), -1, jnp.int32).at[:nq, 0].set(
         gid_q.astype(jnp.int32)
@@ -331,40 +442,29 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
     kstart = jnp.clip(kstart.astype(jnp.int32), 0, k_blocks - 1)
     klast = jnp.clip(klast.astype(jnp.int32), 0, k_blocks - 1)
 
-    def q_index(h_i, j, kk, ks, kl):
+    def held(h_i, j, *_):  # this query block's: q, gid column, outputs
         return (h_i, j, 0)
 
-    def kv_index(h_i, j, kk, ks, kl):
-        return (h_i // group, jnp.minimum(ks[j] + kk, kl[j]), 0)
-
-    def gidq_index(h_i, j, kk, ks, kl):
-        return (j, 0)
-
-    def gidk_index(h_i, j, kk, ks, kl):
-        return (0, jnp.minimum(ks[j] + kk, kl[j]))
-
-    def out_index(h_i, j, kk, ks, kl):
-        return (h_i, j, 0)
-
-    grid = (h, j_blocks, k_windows)
-    out_specs = [pl.BlockSpec((1, bq, dv_pad), out_index)]
+    inner, gk, gidk_spec, (k_spec, v_spec) = _walked(
+        resident, gk, bk, (d_pad, dv_pad), k_windows, lambda h_i: h_i // group)
+    out_specs = [pl.BlockSpec((1, bq, dv_pad), held)]
     out_shape = [jax.ShapeDtypeStruct((h, nq_pad, dv_pad), q.dtype)]
     if emit_stats:
         stats = 1 if emit_stats == "lse" else 2
-        out_specs += [pl.BlockSpec((1, bq, 128), out_index)] * stats
+        out_specs += [pl.BlockSpec((1, bq, 128), held)] * stats
         out_shape += [jax.ShapeDtypeStruct((h, nq_pad, 128), jnp.float32)] * stats
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, emit_stats=emit_stats,
-                          causal=causal),
+                          causal=causal, resident_block_k=bk if resident else 0),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(h, j_blocks) + inner,
             in_specs=[
-                pl.BlockSpec((bq, 1), gidq_index),
-                pl.BlockSpec((1, bk), gidk_index),
-                pl.BlockSpec((1, bq, d_pad), q_index),
-                pl.BlockSpec((1, bk, d_pad), kv_index),
-                pl.BlockSpec((1, bk, dv_pad), kv_index),
+                pl.BlockSpec((bq, 1), lambda h_i, j, *_: (j, 0)),
+                gidk_spec,
+                pl.BlockSpec((1, bq, d_pad), held),
+                k_spec,
+                v_spec,
             ],
             out_specs=out_specs,
             scratch_shapes=[
@@ -376,6 +476,7 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
         out_shape=out_shape,
         interpret=interpret,
         name=tr.HG_FLASH_ATTENTION,
+        compiler_params=_compiler_params(resident),
     )(kstart, klast, gq, gk, qt, kt, vt)
     if padded:
         return out
@@ -579,37 +680,46 @@ def reference_causal_attention(q, k, v, node_graph, node_mask):
     return jnp.einsum("hij,jhd->ihd", probs.astype(v.dtype), vf)
 
 
-def _causal_windows(node_graph, n, block_q, block_k, max_nodes_per_graph):
+def _causal_windows(node_graph, node_mask, block_q, block_k, max_nodes_per_graph):
     """The causal schedule, both ways round. A query block's keys run from
-    the first node of the graph owning its first row to its own last row; a
-    key block's queries run from its own first row to the last node of the
-    graph owning its last row. -> (kstart, klast, k_windows) in k-block
-    units per q block, (qstart, qlast, q_windows) in q-block units per k
-    block. Static step counts cover the worst legal window."""
+    the first node of the graph owning its first row to its own last real
+    row; a key block's queries run from its own first row to the last node of
+    the graph owning its last real row. A block of padding alone (padding is
+    last) keeps its own tile, fully masked. -> (kstart, klast, k_windows) in
+    k-block units per q block, (qstart, qlast, q_windows) in q-block units per
+    k block. The static step counts cover the worst legal window: the grid
+    schedule runs them for every block, the in-kernel loop does not."""
     ng = node_graph.astype(jnp.int32)
+    n = ng.shape[0]
     nmax = max(max_nodes_per_graph, 1)
+    real = jnp.max(jnp.where(node_mask, jnp.arange(1, n + 1, dtype=jnp.int32), 0))
 
     def rows(block):
         blocks = (n + block - 1) // block
         row0 = jnp.minimum(jnp.arange(blocks, dtype=jnp.int32) * block, n - 1)
-        return row0, jnp.minimum(row0 + block - 1, n - 1)
+        last = jnp.clip(jnp.minimum(row0 + block - 1, real - 1), row0, n - 1)
+        return row0, last
 
     q0, q1 = rows(block_q)
     first = jnp.searchsorted(ng, ng[q0], side="left").astype(jnp.int32)
+    kstart = jnp.where(q0 < real, first, q0) // block_k
     k_windows = (block_q + nmax - 1 + block_k - 1) // block_k + 1
     k0, k1 = rows(block_k)
     last = jnp.searchsorted(ng, ng[k1], side="right").astype(jnp.int32) - 1
+    qlast = jnp.where(k0 < real, last, k1) // block_q
     q_windows = (block_k + nmax - 1 + block_q - 1) // block_q + 1
-    return (first // block_k, q1 // block_k, k_windows,
-            k0 // block_q, last // block_q, q_windows)
+    return (kstart, q1 // block_k, k_windows,
+            k0 // block_q, qlast, q_windows)
 
 
 def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
-               do_ref, o_ref, lse_ref, dq_ref, delta_scr, acc_scr, *, scale):
+               do_ref, o_ref, lse_ref, dq_ref, delta_scr, acc_scr, *, scale,
+               resident_block_k=0):
+    """One query block held, its key/value tiles walked for ``dq``: by the
+    grid's third axis, or (``resident_block_k``) by a loop over the head's
+    resident keys and values, as the forward does."""
     j = pl.program_id(1)
-    kk = pl.program_id(2)
 
-    @pl.when(kk == 0)
     def _init():
         acc_scr[:] = jnp.zeros_like(acc_scr)
         delta = jnp.sum(
@@ -618,17 +728,15 @@ def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
         )
         delta_scr[:] = jnp.broadcast_to(delta, delta_scr.shape)
 
-    @pl.when(kstart_ref[j] + kk <= klast_ref[j])
-    def _step():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+    def _tile(kb, k, v, gidk):
+        q, do = q_ref[0], do_ref[0]
         nt = (((1,), (1,)), ((), ()))
         prec = mxu_precision(q.dtype)
         s = jax.lax.dot_general(
             q, k, nt, precision=prec, preferred_element_type=jnp.float32
         ) * scale
         mask = _pair_mask(
-            gidq_ref[:], gidk_ref[:], j * q.shape[0],
-            (kstart_ref[j] + kk) * k.shape[0], True,
+            gidq_ref[:], gidk, j * q.shape[0], kb * k.shape[0], True,
         )
         p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, 0:1]), 0.0)
         dp = jax.lax.dot_general(
@@ -640,28 +748,41 @@ def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             precision=prec, preferred_element_type=jnp.float32,
         )
 
-    @pl.when(kk == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = acc_scr[:].astype(dq_ref.dtype)
+
+    if resident_block_k:
+        def _walk(kb, _):
+            rows = _tile_rows(kb, resident_block_k)
+            _tile(kb, k_ref[0, rows], v_ref[0, rows], gidk_ref[kb])
+
+        _init()
+        jax.lax.fori_loop(kstart_ref[j], klast_ref[j] + 1, _walk, None)
+        _finalize()
+        return
+    kk = pl.program_id(2)
+    pl.when(kk == 0)(_init)
+    pl.when(kstart_ref[j] + kk <= klast_ref[j])(
+        lambda: _tile(kstart_ref[j] + kk, k_ref[0], v_ref[0], gidk_ref[:]))
+    pl.when(kk == pl.num_programs(2) - 1)(_finalize)
 
 
 def _dkv_kernel(qstart_ref, qlast_ref, gidk_ref, gidq_ref, k_ref, v_ref,
                 q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, scale):
-    """One key/value block held, its query blocks streamed; every product is
-    written transposed (``s.T = k @ q.T``) so that no tile is transposed in
-    the kernel: the statistics arrive as lane-major rows ``[8, Bq]``."""
+                dk_scr, dv_scr, *, scale, resident_block_q=0):
+    """One key/value block held, its query tiles walked (by the grid's third
+    axis, or by a loop over the query head's resident ``q``, ``do`` and
+    statistics); every product is written transposed (``s.T = k @ q.T``) so
+    that no tile is transposed in the kernel: the statistics arrive as
+    lane-major rows ``[*, Bq]``."""
     i = pl.program_id(1)
-    qq = pl.program_id(2)
 
-    @pl.when(qq == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(qstart_ref[i] + qq <= qlast_ref[i])
-    def _step():
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+    def _tile(qb, q, do, gidq, lse, delta):
+        k, v = k_ref[0], v_ref[0]
         nt = (((1,), (1,)), ((), ()))
         nn = (((1,), (0,)), ((), ()))
         prec = mxu_precision(q.dtype)
@@ -669,10 +790,10 @@ def _dkv_kernel(qstart_ref, qlast_ref, gidk_ref, gidq_ref, k_ref, v_ref,
             k, q, nt, precision=prec, preferred_element_type=jnp.float32
         ) * scale  # [Bk, Bq]
         mask = _pair_mask(
-            gidk_ref[:], gidq_ref[:], i * k.shape[0],
-            (qstart_ref[i] + qq) * q.shape[0], True, rows_are_queries=False,
+            gidk_ref[:], gidq, i * k.shape[0], qb * q.shape[0], True,
+            rows_are_queries=False,
         )
-        pt = jnp.where(mask, jnp.exp(st - lse_ref[0][0:1, :]), 0.0)
+        pt = jnp.where(mask, jnp.exp(st - lse), 0.0)
         dv_scr[:] += jax.lax.dot_general(
             pt.astype(do.dtype), do, nn, precision=prec,
             preferred_element_type=jnp.float32,
@@ -680,22 +801,57 @@ def _dkv_kernel(qstart_ref, qlast_ref, gidk_ref, gidq_ref, k_ref, v_ref,
         dpt = jax.lax.dot_general(
             v, do, nt, precision=prec, preferred_element_type=jnp.float32
         )
-        dst = pt * (dpt - delta_ref[0][0:1, :]) * scale
+        dst = pt * (dpt - delta) * scale
         dk_scr[:] += jax.lax.dot_general(
             dst.astype(q.dtype), q, nn, precision=prec,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(qq == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:]
         dv_ref[0] = dv_scr[:]
+
+    if resident_block_q:
+        def _walk(qb, _):
+            rows = _tile_rows(qb, resident_block_q)
+            _tile(qb, q_ref[0, rows], do_ref[0, rows], gidq_ref[qb],
+                  lse_ref[0, qb], delta_ref[0, qb])
+
+        _init()
+        jax.lax.fori_loop(qstart_ref[i], qlast_ref[i] + 1, _walk, None)
+        _finalize()
+        return
+    qq = pl.program_id(2)
+    pl.when(qq == 0)(_init)
+    pl.when(qstart_ref[i] + qq <= qlast_ref[i])(
+        lambda: _tile(qstart_ref[i] + qq, q_ref[0], do_ref[0], gidq_ref[:],
+                      lse_ref[0][0:1, :], delta_ref[0][0:1, :]))
+    pl.when(qq == pl.num_programs(2) - 1)(_finalize)
+
+
+def causal_schedule_steps(node_graph, node_mask, max_nodes_per_graph: int, d: int, dv: int,
+                          dtype, block_q: int = CAUSAL_BLOCK_Q,
+                          block_k: int = CAUSAL_BLOCK_K):
+    """What one head of a forward launch of :func:`flash_causal_attention`
+    runs on these operands: -> (tiles visited, the sum over query blocks of
+    their windows' lengths; steps scheduled for them: the same sum where the
+    loop runs inside the kernel, ``q_blocks x k_windows`` under the grid).
+    float32 scalars; the arithmetic and the rule are the launch's own."""
+    bq, bk = normalize_tiles(block_q, block_k)
+    n = node_graph.shape[0]
+    ks, kl, kw, _, _, _ = _causal_windows(node_graph, node_mask, bq, bk, max_nodes_per_graph)
+    visited = jnp.sum((kl - ks + 1).astype(jnp.float32))
+    pad = lambda x, m: -(-x // m) * m
+    k_blocks = pad(n, bk) // bk
+    if _resident(k_blocks * bk, pad(d, _lane_multiple(d)) + pad(dv, _lane_multiple(dv)), dtype):
+        return visited, visited
+    return visited, jnp.float32(ks.shape[0] * max(1, min(kw, k_blocks)))
 
 
 def _causal_prep(node_graph, node_mask, max_nodes_per_graph, block_q, block_k):
     gid = jnp.where(node_mask, node_graph.astype(jnp.int32), -1)
     return gid, _causal_windows(
-        node_graph, node_graph.shape[0], block_q, block_k, max_nodes_per_graph
+        node_graph, node_mask, block_q, block_k, max_nodes_per_graph
     )
 
 
@@ -734,8 +890,9 @@ def flash_causal_attention(
     layout contract as :func:`flash_self_attention` (graphs contiguous,
     ``node_graph`` non-decreasing, padding last); a graph past
     ``max_nodes_per_graph`` is under-covered and the caller poisons it.
-    Forward and backward are Pallas launches under one schedule; reverse
-    mode only, first order."""
+    Forward and backward are Pallas launches under one schedule (each
+    block's own window, walked inside the kernel where a head fits VMEM:
+    the module docstring); reverse mode only, first order."""
     return _flash_causal_attention(
         q, k, v, node_graph, node_mask, max_nodes_per_graph,
         *normalize_tiles(block_q, block_k), interpret,
@@ -779,22 +936,24 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
         qs, ql = clip(qs, j_blocks), clip(ql, j_blocks)
         kw, qw = max(1, min(kw, k_blocks)), max(1, min(qw, j_blocks))
 
-        # ---- dq: the forward's schedule
-        held = lambda h_i, j, kk, s_, l_: (h_i, j, 0)
-        kv = lambda h_i, j, kk, s_, l_: (
-            h_i // group, jnp.minimum(s_[j] + kk, l_[j]), 0)
+        # ---- dq: the forward's schedule, in the forward's place (the grid's
+        # third axis, or the loop over the head's resident keys and values)
+        held = lambda h_i, j, *_: (h_i, j, 0)
+        resident = _resident(nk_pad, d_pad + dv_pad, kt.dtype)
+        inner, gidk, gidk_spec, (k_spec, v_spec) = _walked(
+            resident, grow(nk_pad), bk, (d_pad, dv_pad), kw, lambda h_i: h_i // group)
         dq = pl.pallas_call(
-            functools.partial(_dq_kernel, scale=scale),
+            functools.partial(_dq_kernel, scale=scale,
+                              resident_block_k=bk if resident else 0),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
-                grid=(hq, j_blocks, kw),
+                grid=(hq, j_blocks) + inner,
                 in_specs=[
-                    pl.BlockSpec((bq, 1), lambda h_i, j, kk, s_, l_: (j, 0)),
-                    pl.BlockSpec((1, bk), lambda h_i, j, kk, s_, l_: (
-                        0, jnp.minimum(s_[j] + kk, l_[j]))),
+                    pl.BlockSpec((bq, 1), lambda h_i, j, *_: (j, 0)),
+                    gidk_spec,
                     pl.BlockSpec((1, bq, d_pad), held),
-                    pl.BlockSpec((1, bk, d_pad), kv),
-                    pl.BlockSpec((1, bk, dv_pad), kv),
+                    k_spec,
+                    v_spec,
                     pl.BlockSpec((1, bq, dv_pad), held),
                     pl.BlockSpec((1, bq, dv_pad), held),
                     pl.BlockSpec((1, bq, 128), held),
@@ -808,35 +967,44 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
             out_shape=jax.ShapeDtypeStruct((hq, nq_pad, d_pad), q.dtype),
             interpret=interpret,
             name=tr.HG_FLASH_ATTENTION + tr.BWD,
-        )(ks, kl, gcol(nq_pad), grow(nk_pad), qt, kt, vt, dot, ot, lse)
+            compiler_params=_compiler_params(resident),
+        )(ks, kl, gcol(nq_pad), gidk, qt, kt, vt, dot, ot, lse)
 
         # ---- dk, dv: one key/value block held, per QUERY head; the group's
-        # heads are summed after (float32 partials)
-        rows8 = lambda x: jnp.broadcast_to(x[:, None, :], (hq, 8, nq_pad))
-        lse_row = rows8(lse[:, :, 0])
-        delta_row = rows8(jnp.sum(
-            dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1))
-        kheld = lambda h_i, i, qq, s_, l_: (h_i // group, i, 0)
-        out_held = lambda h_i, i, qq, s_, l_: (h_i, i, 0)
-        qv = lambda h_i, i, qq, s_, l_: (
-            h_i, jnp.minimum(s_[i] + qq, l_[i]), 0)
-        stat = lambda h_i, i, qq, s_, l_: (
-            h_i, 0, jnp.minimum(s_[i] + qq, l_[i]))
+        # heads are summed after (float32 partials). The other way round: the
+        # query head's q, do and statistics are what is streamed or resident
+        lse_row = lse[:, :, 0]
+        delta_row = jnp.sum(
+            dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1)
+        kheld = lambda h_i, i, *_: (h_i // group, i, 0)
+        out_held = lambda h_i, i, *_: (h_i, i, 0)
+        resident = _resident(nq_pad, d_pad + dv_pad, qt.dtype)
+        inner, gidq, gidq_spec, (q_spec, do_spec) = _walked(
+            resident, grow(nq_pad), bq, (d_pad, dv_pad), qw, lambda h_i: h_i)
+        if resident:  # a tile's statistics: an index of the second axis
+            tiled = lambda x: x.reshape(hq, j_blocks, 1, bq)
+            lse_row, delta_row = tiled(lse_row), tiled(delta_row)
+            stat_spec = pl.BlockSpec((1, j_blocks, 1, bq), lambda h_i, *_: (h_i, 0, 0, 0))
+        else:
+            rows8 = lambda x: jnp.broadcast_to(x[:, None, :], (hq, 8, nq_pad))
+            lse_row, delta_row = rows8(lse_row), rows8(delta_row)
+            stat_spec = pl.BlockSpec((1, 8, bq), lambda h_i, i, qq, s_, l_: (
+                h_i, 0, jnp.minimum(s_[i] + qq, l_[i])))
         dk_h, dv_h = pl.pallas_call(
-            functools.partial(_dkv_kernel, scale=scale),
+            functools.partial(_dkv_kernel, scale=scale,
+                              resident_block_q=bq if resident else 0),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
-                grid=(hq, k_blocks, qw),
+                grid=(hq, k_blocks) + inner,
                 in_specs=[
-                    pl.BlockSpec((bk, 1), lambda h_i, i, qq, s_, l_: (i, 0)),
-                    pl.BlockSpec((1, bq), lambda h_i, i, qq, s_, l_: (
-                        0, jnp.minimum(s_[i] + qq, l_[i]))),
+                    pl.BlockSpec((bk, 1), lambda h_i, i, *_: (i, 0)),
+                    gidq_spec,
                     pl.BlockSpec((1, bk, d_pad), kheld),
                     pl.BlockSpec((1, bk, dv_pad), kheld),
-                    pl.BlockSpec((1, bq, d_pad), qv),
-                    pl.BlockSpec((1, bq, dv_pad), qv),
-                    pl.BlockSpec((1, 8, bq), stat),
-                    pl.BlockSpec((1, 8, bq), stat),
+                    q_spec,
+                    do_spec,
+                    stat_spec,
+                    stat_spec,
                 ],
                 out_specs=[pl.BlockSpec((1, bk, d_pad), out_held),
                            pl.BlockSpec((1, bk, dv_pad), out_held)],
@@ -847,7 +1015,8 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
                        jax.ShapeDtypeStruct((hq, nk_pad, dv_pad), jnp.float32)],
             interpret=interpret,
             name=tr.HG_FLASH_ATTENTION + tr.BWD,
-        )(qs, ql, gcol(nk_pad), grow(nq_pad), kt, vt, qt, dot, lse_row, delta_row)
+            compiler_params=_compiler_params(resident),
+        )(qs, ql, gcol(nk_pad), gidq, kt, vt, qt, dot, lse_row, delta_row)
 
         def per_kv_head(x, width):
             x = x.reshape(hk, group, nk_pad, x.shape[2]).sum(axis=1)

@@ -64,6 +64,10 @@ CT_TOKENS_ROUTED_HERE = "count:tokens_routed_here"  # tokens whose expert is hel
 CT_EXPERT_LOAD_MAX = "count:expert_load_max"  # largest load of a held expert, summed over layers
 CT_EXPERT_LOAD_MEAN = "count:expert_load_mean"  # mean load of the held experts, summed over layers
 CT_CAUSAL_PAIRS = "count:causal_pairs"  # (query, key) pairs within graphs, one layer's
+# the causal flash launch's schedule, one block's forward launch, one head
+# (ops/pallas_flash_attention.py causal_schedule_steps)
+CT_FLASH_TILES_VISITED = "count:flash_tiles_visited"  # tiles in the query blocks' key windows
+CT_FLASH_STEPS_SCHEDULED = "count:flash_steps_scheduled"  # steps run for them: loop trips, or q_blocks x k_windows under the grid
 CT_EXPERT_ROWS_HERE = "count:expert_rows_here"  # JOYAI: rows computed on this chip (a token is 0..k), over layers
 CT_EXPERT_ROWS_OVERRUN = "count:expert_rows_overrun"  # JOYAI: rows past the row budget (the step is poisoned)
 CT_MTP_PAIRS = "count:mtp_pairs"  # JOYAI: nodes whose two successors lie in their document

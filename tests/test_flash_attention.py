@@ -461,3 +461,124 @@ def pytest_performer_bf16_under_jit_finite_and_close():
         np.asarray(out_f32)[mask],
         rtol=1e-1, atol=1e-1,
     )
+
+
+# ---------------------------------------------------------------------------
+# the causal launches' two schedules: the window's loop inside the kernel over
+# a head's resident operands, or the grid's third axis over streamed tiles
+# (ops/pallas_flash_attention.py CAUSAL_RESIDENT_BYTES). 128 x 128 tiles here.
+# ---------------------------------------------------------------------------
+
+from hydragnn_tpu.ops import pallas_flash_attention as pfa  # noqa: E402
+
+# (document sizes, trailing padding, max_nodes_per_graph): the schedule's edges
+CAUSAL_PACKS = {
+    "boundary_inside_a_tile": ([40, 3, 150, 70, 1], 24, 150),
+    "document_of_exactly_max_nodes": ([60, 300, 17], 7, 300),
+    "document_spanning_four_tiles": ([50, 420, 30], 12, 512),
+    "all_padding_last_query_block": ([100, 60], 200, 128),
+    "padding_over_three_blocks": ([100, 60], 420, 128),
+}
+# (query heads, key/value heads, width of queries and keys, width of values)
+CAUSAL_HEADS = {"group4_d16": (4, 1, 16, 16), "mla_192_128": (2, 2, 192, 128)}
+
+
+def _causal_case(pack, heads, dtype, seed=0):
+    sizes, pad, nmax = CAUSAL_PACKS[pack]
+    hq, hk, d, dv = CAUSAL_HEADS[heads]
+    n = sum(sizes) + pad
+    node_graph = jnp.asarray(np.concatenate(
+        [np.full(s, i) for i, s in enumerate(sizes)] + [np.full(pad, len(sizes))]).astype(np.int32))
+    node_mask = jnp.asarray(np.arange(n) < sum(sizes))
+    rng = np.random.default_rng(seed)
+    mk = lambda h, w: jnp.asarray(rng.normal(size=(n, h, w)), jnp.float32).astype(dtype)
+    q, k, v = mk(hq, d), mk(hk, d), mk(hk, dv)
+    w = mk(hq, dv).astype(jnp.float32) * node_mask[:, None, None]
+    return (q, k, v), w, node_graph, node_mask, nmax
+
+
+def _causal_all(fn, ops, w):
+    """Output and the three gradients of ``sum(fn(q, k, v) * w)``, float32."""
+    f32 = lambda a: a.astype(jnp.float32)
+    out = fn(*ops)
+    grads = jax.grad(lambda *a: jnp.sum(f32(fn(*a)) * w), (0, 1, 2))(*ops)
+    return [f32(out) * (w != 0).any(axis=2, keepdims=True)] + [f32(g) for g in grads]
+
+
+def _first_grids(closed):
+    import re
+
+    return [tuple(int(g) for g in m.split(",") if g.strip())
+            for m in re.findall(r"GridMapping\(grid=\(([\d, ]+)\)", str(closed))]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", list(CAUSAL_HEADS))
+@pytest.mark.parametrize("pack", list(CAUSAL_PACKS))
+def pytest_causal_in_kernel_loop_matches_reference_and_grid(monkeypatch, pack, heads, dtype):
+    """The in-kernel-loop launches (forward, ``dq``, ``dk``/``dv``) against the
+    flat masked reference, and against the grid schedule on the same operands:
+    the same tiles in the same order, so equal to float32 rounding."""
+    ops, w, node_graph, node_mask, nmax = _causal_case(pack, heads, dtype)
+    kernel = lambda *a: pfa.flash_causal_attention(*a, node_graph, node_mask, nmax, 128, 128, True)
+    grids = _first_grids(jax.make_jaxpr(lambda *a: _causal_all(kernel, a, w))(*ops))
+    assert grids and all(len(g) == 2 for g in grids), grids
+    loop = _causal_all(kernel, ops, w)
+    f32 = lambda a: a.astype(jnp.float32)
+    want = _causal_all(lambda *a: pfa.reference_causal_attention(*a, node_graph, node_mask),
+                       [f32(a) for a in ops], w)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    for got, ref in zip(loop, want):
+        assert np.isfinite(np.asarray(got)).all()
+        assert float(jnp.abs(got - ref).max()) <= tol * max(float(jnp.abs(ref).max()), 1.0) * 4
+    monkeypatch.setattr(pfa, "CAUSAL_RESIDENT_BYTES", 0)
+    grids = _first_grids(jax.make_jaxpr(lambda *a: _causal_all(kernel, a, w))(*ops))
+    assert all(len(g) == 3 for g in grids), grids
+    for got, ref in zip(loop, _causal_all(kernel, ops, w)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-6, atol=1e-6)
+
+
+def pytest_causal_schedule_follows_the_resident_bytes(monkeypatch):
+    """A head's streamed operands (rows x (qk width + value width) x bytes, one
+    copy) at the budget: the loop; one byte past it: the grid. One result."""
+    # at the constant as it stands: both decoder cells' heads fit, a 128k pack's does not
+    fits = lambda n, width: pfa._resident(n, width, jnp.bfloat16)
+    assert fits(32768, 256) and fits(16384, 320) and fits(65536, 256) and not fits(131072, 256)
+    ops, w, node_graph, node_mask, nmax = _causal_case("document_spanning_four_tiles", "mla_192_128", jnp.bfloat16)
+    n_pad = -(-ops[0].shape[0] // 128) * 128
+    head_bytes = n_pad * (192 + 128) * 2
+    kernel = lambda *a: pfa.flash_causal_attention(*a, node_graph, node_mask, nmax, 128, 128, True)
+    results = {}
+    for name, budget, axes in (("loop", head_bytes, 2), ("grid", head_bytes - 1, 3)):
+        monkeypatch.setattr(pfa, "CAUSAL_RESIDENT_BYTES", budget)
+        grids = _first_grids(jax.make_jaxpr(lambda *a: _causal_all(kernel, a, w))(*ops))
+        # the forward alone, then the forward, ``dq`` and ``dk``/``dv`` of the gradient
+        assert len(grids) == 4 and all(len(g) == axes for g in grids), (name, grids)
+        results[name] = _causal_all(kernel, ops, w)
+    for a, b in zip(results["loop"], results["grid"]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("pack", list(CAUSAL_PACKS))
+def pytest_causal_schedule_steps_count_the_windows(monkeypatch, pack):
+    """``causal_schedule_steps`` against a replay of the windows in numpy: a
+    query block's tiles run from the tile of its first row's document start to
+    the tile of its own last real row; a block of padding alone keeps one."""
+    _, _, node_graph, node_mask, nmax = _causal_case(pack, "group4_d16", jnp.float32)
+    ng, real = np.asarray(node_graph), int(np.asarray(node_mask).sum())
+    n, b = ng.shape[0], 128
+    visited = 0
+    for row0 in range(0, n, b):
+        if row0 >= real:
+            visited += 1
+            continue
+        row1 = min(row0 + b - 1, real - 1)
+        visited += row1 // b - int(np.searchsorted(ng, ng[row0], side="left")) // b + 1
+    got = pfa.causal_schedule_steps(node_graph, node_mask, nmax, 16, 16, jnp.float32, b, b)
+    assert [float(x) for x in got] == [visited, visited]
+    monkeypatch.setattr(pfa, "CAUSAL_RESIDENT_BYTES", 0)
+    q_blocks = -(-n // b)
+    k_windows = min((b + nmax - 1 + b - 1) // b + 1, q_blocks)
+    got = pfa.causal_schedule_steps(node_graph, node_mask, nmax, 16, 16, jnp.float32, b, b)
+    assert [float(x) for x in got] == [visited, q_blocks * k_windows]
+    assert visited <= q_blocks * k_windows

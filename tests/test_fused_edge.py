@@ -787,33 +787,38 @@ def pytest_bf16_kernel_compiles_for_v5e_at_the_cell_shape(v5e_chip, tangent):
         assert stream in text, stream
 
 
-def pytest_causal_flash_launches_compile_for_v5e_at_latent_attention_widths(v5e_chip):
+@pytest.mark.parametrize("n,hq,hk,d_qk,d_v", [(16384, 32, 32, 192, 128), (32768, 8, 2, 128, 128)],
+                         ids=["joyai_latent_attention_widths", "zaya_grouped_query_heads"])
+def pytest_causal_flash_launches_compile_for_v5e_at_the_decoder_cells_shapes(v5e_chip, n, hq, hk, d_qk, d_v):
     """Mosaic accepts the causal flash kernel's three launches (forward,
-    ``dq``, ``dk``/``dv``) at the JOYAI cell's shape: 16384 tokens, 32 heads,
-    queries and keys 192 wide beside values 128 wide, bf16. The 192 streams
-    as it is: no operand grows to 256 lanes (the heads-first copies are
-    ``[32, 16384, 192]`` and ``[32, 16384, 128]``)."""
+    ``dq``, ``dk``/``dv``) at the decoder cells' shapes, bf16, with a head's
+    streamed operands RESIDENT in VMEM (two copies of up to 16.8 MB under the
+    raised scoped limit) and the window's loop inside the kernel. JOYAI:
+    16384 tokens, 32 heads, queries and keys 192 wide beside values 128
+    wide; the 192 streams as it is: no operand grows to 256 lanes (the
+    heads-first copies are ``[32, 16384, 192]`` and ``[32, 16384, 128]``).
+    ZAYA: 32768 tokens, 8 query heads on 2 key/value heads of 128."""
     import re
 
-    from hydragnn_tpu.ops.pallas_flash_attention import flash_causal_attention
+    from hydragnn_tpu.ops import pallas_flash_attention as pfa
 
-    n, h, d_qk, d_v = 16384, 32, 192, 128
+    assert pfa._resident(n, d_qk + d_v, jnp.bfloat16)
     shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
 
     def loss(q, k, v, node_graph, node_mask):
-        out = flash_causal_attention(q, k, v, node_graph, node_mask, 8192)
+        out = pfa.flash_causal_attention(q, k, v, node_graph, node_mask, 8192)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        shaped((n, h, d_qk)), shaped((n, h, d_qk)), shaped((n, h, d_v)),
+        shaped((n, hq, d_qk)), shaped((n, hk, d_qk)), shaped((n, hk, d_v)),
         shaped((n,), jnp.int32), shaped((n,), jnp.bool_)).compile()
     text = compiled.as_text()
     calls = re.findall(r"^\s*%(hg_flash_attention[a-z_]*)[.\d]* = .*custom-call\(", text, re.MULTILINE)
     assert sorted(calls) == ["hg_flash_attention", "hg_flash_attention_bwd", "hg_flash_attention_bwd"], calls
-    assert "bf16[32,16384,192]" in text and "bf16[32,16384,128]" in text
-    assert "bf16[32,16384,256]" not in text
-    # dq, dk (192 wide) and dv (128 wide) in the operands' shapes (and a tuple's few bytes)
-    assert 0 <= compiled.memory_analysis().output_size_in_bytes - 2 * n * h * (2 * d_qk + d_v) < 4096
+    assert f"bf16[{hq},{n},{d_qk}]" in text and f"bf16[{hk},{n},{d_v}]" in text
+    assert f"bf16[{hq},{n},256]" not in text
+    # dq, dk (qk wide) and dv in the operands' shapes (and a tuple's few bytes)
+    assert 0 <= compiled.memory_analysis().output_size_in_bytes - 2 * n * (hq * d_qk + hk * (d_qk + d_v)) < 4096
 
 
 def _cell_train_step_text(monkeypatch, v5e_chip):

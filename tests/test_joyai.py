@@ -406,6 +406,10 @@ def pytest_step_counters_reach_the_tracer_at_the_epoch_drain(built):
     assert regions[tr.CT_EXPERT_LOAD_MAX]["total"] >= regions[tr.CT_EXPERT_LOAD_MEAN]["total"] > 0
     mtp = sum(int(np.maximum(np.asarray(b.nodes_per_graph)[np.asarray(b.graph_mask)] - 2, 0).sum()) for b in loader)
     assert regions[tr.CT_MTP_PAIRS]["total"] == mtp
+    # one query block, one key tile a step; the head is resident: no step more
+    steps = sum(1 for _ in loader)
+    assert regions[tr.CT_FLASH_TILES_VISITED]["total"] == steps
+    assert regions[tr.CT_FLASH_STEPS_SCHEDULED]["total"] == steps
     assert tr.CT_CAUSAL_PAIRS in tasks and "next_token" in tasks and "mtp" in tasks
 
 

@@ -120,8 +120,10 @@ CASES = [
     ("fused_multi_agg c=866 alone", multi_agg(866, False, False), "bfloat16", (128, 512, 128), (7, 95, 10)),
     ("flash_self_attention", flash_self, "bfloat16", (128, 128), (8, 9, 9)),
     ("flash_self_attention", flash_self, "float32", (128, 128), (8, 9, 9)),
-    ("flash_causal_attention", flash_causal, "bfloat16", (512, 512), (8, 64, 18)),
-    ("flash_causal_attention", flash_causal, "float32", (512, 512), (8, 64, 18)),
+    # since PR 34 the window's loop runs inside the kernel: no third grid axis
+    # (it was 18), a key/value head's arrays resident as one block each
+    ("flash_causal_attention", flash_causal, "bfloat16", (512, 512), (8, 64)),
+    ("flash_causal_attention", flash_causal, "float32", (512, 512), (8, 64)),
     ("grouped_matmul", grouped, "bfloat16", (512, 1024, 512), (72, 2, 4)),
     ("grouped_matmul", grouped, "float32", (512, 512, 512), (72, 4, 4)),
 ]
@@ -134,6 +136,13 @@ def first_grid(closed):
     return tuple(int(g) for g in found.group(1).split(",") if g.strip())
 
 
+def first_blocks(closed):
+    """The block shapes of the first ``pallas_call``'s operands and outputs."""
+    mappings = str(closed).split("GridMapping(", 1)[1].split("input_output_aliases", 1)[0]
+    return [tuple(int(b) for b in re.findall(r"block_size=(\d+)", m))
+            for m in mappings.split("BlockMapping(")[1:]]
+
+
 @pytest.mark.parametrize("name,launch,dtype,tiles,grid", CASES,
                          ids=[f"{c[0]} {c[2]}".replace(" ", "-") for c in CASES])
 def pytest_entry_point_given_no_tiles_runs_the_measured_ones(name, launch, dtype, tiles, grid):
@@ -144,6 +153,12 @@ def pytest_entry_point_given_no_tiles_runs_the_measured_ones(name, launch, dtype
     given_none = trace()
     assert str(given_none) == str(trace(**dict(zip(names, tiles))))
     assert first_grid(given_none) == grid
+    if name == "flash_causal_attention":
+        # graph-id column, the key ids as [k_blocks, 1, block_k], the query
+        # block, the head's keys and values whole, the output and lse blocks
+        held = (1, 512, 128)
+        assert first_blocks(given_none) == [
+            (512, 1), (T // 512, 1, 512), held, (1, T, D), (1, T, D), held, held]
 
 
 # ---------------------------------------------------------------------------

@@ -370,6 +370,11 @@ def pytest_step_counters_reach_the_tracer_at_the_epoch_drain(docs):
     pairs = sum(int(n) * (int(n) + 1) // 2 for b in loader
                 for n in np.asarray(b.nodes_per_graph)[np.asarray(b.graph_mask)])
     assert regions[tr.CT_CAUSAL_PAIRS]["total"] == pairs
+    # 160 node slots are one query block with one key tile in its window; a
+    # head this small is resident, so the schedule runs the visited tiles only
+    steps = sum(1 for _ in loader)
+    assert regions[tr.CT_FLASH_TILES_VISITED]["total"] == steps
+    assert regions[tr.CT_FLASH_STEPS_SCHEDULED]["total"] == steps
     assert tr.CT_TOKENS in tasks and "next_token" in tasks
 
 
