@@ -388,9 +388,10 @@ def kernel_leg(**shape) -> None:
             _check(f"{name} {label}", err, tol[0] if tol else TOL[dt])
 
 
-def _blocked_causal_reference(q, k, v, node_graph, node_mask, block=1024):
+def _blocked_causal_reference(q, k, v, node_graph, node_mask, block=1024, window=None):
     """Causal same-graph attention in plain jnp, one block of queries at a
-    time (``[H, block, N]`` scores), so that 32768 nodes fit."""
+    time (``[H, block, N]`` scores), so that 32768 nodes fit; under
+    ``window`` a query sees its ``window`` latest keys only."""
     import jax
     import jax.numpy as jnp
 
@@ -404,6 +405,8 @@ def _blocked_causal_reference(q, k, v, node_graph, node_mask, block=1024):
         s = jnp.einsum("ihd,jhd->hij", qb, kf) / np.sqrt(d)
         ok = ((gb[:, None] == node_graph[None, :]) & (rb[:, None] & node_mask[None, :])
               & (idx[None, :] <= ib[:, None]))
+        if window is not None:
+            ok = ok & (ib[:, None] - idx[None, :] < window)
         p = jnp.where(ok[None], jax.nn.softmax(jnp.where(ok[None], s, -1e30), axis=-1), 0.0)
         return jnp.einsum("hij,jhd->ihd", p, vf)
 
@@ -416,7 +419,7 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
                        longest=8192, groups=8, width=2048, interpret=False,
                        dtypes=("bfloat16", "float32"), value_dim=None,
                        width_out=None, topk=0, experts=0, capacity=2.0,
-                       block=1024) -> dict:
+                       block=1024, window=None) -> dict:
     """The decoder's two kernels alone at a cell's shapes, forward and
     backward, against plain jnp: causal grouped-query flash attention over
     ``[tokens, heads x head_dim]`` (values ``value_dim`` wide, ``head_dim``
@@ -427,6 +430,8 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
     JOYAI cell's layout instead: every token chooses ``topk`` of ``experts``,
     ``groups`` of them held, rows within ``capacity`` times the balanced
     count, dispatch and combine around the product (``joyai_kernel_leg``).
+    ``window`` adds the SLIDING launches on the same operands beside the full
+    ones (``trinity_kernel_leg``: a step of that cell holds both kinds).
     Prints each launch's time (a set-up fact, not a throughput)."""
     import jax
     import jax.numpy as jnp
@@ -457,20 +462,22 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
         q, k, v = arr((tokens, heads, head_dim)), arr((tokens, kv_heads, head_dim)), arr((tokens, kv_heads, dv))
         w = arr((tokens, heads, dv)) * node_mask[:, None, None].astype(dtype)
         f32 = lambda a: a.astype(jnp.float32)
-        blocked = lambda q_, k_, v_, g_, m_: _blocked_causal_reference(q_, k_, v_, g_, m_, block)
-        with jax.default_matmul_precision("highest"):
-            ref_loss = lambda q_, k_, v_: jnp.sum(blocked(q_, k_, v_, node_graph, node_mask) * f32(w))
-            ref_out = jax.jit(blocked)(f32(q), f32(k), f32(v), node_graph, node_mask)
-            ref_grads = jax.jit(jax.grad(ref_loss, (0, 1, 2)))(f32(q), f32(k), f32(v))
-        causal = lambda q_, k_, v_: flash_causal_attention(
-            q_, k_, v_, node_graph, node_mask, longest, interpret=interpret)
-        bwd = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(f32(causal(q_, k_, v_)) * f32(w)), (0, 1, 2)))
-        tag = f"flash_causal {dt}" + (f" {heads}x{head_dim}/{dv}" if value_dim else "")
-        out = timed(tag + " fwd_ms", jax.jit(causal), q, k, v)
-        _check(tag + " forward", _rel_err(out[:n_real], ref_out[:n_real]), TOL[dt])
-        grads = timed(tag + " fwd+bwd_ms", bwd, q, k, v)
-        for name, got, want in zip("qkv", grads, ref_grads):
-            _check(f"{tag} d{name}", _rel_err(got, want), TOL_DECODER_BWD[dt])
+        for win in (None, window) if window else (None,):
+            blocked = lambda q_, k_, v_, g_, m_: _blocked_causal_reference(q_, k_, v_, g_, m_, block, win)
+            with jax.default_matmul_precision("highest"):
+                ref_loss = lambda q_, k_, v_: jnp.sum(blocked(q_, k_, v_, node_graph, node_mask) * f32(w))
+                ref_out = jax.jit(blocked)(f32(q), f32(k), f32(v), node_graph, node_mask)
+                ref_grads = jax.jit(jax.grad(ref_loss, (0, 1, 2)))(f32(q), f32(k), f32(v))
+            causal = lambda q_, k_, v_: flash_causal_attention(
+                q_, k_, v_, node_graph, node_mask, longest, interpret=interpret, window=win)
+            bwd = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(f32(causal(q_, k_, v_)) * f32(w)), (0, 1, 2)))
+            tag = (f"flash_window({win})" if win else "flash_causal") + f" {dt}" + (
+                f" {heads}x{head_dim}/{dv}" if value_dim else "")
+            out = timed(tag + " fwd_ms", jax.jit(causal), q, k, v)
+            _check(tag + " forward", _rel_err(out[:n_real], ref_out[:n_real]), TOL[dt])
+            grads = timed(tag + " fwd+bwd_ms", bwd, q, k, v)
+            for name, got, want in zip("qkv", grads, ref_grads):
+                _check(f"{tag} d{name}", _rel_err(got, want), TOL_DECODER_BWD[dt])
 
         # ---- grouped product: ragged groups, one of them empty
         wide = width_out or width
@@ -542,6 +549,17 @@ def joyai_kernel_leg(interpret=False, **small) -> dict:
     dispatch and combine; bfloat16, the cell's precision."""
     shapes = dict(tokens=16384, heads=32, kv_heads=32, head_dim=192, value_dim=128, longest=8192,
                   groups=16, width=2048, width_out=768, topk=8, experts=256, dtypes=("bfloat16",), block=256)
+    return decoder_kernel_leg(interpret=interpret, **{**shapes, **small})
+
+
+def trinity_kernel_leg(interpret=False, **small) -> dict:
+    """``decoder_kernel_leg`` at the Trinity cell's shapes: 32 query heads on
+    4 key/value heads of 128 over 16,384 tokens with a document of 12,288, the
+    full launches AND the sliding ones (window 2,048) on the same operands,
+    and the top-8 layout (8 of 128 experts held, 2048 -> 1024) with dispatch
+    and combine; bfloat16, the cell's precision."""
+    shapes = dict(tokens=16384, heads=32, kv_heads=4, head_dim=128, longest=12288, window=2048,
+                  groups=8, width=2048, width_out=1024, topk=8, experts=128, dtypes=("bfloat16",), block=256)
     return decoder_kernel_leg(interpret=interpret, **{**shapes, **small})
 
 
@@ -978,7 +996,7 @@ def main() -> int:
     workdir = tempfile.mkdtemp(prefix="chip_smoke_")
     os.chdir(workdir)
     todo = [("kernels", kernel_leg), ("decoder_kernels", decoder_kernel_leg),
-            ("joyai_kernels", joyai_kernel_leg), ("main", main_leg),
+            ("joyai_kernels", joyai_kernel_leg), ("trinity_kernels", trinity_kernel_leg), ("main", main_leg),
             ("second_order", second_order_leg)]
     if jax.local_device_count() > 1:
         todo.append(("mesh", mesh_leg))

@@ -184,6 +184,21 @@ def update_config(
             arch.setdefault("experts_held", list(range(int(arch["n_routed_experts"]))))
         JoyaiConfig.from_arch(arch)
         arch.setdefault("use_sorted_aggregation", False)
+    if arch["mpnn_type"] == "AFMOE":
+        from ..models.afmoe import FULL, AfmoeConfig
+
+        for key, default in (
+                ("rope_theta", 1.0e4), ("sliding_window", None), ("num_shared_experts", 1),
+                ("num_dense_layers", 1), ("route_scale", 1.0), ("route_norm", True),
+                ("load_balance_coeff", 0.001), ("mup_enabled", True), ("expert_row_capacity", None),
+                ("rms_norm_eps", 1.0e-5), ("loss_chunk_rows", 4096)):
+            arch.setdefault(key, default)
+        if arch.get("num_conv_layers") is not None:
+            arch.setdefault("layer_types", [FULL] * int(arch["num_conv_layers"]))
+        if arch.get("num_experts") is not None:
+            arch.setdefault("experts_held", list(range(int(arch["num_experts"]))))
+        AfmoeConfig.from_arch(arch)
+        arch.setdefault("use_sorted_aggregation", False)
 
     # GPS defaults (reference: config_utils.py:40-47)
     arch.setdefault("global_attn_engine", None)

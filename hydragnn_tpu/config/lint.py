@@ -147,6 +147,15 @@ _HANDLED = {
     "NeuralNetwork.Architecture.num_nextn_predict_layers",
     "NeuralNetwork.Architecture.mtp_loss_weight",
     "NeuralNetwork.Architecture.expert_row_capacity",
+    # the third decoder stack (mpnn_type AFMOE, models/afmoe.py)
+    "NeuralNetwork.Architecture.layer_types",
+    "NeuralNetwork.Architecture.sliding_window",
+    "NeuralNetwork.Architecture.num_dense_layers",
+    "NeuralNetwork.Architecture.num_shared_experts",
+    "NeuralNetwork.Architecture.route_scale",
+    "NeuralNetwork.Architecture.route_norm",
+    "NeuralNetwork.Architecture.load_balance_coeff",
+    "NeuralNetwork.Architecture.mup_enabled",
     "NeuralNetwork.Architecture.branch_loss_weights",
     "NeuralNetwork.Architecture.branch_loss_metrics",
     "NeuralNetwork.Architecture.dropout",
@@ -333,7 +342,7 @@ _TOPLEVEL_SECTIONS = (
 
 @dataclasses.dataclass(frozen=True)
 class Finding:
-    status: str  # handled | not-applicable | legacy | unknown
+    status: str  # handled | not-applicable | legacy | unknown | invalid
     path: str
     message: str = ""
 
@@ -350,8 +359,27 @@ def _walk(d: Dict[str, Any], prefix: str = "") -> List[str]:
     return out
 
 
+def _afmoe_rules(config: Dict[str, Any]) -> List[Finding]:
+    """The AFMOE stack's two rules between keys (models/afmoe.py refuses the
+    same at config completion): one ``layer_types`` entry a layer, and a
+    ``sliding_window`` on a stack that has a sliding layer."""
+    arch = (config.get("NeuralNetwork") or {}).get("Architecture") or {}
+    kinds = arch.get("layer_types")
+    if arch.get("mpnn_type") != "AFMOE" or not isinstance(kinds, list):
+        return []
+    out, at = [], "NeuralNetwork.Architecture."
+    layers = arch.get("num_conv_layers")
+    if layers is not None and len(kinds) != int(layers):
+        out.append(Finding("invalid", at + "layer_types",
+                           f"{len(kinds)} entries for num_conv_layers {int(layers)}: one entry a layer"))
+    if "sliding_attention" in kinds and not arch.get("sliding_window"):
+        out.append(Finding("invalid", at + "sliding_window",
+                           "layer_types has a sliding_attention layer: give its window (a count of keys)"))
+    return out
+
+
 def lint_config(config: Dict[str, Any]) -> List[Finding]:
-    findings: List[Finding] = []
+    findings: List[Finding] = _afmoe_rules(config)
     for path in _walk(config):
         if path in _NOT_APPLICABLE:
             findings.append(Finding("not-applicable", path, _NOT_APPLICABLE[path]))
@@ -382,7 +410,7 @@ def lint_config(config: Dict[str, Any]) -> List[Finding]:
 
 
 def format_report(findings: List[Finding]) -> str:
-    order = {"unknown": 0, "legacy": 1, "not-applicable": 2, "handled": 3}
+    order = {"invalid": -1, "unknown": 0, "legacy": 1, "not-applicable": 2, "handled": 3}
     lines = []
     counts: Dict[str, int] = {}
     for f in sorted(findings, key=lambda f: (order[f.status], f.path)):
@@ -404,7 +432,7 @@ def main(argv=None) -> int:
     if len(argv) != 1:
         print("usage: python -m hydragnn_tpu.config.lint config.json")
         return 2
-    # exit codes: 0 = clean, 1 = unknown keys found, 2 = could not lint —
+    # exit codes: 0 = clean, 1 = unknown or invalid keys found, 2 = could not lint —
     # migration scripts branch on 1 vs 2
     try:
         with open(argv[0]) as fh:
@@ -423,7 +451,7 @@ def main(argv=None) -> int:
         return 2
     findings = lint_config(config)
     print(format_report(findings))
-    return 1 if any(f.status == "unknown" for f in findings) else 0
+    return 1 if any(f.status in ("unknown", "invalid") for f in findings) else 0
 
 
 if __name__ == "__main__":

@@ -159,11 +159,13 @@ class ModelConfig:
     zaya: Optional[Any] = None
     # --- the second decoder stack (mpnn_type "JOYAI", models/joyai.py)
     joyai: Optional[Any] = None
+    # --- the third decoder stack (mpnn_type "AFMOE", models/afmoe.py)
+    afmoe: Optional[Any] = None
 
     @property
     def decoder(self) -> Optional[Any]:
         """The keys of whichever decoder stack this is, or None."""
-        return self.zaya or self.joyai
+        return self.zaya or self.joyai or self.afmoe
 
     @property
     def num_heads(self) -> int:

@@ -163,6 +163,7 @@ def model_config_from(config: Dict[str, Any]) -> ModelConfig:
         max_neighbours=arch.get("max_neighbours"),
         zaya=_zaya_config(arch),
         joyai=_joyai_config(arch),
+        afmoe=_afmoe_config(arch),
     )
 
 
@@ -180,6 +181,14 @@ def _joyai_config(arch: Dict[str, Any]):
     from .joyai import JoyaiConfig
 
     return JoyaiConfig.from_arch(arch)
+
+
+def _afmoe_config(arch: Dict[str, Any]):
+    if arch["mpnn_type"] != "AFMOE":
+        return None
+    from .afmoe import AfmoeConfig
+
+    return AfmoeConfig.from_arch(arch)
 
 
 def create_model(config: Dict[str, Any]):
@@ -211,6 +220,12 @@ def create_model(config: Dict[str, Any]):
         from .joyai import JoyaiModel
 
         return JoyaiModel(cfg=cfg)
+    if cfg.mpnn_type == "AFMOE":
+        # the third decoder stack: sliding-window and full attention layers
+        # in one stack, gated attention between sandwich norms (models/afmoe.py)
+        from .afmoe import AfmoeModel
+
+        return AfmoeModel(cfg=cfg)
     return HydraModel(cfg=cfg)
 
 
@@ -224,4 +239,4 @@ def init_model(
 
 
 def available_models() -> Tuple[str, ...]:
-    return conv_registry() + ("MACE", "ZAYA", "JOYAI")
+    return conv_registry() + ("MACE", "ZAYA", "JOYAI", "AFMOE")

@@ -1,4 +1,5 @@
-"""What the decoder stacks share (models/zaya.py, models/joyai.py).
+"""What the decoder stacks share (models/zaya.py, models/joyai.py,
+models/afmoe.py).
 
 Token = node, document = graph, packed sequence = packed batch: the batcher
 lays graphs out contiguously along the flat node axis, so ``node_graph`` is a
@@ -6,14 +7,18 @@ packed sequence's segment ids and a node's index within its graph is its
 position. Here, once: RMSNorm, RoPE from that index (rotate-half and
 interleaved), the token embedding, the route to the causal flash kernel, the
 SiLU-gated expert products on group-aligned rows, dispatch and combine around
-them for a token of several assignments (top-k), the balancing rule of a
-router's bias buffer, the initial scales, the per-layer rematerialisation and
+them for a token of several assignments (top-k), the sigmoid top-k router and
+the expert sublayer of the stacks that route so (``ExpertSpec``, ``route``,
+``expert_sublayer``), the balancing rules of a router's bias buffer, the
+initial scales, the per-layer rematerialisation and
 the poison of a step that cannot stand.
 """
 
 from __future__ import annotations
 
-from typing import Dict
+import dataclasses
+import math
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -85,19 +90,20 @@ def follows(node_graph, node_mask, ahead: int):
     return same.at[-ahead:].set(False)
 
 
-def causal_attention(q, k, v, aux, max_nodes: int):
+def causal_attention(q, k, v, aux, max_nodes: int, window: Optional[int] = None):
     """Route: the Pallas flash kernel on the TPU (or where
     ``HYDRAGNN_PALLAS_FLASH`` forces it, interpreted), else the flat masked
-    reference. ``v`` may be narrower than ``q`` and ``k``."""
+    reference. ``v`` may be narrower than ``q`` and ``k``; ``window`` is a
+    sliding layer's bound (a query sees its ``window`` latest keys)."""
     from ..ops.pallas_flash_attention import (
         _flash_route_enabled, flash_causal_attention, reference_causal_attention)
 
     node_graph, node_mask = aux["node_graph"], aux["node_mask"]
     if not _flash_route_enabled():
-        return reference_causal_attention(q, k, v, node_graph, node_mask)
+        return reference_causal_attention(q, k, v, node_graph, node_mask, window)
     return flash_causal_attention(
         q, k, v, node_graph, node_mask, max_nodes,
-        interpret=jax.default_backend() != "tpu",
+        interpret=jax.default_backend() != "tpu", window=window,
     )
 
 
@@ -179,6 +185,114 @@ def combine_rows(out_rows, gate_row, token, tokens: int):
     return jnp.zeros((tokens + 1, out_rows.shape[1]), jnp.float32).at[token].add(w)[:tokens]
 
 
+@dataclasses.dataclass(frozen=True)
+class ExpertSpec:
+    """What the sigmoid top-k router and the expert sublayer read of a
+    stack's keys (``JoyaiConfig.experts``, ``AfmoeConfig.experts``)."""
+
+    num_experts: int  # the router's width: all the experts of a layer
+    top_k: int
+    experts_held: Tuple[int, ...]
+    width: int  # an expert's intermediate size
+    shared: int  # shared experts (one MLP of ``width x shared``)
+    scale: float  # the gates' factor
+    norm_gates: bool = True
+    row_capacity: float = 0.0
+
+    def row_budget(self, tokens: int, block_m: int) -> int:
+        """Static rows of the aligned buffer for ``tokens`` token slots:
+        ``row_capacity`` times the rows a balanced router sends here plus one
+        row tile a held expert; 0 is the worst case."""
+        if self.row_capacity <= 0:
+            return 0
+        expected = tokens * self.top_k * len(self.experts_held) / self.num_experts
+        rows = math.ceil(self.row_capacity * expected)
+        return -(-rows // block_m) * block_m + len(self.experts_held) * block_m
+
+
+def route(p: Dict, beta, u, e: ExpertSpec):
+    """The router, in float32: ``s = sigmoid(W_r u)`` over ALL experts, the
+    choice the ``top_k`` largest of ``s + beta``, the gates the chosen ``s``
+    (normalised to sum 1 under ``norm_gates``) times ``scale``. -> (choice
+    [T, k], gate [T, k])."""
+    s = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32), p["router"].astype(jnp.float32), precision="highest"))
+    # the balancing bias is a buffer: it moves the choice, takes no gradient
+    _, choice = jax.lax.top_k(s + jax.lax.stop_gradient(beta.astype(jnp.float32)), e.top_k)
+    gate = jnp.take_along_axis(s, choice, axis=-1)
+    if e.norm_gates:
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
+    return choice, gate * e.scale
+
+
+def expert_sublayer(p: Dict, beta, u, node_mask, e: ExpertSpec, choice=None,
+                    row_budget: Optional[Callable[[int, int], int]] = None):
+    """The expert sublayer on the normalised stream ``u [T, D]``: route over
+    all experts, compute the rows whose expert is in ``e.experts_held``
+    (``p["experts_*"]`` hold those, in that order), nothing for the others,
+    and the shared expert on every token. -> (y [T, D] before the residual
+    add, the held experts' loads [held], every expert's load [num_experts],
+    [rows past the budget, tokens with a row here]). ``choice`` overrides the
+    router's (tests); ``row_budget`` the spec's own rule."""
+    from ..ops.pallas_grouped_matmul import normalize_tiles
+
+    t, d_model = u.shape
+    k = e.top_k
+    with tr.scope(tr.HG_ROUTER):
+        routed, gate = route(p, beta, u, e)
+        choice = routed if choice is None else choice
+        kernel = jax.default_backend() == "tpu"
+        # each expert's rows start at a multiple of the kernel's row tile
+        block_m = normalize_tiles(t * k, d_model, e.width, dtype=u.dtype)[0]
+        layout = topk_layout(choice, node_mask, e.experts_held, e.num_experts, block_m,
+                             (row_budget or e.row_budget)(t, block_m))
+    with tr.scope(tr.HG_MOE_DISPATCH):
+        rows = dispatch_rows(u, layout["token"])
+    out_rows = expert_products(rows, p["experts_gate"], p["experts_up"], p["experts_down"],
+                               layout, block_m, kernel)
+    with tr.scope(tr.HG_MOE_COMBINE):
+        gate_row = jnp.concatenate([gate.reshape(-1), jnp.zeros((1,), gate.dtype)])[layout["src"]]
+        y = combine_rows(out_rows, gate_row, layout["token"], t)
+    if e.shared:
+        with tr.scope(tr.HG_SHARED_EXPERT):
+            y = y + gated_mlp(u, p["shared_gate"], p["shared_up"], p["shared_down"]).astype(jnp.float32)
+    every = jnp.zeros((e.num_experts,), jnp.float32).at[choice.reshape(-1)].add(
+        jnp.repeat(node_mask.astype(jnp.float32), k))
+    return y.astype(u.dtype), layout["counts"], every, jnp.stack([layout["overrun"], layout["tokens_here"]])
+
+
+def expert_layer_stats(counts, overrun, here):
+    """A layer's five counters from ``expert_sublayer``'s: [rows computed
+    here, largest held load, mean held load, rows past the budget, tokens
+    with a row here], float32."""
+    counts, overrun = counts.astype(jnp.float32), overrun.astype(jnp.float32)
+    return jnp.stack([jnp.sum(counts) - overrun, jnp.max(counts), jnp.mean(counts), overrun,
+                      here.astype(jnp.float32)])
+
+
+def expert_param_shapes(d: int, e: ExpertSpec) -> Dict[str, Tuple[Tuple[int, ...], str]]:
+    """name -> (shape, init kind) of an expert sublayer's leaves: the router
+    over all experts, the held experts' banks, the shared expert."""
+    f, held = e.width, len(e.experts_held)
+    shapes = {
+        "router": ((d, e.num_experts), "lecun"),
+        "experts_gate": ((held, d, f), "lecun"), "experts_up": ((held, d, f), "lecun"),
+        "experts_down": ((held, f, d), "small"),
+    }
+    if e.shared:
+        fs = f * e.shared
+        shapes.update({"shared_gate": ((d, fs), "lecun"), "shared_up": ((d, fs), "lecun"),
+                       "shared_down": ((fs, d), "small")})
+    return shapes
+
+
+def sign_balanced_bias(beta, loads, rate: float):
+    """Loss-free balancing as published (arXiv:2408.15664): once a training
+    step, outside the gradient, each expert's bias moves by ``rate`` towards
+    the mean load, ``b_e += rate * sign(mean load - load_e)``."""
+    loads = jax.lax.stop_gradient(loads)
+    return beta + rate * jnp.sign(jnp.mean(loads) - loads)
+
+
 def balanced_bias(beta, loads):
     """The balancing rule of the router's bias buffer (loss-free balancing,
     arXiv:2408.15664, its proportional variant), applied once a training step
@@ -239,12 +353,25 @@ def causal_pairs(batch):
     return jnp.sum(n_g * (n_g + 1.0) * 0.5)
 
 
-def flash_steps(batch, max_nodes: int, d: int, dv: int, dtype) -> Dict:
+def window_pairs(batch, window: int):
+    """(query, key) pairs within graphs and within a sliding ``window``
+    (``0 <= i - j < window``), one layer's: a graph of n nodes has ``n (n +
+    1) / 2`` up to the window and ``W (W + 1) / 2 + (n - W) W`` past it."""
+    n_g = batch.nodes_per_graph.astype(jnp.float32) * batch.graph_mask.astype(jnp.float32)
+    w = jnp.float32(window)
+    return jnp.sum(jnp.where(n_g <= w, n_g * (n_g + 1.0) * 0.5, w * (w + 1.0) * 0.5 + (n_g - w) * w))
+
+
+def flash_steps(batch, max_nodes: int, d: int, dv: int, dtype, window: Optional[int] = None) -> Dict:
     """The causal flash launch's schedule on this batch, one block's forward
     launch, one head (queries and keys ``d`` wide, values ``dv``, streamed as
     ``dtype``): the tiles its windows hold and the steps the schedule runs for
-    them, as the step's two ``count:flash_*`` entries."""
+    them, as the step's two ``count:flash_*`` entries; a sliding layer's
+    (``window``) under names of their own."""
     from ..ops.pallas_flash_attention import causal_schedule_steps
 
-    visited, scheduled = causal_schedule_steps(batch.node_graph, batch.node_mask, max_nodes, d, dv, dtype)
+    visited, scheduled = causal_schedule_steps(batch.node_graph, batch.node_mask, max_nodes, d, dv, dtype,
+                                               window=window)
+    if window is not None:
+        return {tr.CT_FLASH_WINDOW_TILES_VISITED: visited, tr.CT_FLASH_WINDOW_STEPS_SCHEDULED: scheduled}
     return {tr.CT_FLASH_TILES_VISITED: visited, tr.CT_FLASH_STEPS_SCHEDULED: scheduled}

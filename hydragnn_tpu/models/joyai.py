@@ -47,7 +47,6 @@ The plain reference of these equations is benchmarks/reference/joyai.py.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Dict, Tuple
 
 import jax
@@ -139,14 +138,18 @@ class JoyaiConfig:
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    @property
+    def experts(self) -> dc.ExpertSpec:
+        """The router's and the expert sublayer's numbers, as models/decoder.py reads them."""
+        return dc.ExpertSpec(
+            num_experts=self.n_routed_experts, top_k=self.num_experts_per_tok, experts_held=self.experts_held,
+            width=self.moe_intermediate_size, shared=self.n_shared_experts, scale=self.routed_scaling_factor,
+            norm_gates=self.norm_topk_prob, row_capacity=self.expert_row_capacity)
+
     def row_budget(self, tokens: int, block_m: int) -> int:
         """Static rows of the aligned buffer for ``tokens`` token slots; 0 is
         the worst case."""
-        if self.expert_row_capacity <= 0:
-            return 0
-        expected = tokens * self.num_experts_per_tok * len(self.experts_held) / self.n_routed_experts
-        rows = math.ceil(self.expert_row_capacity * expected)
-        return -(-rows // block_m) * block_m + len(self.experts_held) * block_m
+        return self.experts.row_budget(tokens, block_m)
 
 
 def mla_sublayer(p: Dict, u, aux, z: JoyaiConfig, max_nodes: int):
@@ -171,50 +174,15 @@ def mla_sublayer(p: Dict, u, aux, z: JoyaiConfig, max_nodes: int):
 
 
 def route(p: Dict, beta, u, z: JoyaiConfig):
-    """The router, in float32: -> (choice [T, k] over ALL experts, gate
-    [T, k])."""
-    s = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32), p["router"].astype(jnp.float32), precision="highest"))
-    # the balancing bias is a buffer: it moves the choice, takes no gradient
-    _, choice = jax.lax.top_k(s + jax.lax.stop_gradient(beta.astype(jnp.float32)), z.num_experts_per_tok)
-    gate = jnp.take_along_axis(s, choice, axis=-1)
-    if z.norm_topk_prob:
-        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
-    return choice, gate * z.routed_scaling_factor
+    """The router (``decoder.route``) at this stack's numbers: -> (choice
+    [T, k] over ALL experts, gate [T, k])."""
+    return dc.route(p, beta, u, z.experts)
 
 
 def expert_sublayer(p: Dict, beta, u, node_mask, z: JoyaiConfig, choice=None):
-    """The expert sublayer on the normalised stream ``u [T, D]``: route over
-    all experts, compute the rows whose expert is in ``z.experts_held``
-    (``p["experts_*"]`` hold those, in that order), nothing for the others,
-    and the shared expert on every token. -> (y [T, D] before the residual
-    add, the held experts' loads [held], every expert's load
-    [n_routed_experts], [rows past the budget, tokens with a row here]).
-    ``choice`` overrides the router's (tests)."""
-    from ..ops.pallas_grouped_matmul import normalize_tiles
-
-    t, d_model = u.shape
-    k = z.num_experts_per_tok
-    with tr.scope(tr.HG_ROUTER):
-        routed, gate = route(p, beta, u, z)
-        choice = routed if choice is None else choice
-        kernel = jax.default_backend() == "tpu"
-        # each expert's rows start at a multiple of the kernel's row tile
-        block_m = normalize_tiles(t * k, d_model, z.moe_intermediate_size, dtype=u.dtype)[0]
-        layout = dc.topk_layout(choice, node_mask, z.experts_held, z.n_routed_experts, block_m,
-                                z.row_budget(t, block_m))
-    with tr.scope(tr.HG_MOE_DISPATCH):
-        rows = dc.dispatch_rows(u, layout["token"])
-    out_rows = dc.expert_products(rows, p["experts_gate"], p["experts_up"], p["experts_down"],
-                                  layout, block_m, kernel)
-    with tr.scope(tr.HG_MOE_COMBINE):
-        gate_row = jnp.concatenate([gate.reshape(-1), jnp.zeros((1,), gate.dtype)])[layout["src"]]
-        y = dc.combine_rows(out_rows, gate_row, layout["token"], t)
-    if z.n_shared_experts:
-        with tr.scope(tr.HG_SHARED_EXPERT):
-            y = y + dc.gated_mlp(u, p["shared_gate"], p["shared_up"], p["shared_down"]).astype(jnp.float32)
-    every = jnp.zeros((z.n_routed_experts,), jnp.float32).at[choice.reshape(-1)].add(
-        jnp.repeat(node_mask.astype(jnp.float32), k))
-    return y.astype(u.dtype), layout["counts"], every, jnp.stack([layout["overrun"], layout["tokens_here"]])
+    """The expert sublayer (``decoder.expert_sublayer``) at this stack's
+    numbers, its row budget ``z.row_budget``."""
+    return dc.expert_sublayer(p, beta, u, node_mask, z.experts, choice, z.row_budget)
 
 
 def layer_param_shapes(hidden: int, z: JoyaiConfig, dense_mlp: bool) -> Dict[str, Tuple[Tuple[int, ...], str]]:
@@ -235,16 +203,7 @@ def layer_param_shapes(hidden: int, z: JoyaiConfig, dense_mlp: bool) -> Dict[str
         f = z.intermediate_size
         shapes.update({"mlp_gate": ((d, f), "lecun"), "mlp_up": ((d, f), "lecun"), "mlp_down": ((f, d), "small")})
         return shapes
-    f, held = z.moe_intermediate_size, len(z.experts_held)
-    shapes.update({
-        "router": ((d, z.n_routed_experts), "lecun"),
-        "experts_gate": ((held, d, f), "lecun"), "experts_up": ((held, d, f), "lecun"),
-        "experts_down": ((held, f, d), "small"),
-    })
-    if z.n_shared_experts:
-        fs = f * z.n_shared_experts
-        shapes.update({"shared_gate": ((d, fs), "lecun"), "shared_up": ((d, fs), "lecun"),
-                       "shared_down": ((fs, d), "small")})
+    shapes.update(dc.expert_param_shapes(d, z.experts))
     return shapes
 
 
@@ -269,9 +228,7 @@ class JoyaiLayer(nn.Module):
             y = dc.gated_mlp(u, p["mlp_gate"], p["mlp_up"], p["mlp_down"])
             return x + y, jnp.zeros((5,), jnp.float32), jnp.zeros((z.n_routed_experts,), jnp.float32)
         y, counts, every, (overrun, here) = expert_sublayer(p, beta, u, aux["node_mask"], z)
-        counts, overrun = counts.astype(jnp.float32), overrun.astype(jnp.float32)
-        return (x + y, jnp.stack([jnp.sum(counts) - overrun, jnp.max(counts), jnp.mean(counts), overrun,
-                                  here.astype(jnp.float32)]), every)
+        return x + y, dc.expert_layer_stats(counts, overrun, here), every
 
 
 class JoyaiModel(nn.Module):

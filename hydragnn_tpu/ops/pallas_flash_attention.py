@@ -51,7 +51,14 @@ holding a query block and streaming its keys for ``dq``, one holding a
 key/value block and streaming its queries for ``dk``/``dv``, both rebuilding
 the probabilities from the forward's saved log-sum-exp. No ``[*, N, N]``
 array exists in either direction, so a graph of 8192 nodes trains. It is a
-first-order ``custom_vjp`` (the token loss needs no more).
+first-order ``custom_vjp`` (the token loss needs no more). A static ``window``
+W makes the launches a SLIDING layer's (models/afmoe.py): query ``i`` sees key
+``j`` iff same graph, ``j <= i`` and ``i - j < W``. That is window arithmetic
+(a query block's walk starts at the tile of ``row0 - W + 1``, a key block's
+ends at the tile of ``col_last + W - 1``: ``_causal_windows``) and one more
+iota compare in the three kernels' mask; the launches carry the name
+``hg_flash_window`` / ``_bwd``, and ``window=None`` traces the kernels as they
+were (the jaxprs of all three launches are the ones without the argument).
 
 Where each launch's window loop runs. The self-attention and block-summary
 launches (GPS, the ring: windows of 2-4 tiles of 128) run it as the grid's
@@ -218,11 +225,13 @@ def reference_block_summary(q, k, v, key_mask):
 # ---------------------------------------------------------------------------
 
 
-def _pair_mask(gid_rows, gid_cols, row0, col0, causal, rows_are_queries=True):
+def _pair_mask(gid_rows, gid_cols, row0, col0, causal, rows_are_queries=True,
+               window=None):
     """Same-graph mask of one tile from the streamed graph-id column
     ``[R, 1]`` and row ``[1, C]`` (padding nodes carry -1 on the key side
     and never match); under ``causal`` also key index <= query index, from
-    the tile's first flat row/column index."""
+    the tile's first flat row/column index, and under a sliding ``window``
+    query index - key index < ``window``."""
     keys = gid_cols if rows_are_queries else gid_rows
     mask = (gid_rows == gid_cols) & (keys >= 0)
     if causal:
@@ -230,6 +239,8 @@ def _pair_mask(gid_rows, gid_cols, row0, col0, causal, rows_are_queries=True):
         r = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         c = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         mask = mask & ((c <= r) if rows_are_queries else (r <= c))
+        if window is not None:
+            mask = mask & ((r - c < window) if rows_are_queries else (c - r < window))
     return mask
 
 
@@ -274,7 +285,8 @@ def _tile_rows(b, block):
 
 
 def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
-            *refs, scale, emit_stats, causal=False, resident_block_k=0):
+            *refs, scale, emit_stats, causal=False, resident_block_k=0,
+            window=None):
     # stats outputs exist only for the block-summary (ring) launch: the
     # self-attention launch would have to WRITE two [H, N, 128] f32 arrays
     # to HBM just to discard them (pallas outputs cannot be DCE'd)
@@ -312,7 +324,7 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             _softmax_tile(
                 q, k_ref, v_ref, (0, _tile_rows(kb, bk)),
                 lambda: _pair_mask(gidq_ref[:], gidk_ref[kb], j * q.shape[0],
-                                   kb * bk, causal),
+                                   kb * bk, causal, window=window),
                 m_scr, l_scr, acc_scr, scale,
             )
 
@@ -334,7 +346,7 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             q, k_ref, v_ref, (0,),
             lambda: _pair_mask(
                 gidq_ref[:], gidk_ref[:], j * q.shape[0],
-                (kstart_ref[j] + kk) * k_ref.shape[1], causal,
+                (kstart_ref[j] + kk) * k_ref.shape[1], causal, window=window,
             ),
             m_scr, l_scr, acc_scr, scale,
         )
@@ -373,6 +385,12 @@ def _compiler_params(resident: bool):
         vmem_limit_bytes=2 * CAUSAL_RESIDENT_BYTES + 32 * 2 ** 20)
 
 
+def _causal_name(window) -> str:
+    """A launch's name in the device trace: the sliding launches carry their
+    own, so that a trace tells the two kinds of one step apart."""
+    return tr.HG_FLASH_ATTENTION if window is None else tr.HG_FLASH_WINDOW
+
+
 def _walked(resident: bool, id_row, block, widths, windows, head):
     """The side of a launch that its window walks (keys and values of the
     forward and ``dq`` launches, queries and their cotangents of the
@@ -402,7 +420,7 @@ def _walked(resident: bool, id_row, block, widths, windows, head):
 
 def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
              block_q, block_k, interpret, emit_stats=False, causal=False,
-             padded=False):
+             padded=False, window=None):
     """Shared launch: q ``[Nq, H, d]`` against k/v ``[Nk, H, d]`` with
     per-q-block key-window schedule (kstart/klast in k-block units) and
     per-node graph ids (-1 = never a valid key). Returns the normalized
@@ -415,7 +433,9 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
     what the tiled backward streams. A ``causal`` launch whose head fits
     (``_resident``) takes the grid ``(H, q_blocks)``: a head's keys, values
     and key ids are one block each and each query block's window is a loop
-    inside the kernel, at its own length."""
+    inside the kernel, at its own length. ``window`` (causal launches only)
+    is the sliding bound of the mask; the schedule it is given already stops
+    at it, and the launch carries the name ``hg_flash_window``."""
     nq, h, d = q.shape
     nk = k.shape[0]
     group = h // k.shape[1]
@@ -455,7 +475,8 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
         out_shape += [jax.ShapeDtypeStruct((h, nq_pad, 128), jnp.float32)] * stats
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, emit_stats=emit_stats,
-                          causal=causal, resident_block_k=bk if resident else 0),
+                          causal=causal, resident_block_k=bk if resident else 0,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(h, j_blocks) + inner,
@@ -475,7 +496,7 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
         ),
         out_shape=out_shape,
         interpret=interpret,
-        name=tr.HG_FLASH_ATTENTION,
+        name=_causal_name(window),
         compiler_params=_compiler_params(resident),
     )(kstart, klast, gq, gk, qt, kt, vt)
     if padded:
@@ -656,10 +677,10 @@ def _summary_jvp(block_q, block_k, interpret, primals, tangents):
 # ---------------------------------------------------------------------------
 
 
-def reference_causal_attention(q, k, v, node_graph, node_mask):
+def reference_causal_attention(q, k, v, node_graph, node_mask, window=None):
     """Flat ``[N, N]``-masked causal grouped-query attention in plain jnp:
     node ``i`` attends node ``j`` iff both are real, share a graph and
-    ``j <= i``. ``q [N, Hq, d]``, ``k [N, Hk, d]``, ``v [N, Hk, dv]`` with
+    ``j <= i`` (and, under a sliding ``window``, ``i - j < window``). ``q [N, Hq, d]``, ``k [N, Hk, d]``, ``v [N, Hk, dv]`` with
     ``Hq`` a multiple of ``Hk``. The oracle of the kernel and the route off
     the TPU; scores and softmax in float32."""
     n, hq, d = q.shape
@@ -672,6 +693,8 @@ def reference_causal_attention(q, k, v, node_graph, node_mask):
         & (node_mask[:, None] & node_mask[None, :])
         & (idx[None, :] <= idx[:, None])
     )
+    if window is not None:
+        allowed = allowed & (idx[:, None] - idx[None, :] < window)
     logits = jnp.einsum(
         "ihd,jhd->hij", q, kf, preferred_element_type=jnp.float32
     ) * (1.0 / float(d) ** 0.5)
@@ -680,11 +703,13 @@ def reference_causal_attention(q, k, v, node_graph, node_mask):
     return jnp.einsum("hij,jhd->ihd", probs.astype(v.dtype), vf)
 
 
-def _causal_windows(node_graph, node_mask, block_q, block_k, max_nodes_per_graph):
+def _causal_windows(node_graph, node_mask, block_q, block_k, max_nodes_per_graph,
+                    window=None):
     """The causal schedule, both ways round. A query block's keys run from
-    the first node of the graph owning its first row to its own last real
+    the first node of the graph owning its first row (under a sliding
+    ``window`` W no earlier than row ``row0 - W + 1``) to its own last real
     row; a key block's queries run from its own first row to the last node of
-    the graph owning its last real row. A block of padding alone (padding is
+    the graph owning its last real row (no later than ``col_last + W - 1``). A block of padding alone (padding is
     last) keeps its own tile, fully masked. -> (kstart, klast, k_windows) in
     k-block units per q block, (qstart, qlast, q_windows) in q-block units per
     k block. The static step counts cover the worst legal window: the grid
@@ -692,6 +717,7 @@ def _causal_windows(node_graph, node_mask, block_q, block_k, max_nodes_per_graph
     ng = node_graph.astype(jnp.int32)
     n = ng.shape[0]
     nmax = max(max_nodes_per_graph, 1)
+    reach = nmax if window is None else min(nmax, window)  # keys a query can see
     real = jnp.max(jnp.where(node_mask, jnp.arange(1, n + 1, dtype=jnp.int32), 0))
 
     def rows(block):
@@ -702,19 +728,23 @@ def _causal_windows(node_graph, node_mask, block_q, block_k, max_nodes_per_graph
 
     q0, q1 = rows(block_q)
     first = jnp.searchsorted(ng, ng[q0], side="left").astype(jnp.int32)
+    if window is not None:
+        first = jnp.maximum(first, q0 - (window - 1))
     kstart = jnp.where(q0 < real, first, q0) // block_k
-    k_windows = (block_q + nmax - 1 + block_k - 1) // block_k + 1
+    k_windows = (block_q + reach - 1 + block_k - 1) // block_k + 1
     k0, k1 = rows(block_k)
     last = jnp.searchsorted(ng, ng[k1], side="right").astype(jnp.int32) - 1
+    if window is not None:
+        last = jnp.minimum(last, k1 + (window - 1))
     qlast = jnp.where(k0 < real, last, k1) // block_q
-    q_windows = (block_k + nmax - 1 + block_q - 1) // block_q + 1
+    q_windows = (block_k + reach - 1 + block_q - 1) // block_q + 1
     return (kstart, q1 // block_k, k_windows,
             k0 // block_q, qlast, q_windows)
 
 
 def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
                do_ref, o_ref, lse_ref, dq_ref, delta_scr, acc_scr, *, scale,
-               resident_block_k=0):
+               resident_block_k=0, window=None):
     """One query block held, its key/value tiles walked for ``dq``: by the
     grid's third axis, or (``resident_block_k``) by a loop over the head's
     resident keys and values, as the forward does."""
@@ -737,6 +767,7 @@ def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
         ) * scale
         mask = _pair_mask(
             gidq_ref[:], gidk, j * q.shape[0], kb * k.shape[0], True,
+            window=window,
         )
         p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, 0:1]), 0.0)
         dp = jax.lax.dot_general(
@@ -769,7 +800,7 @@ def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
 
 def _dkv_kernel(qstart_ref, qlast_ref, gidk_ref, gidq_ref, k_ref, v_ref,
                 q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, scale, resident_block_q=0):
+                dk_scr, dv_scr, *, scale, resident_block_q=0, window=None):
     """One key/value block held, its query tiles walked (by the grid's third
     axis, or by a loop over the query head's resident ``q``, ``do`` and
     statistics); every product is written transposed (``s.T = k @ q.T``) so
@@ -791,7 +822,7 @@ def _dkv_kernel(qstart_ref, qlast_ref, gidk_ref, gidq_ref, k_ref, v_ref,
         ) * scale  # [Bk, Bq]
         mask = _pair_mask(
             gidk_ref[:], gidq, i * k.shape[0], qb * q.shape[0], True,
-            rows_are_queries=False,
+            rows_are_queries=False, window=window,
         )
         pt = jnp.where(mask, jnp.exp(st - lse), 0.0)
         dv_scr[:] += jax.lax.dot_general(
@@ -831,15 +862,16 @@ def _dkv_kernel(qstart_ref, qlast_ref, gidk_ref, gidq_ref, k_ref, v_ref,
 
 def causal_schedule_steps(node_graph, node_mask, max_nodes_per_graph: int, d: int, dv: int,
                           dtype, block_q: int = CAUSAL_BLOCK_Q,
-                          block_k: int = CAUSAL_BLOCK_K):
+                          block_k: int = CAUSAL_BLOCK_K, window=None):
     """What one head of a forward launch of :func:`flash_causal_attention`
     runs on these operands: -> (tiles visited, the sum over query blocks of
     their windows' lengths; steps scheduled for them: the same sum where the
     loop runs inside the kernel, ``q_blocks x k_windows`` under the grid).
-    float32 scalars; the arithmetic and the rule are the launch's own."""
+    float32 scalars; the arithmetic and the rule are the launch's own, a
+    sliding ``window``'s too."""
     bq, bk = normalize_tiles(block_q, block_k)
     n = node_graph.shape[0]
-    ks, kl, kw, _, _, _ = _causal_windows(node_graph, node_mask, bq, bk, max_nodes_per_graph)
+    ks, kl, kw, _, _, _ = _causal_windows(node_graph, node_mask, bq, bk, max_nodes_per_graph, window)
     visited = jnp.sum((kl - ks + 1).astype(jnp.float32))
     pad = lambda x, m: -(-x // m) * m
     k_blocks = pad(n, bk) // bk
@@ -848,22 +880,23 @@ def causal_schedule_steps(node_graph, node_mask, max_nodes_per_graph: int, d: in
     return visited, jnp.float32(ks.shape[0] * max(1, min(kw, k_blocks)))
 
 
-def _causal_prep(node_graph, node_mask, max_nodes_per_graph, block_q, block_k):
+def _causal_prep(node_graph, node_mask, max_nodes_per_graph, block_q, block_k,
+                 window=None):
     gid = jnp.where(node_mask, node_graph.astype(jnp.int32), -1)
     return gid, _causal_windows(
-        node_graph, node_mask, block_q, block_k, max_nodes_per_graph
+        node_graph, node_mask, block_q, block_k, max_nodes_per_graph, window
     )
 
 
 def _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
-                block_q, block_k, interpret):
+                block_q, block_k, interpret, window=None):
     gid, (ks, kl, kw, _, _, _) = _causal_prep(
-        node_graph, node_mask, max_nodes_per_graph, block_q, block_k
+        node_graph, node_mask, max_nodes_per_graph, block_q, block_k, window
     )
-    with tr.scope(tr.HG_FLASH_ATTENTION):
+    with tr.scope(_causal_name(window)):
         o_pad, lse = _forward(
             q, k, v, gid, gid, ks, kl, kw, block_q, block_k, interpret,
-            emit_stats="lse", causal=True, padded=True,
+            emit_stats="lse", causal=True, padded=True, window=window,
         )
         o = jnp.transpose(o_pad, (1, 0, 2))[:q.shape[0], :, :v.shape[2]]
     return o, lse  # lse [H, Nq_pad, 128], lane-broadcast
@@ -879,6 +912,7 @@ def flash_causal_attention(
     block_q: int = CAUSAL_BLOCK_Q,
     block_k: int = CAUSAL_BLOCK_K,
     interpret: bool = False,
+    window=None,
 ):
     """Causal grouped-query flash attention over the flat node array.
 
@@ -892,28 +926,37 @@ def flash_causal_attention(
     ``max_nodes_per_graph`` is under-covered and the caller poisons it.
     Forward and backward are Pallas launches under one schedule (each
     block's own window, walked inside the kernel where a head fits VMEM:
-    the module docstring); reverse mode only, first order."""
+    the module docstring); reverse mode only, first order. A static
+    ``window`` W (a sliding layer) also bounds ``i - j < W``: every block's
+    window then starts no earlier than W - 1 rows before it, the mask gains
+    one compare, and the three launches are named ``hg_flash_window`` /
+    ``_bwd``; ``None`` is the launches as they were."""
+    if window is not None and int(window) < 1:
+        raise ValueError(f"window must be a positive count of keys, got {window}")
     return _flash_causal_attention(
         q, k, v, node_graph, node_mask, max_nodes_per_graph,
         *normalize_tiles(block_q, block_k), interpret,
+        None if window is None else int(window),
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _flash_causal_attention(q, k, v, node_graph, node_mask,
-                            max_nodes_per_graph, block_q, block_k, interpret):
+                            max_nodes_per_graph, block_q, block_k, interpret,
+                            window):
     return _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
-                       block_q, block_k, interpret)[0]
+                       block_q, block_k, interpret, window)[0]
 
 
 def _causal_vjp_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
-                    block_q, block_k, interpret):
+                    block_q, block_k, interpret, window):
     o, lse = _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
-                         block_q, block_k, interpret)
+                         block_q, block_k, interpret, window)
     return o, (q, k, v, o, lse, node_graph, node_mask)
 
 
-def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
+def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
+                    res, do):
     q, k, v, o, lse, node_graph, node_mask = res
     n, hq, d = q.shape
     hk = k.shape[1]
@@ -921,9 +964,10 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
     bq, bk = block_q, block_k
     scale = 1.0 / float(d) ** 0.5
     gid, (ks, kl, kw, qs, ql, qw) = _causal_prep(
-        node_graph, node_mask, max_nodes_per_graph, bq, bk
+        node_graph, node_mask, max_nodes_per_graph, bq, bk, window
     )
-    with tr.scope(tr.HG_FLASH_ATTENTION + tr.BWD):
+    bwd_name = _causal_name(window) + tr.BWD
+    with tr.scope(bwd_name):
         qt, dot, ot = (_heads_first(x, bq) for x in (q, do.astype(q.dtype), o))
         kt, vt = _heads_first(k, bk), _heads_first(v, bk)
         nq_pad, nk_pad = qt.shape[1], kt.shape[1]
@@ -944,7 +988,8 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
             resident, grow(nk_pad), bk, (d_pad, dv_pad), kw, lambda h_i: h_i // group)
         dq = pl.pallas_call(
             functools.partial(_dq_kernel, scale=scale,
-                              resident_block_k=bk if resident else 0),
+                              resident_block_k=bk if resident else 0,
+                              window=window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(hq, j_blocks) + inner,
@@ -966,7 +1011,7 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
             ),
             out_shape=jax.ShapeDtypeStruct((hq, nq_pad, d_pad), q.dtype),
             interpret=interpret,
-            name=tr.HG_FLASH_ATTENTION + tr.BWD,
+            name=bwd_name,
             compiler_params=_compiler_params(resident),
         )(ks, kl, gcol(nq_pad), gidk, qt, kt, vt, dot, ot, lse)
 
@@ -992,7 +1037,8 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
                 h_i, 0, jnp.minimum(s_[i] + qq, l_[i])))
         dk_h, dv_h = pl.pallas_call(
             functools.partial(_dkv_kernel, scale=scale,
-                              resident_block_q=bq if resident else 0),
+                              resident_block_q=bq if resident else 0,
+                              window=window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(hq, k_blocks) + inner,
@@ -1014,7 +1060,7 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, do):
             out_shape=[jax.ShapeDtypeStruct((hq, nk_pad, d_pad), jnp.float32),
                        jax.ShapeDtypeStruct((hq, nk_pad, dv_pad), jnp.float32)],
             interpret=interpret,
-            name=tr.HG_FLASH_ATTENTION + tr.BWD,
+            name=bwd_name,
             compiler_params=_compiler_params(resident),
         )(qs, ql, gcol(nk_pad), gidq, kt, vt, qt, dot, lse_row, delta_row)
 

@@ -47,12 +47,14 @@ HG_LOSS = "hg_loss"  # value_and_grad: jvp( = forward, transpose( = backward
 HG_OPTIMIZER = "hg_optimizer"  # tx.update + apply_updates
 HG_GUARD = "hg_guard"  # step_ok + the guarded select
 HG_CCA_CONV = "hg_cca_conv"  # ZAYA: the two causal convolutions + q-k mean of a CCA sublayer
-HG_ROUTER = "hg_router"  # ZAYA, JOYAI: the float32 router, the choice and the expert layout
+HG_ROUTER = "hg_router"  # ZAYA, JOYAI, AFMOE: the float32 router, the choice and the expert layout
 HG_MOE = "hg_moe"  # ZAYA: gather to expert rows, the grouped products, scatter back
 HG_MLA_PROJ = "hg_mla_proj"  # JOYAI: the four latent projections of an MLA block, their norms and RoPE
-HG_MOE_DISPATCH = "hg_moe_dispatch"  # JOYAI: the gather with repeats of tokens into expert rows
-HG_MOE_COMBINE = "hg_moe_combine"  # JOYAI: the gate-weighted sum over a token's rows
-HG_SHARED_EXPERT = "hg_shared_expert"  # JOYAI: the shared expert's MLP on every token
+HG_MOE_DISPATCH = "hg_moe_dispatch"  # JOYAI, AFMOE: the gather with repeats of tokens into expert rows
+HG_MOE_COMBINE = "hg_moe_combine"  # JOYAI, AFMOE: the gate-weighted sum over a token's rows
+HG_SHARED_EXPERT = "hg_shared_expert"  # JOYAI, AFMOE: the shared expert's MLP on every token
+HG_ATTN_PROJ = "hg_attn_proj"  # AFMOE: the query, key and value projections of an attention sublayer, the head norms and the rotation
+HG_ATTN_GATE = "hg_attn_gate"  # AFMOE: the gate's projection, its sigmoid and the product with the attention output
 HG_MTP = "hg_mtp"  # JOYAI: the multi-token-prediction module (join, its own layer, its norm)
 HG_TOKEN_LOSS = "hg_token_loss"  # the chunked next-node cross-entropy
 
@@ -68,8 +70,12 @@ CT_CAUSAL_PAIRS = "count:causal_pairs"  # (query, key) pairs within graphs, one 
 # (ops/pallas_flash_attention.py causal_schedule_steps)
 CT_FLASH_TILES_VISITED = "count:flash_tiles_visited"  # tiles in the query blocks' key windows
 CT_FLASH_STEPS_SCHEDULED = "count:flash_steps_scheduled"  # steps run for them: loop trips, or q_blocks x k_windows under the grid
-CT_EXPERT_ROWS_HERE = "count:expert_rows_here"  # JOYAI: rows computed on this chip (a token is 0..k), over layers
-CT_EXPERT_ROWS_OVERRUN = "count:expert_rows_overrun"  # JOYAI: rows past the row budget (the step is poisoned)
+CT_WINDOW_PAIRS = "count:window_pairs"  # AFMOE: (query, key) pairs within graphs AND within the sliding window, one sliding layer's
+# a sliding layer's launches (flash_causal_attention(window=W)), as the two entries above
+CT_FLASH_WINDOW_TILES_VISITED = "count:flash_window_tiles_visited"
+CT_FLASH_WINDOW_STEPS_SCHEDULED = "count:flash_window_steps_scheduled"
+CT_EXPERT_ROWS_HERE = "count:expert_rows_here"  # JOYAI, AFMOE: rows computed on this chip (a token is 0..k), over layers
+CT_EXPERT_ROWS_OVERRUN = "count:expert_rows_overrun"  # JOYAI, AFMOE: rows past the row budget (the step is poisoned)
 CT_MTP_PAIRS = "count:mtp_pairs"  # JOYAI: nodes whose two successors lie in their document
 
 # -- Pallas kernels: pallas_call(name=...) inside a scope of the same name;
@@ -78,6 +84,9 @@ HG_FUSED_EDGE = "hg_fused_edge"
 HG_SORTED_SEGMENT = "hg_sorted_segment"
 HG_MULTI_AGG = "hg_multi_agg"
 HG_FLASH_ATTENTION = "hg_flash_attention"
+# the causal launches of a SLIDING layer (flash_causal_attention(window=W)): the same kernels under a
+# name of their own, so a trace tells a step's two kinds of launch apart
+HG_FLASH_WINDOW = "hg_flash_window"
 HG_GROUPED_EXPERT = "hg_grouped_expert"
 TANGENT = "_tangent"
 # the transpose of ops/segment.py gather(sorted_ids=True): a scope AROUND the

@@ -161,6 +161,11 @@ def pytest_chip_smoke_force_gradient_gap_rehearsed(monkeypatch):
     ("joyai_kernel_leg", dict(tokens=512, heads=2, kv_heads=2, longest=160, groups=4, width=64, width_out=48,
                               experts=32, topk=4),
      ("flash_causal float32 2x192/128 fwd+bwd_ms", "grouped_expert float32 groups=4 64->48 top-4 fwd+bwd_ms")),
+    # the Trinity cell's: the sliding launches beside the full ones on the same grouped-query operands
+    ("trinity_kernel_leg", dict(tokens=512, heads=4, kv_heads=2, head_dim=32, longest=300, window=40, groups=4,
+                                width=64, width_out=48, experts=32, topk=4),
+     ("flash_causal float32 fwd+bwd_ms", "flash_window(40) float32 fwd+bwd_ms",
+      "grouped_expert float32 groups=4 64->48 top-4 fwd+bwd_ms")),
 ])
 def pytest_chip_smoke_decoder_kernel_legs_rehearsed(leg, shapes, tags):
     """Both decoder kernel legs at a tiny size in interpret mode: every check
@@ -176,4 +181,8 @@ def pytest_chip_smoke_decoder_kernel_legs_rehearsed(leg, shapes, tags):
     src = inspect.getsource(smoke.joyai_kernel_leg)
     for shape in ("tokens=16384", "heads=32", "head_dim=192", "value_dim=128", "groups=16", "width_out=768",
                   "topk=8", "experts=256"):
+        assert shape in src, shape
+    src = inspect.getsource(smoke.trinity_kernel_leg)
+    for shape in ("tokens=16384", "heads=32", "kv_heads=4", "head_dim=128", "window=2048", "groups=8",
+                  "width_out=1024", "topk=8", "experts=128"):
         assert shape in src, shape

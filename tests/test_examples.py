@@ -449,3 +449,12 @@ def pytest_example_joyai_flash():
     attention, top-4 of 16 experts beside a shared one, the module on."""
     out = _run_example("examples/joyai_flash/joyai_flash.py", "--num_docs", "48", "--num_epoch", "2")
     assert "train loss by epoch" in out
+
+
+@pytest.mark.slow  # full example subprocess: exceeds the capped fast tier; runs in the ci.sh suite
+def pytest_example_trinity_mini():
+    """The third decoder stack's preset (examples/trinity_mini): four sliding
+    layers (window 16) and a full one, gated attention between sandwich norms,
+    top-4 of 16 experts beside a shared one."""
+    out = _run_example("examples/trinity_mini/trinity_mini.py", "--num_docs", "48", "--num_epoch", "2")
+    assert "train loss by epoch" in out

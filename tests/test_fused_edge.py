@@ -821,6 +821,32 @@ def pytest_causal_flash_launches_compile_for_v5e_at_the_decoder_cells_shapes(v5e
     assert 0 <= compiled.memory_analysis().output_size_in_bytes - 2 * n * (hq * d_qk + hk * (d_qk + d_v)) < 4096
 
 
+def pytest_sliding_flash_launches_compile_for_v5e_at_the_trinity_cells_shape(v5e_chip):
+    """Mosaic accepts the three launches WITH a sliding window at the Trinity
+    cell's shape (16,384 tokens, 32 query heads on 4 key/value heads of 128,
+    window 2,048 under documents of up to 16,383 tokens, bf16, the head
+    resident), under names of their own, beside the full launches of the same
+    operands in one program."""
+    import re
+
+    from hydragnn_tpu.ops import pallas_flash_attention as pfa
+
+    n, hq, hk, d = 16384, 32, 4, 128
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+
+    def loss(q, k, v, node_graph, node_mask):
+        out = pfa.flash_causal_attention(q, k, v, node_graph, node_mask, 16383, window=2048)
+        out = out + pfa.flash_causal_attention(q, k, v, node_graph, node_mask, 16383)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shaped((n, hq, d)), shaped((n, hk, d)), shaped((n, hk, d)),
+        shaped((n,), jnp.int32), shaped((n,), jnp.bool_)).compile()
+    calls = re.findall(r"^\s*%(hg_flash_[a-z_]*)[.\d]* = .*custom-call\(", compiled.as_text(), re.MULTILINE)
+    assert sorted(calls) == ["hg_flash_attention", "hg_flash_attention_bwd", "hg_flash_attention_bwd",
+                             "hg_flash_window", "hg_flash_window_bwd", "hg_flash_window_bwd"], calls
+
+
 def _cell_train_step_text(monkeypatch, v5e_chip):
     """The EGNN-866 cells' own train step (benchmarks/configs/
     egnn866_sc25.json, bf16), lowered at the packed cell's batch shape and
