@@ -285,6 +285,7 @@ class AfmoeModel(nn.Module):
             tr.CT_EXPERT_LOAD_MEAN: stats[2],
             tr.CT_EXPERT_ROWS_OVERRUN: stats[3],
             tr.CT_CAUSAL_PAIRS: dc.causal_pairs(batch),
+            **dc.flash_blocks(cfg.num_conv_layers, train),
         }
         steps = lambda window: dc.flash_steps(batch, cfg.max_nodes_per_graph, z.head_dim, z.head_dim, x.dtype, window)
         if FULL in z.layer_types:

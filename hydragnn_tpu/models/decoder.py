@@ -10,8 +10,10 @@ SiLU-gated expert products on group-aligned rows, dispatch and combine around
 them for a token of several assignments (top-k), the sigmoid top-k router and
 the expert sublayer of the stacks that route so (``ExpertSpec``, ``route``,
 ``expert_sublayer``), the balancing rules of a router's bias buffer, the
-initial scales, the per-layer rematerialisation and
-the poison of a step that cannot stand.
+initial scales, the per-layer rematerialisation (a layer keeps its incoming
+residual stream and, of its causal flash launch, ``o`` and one ``lse`` number a
+row: the forward kernel runs once a step) and the poison of a step that cannot
+stand.
 """
 
 from __future__ import annotations
@@ -24,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..ops.remat import CAUSAL_FLASH_RESIDUAL_NAMES
 from ..utils import tracer as tr
 
 # the key of a multi-token-prediction module's hidden state among a model's
@@ -330,9 +333,22 @@ def layer_params(module: nn.Module, shapes: Dict) -> Dict:
 
 
 def remat_in_training(layer_cls, train: bool):
-    """Every layer is rematerialised in training: one saved residual stream a
-    layer."""
-    return nn.remat(layer_cls) if train else layer_cls
+    """Every layer is rematerialised in training. Kept across the remat: the
+    layer's incoming residual stream and what the causal flash launch's
+    backward reads of its forward (``o [T, Hq, dv]`` and one float32 a (head,
+    row) of ``lse``, tagged in ops/pallas_flash_attention.py
+    ``_causal_vjp_fwd``), so that the forward kernel runs once a step;
+    everything else of a layer is computed again in the backward pass."""
+    if not train:
+        return layer_cls
+    return nn.remat(layer_cls, policy=jax.checkpoint_policies.save_only_these_names(*CAUSAL_FLASH_RESIDUAL_NAMES))
+
+
+def flash_blocks(blocks: int, train: bool) -> Dict:
+    """A stack's attention blocks and those whose flash residuals its layers'
+    remat keeps (``remat_in_training``: all of them in training), as the
+    step's two ``count:flash_blocks*`` entries."""
+    return {tr.CT_FLASH_BLOCKS: jnp.float32(blocks), tr.CT_FLASH_BLOCKS_SAVED: jnp.float32(blocks if train else 0)}
 
 
 def poison(x, bad):

@@ -304,6 +304,7 @@ class JoyaiModel(nn.Module):
             tr.CT_EXPERT_ROWS_OVERRUN: stats[3],
             tr.CT_CAUSAL_PAIRS: dc.causal_pairs(batch),
             **dc.flash_steps(batch, cfg.max_nodes_per_graph, z.qk_head_dim, z.v_head_dim, x.dtype),
+            **dc.flash_blocks(cfg.num_conv_layers + z.num_nextn_predict_layers, train),
             tr.CT_MTP_PAIRS: jnp.sum(dc.follows(batch.node_graph, mask, 2).astype(jnp.float32))
             * z.num_nextn_predict_layers,
         })

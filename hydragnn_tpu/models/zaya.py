@@ -25,7 +25,8 @@ router path is float32 (top-1 is discrete): the model states which leaves the
 mixed-precision cast leaves alone, ``ZayaModel.float32_leaves``. The model returns the final normalised
 hidden state; the tied head and the next-node cross-entropy are
 train/loss.py ``token_loss`` (the ``[T, V]`` logits never exist whole).
-Every layer is rematerialised in training: one saved residual stream a layer.
+Every layer is rematerialised in training: one saved residual stream a layer
+and, of its flash launch, ``o`` and a row's ``lse`` (``decoder.remat_in_training``).
 What this stack shares with models/joyai.py (norm, RoPE, embedding, the
 routes to the two kernels, the balancing rule, initial scales) is
 models/decoder.py.
@@ -49,7 +50,7 @@ from ..utils import tracer as tr
 # importable from here
 from .decoder import (  # noqa: F401
     INIT as _INIT, ROUTER_BIAS_GAIN, balanced_bias, batch_aux, causal_attention, causal_pairs,
-    dense as _dense, embed_tokens, expert_products, flash_steps, graphs_overflow, held_table, layer_params,
+    dense as _dense, embed_tokens, expert_products, flash_blocks, flash_steps, graphs_overflow, held_table, layer_params,
     poison, remat_in_training, rms_norm, rope)
 
 ARCH_KEYS = (
@@ -320,4 +321,5 @@ class ZayaModel(nn.Module):
             tr.CT_EXPERT_LOAD_MEAN: counters[2],
             tr.CT_CAUSAL_PAIRS: causal_pairs(batch),
             **flash_steps(batch, cfg.max_nodes_per_graph, z.head_dim, z.head_dim, x.dtype),
+            **flash_blocks(cfg.num_conv_layers, train),
         }

@@ -51,7 +51,12 @@ holding a query block and streaming its keys for ``dq``, one holding a
 key/value block and streaming its queries for ``dk``/``dv``, both rebuilding
 the probabilities from the forward's saved log-sum-exp. No ``[*, N, N]``
 array exists in either direction, so a graph of 8192 nodes trains. It is a
-first-order ``custom_vjp`` (the token loss needs no more). A static ``window``
+first-order ``custom_vjp`` (the token loss needs no more); what its backward
+keeps of the forward's result is the output and ONE float32 a (head, row) of
+the log-sum-exp (the launch writes each across 128 lanes; both backward
+launches read the row form), both tagged (ops/remat.py
+``CAUSAL_FLASH_RESIDUAL_NAMES``) so that a decoder layer's remat keeps them and
+the forward launch runs once a step. A static ``window``
 W makes the launches a SLIDING layer's (models/afmoe.py): query ``i`` sees key
 ``j`` iff same graph, ``j <= i`` and ``i - j < W``. That is window arithmetic
 (a query block's walk starts at the tile of ``row0 - W + 1``, a key block's
@@ -100,6 +105,7 @@ from ..utils import tracer as tr
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_segment import _pad_to, mxu_precision
+from .remat import CAUSAL_FLASH_RESIDUAL_NAMES, tag
 
 # masking constant: large-negative instead of finfo.min so the f32
 # running-max arithmetic (exp of differences) never overflows; shared by
@@ -743,11 +749,13 @@ def _causal_windows(node_graph, node_mask, block_q, block_k, max_nodes_per_graph
 
 
 def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
-               do_ref, o_ref, lse_ref, dq_ref, delta_scr, acc_scr, *, scale,
+               do_ref, o_ref, lse_ref, dq_ref, delta_scr, lse_scr, acc_scr, *, scale,
                resident_block_k=0, window=None):
     """One query block held, its key/value tiles walked for ``dq``: by the
     grid's third axis, or (``resident_block_k``) by a loop over the head's
-    resident keys and values, as the forward does."""
+    resident keys and values, as the forward does. ``lse_ref`` holds the
+    head's statistics as lane-major rows ``[1, q_blocks, 1, Bq]``, the form
+    the ``dk``/``dv`` launch reads."""
     j = pl.program_id(1)
 
     def _init():
@@ -757,6 +765,10 @@ def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             axis=1, keepdims=True,
         )
         delta_scr[:] = jnp.broadcast_to(delta, delta_scr.shape)
+        # this block's statistics arrive as a lane-major row: across 128
+        # sublanes, then turned, they are the column the tiles read
+        row = lse_ref[0, j]  # [1, Bq]
+        lse_scr[:] = jnp.transpose(jnp.broadcast_to(row, (lse_scr.shape[1], row.shape[1])))
 
     def _tile(kb, k, v, gidk):
         q, do = q_ref[0], do_ref[0]
@@ -769,7 +781,7 @@ def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             gidq_ref[:], gidk, j * q.shape[0], kb * k.shape[0], True,
             window=window,
         )
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0][:, 0:1]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse_scr[:, 0:1]), 0.0)
         dp = jax.lax.dot_general(
             do, v, nt, precision=prec, preferred_element_type=jnp.float32
         )
@@ -952,12 +964,18 @@ def _causal_vjp_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
                     block_q, block_k, interpret, window):
     o, lse = _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
                          block_q, block_k, interpret, window)
-    return o, (q, k, v, o, lse, node_graph, node_mask)
+    # what the backward reads of the launch's result, under the names a
+    # decoder layer's remat keeps (models/decoder.py ``remat_in_training``): the
+    # output as it is returned, and ONE float32 a (head, padded row) of the
+    # statistics (the launch writes it across 128 lanes)
+    out_name, lse_name = CAUSAL_FLASH_RESIDUAL_NAMES
+    o, lse_row = tag(o, out_name), tag(lse[:, :, 0], lse_name)
+    return o, (q, k, v, o, lse_row, node_graph, node_mask)
 
 
 def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
                     res, do):
-    q, k, v, o, lse, node_graph, node_mask = res
+    q, k, v, o, lse_row, node_graph, node_mask = res
     n, hq, d = q.shape
     hk = k.shape[1]
     group = hq // hk
@@ -981,7 +999,12 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
         kw, qw = max(1, min(kw, k_blocks)), max(1, min(qw, j_blocks))
 
         # ---- dq: the forward's schedule, in the forward's place (the grid's
-        # third axis, or the loop over the head's resident keys and values)
+        # third axis, or the loop over the head's resident keys and values).
+        # Both launches read the kept row form of the statistics, a head's
+        # rows one block (a tile's: an index of the second axis); ``dq`` holds
+        # a query block and turns its row into a column inside the kernel
+        tiled = lambda x: x.reshape(hq, j_blocks, 1, bq)
+        stat_rows = pl.BlockSpec((1, j_blocks, 1, bq), lambda h_i, *_: (h_i, 0, 0, 0))
         held = lambda h_i, j, *_: (h_i, j, 0)
         resident = _resident(nk_pad, d_pad + dv_pad, kt.dtype)
         inner, gidk, gidk_spec, (k_spec, v_spec) = _walked(
@@ -1001,10 +1024,11 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
                     v_spec,
                     pl.BlockSpec((1, bq, dv_pad), held),
                     pl.BlockSpec((1, bq, dv_pad), held),
-                    pl.BlockSpec((1, bq, 128), held),
+                    stat_rows,
                 ],
                 out_specs=pl.BlockSpec((1, bq, d_pad), held),
                 scratch_shapes=[
+                    pltpu.VMEM((bq, 128), jnp.float32),
                     pltpu.VMEM((bq, 128), jnp.float32),
                     pltpu.VMEM((bq, d_pad), jnp.float32),
                 ],
@@ -1013,12 +1037,11 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
             interpret=interpret,
             name=bwd_name,
             compiler_params=_compiler_params(resident),
-        )(ks, kl, gcol(nq_pad), gidk, qt, kt, vt, dot, ot, lse)
+        )(ks, kl, gcol(nq_pad), gidk, qt, kt, vt, dot, ot, tiled(lse_row))
 
         # ---- dk, dv: one key/value block held, per QUERY head; the group's
         # heads are summed after (float32 partials). The other way round: the
         # query head's q, do and statistics are what is streamed or resident
-        lse_row = lse[:, :, 0]
         delta_row = jnp.sum(
             dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1)
         kheld = lambda h_i, i, *_: (h_i // group, i, 0)
@@ -1026,10 +1049,9 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
         resident = _resident(nq_pad, d_pad + dv_pad, qt.dtype)
         inner, gidq, gidq_spec, (q_spec, do_spec) = _walked(
             resident, grow(nq_pad), bq, (d_pad, dv_pad), qw, lambda h_i: h_i)
-        if resident:  # a tile's statistics: an index of the second axis
-            tiled = lambda x: x.reshape(hq, j_blocks, 1, bq)
+        if resident:
             lse_row, delta_row = tiled(lse_row), tiled(delta_row)
-            stat_spec = pl.BlockSpec((1, j_blocks, 1, bq), lambda h_i, *_: (h_i, 0, 0, 0))
+            stat_spec = stat_rows
         else:
             rows8 = lambda x: jnp.broadcast_to(x[:, None, :], (hq, 8, nq_pad))
             lse_row, delta_row = rows8(lse_row), rows8(delta_row)

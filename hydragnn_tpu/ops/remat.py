@@ -49,11 +49,18 @@ KERNEL_OUTPUT_NAMES = (
     "flash_attention_out", # models/gps.py flash attention
 )
 
+# what the causal flash launch's backward reads of its forward
+# (ops/pallas_flash_attention.py ``_causal_vjp_fwd``): the save set of a
+# decoder layer's remat (models/decoder.py ``remat_in_training``), so that the
+# forward kernel runs once a step. Not among ``KERNEL_OUTPUT_NAMES``: the
+# ``names`` policy and the steps compiled under it stay as they are
+CAUSAL_FLASH_RESIDUAL_NAMES = ("flash_causal_out", "flash_causal_lse")
+
 
 def tag(x, name: str):
     """Tag a kernel output (array or pytree) for ``save_only_these_names``.
-    A no-op unless the surrounding ``jax.checkpoint`` runs the ``names``
-    policy, so call sites tag unconditionally."""
+    A no-op unless the surrounding ``jax.checkpoint`` runs a policy that
+    saves ``name``, so call sites tag unconditionally."""
     from jax.ad_checkpoint import checkpoint_name
 
     return jax.tree_util.tree_map(lambda v: checkpoint_name(v, name), x)

@@ -74,6 +74,10 @@ CT_WINDOW_PAIRS = "count:window_pairs"  # AFMOE: (query, key) pairs within graph
 # a sliding layer's launches (flash_causal_attention(window=W)), as the two entries above
 CT_FLASH_WINDOW_TILES_VISITED = "count:flash_window_tiles_visited"
 CT_FLASH_WINDOW_STEPS_SCHEDULED = "count:flash_window_steps_scheduled"
+# a decoder stack's attention blocks, and those whose causal flash launch's residuals (``o``, a row's
+# ``lse``) the layer's remat keeps, so that the forward kernel runs once (models/decoder.py remat_in_training)
+CT_FLASH_BLOCKS = "count:flash_blocks"
+CT_FLASH_BLOCKS_SAVED = "count:flash_blocks_saved"
 CT_EXPERT_ROWS_HERE = "count:expert_rows_here"  # JOYAI, AFMOE: rows computed on this chip (a token is 0..k), over layers
 CT_EXPERT_ROWS_OVERRUN = "count:expert_rows_overrun"  # JOYAI, AFMOE: rows past the row budget (the step is poisoned)
 CT_MTP_PAIRS = "count:mtp_pairs"  # JOYAI: nodes whose two successors lie in their document

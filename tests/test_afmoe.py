@@ -430,6 +430,11 @@ def pytest_step_counters_reach_the_tracer_at_the_epoch_drain(built):
     assert "next_token" in tasks
 
 
+def pytest_a_training_step_runs_each_blocks_flash_forward_once(built, flash_forward_once):
+    config, arch, loader, model, variables = built
+    flash_forward_once(config, loader, model, variables, 5)  # four sliding layers and a full one
+
+
 def pytest_expert_rule_places_the_afmoe_expert_banks():
     from hydragnn_tpu.parallel import rules
 

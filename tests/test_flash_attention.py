@@ -582,3 +582,124 @@ def pytest_causal_schedule_steps_count_the_windows(monkeypatch, pack):
     got = pfa.causal_schedule_steps(node_graph, node_mask, nmax, 16, 16, jnp.float32, b, b)
     assert [float(x) for x in got] == [visited, q_blocks * k_windows]
     assert visited <= q_blocks * k_windows
+
+
+# ---------------------------------------------------------------------------
+# what a decoder layer's remat keeps of its causal launch (models/decoder.py
+# remat_in_training; the tags are ops/pallas_flash_attention.py
+# _causal_vjp_fwd's): ``o`` and one ``lse`` number a row, so the forward kernel
+# runs once a step. Two layers of a projection, the launch and a residual add.
+# ---------------------------------------------------------------------------
+
+from flax import linen as nn  # noqa: E402
+
+from hydragnn_tpu.models import decoder as dc  # noqa: E402
+
+KEPT_WINDOWS = {"full": None, "window96": 96}
+
+
+class _AttentionLayer(nn.Module):
+    heads: tuple  # (query heads, key/value heads, width of queries and keys, width of values)
+    window: object
+    nmax: int
+
+    @nn.compact
+    def __call__(self, x, node_graph, node_mask):
+        (hq, hk, d, dv), n = self.heads, x.shape[0]
+        cut = (hq * d, (hq + hk) * d)
+        y = x @ self.param("w_in", dc.INIT["lecun"], (x.shape[1], cut[1] + hk * dv))
+        q, k, v = y[:, :cut[0]], y[:, cut[0]:cut[1]], y[:, cut[1]:]
+        o = pfa.flash_causal_attention(q.reshape(n, hq, d), k.reshape(n, hk, d), v.reshape(n, hk, dv),
+                                       node_graph, node_mask, self.nmax, 128, 128, True, window=self.window)
+        return x + jnp.tanh(o.reshape(n, hq * dv)) @ self.param("w_out", dc.INIT["lecun"], (hq * dv, x.shape[1]))
+
+
+class _AttentionStack(nn.Module):
+    layer_cls: type
+    heads: tuple
+    window: object
+    nmax: int
+
+    @nn.compact
+    def __call__(self, x, node_graph, node_mask):
+        for i in range(2):
+            x = self.layer_cls(self.heads, self.window, self.nmax, name=f"layers_{i}")(x, node_graph, node_mask)
+        return jnp.sum(x * x * node_mask[:, None])
+
+
+# how a layer is wrapped: the decoders' rule, a bare remat, none
+KEPT_WRAPS = {"decoders": lambda cls: dc.remat_in_training(cls, True), "bare": nn.remat, "none": lambda cls: cls}
+
+
+def _stack_grad(wrap, heads, window, seed=3):
+    """-> (the gradient function of a two-layer stack over (input, parameters),
+    its arguments)."""
+    _, _, node_graph, node_mask, nmax = _causal_case("document_spanning_four_tiles", heads, jnp.float32)
+    n = node_graph.shape[0]
+    x = jnp.asarray(np.random.default_rng(seed).normal(size=(n, 32)), jnp.float32)
+    stack = _AttentionStack(KEPT_WRAPS[wrap](_AttentionLayer), CAUSAL_HEADS[heads], window, nmax)
+    params = _AttentionStack(_AttentionLayer, CAUSAL_HEADS[heads], window, nmax).init(
+        jax.random.PRNGKey(seed), x, node_graph, node_mask)
+    return jax.grad(lambda x_, p: stack.apply(p, x_, node_graph, node_mask), (0, 1)), (x, params)
+
+
+def _launch_names(closed):
+    """The names of every ``pallas_call`` of a jaxpr, nested jaxprs included."""
+    import re
+
+    return re.findall(r"name=(hg_\w+)", str(closed))
+
+
+@pytest.mark.parametrize("heads", list(CAUSAL_HEADS))
+@pytest.mark.parametrize("window", list(KEPT_WINDOWS))
+def pytest_decoders_remat_runs_the_causal_forward_once_a_layer(window, heads):
+    """The gradient's jaxpr of a two-layer stack: under the decoders' policy
+    one forward launch a layer, under the bare remat two; ``dq`` and
+    ``dk``/``dv`` one each either way."""
+    name = pfa._causal_name(KEPT_WINDOWS[window])
+    for wrap, forwards in (("decoders", 2), ("bare", 4), ("none", 2)):
+        grad, args = _stack_grad(wrap, heads, KEPT_WINDOWS[window])
+        names = _launch_names(jax.make_jaxpr(grad)(*args))
+        assert (names.count(name), names.count(name + "_bwd")) == (forwards, 4), (wrap, names)
+
+
+@pytest.mark.parametrize("heads", list(CAUSAL_HEADS))
+@pytest.mark.parametrize("window", list(KEPT_WINDOWS))
+def pytest_decoders_remat_gradients_equal_bare_and_unwrapped_to_the_bit(window, heads):
+    got = {}
+    for wrap in KEPT_WRAPS:
+        grad, args = _stack_grad(wrap, heads, KEPT_WINDOWS[window])
+        got[wrap] = jax.tree_util.tree_leaves(jax.jit(grad)(*args))
+    assert all(float(jnp.abs(g).max()) > 0 for g in got["none"])
+    for wrap in ("decoders", "bare"):
+        for a, b in zip(got[wrap], got["none"]):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("schedule", ["loop", "grid"])
+@pytest.mark.parametrize("heads", list(CAUSAL_HEADS))
+@pytest.mark.parametrize("window", list(KEPT_WINDOWS))
+def pytest_causal_backward_reads_the_row_form_lse_to_the_bit(monkeypatch, window, heads, schedule):
+    """The forward writes each row's ``lse`` across 128 lanes; ``dq`` streamed
+    that array and read lane 0, ``dk``/``dv`` read lane 0 as a row. What is
+    kept now is lane 0 alone, and both launches read the row. Every lane holds
+    lane 0's bits, so nothing a launch reads has changed, and ``dq``, ``dk``,
+    ``dv`` from a row form cut by hand are the rule's own."""
+    if schedule == "grid":
+        monkeypatch.setattr(pfa, "CAUSAL_RESIDENT_BYTES", 0)
+    w = KEPT_WINDOWS[window]
+    ops, do, node_graph, node_mask, nmax = _causal_case("document_spanning_four_tiles", heads, jnp.bfloat16)
+    o, lse = pfa._causal_fwd(*ops, node_graph, node_mask, nmax, 128, 128, True, w)
+    row = lse[:, :, 0]
+    assert lse.shape == row.shape + (128,) and row.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(lse), np.broadcast_to(np.asarray(row)[:, :, None], lse.shape))
+    _, (q, k, v, o_kept, lse_kept, _, _) = pfa._causal_vjp_fwd(*ops, node_graph, node_mask, nmax, 128, 128, True, w)
+    np.testing.assert_array_equal(np.asarray(lse_kept), np.asarray(row))
+    np.testing.assert_array_equal(np.asarray(o_kept, np.float32), np.asarray(o, np.float32))
+    do = do.astype(jnp.bfloat16)
+    got = pfa._causal_vjp_bwd(nmax, 128, 128, True, w, (q, k, v, o, row, node_graph, node_mask), do)[:3]
+    kernel = lambda *a: pfa.flash_causal_attention(*a, node_graph, node_mask, nmax, 128, 128, True, window=w)
+    want = jax.vjp(kernel, *ops)[1](do)
+    for a, b in zip(got, want):
+        assert float(jnp.abs(b.astype(jnp.float32)).max()) > 0
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))

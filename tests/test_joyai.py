@@ -413,6 +413,11 @@ def pytest_step_counters_reach_the_tracer_at_the_epoch_drain(built):
     assert tr.CT_CAUSAL_PAIRS in tasks and "next_token" in tasks and "mtp" in tasks
 
 
+def pytest_a_training_step_runs_each_blocks_flash_forward_once(built, flash_forward_once):
+    config, arch, loader, model, variables = built
+    flash_forward_once(config, loader, model, variables, 4)  # three layers and the module's
+
+
 def pytest_expert_rule_places_the_joyai_expert_banks():
     from hydragnn_tpu.parallel import rules
 

@@ -378,6 +378,11 @@ def pytest_step_counters_reach_the_tracer_at_the_epoch_drain(docs):
     assert tr.CT_TOKENS in tasks and "next_token" in tasks
 
 
+def pytest_a_training_step_runs_each_blocks_flash_forward_once(docs, flash_forward_once):
+    config, arch, loader, model, variables = build(docs)
+    flash_forward_once(config, loader, model, variables, 2)  # two layers
+
+
 def pytest_expert_rule_places_the_expert_axis_and_nothing_else():
     from hydragnn_tpu.parallel import rules
 
