@@ -40,10 +40,17 @@ used to repay the full compile bill from zero. Three mechanisms close that:
    nearest known signature and handled per ``Training.retrace_policy:
    warn (default) | error``.
 
-Observability: per-specialization compile seconds, cache hit/miss counts
-(via ``jax.monitoring``), and time-to-first-step land in ``utils.Timer`` /
-``utils.tracer`` and in the plane's ``report()``; bench.py banks them
-(``time_to_first_step`` / ``compile_time_s`` / ``BENCH_COMPILE`` cells).
+Observability: ``jax.monitoring`` reports the three phases of every program
+jax builds (trace, lower, backend compile or cache fetch) with the program's
+name, and cache hits, misses and retrieval seconds. The listeners here keep
+them as process-wide counters (``compile_metrics()``: flat, numeric), by
+program (``compile_programs()``), and as regions of ``utils.tracer``
+(``compile_trace`` / ``compile_lower`` / ``compile_backend``, on the thread
+that compiles, so a recompile is a named host span in a device trace). The
+plane's ``report()`` takes their differences over a run and prints them with
+time-to-first-step (``format_report``); the benchmark's readers under
+``benchmarks/metrics/`` (``start_*``, ``compile_seconds_in_window``,
+``compiles_in_window``) read ``compile_metrics()`` through the driver.
 """
 
 from __future__ import annotations
@@ -58,6 +65,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 from ..utils import envflags
+from ..utils import tracer as tr
 
 PRECOMPILE_MODES = ("off", "blocking", "background", "analysis")
 RETRACE_POLICIES = ("warn", "error")
@@ -85,8 +93,46 @@ _METRICS = {
     "cache_misses": 0,
     "backend_compile_s": 0.0,
     "cache_retrieval_s": 0.0,
+    "trace_s": 0.0,
+    "lower_s": 0.0,
+    "programs": 0,
 }
+# compile_programs(): fun_name -> {n, trace_s, lower_s, backend_s, t_first}
+_PROGRAMS: Dict[str, Dict[str, float]] = {}
 _LISTENERS_INSTALLED = False
+_T_INSTALLED = 0.0  # time.time() at installation: jax stamps its events with that clock
+
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+# the three phases of every program jax builds (jax/_src/dispatch.py
+# log_elapsed_time: a scalar with fun_name= at entry, a duration with
+# fun_name= at exit): event -> (key of compile_metrics(), key of a
+# compile_programs() row, region)
+_PHASES = {
+    _TRACE_EVENT: ("trace_s", "trace_s", tr.COMPILE_TRACE),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("lower_s", "lower_s", tr.COMPILE_LOWER),
+    _BACKEND_EVENT: ("backend_compile_s", "backend_s", tr.COMPILE_BACKEND),
+}
+# the same program is `step` in its trace event and `jit(step)` in the other two
+_WRAPPED_NAME_RE = re.compile(r"^\w+\((.*)\)$")
+
+
+class _OpenPhases(threading.local):
+    """One thread's open phases, innermost last, as ``[event, counted,
+    seconds of the counted phases that closed inside it]``. Phases nest: a
+    trace encloses the traces of the jitted functions it calls (a decoder
+    step's, hundreds) and a lowering encloses the traces Mosaic makes of a
+    kernel's helpers; such an inner trace is not counted, its seconds are
+    its parent's. A program compiled eagerly inside a trace is counted, and
+    its seconds are taken out of that trace's: every phase's seconds are
+    its own, so the sums never pass the thread's wall clock."""
+
+    def __init__(self):
+        self.stack: list = []
+
+
+_OPEN = _OpenPhases()
 
 
 def _on_event(name: str, **kw) -> None:
@@ -109,40 +155,126 @@ def _on_event(name: str, **kw) -> None:
             pass
 
 
+def _on_scalar(name: str, value: float, **kw) -> None:
+    """A phase opens (jax records its start time as a scalar under the
+    duration event's name): push it, and open its region if it will be
+    counted. Never raises into jax."""
+    phase = _PHASES.get(name)
+    if phase is None:
+        return
+    try:
+        stack = _OPEN.stack
+        counted = not (stack and name == _TRACE_EVENT)
+        stack.append([name, counted, 0.0])
+        if counted:
+            # sync=False: draining the device would run a program from
+            # inside the compile of another
+            tr.start(phase[2], sync=False, fun_name=_program_key(kw.get("fun_name")))
+    except Exception:
+        pass
+
+
 def _on_duration(name: str, secs: float, **kw) -> None:
-    if name == "/jax/core/compile/backend_compile_duration":
-        with _METRICS_LOCK:
-            _METRICS["backend_compile_s"] += float(secs)
-    elif name == "/jax/compilation_cache/cache_retrieval_time_sec":
+    if name == _RETRIEVAL_EVENT:
         with _METRICS_LOCK:
             _METRICS["cache_retrieval_s"] += float(secs)
+        return
+    phase = _PHASES.get(name)
+    if phase is None:
+        return
+    try:
+        _close_phase(name, phase, float(secs), kw.get("fun_name"))
+    except Exception:
+        pass  # a listener never fails the compile it watches
+
+
+def _close_phase(name: str, phase: Tuple[str, str, str], secs: float, fun_name) -> None:
+    stack = _OPEN.stack
+    # an exit whose entry the listeners did not see (installed inside the
+    # phase) is counted whole
+    _, counted, inner_s = stack.pop() if stack and stack[-1][0] == name else (name, True, 0.0)
+    if stack:
+        stack[-1][2] += secs if counted else inner_s
+    if not counted:
+        return
+    total_key, row_key, region = phase
+    tr.stop(region, sync=False)
+    own_s = max(secs - inner_s, 0.0)
+    key = _program_key(fun_name)  # here, not for each of a step's hundreds of inner traces
+    with _METRICS_LOCK:
+        row = _PROGRAMS.get(key)
+        if row is None:
+            row = _PROGRAMS[key] = {
+                "n": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                "t_first": time.time() - secs - _T_INSTALLED,
+            }
+        _METRICS[total_key] += own_s
+        row[row_key] += own_s
+        if name == _BACKEND_EVENT:
+            _METRICS["programs"] += 1
+            row["n"] += 1
+
+
+def _program_key(fun_name) -> str:
+    name = str(fun_name)
+    m = _WRAPPED_NAME_RE.match(name)
+    return m.group(1) if m else name
 
 
 def install_metrics_listeners() -> None:
     """Idempotently subscribe the counters to jax.monitoring. Must run
     before the compiles it should observe; listeners cannot be removed, so
     there is exactly one registration per process."""
-    global _LISTENERS_INSTALLED
+    global _LISTENERS_INSTALLED, _T_INSTALLED
     with _METRICS_LOCK:
         if _LISTENERS_INSTALLED:
             return
         _LISTENERS_INSTALLED = True
+        _T_INSTALLED = time.time()
     import jax
 
     jax.monitoring.register_event_listener(_on_event)
+    jax.monitoring.register_scalar_listener(_on_scalar)
     jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def compile_metrics() -> Dict[str, float]:
-    """Snapshot of the process-wide compile counters (cache hits/misses,
-    cumulative backend-compile and cache-retrieval seconds)."""
+    """Snapshot of the process-wide compile counters, a flat dict of
+    numbers (callers take differences over every key): cache hits and
+    misses, ``programs`` built or fetched (backend-compile events), and
+    cumulative seconds of tracing (``trace_s``), lowering (``lower_s``),
+    ``compile_or_get_cached`` (``backend_compile_s``: the XLA compile cold,
+    the fetch warm) and, inside that, cache retrieval."""
     with _METRICS_LOCK:
         return dict(_METRICS)
+
+
+def compile_programs() -> Dict[str, Dict[str, float]]:
+    """The same seconds by program: ``fun_name -> {n, trace_s, lower_s,
+    backend_s, t_first}``: executables built or fetched, the three phases'
+    sums (over programs they equal ``compile_metrics()``'s) and seconds from
+    the listeners' installation to the program's first phase. Process-wide
+    like ``compile_metrics()``. Retrieval seconds carry no name in jax and
+    stay a total."""
+    with _METRICS_LOCK:
+        return {k: dict(v) for k, v in _PROGRAMS.items()}
 
 
 def _metrics_delta(before: Dict[str, float]) -> Dict[str, float]:
     now = compile_metrics()
     return {k: now[k] - before.get(k, 0) for k in now}
+
+
+def _programs_delta(before: Dict[str, Dict[str, float]]) -> Dict[str, Dict[str, float]]:
+    """``compile_programs()`` since ``before``: the programs that ran a
+    phase since, with that interval's calls and seconds."""
+    out = {}
+    for name, row in compile_programs().items():
+        was = before.get(name, {})
+        d = {k: row[k] - was.get(k, 0) for k in ("n", "trace_s", "lower_s", "backend_s")}
+        if any(d.values()):
+            out[name] = d
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -714,6 +846,7 @@ class CompilePlane:
         self.time_to_first_step: Optional[float] = None
         self._t0: Optional[float] = None
         self._m0: Dict[str, float] = {}
+        self._p0: Dict[str, Dict[str, float]] = {}
         self._counts0: Dict[str, int] = {}
         self._viol0 = 0
         self._worker: Optional[threading.Thread] = None
@@ -769,7 +902,6 @@ class CompilePlane:
         the FLOPs/HBM/collective tables and the MFU gauge where a
         persistent cache is switched off (shared-FS quota; the cache-less
         children of run-scripts/fleet_smoke.py)."""
-        from ..utils import tracer as tr
         from ..utils.timers import Timer
 
         install_metrics_listeners()
@@ -780,6 +912,7 @@ class CompilePlane:
         # first completed step)
         ttfs_timer = Timer("time_to_first_step").start()
         self._m0 = compile_metrics()
+        self._p0 = compile_programs()
         self._counts0 = _SENTINEL.counts()
         # the sentinel is process-global; baseline its violation count so
         # this plane's report never attributes an earlier run's retraces
@@ -1046,7 +1179,8 @@ class CompilePlane:
         rep = self.report()
         _SENTINEL.disarm()
         if verbosity > 0:
-            print(f"[{self.log_name}] {format_report(rep)}", file=sys.stderr)
+            for line in format_report(rep).splitlines():
+                print(f"[{self.log_name}] {line}", file=sys.stderr)
         return rep
 
     def report(self) -> Dict[str, Any]:
@@ -1069,6 +1203,14 @@ class CompilePlane:
             "backend_compile_s": round(delta["backend_compile_s"], 3),
             "cache_hits": int(delta["cache_hits"]),
             "cache_misses": int(delta["cache_misses"]),
+            # where the start's compile seconds went (jax.monitoring's three
+            # phases, by program name): tracing, lowering, and above as
+            # backend_compile_s the XLA compile or, warm, the cache fetch
+            "trace_s": round(delta["trace_s"], 3),
+            "lower_s": round(delta["lower_s"], 3),
+            "cache_retrieval_s": round(delta["cache_retrieval_s"], 3),
+            "programs": int(delta["programs"]),
+            "top_programs": top_programs(_programs_delta(self._p0), 5),
             "time_to_first_step": (
                 round(self.time_to_first_step, 3)
                 if self.time_to_first_step is not None
@@ -1132,8 +1274,22 @@ def device_bytes_limit() -> Optional[float]:
         return None
 
 
+def top_programs(programs: Dict[str, Dict[str, float]], k: int) -> List[Dict[str, Any]]:
+    """The ``k`` costliest rows of a ``compile_programs()`` table by
+    ``trace_s + lower_s + backend_s``, as ``{name, n, <the three>}``."""
+    cost = lambda row: row["trace_s"] + row["lower_s"] + row["backend_s"]
+    rows = sorted(programs.items(), key=lambda kv: cost(kv[1]), reverse=True)[:k]
+    return [
+        {"name": name, "n": int(row["n"]),
+         **{key: round(row[key], 3) for key in ("trace_s", "lower_s", "backend_s")}}
+        for name, row in rows
+    ]
+
+
 def format_report(rep: Dict[str, Any]) -> str:
-    """One grep-able line (the chaos/compile smokes parse these fields)."""
+    """Two grep-able lines: the one the chaos/compile smokes parse, then
+    where the start's compile seconds went, with the costliest programs as
+    ``name*n:trace+lower+backend`` seconds."""
     ttfs = rep.get("time_to_first_step")
     hbm = rep.get("hbm_peak_bytes")
     comm = rep.get("comm_bytes_peak")
@@ -1157,4 +1313,13 @@ def format_report(rep: Dict[str, Any]) -> str:
         f"comm_frac_est={round(max(fracs), 4) if fracs else 'n/a'}"
         + (f" warmup_errors={len(rep['warmup_errors'])}"
            if rep["warmup_errors"] else "")
+        + "\n"
+        f"compile plane start: programs={rep['programs']} "
+        f"trace_s={rep['trace_s']} lower_s={rep['lower_s']} "
+        f"backend_compile_s={rep['backend_compile_s']} "
+        f"cache_retrieval_s={rep['cache_retrieval_s']} top="
+        + (",".join(
+            f"{p['name']}*{p['n']}:{p['trace_s']}+{p['lower_s']}+{p['backend_s']}"
+            for p in rep["top_programs"]
+        ) or "n/a")
     )

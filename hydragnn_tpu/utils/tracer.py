@@ -40,6 +40,11 @@ EPOCH_RESTART = "epoch_restart"  # main: iter(loader) to the epoch's first batch
 EPOCH_DRAIN = "epoch_drain"  # main: the epoch-end read of the per-step losses
 BATCH_BUILD = "batch_build"  # loader producer: building ONE batch
 H2D_STAGE = "h2d_stage"  # staging thread: the jax.device_put call
+# the three phases of every program jax builds, opened and closed by the compile plane's jax.monitoring
+# listeners (train/compile_plane.py) on the thread that compiles, with fun_name= as the attribute
+COMPILE_TRACE = "compile_trace"  # Python to jaxpr; the outermost trace only, which encloses those of the functions it calls
+COMPILE_LOWER = "compile_lower"  # jaxpr to an MLIR module (the Mosaic kernels are lowered here)
+COMPILE_BACKEND = "compile_backend"  # compile_or_get_cached: the XLA compile cold, the cache fetch warm
 
 # -- jax.named_scope names inside the compiled step (metadata only) ---------
 HG_CAST = "hg_cast"  # the bf16 casts of mixed precision

@@ -419,6 +419,228 @@ def pytest_train_validate_test_wires_the_plane(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------------
+# where a start goes: trace / lower / compile-or-fetch seconds by program
+# ---------------------------------------------------------------------------
+
+_PHASE_KEYS = (("trace_s", "trace_s"), ("lower_s", "lower_s"), ("backend_compile_s", "backend_s"))
+
+
+@pytest.fixture
+def phases():
+    """Listeners on, tracer enabled and clean; ``phases()`` is the pair of
+    differences (counters, programs) since the test began."""
+    from hydragnn_tpu.utils import tracer as tr
+
+    cp.install_metrics_listeners()
+    tr.reset()
+    tr.enable()
+    m0, p0 = cp.compile_metrics(), cp.compile_programs()
+    yield lambda: (cp._metrics_delta(m0), cp._programs_delta(p0))
+    tr.disable()
+    tr.reset()
+
+
+def pytest_compile_metrics_count_the_three_phases_and_stay_flat(phases):
+    def fresh_phase_fn(x):
+        return jnp.tanh(x) * 3.0
+
+    jax.jit(fresh_phase_fn)(np.ones((3, 5), np.float32))
+    delta, _ = phases()
+    assert delta["trace_s"] > 0 and delta["lower_s"] > 0 and delta["backend_compile_s"] > 0
+    assert delta["programs"] == 1
+    # benchmarks/drive_train.py takes m1[k] - m0[k] over EVERY key
+    assert all(type(v) in (int, float) for v in cp.compile_metrics().values())
+
+
+def pytest_compile_programs_rows_by_name_sum_to_the_totals(phases):
+    def fresh_by_name_fn(x):
+        return jnp.cos(x) + 1.0
+
+    f = jax.jit(fresh_by_name_fn)
+    f(np.ones((4,), np.float32))
+    _, programs = phases()
+    # one key for `fresh_by_name_fn` (trace) and `jit(fresh_by_name_fn)` (lower, backend)
+    assert "jit(fresh_by_name_fn)" not in programs
+    row = programs["fresh_by_name_fn"]
+    assert row["n"] == 1 and min(row["trace_s"], row["lower_s"], row["backend_s"]) > 0
+    f(np.ones((6,), np.float32))  # a second shape: the same program once more
+    delta, programs = phases()
+    assert programs["fresh_by_name_fn"]["n"] == 2
+    assert cp.compile_programs()["fresh_by_name_fn"]["t_first"] > 0
+    for total, field in _PHASE_KEYS:
+        assert sum(r[field] for r in programs.values()) == pytest.approx(delta[total], rel=1e-9)
+    assert sum(r["n"] for r in programs.values()) == delta["programs"]
+
+
+def pytest_nested_traces_count_once_under_the_outer_program(phases):
+    """A trace encloses the traces of the jitted functions it calls, each
+    with its own entry and duration: only the outermost adds to
+    ``trace_s``, to a row and to the region."""
+    from hydragnn_tpu.utils import tracer as tr
+
+    inner_a = jax.jit(lambda x: jnp.sin(x) * 2.0)
+    inner_b = jax.jit(lambda x, y: x @ y)
+
+    def fresh_outer_fn(x):
+        return inner_b(inner_a(x), x)
+
+    jax.jit(fresh_outer_fn)(np.ones((4, 4), np.float32))
+    delta, programs = phases()
+    assert list(programs) == ["fresh_outer_fn"], programs
+    row = programs["fresh_outer_fn"]
+    assert row["n"] == 1 and delta["programs"] == 1
+    assert row["trace_s"] == pytest.approx(delta["trace_s"], rel=1e-9)
+    regions = tr.get_regions()
+    assert regions[tr.COMPILE_TRACE]["count"] == 1
+    # the region lies inside jax's own span of the outer trace
+    assert 0 < regions[tr.COMPILE_TRACE]["total"] <= row["trace_s"] + 1e-3
+
+
+def pytest_a_program_compiled_inside_a_trace_keeps_its_own_seconds(phases):
+    """An eager op inside a trace is a program of its own; its seconds are
+    taken out of the enclosing trace's, so no second is counted twice."""
+    from hydragnn_tpu.utils import tracer as tr
+
+    def fresh_eager_inside_fn(x):
+        with jax.ensure_compile_time_eval():
+            c = jnp.arange(7.0) * 2.5  # compiled and run while tracing
+        return x + c.sum()
+
+    jax.jit(fresh_eager_inside_fn)(np.ones((7,), np.float32))
+    delta, programs = phases()
+    outer = programs["fresh_eager_inside_fn"]
+    assert len(programs) > 1 and delta["programs"] == len(programs)
+    inner_s = sum(r["lower_s"] + r["backend_s"] for k, r in programs.items() if k != "fresh_eager_inside_fn")
+    span = tr.get_regions()[tr.COMPILE_TRACE]
+    assert span["count"] == 1  # the inner programs' traces are inside it
+    assert inner_s > 0 and outer["trace_s"] < span["total"] - 0.5 * inner_s
+
+
+def pytest_phase_regions_nest_inside_an_open_region(phases):
+    from hydragnn_tpu.utils import tracer as tr
+
+    tr.start("outer_of_compile")
+    jax.jit(lambda x: x * 5.0 - 1.0)(np.ones((9,), np.float32))
+    assert list(tr._state.open) == ["outer_of_compile"]  # still open, nothing else left open
+    tr.stop("outer_of_compile")
+    regions = tr.get_regions()
+    for name in (tr.COMPILE_TRACE, tr.COMPILE_LOWER, tr.COMPILE_BACKEND):
+        assert regions[name]["count"] == 1 and regions[name]["total"] > 0, name
+    inside = sum(regions[n]["total"] for n in (tr.COMPILE_TRACE, tr.COMPILE_LOWER, tr.COMPILE_BACKEND))
+    assert inside <= regions["outer_of_compile"]["total"]
+
+
+def pytest_phase_annotations_carry_the_program_name(phases, monkeypatch):
+    from hydragnn_tpu.utils import tracer as tr
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **attrs):
+            self.name, self.attrs = name, attrs
+
+        def __enter__(self):
+            seen.append((self.name, self.attrs))
+
+        def __exit__(self, *exc):
+            seen.append((self.name, "exit"))
+
+    monkeypatch.setattr(tr, "_annotation", Annotation)
+
+    def fresh_annotated_fn(x):
+        return x - 2.0
+
+    jax.jit(fresh_annotated_fn)(np.ones((2,), np.float32))
+    assert seen == [
+        (region, what)
+        for region in (tr.COMPILE_TRACE, tr.COMPILE_LOWER, tr.COMPILE_BACKEND)
+        for what in ({"fun_name": "fresh_annotated_fn"}, "exit")
+    ]
+
+
+def pytest_tracer_disabled_counters_count_and_no_region_opens(phases):
+    from hydragnn_tpu.utils import tracer as tr
+
+    tr.disable()
+    jax.jit(lambda x: x / 7.0)(np.ones((11,), np.float32))
+    delta, programs = phases()
+    assert delta["programs"] == 1 and delta["trace_s"] > 0 and len(programs) == 1
+    assert tr.get_regions() == {}
+
+
+def pytest_compile_on_a_second_thread_is_recorded(phases):
+    import threading
+
+    from hydragnn_tpu.utils import tracer as tr
+
+    def fresh_worker_fn(x):
+        return x * x + 4.0
+
+    worker = threading.Thread(target=lambda: jax.jit(fresh_worker_fn)(np.ones((13,), np.float32)))
+    tr.start("main_thread_region")  # the worker's phases are on its own stack
+    worker.start()
+    worker.join()
+    assert list(tr._state.open) == ["main_thread_region"]
+    tr.stop("main_thread_region")
+    _, programs = phases()
+    assert programs["fresh_worker_fn"]["n"] == 1
+    assert tr.get_regions()[tr.COMPILE_BACKEND]["count"] == 1
+
+
+@pytest.mark.parametrize("broken", ["_close_phase", "_program_key"])
+def pytest_listener_that_raises_does_not_fail_the_jit_call(phases, monkeypatch, broken):
+    def boom(*a, **k):
+        raise RuntimeError("listener bug")
+
+    monkeypatch.setattr(cp, broken, boom)
+    out = jax.jit(lambda x: x + 17.0)(np.ones((3,), np.float32))
+    np.testing.assert_allclose(np.asarray(out), 18.0)
+    monkeypatch.undo()
+    jax.jit(lambda x: x + 19.0)(np.ones((3,), np.float32))  # and the next compile is counted
+    assert phases()[0]["programs"] >= 1
+
+
+def _smoke_regex(script):
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "run-scripts", script)
+    spec = importlib.util.spec_from_file_location(script[:-3], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod._PLANE_RE
+
+
+@pytest.mark.parametrize("script", ["compile_smoke.py", "chaos_smoke.py"])
+def pytest_report_first_line_still_matches_the_smokes(phases, script):
+    """The smokes parse the report's first line; the start's breakdown is a
+    line of its own after it."""
+    jax.jit(lambda x: x * 23.0)(np.ones((3,), np.float32))
+    plane = cp.CompilePlane(mode="off")
+    plane._m0, plane._p0 = cp.compile_metrics(), cp.compile_programs()
+
+    def fresh_reported_fn(x):
+        return jnp.exp(x) - 29.0
+
+    jax.jit(fresh_reported_fn)(np.ones((3,), np.float32))
+    rep = plane.report()
+    assert rep["programs"] == 1 and rep["trace_s"] >= 0 and rep["lower_s"] >= 0
+    assert [p["name"] for p in rep["top_programs"]] == ["fresh_reported_fn"]
+    assert rep["top_programs"][0]["n"] == 1
+    first, second = cp.format_report(rep).splitlines()
+    matches = list(_smoke_regex(script).finditer(cp.format_report(rep)))
+    assert len(matches) == 1 and matches[0].group(0) in first
+    assert second.startswith("compile plane start: programs=1 trace_s=")
+    assert "top=fresh_reported_fn*1:" in second
+
+
+def pytest_report_lists_the_five_costliest_programs():
+    rows = {f"p{i}": {"n": i, "trace_s": float(i), "lower_s": 0.5, "backend_s": 0.25} for i in range(8)}
+    top = cp.top_programs(rows, 5)
+    assert [r["name"] for r in top] == ["p7", "p6", "p5", "p4", "p3"]
+    assert top[0] == {"name": "p7", "n": 7, "trace_s": 7.0, "lower_s": 0.5, "backend_s": 0.25}
+
+
+# ---------------------------------------------------------------------------
 # stacked-loader template
 # ---------------------------------------------------------------------------
 
