@@ -429,7 +429,8 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
     defaults are the ZAYA cell's (one slot a token); ``topk`` > 0 takes the
     JOYAI cell's layout instead: every token chooses ``topk`` of ``experts``,
     ``groups`` of them held, rows within ``capacity`` times the balanced
-    count, dispatch and combine around the product (``joyai_kernel_leg``).
+    count, dispatch and combine around the product, and the routing alone
+    (``routing_times``) (``joyai_kernel_leg``).
     ``window`` adds the SLIDING launches on the same operands beside the full
     ones (``trinity_kernel_leg``: a step of that cell holds both kinds).
     Prints each launch's time (a set-up fact, not a throughput)."""
@@ -510,6 +511,7 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
             assert int(lay["overrun"]) == 0 and int(lay["counts"][0]) == 0 and int(lay["counts"].sum()) > 0
             print(f"  top-{topk} of {experts}, {groups} held: {int(lay['counts'].sum())} rows of {tokens} tokens in "
                   f"{lay['src'].shape[0]} row slots, {int(lay['n_tiles'])} tiles of {bm} in use", flush=True)
+            times.update(routing_times(x, choice, node_mask, groups, experts, bm, rows_budget, rng))
         else:
             share = rng.dirichlet(np.ones(groups - 1) * 2.0)
             slot_np = rng.choice(groups - 1, size=tokens, p=share)
@@ -540,6 +542,42 @@ def decoder_kernel_leg(tokens=32768, heads=8, kv_heads=2, head_dim=128,
             _check(f"{tag} {name}", _rel_err(got, want), TOL[dt])
     print("  launch times (ms): " + json.dumps(times), flush=True)
     return {"launch_ms": times}
+
+
+def routing_times(x, choice, node_mask, groups, experts, block_m, rows_budget, rng) -> dict:
+    """The top-k routing alone at a cell's shapes, milliseconds: the layout of
+    a given ``choice [T, k]``, then the whole router (float32 scores over all
+    ``experts`` at ``highest``, top-k, gates, layout, each row's gate, the
+    loads), forward and forward + backward to the stream ``x`` and the
+    router's matrix."""
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.models import decoder as dc
+    from hydragnn_tpu.ops import pallas_grouped_matmul as gm
+
+    held, topk = tuple(range(groups)), choice.shape[1]
+    # the router reads no expert width
+    spec = dc.ExpertSpec(num_experts=experts, top_k=topk, experts_held=held, width=0, shared=0, scale=1.0)
+    w_r = jnp.asarray(rng.normal(size=(x.shape[1], experts)) / np.sqrt(x.shape[1]), jnp.float32)
+    probe = jnp.asarray(rng.normal(size=(rows_budget,)), jnp.float32)
+
+    def router(x_, w_):
+        ch, gate = dc.route({"router": w_}, jnp.zeros((experts,), jnp.float32), x_, spec)
+        lay = dc.topk_layout(ch, node_mask, held, experts, block_m, rows_budget)
+        gate_row = gm.permute_rows(gate.reshape(-1, 1), lay["src"], lay["dest"])[:, 0]
+        return gate_row, dc.expert_loads(ch, node_mask, experts), lay["counts"]
+
+    tag = f"routing top-{topk} of {experts} held={groups}"
+    layout = jax.jit(lambda ch: dc.topk_layout(ch, node_mask, held, experts, block_m, rows_budget))
+    times = {}
+    for name, fn, args in (
+            ("layout_ms", layout, (choice,)),
+            ("router fwd_ms", jax.jit(router), (x, w_r)),
+            ("router fwd+bwd_ms", jax.jit(jax.grad(lambda x_, w_: jnp.sum(router(x_, w_)[0] * probe), (0, 1))),
+             (x, w_r))):
+        times[f"{tag} {name}"] = round(_timed_ms(fn, *args)[1], 3)
+    return times
 
 
 def joyai_kernel_leg(interpret=False, **small) -> dict:

@@ -132,11 +132,14 @@ def gated_mlp(u, w_gate, w_up, w_down):
     return dense(jax.nn.silu(dense(u, w_gate)) * dense(u, w_up), w_down)
 
 
-def held_table(experts_held, num_experts: int):
-    """expert id -> its place among the experts held, or ``held`` (none)."""
+def held_slot(choice, experts_held):
+    """Each chosen expert's place among the experts held, or
+    ``len(experts_held)`` for one held elsewhere: a compare with each held id
+    inside one reduction, with no gather (a table lookup costs a scalar read
+    an assignment on the chip)."""
     held = len(experts_held)
-    return jnp.full((num_experts,), held, jnp.int32).at[jnp.asarray(experts_held)].set(
-        jnp.arange(held, dtype=jnp.int32))
+    hit = choice[..., None] == jnp.asarray(experts_held, choice.dtype)
+    return held + jnp.sum(jnp.where(hit, jnp.arange(held, dtype=choice.dtype) - held, 0), axis=-1)
 
 
 def topk_layout(choice, node_mask, experts_held, num_experts: int, block_m: int, rows: int = 0):
@@ -149,7 +152,7 @@ def topk_layout(choice, node_mask, experts_held, num_experts: int, block_m: int,
     from ..ops.pallas_grouped_matmul import aligned_layout
 
     t, k = choice.shape
-    slot = jnp.where(node_mask[:, None], held_table(experts_held, num_experts)[choice], len(experts_held))
+    slot = jnp.where(node_mask[:, None], held_slot(choice, experts_held), len(experts_held))
     layout = aligned_layout(slot.reshape(-1), len(experts_held), block_m, rows)
     layout["token"] = jnp.where(layout["src"] < t * k, layout["src"] // k, t)
     layout["tokens_here"] = jnp.sum(jnp.any(slot < len(experts_held), axis=1).astype(jnp.int32))
@@ -213,6 +216,43 @@ class ExpertSpec:
         return -(-rows // block_m) * block_m + len(self.experts_held) * block_m
 
 
+def _chosen(choice, experts: int):
+    """``[T, k, experts]``: whether expert ``e`` is the token's ``j``-th
+    choice. Only ever computed inside the reduction that reads it."""
+    return choice[..., None] == jnp.arange(experts, dtype=choice.dtype)
+
+
+@jax.custom_vjp
+def pick(s, choice):
+    """``take_along_axis(s, choice, -1)`` for ``s [T, E]`` and ``choice [T,
+    k]``, spelt as a sum over the one-hot of each choice: a token's choices
+    are distinct, so every value is the chosen one plus exact zeros, and the
+    cotangent is the same kind of sum, with no scatter."""
+    return jnp.sum(jnp.where(_chosen(choice, s.shape[-1]), s[:, None, :], 0.0), axis=-1)
+
+
+def _pick_fwd(s, choice):
+    # the residual carries the width and the dtype as an empty array
+    return pick(s, choice), (choice, jnp.zeros((0, s.shape[-1]), s.dtype))
+
+
+def _pick_bwd(res, g):
+    choice, like = res
+    ds = jnp.sum(jnp.where(_chosen(choice, like.shape[-1]), g[..., None].astype(like.dtype), 0.0), axis=1)
+    return ds, None
+
+
+pick.defvjp(_pick_fwd, _pick_bwd)
+
+
+def expert_loads(choice, weight, experts: int):
+    """Each expert's load over a layer's choices ``[T, k]``: the sum of
+    ``weight [T]`` over the tokens that chose it, in float32 (whole numbers
+    for a 0/1 weight, so exact), as a one-hot sum with no scatter."""
+    return jnp.sum(jnp.where(_chosen(choice, experts), weight.astype(jnp.float32)[:, None, None], 0.0),
+                   axis=(0, 1))
+
+
 def route(p: Dict, beta, u, e: ExpertSpec):
     """The router, in float32: ``s = sigmoid(W_r u)`` over ALL experts, the
     choice the ``top_k`` largest of ``s + beta``, the gates the chosen ``s``
@@ -221,7 +261,7 @@ def route(p: Dict, beta, u, e: ExpertSpec):
     s = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32), p["router"].astype(jnp.float32), precision="highest"))
     # the balancing bias is a buffer: it moves the choice, takes no gradient
     _, choice = jax.lax.top_k(s + jax.lax.stop_gradient(beta.astype(jnp.float32)), e.top_k)
-    gate = jnp.take_along_axis(s, choice, axis=-1)
+    gate = pick(s, choice)
     if e.norm_gates:
         gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-20)
     return choice, gate * e.scale
@@ -236,7 +276,7 @@ def expert_sublayer(p: Dict, beta, u, node_mask, e: ExpertSpec, choice=None,
     add, the held experts' loads [held], every expert's load [num_experts],
     [rows past the budget, tokens with a row here]). ``choice`` overrides the
     router's (tests); ``row_budget`` the spec's own rule."""
-    from ..ops.pallas_grouped_matmul import normalize_tiles
+    from ..ops.pallas_grouped_matmul import normalize_tiles, permute_rows
 
     t, d_model = u.shape
     k = e.top_k
@@ -253,13 +293,13 @@ def expert_sublayer(p: Dict, beta, u, node_mask, e: ExpertSpec, choice=None,
     out_rows = expert_products(rows, p["experts_gate"], p["experts_up"], p["experts_down"],
                                layout, block_m, kernel)
     with tr.scope(tr.HG_MOE_COMBINE):
-        gate_row = jnp.concatenate([gate.reshape(-1), jnp.zeros((1,), gate.dtype)])[layout["src"]]
+        # a row's gate; its cotangent goes back through the inverse map
+        gate_row = permute_rows(gate.reshape(-1, 1), layout["src"], layout["dest"])[:, 0]
         y = combine_rows(out_rows, gate_row, layout["token"], t)
     if e.shared:
         with tr.scope(tr.HG_SHARED_EXPERT):
             y = y + gated_mlp(u, p["shared_gate"], p["shared_up"], p["shared_down"]).astype(jnp.float32)
-    every = jnp.zeros((e.num_experts,), jnp.float32).at[choice.reshape(-1)].add(
-        jnp.repeat(node_mask.astype(jnp.float32), k))
+    every = expert_loads(choice, node_mask, e.num_experts)
     return y.astype(u.dtype), layout["counts"], every, jnp.stack([layout["overrun"], layout["tokens_here"]])
 
 

@@ -50,8 +50,8 @@ from ..utils import tracer as tr
 # importable from here
 from .decoder import (  # noqa: F401
     INIT as _INIT, ROUTER_BIAS_GAIN, balanced_bias, batch_aux, causal_attention, causal_pairs,
-    dense as _dense, embed_tokens, expert_products, flash_blocks, flash_steps, graphs_overflow, held_table, layer_params,
-    poison, remat_in_training, rms_norm, rope)
+    dense as _dense, embed_tokens, expert_loads, expert_products, flash_blocks, flash_steps, graphs_overflow, held_slot,
+    layer_params, pick, poison, remat_in_training, rms_norm, rope)
 
 ARCH_KEYS = (
     "num_attention_heads", "num_key_value_heads", "head_dim", "cca_time0",
@@ -186,7 +186,7 @@ def route(p: Dict, beta, u, s_prev, z: ZayaConfig, first: bool):
     probs = jax.nn.softmax(hp(hdn, f32("router_out")), axis=-1)
     # the balancing bias is a buffer: it moves the choice, takes no gradient
     choice = jnp.argmax(probs + jax.lax.stop_gradient(beta.astype(jnp.float32)), axis=-1)
-    return choice, jnp.take_along_axis(probs, choice[:, None], axis=-1)[:, 0], s
+    return choice, pick(probs, choice[:, None])[:, 0], s
 
 
 def expert_sublayer(p: Dict, beta, u, s_prev, node_mask, z: ZayaConfig, first: bool,
@@ -204,7 +204,7 @@ def expert_sublayer(p: Dict, beta, u, s_prev, node_mask, z: ZayaConfig, first: b
         routed, gate, s = route(p, beta, u, s_prev, z, first)
         choice = routed if choice is None else choice
         held = len(z.experts_held)
-        slot = jnp.where(node_mask, held_table(z.experts_held, z.num_experts)[choice], held)
+        slot = jnp.where(node_mask, held_slot(choice, z.experts_held), held)
         kernel = jax.default_backend() == "tpu"
         # each expert's rows start at a multiple of the kernel's row tile
         block_m = normalize_tiles(t, d_model, z.moe_intermediate_size, dtype=u.dtype)[0]
@@ -214,7 +214,7 @@ def expert_sublayer(p: Dict, beta, u, s_prev, node_mask, z: ZayaConfig, first: b
         out_rows = expert_products(rows, p["experts_gate"], p["experts_up"], p["experts_down"],
                                    layout, block_m, kernel)
         y = permute_rows(out_rows, layout["dest"], layout["src"]) * gate[:, None].astype(u.dtype)
-    every = jnp.zeros((z.num_experts,), jnp.float32).at[choice].add(node_mask.astype(jnp.float32))
+    every = expert_loads(choice[:, None], node_mask, z.num_experts)
     return y, s, layout["counts"], every
 
 

@@ -3,7 +3,7 @@
 An expert layer sends every token to one expert (top-1, models/zaya.py) or to
 several (top-k, models/joyai.py: a token is then ``k`` ASSIGNMENTS, each its
 own row); the chip holds some of the experts. The rows routed to the experts
-held are laid out SORTED BY EXPERT in a group-aligned buffer: group ``g``
+held are laid out GROUPED BY EXPERT in a group-aligned buffer: group ``g``
 starts at a multiple of ``block_m`` and is padded with zero rows to the next
 multiple (``aligned_layout``). Group sizes are ragged and known only on the
 device; the buffer's static length ``A + G * block_m`` for ``A`` assignments
@@ -85,48 +85,75 @@ def aligned_rows(tokens: int, groups: int, block_m: int) -> int:
     return _round_up(tokens, block_m) + groups * block_m
 
 
+# positions of a running count that a search reads at once (``_first_reaching``)
+_SEARCH_CHUNK = 512
+
+
+def _first_reaching(cum, group, target):
+    """For each query ``q``, the first ``i`` with ``cum[group[q], i] >=
+    target[q]``, where each row of ``cum [G, A]`` is non-decreasing and
+    reaches the target: the chunks whose last count falls short of it are
+    counted, then the positions that fall short in the chunk that reaches it
+    (one row gather a query)."""
+    g, a = cum.shape
+    c = min(_SEARCH_CHUNK, a)
+    n = -(-a // c)
+    blocks = jnp.pad(cum, ((0, 0), (0, n * c - a)), mode="edge").reshape(g, n, c)
+    short = lambda rows: jnp.sum((rows < target[:, None]).astype(jnp.int32), axis=1)
+    k = jnp.minimum(short(blocks[:, :, -1][group]), n - 1)
+    return k * c + short(blocks[group, k])
+
+
 def aligned_layout(slot, groups: int, block_m: int, rows: int = 0):
-    """Where each assignment goes. ``slot [T]`` is the assignment's local
+    """Where each assignment goes. ``slot [A]`` is the assignment's local
     expert in ``[0, groups)``, or ``groups`` for one that is not computed here
     (its expert lives elsewhere, or it is padding). ``rows`` > 0 is a budget:
     the buffer's length ``R`` (a multiple of ``block_m``) in place of the
-    worst case ``aligned_rows(T, groups, block_m)``.
+    worst case ``aligned_rows(A, groups, block_m)``. A group's rows keep the
+    assignments' order.
 
-    -> dict of ``dest [T]`` (the assignment's row in the aligned buffer, or
+    -> dict of ``dest [A]`` (the assignment's row in the aligned buffer, or
     ``R``, the appended zero row, when it is not computed here), ``src [R]``
-    (the assignment of each aligned row, or ``T``, the appended zero row),
+    (the assignment of each aligned row, or ``A``, the appended zero row),
     ``tile_group [R / block_m]``, ``n_tiles []`` (tiles in use), ``counts
     [groups]`` and ``overrun []`` (rows past a budget: they are not computed,
     and the caller must not let the step stand).
+
+    Built by counting, with no sort and no scatter: a running count of each
+    group's assignments (``[groups, A]``, the assignments on the lanes) gives
+    an assignment its rank in its group, and the row of each rank is found by
+    a search of that group's count.
     """
-    t = slot.shape[0]
-    r = aligned_rows(t, groups, block_m)
+    a = slot.shape[0]
+    r = aligned_rows(a, groups, block_m)
     if rows:
         r = min(r, _round_up(rows, block_m))
     slot = slot.astype(jnp.int32)
-    counts = jnp.zeros((groups + 1,), jnp.int32).at[slot].add(1)[:groups]
+    onehot = slot[None, :] == jnp.arange(groups, dtype=jnp.int32)[:, None]
+    cum = jnp.cumsum(onehot.astype(jnp.int32), axis=1)
+    counts = cum[:, -1]
     tiles = jnp.maximum((counts + block_m - 1) // block_m, 1)
     tile_end = jnp.cumsum(tiles)
     row_start = (tile_end - tiles) * block_m
-    order = jnp.argsort(slot, stable=True).astype(jnp.int32)
-    sorted_slot = slot[order]
-    group_first = jnp.cumsum(counts) - counts
-    held = sorted_slot < groups
-    safe = jnp.minimum(sorted_slot, groups - 1)
-    rank = jnp.arange(t, dtype=jnp.int32) - group_first[safe]
-    dest_sorted = row_start[safe] + rank
+    held = slot < groups
+    # a held assignment's row: its group's first row plus its rank there
+    dest = jnp.sum(jnp.where(onehot, row_start[:, None] + cum - 1, 0), axis=0)
     # a row past a budget is not computed (and counted): the worst-case
     # buffer has none
-    fits = dest_sorted < r
+    fits = dest < r
     overrun = jnp.sum((held & ~fits).astype(jnp.int32))
-    dest_sorted = jnp.where(held & fits, dest_sorted, r)
-    dest = jnp.zeros((t,), jnp.int32).at[order].set(dest_sorted)
-    src = jnp.full((r,), t, jnp.int32).at[dest_sorted].set(order, mode="drop")
+    dest = jnp.where(held & fits, dest, r)
     tile_group = jnp.minimum(
         jnp.searchsorted(tile_end, jnp.arange(r // block_m, dtype=jnp.int32),
                          side="right"),
         groups - 1,
     ).astype(jnp.int32)
+    # the inverse: row ``row_start[g] + j`` holds group g's assignment of rank j
+    row = jnp.arange(r, dtype=jnp.int32)
+    group = jnp.repeat(tile_group, block_m)
+    of_group = lambda v: jnp.sum(jnp.where(group[:, None] == jnp.arange(groups), v, 0), axis=1)
+    j = row - of_group(row_start)
+    src = jnp.where(j < of_group(counts), _first_reaching(cum, group, j + 1), a)
     return {"dest": dest, "src": src, "tile_group": tile_group,
             "n_tiles": jnp.minimum(tile_end[-1], r // block_m).astype(jnp.int32),
             "counts": counts, "overrun": overrun}

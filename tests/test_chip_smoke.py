@@ -160,12 +160,13 @@ def pytest_chip_smoke_force_gradient_gap_rehearsed(monkeypatch):
     # the JOYAI cell's: 192-wide queries and keys beside 128-wide values, top-k rows within a budget
     ("joyai_kernel_leg", dict(tokens=512, heads=2, kv_heads=2, longest=160, groups=4, width=64, width_out=48,
                               experts=32, topk=4),
-     ("flash_causal float32 2x192/128 fwd+bwd_ms", "grouped_expert float32 groups=4 64->48 top-4 fwd+bwd_ms")),
+     ("flash_causal float32 2x192/128 fwd+bwd_ms", "grouped_expert float32 groups=4 64->48 top-4 fwd+bwd_ms",
+      "routing top-4 of 32 held=4 layout_ms", "routing top-4 of 32 held=4 router fwd+bwd_ms")),
     # the Trinity cell's: the sliding launches beside the full ones on the same grouped-query operands
     ("trinity_kernel_leg", dict(tokens=512, heads=4, kv_heads=2, head_dim=32, longest=300, window=40, groups=4,
                                 width=64, width_out=48, experts=32, topk=4),
      ("flash_causal float32 fwd+bwd_ms", "flash_window(40) float32 fwd+bwd_ms",
-      "grouped_expert float32 groups=4 64->48 top-4 fwd+bwd_ms")),
+      "grouped_expert float32 groups=4 64->48 top-4 fwd+bwd_ms", "routing top-4 of 32 held=4 router fwd_ms")),
 ])
 def pytest_chip_smoke_decoder_kernel_legs_rehearsed(leg, shapes, tags):
     """Both decoder kernel legs at a tiny size in interpret mode: every check
