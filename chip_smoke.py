@@ -601,6 +601,57 @@ def trinity_kernel_leg(interpret=False, **small) -> dict:
     return decoder_kernel_leg(interpret=interpret, **{**shapes, **small})
 
 
+def dsa_times(tokens=32768, heads=32, kv_heads=4, head_dim=128, index_heads=16, index_dim=64, topk=2048,
+              sizes=(20000, 7000, 3000, 1500), interpret=False, dtype="bfloat16") -> dict:
+    """The learned sparse attention's launches alone at the Keye-VL-2.0
+    cell's shapes, milliseconds: the indexer's selection (``hg_dsa_indexer``)
+    over documents of ``sizes`` tokens, the launch of its loss and gradient
+    (``hg_dsa_indexer_bwd``), and one masked causal launch under that
+    selection (``hg_flash_sparse``), forward and forward + backward. Checks
+    that every row selects ``min(n_t, topk)`` keys and that the launches'
+    outputs are finite. The first readings a schedule is sized from."""
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.ops import pallas_dsa_indexer as dsa
+    from hydragnn_tpu.ops.pallas_flash_attention import flash_causal_attention
+
+    rng = np.random.default_rng(0)
+    n_real = sum(sizes)
+    node_graph = jnp.asarray(np.concatenate(
+        [np.full(s, i) for i, s in enumerate(sizes)] + [np.full(tokens - n_real, len(sizes))]).astype(np.int32))
+    node_mask = jnp.asarray(np.arange(tokens) < n_real)
+    pos_np = np.concatenate([np.arange(s) for s in sizes] + [np.zeros(tokens - n_real, int)])
+    pos = jnp.asarray(pos_np, jnp.int32)
+    arr = lambda shape: jnp.asarray(rng.normal(size=shape), jnp.float32).astype(jnp.dtype(dtype))
+    qi, ki, w = arr((tokens, index_heads, index_dim)), arr((tokens, index_dim)), arr((tokens, index_heads))
+    q, k, v = arr((tokens, heads, head_dim)), arr((tokens, kv_heads, head_dim)), arr((tokens, kv_heads, head_dim))
+    nmax = max(sizes)
+    select = jax.jit(lambda *a: dsa.dsa_select(*a, node_graph, node_mask, pos, topk, nmax, interpret))
+    (words, _, _, lse_i), sel_ms = _timed_ms(select, qi, ki, w, repeats=3)
+    counts = jax.jit(lambda x: jnp.sum(jax.lax.population_count(x), axis=(0, 2)))(words)[:tokens]
+    want = np.where(np.asarray(node_mask), np.minimum(pos_np + 1, topk), 0)
+    if not np.array_equal(np.asarray(counts), want):
+        raise AssertionError("hg_dsa_indexer: a row does not hold min(n_t, topk) keys")
+    attend = jax.jit(lambda q_, k_, v_: flash_causal_attention(q_, k_, v_, node_graph, node_mask, nmax,
+                                                               interpret=interpret, select=words))
+    (o, lse), fwd_ms = _timed_ms(attend, q, k, v, repeats=3)
+    grad = jax.jit(jax.grad(lambda q_, k_, v_: jnp.sum(attend(q_, k_, v_)[0].astype(jnp.float32)), (0, 1, 2)))
+    grads, both_ms = _timed_ms(grad, q, k, v, repeats=3)
+    loss = jax.jit(jax.value_and_grad(lambda a, b, c: dsa.dsa_index_loss(
+        a, b, c, q, k, lse, words, lse_i, node_graph, node_mask, nmax, interpret), (0, 1, 2)))
+    (value, igrads), loss_ms = _timed_ms(loss, qi, ki, w, repeats=3)
+    for name, x in (("o", o), ("dq", grads[0]), ("index loss", value), ("dqI", igrads[0])):
+        if not np.isfinite(np.asarray(x, np.float32)).all():
+            raise AssertionError(f"{name}: non-finite")
+    tag = f"dsa {tokens} tokens top-{topk}"
+    times = {f"{tag} hg_dsa_indexer_ms": round(sel_ms, 2), f"{tag} hg_dsa_indexer_bwd_ms": round(loss_ms, 2),
+             f"{tag} hg_flash_sparse fwd_ms": round(fwd_ms, 2), f"{tag} hg_flash_sparse fwd+bwd_ms": round(both_ms, 2),
+             f"{tag} selected_pairs": int(want.sum())}
+    print("  dsa times (ms): " + json.dumps(times), flush=True)
+    return {"launch_ms": times}
+
+
 # ---------------------------------------------------------------------------
 # main leg
 # ---------------------------------------------------------------------------
@@ -1034,7 +1085,8 @@ def main() -> int:
     workdir = tempfile.mkdtemp(prefix="chip_smoke_")
     os.chdir(workdir)
     todo = [("kernels", kernel_leg), ("decoder_kernels", decoder_kernel_leg),
-            ("joyai_kernels", joyai_kernel_leg), ("trinity_kernels", trinity_kernel_leg), ("main", main_leg),
+            ("joyai_kernels", joyai_kernel_leg), ("trinity_kernels", trinity_kernel_leg), ("dsa", dsa_times),
+            ("main", main_leg),
             ("second_order", second_order_leg)]
     if jax.local_device_count() > 1:
         todo.append(("mesh", mesh_leg))

@@ -199,6 +199,18 @@ def update_config(
             arch.setdefault("experts_held", list(range(int(arch["num_experts"]))))
         AfmoeConfig.from_arch(arch)
         arch.setdefault("use_sorted_aggregation", False)
+    if arch["mpnn_type"] == "KEYEVL2":
+        from ..models.keyevl2 import KeyeConfig
+
+        for key, default in (
+                ("rope_theta", 1.0e7), ("norm_topk_prob", True), ("expert_row_capacity", None),
+                ("indexer_num_kv_heads", 1),
+                ("rms_norm_eps", 1.0e-6), ("loss_chunk_rows", 4096)):
+            arch.setdefault(key, default)
+        if arch.get("num_experts") is not None:
+            arch.setdefault("experts_held", list(range(int(arch["num_experts"]))))
+        KeyeConfig.from_arch(arch)
+        arch.setdefault("use_sorted_aggregation", False)
 
     # GPS defaults (reference: config_utils.py:40-47)
     arch.setdefault("global_attn_engine", None)

@@ -156,6 +156,11 @@ _HANDLED = {
     "NeuralNetwork.Architecture.route_norm",
     "NeuralNetwork.Architecture.load_balance_coeff",
     "NeuralNetwork.Architecture.mup_enabled",
+    # the fourth decoder stack (mpnn_type KEYEVL2, models/keyevl2.py)
+    "NeuralNetwork.Architecture.indexer_num_heads",
+    "NeuralNetwork.Architecture.indexer_head_dim",
+    "NeuralNetwork.Architecture.indexer_num_kv_heads",
+    "NeuralNetwork.Architecture.indexer_topk",
     "NeuralNetwork.Architecture.branch_loss_weights",
     "NeuralNetwork.Architecture.branch_loss_metrics",
     "NeuralNetwork.Architecture.dropout",

@@ -161,11 +161,13 @@ class ModelConfig:
     joyai: Optional[Any] = None
     # --- the third decoder stack (mpnn_type "AFMOE", models/afmoe.py)
     afmoe: Optional[Any] = None
+    # --- the fourth decoder stack (mpnn_type "KEYEVL2", models/keyevl2.py)
+    keyevl2: Optional[Any] = None
 
     @property
     def decoder(self) -> Optional[Any]:
         """The keys of whichever decoder stack this is, or None."""
-        return self.zaya or self.joyai or self.afmoe
+        return self.zaya or self.joyai or self.afmoe or self.keyevl2
 
     @property
     def num_heads(self) -> int:

@@ -164,6 +164,7 @@ def model_config_from(config: Dict[str, Any]) -> ModelConfig:
         zaya=_zaya_config(arch),
         joyai=_joyai_config(arch),
         afmoe=_afmoe_config(arch),
+        keyevl2=_keyevl2_config(arch),
     )
 
 
@@ -189,6 +190,14 @@ def _afmoe_config(arch: Dict[str, Any]):
     from .afmoe import AfmoeConfig
 
     return AfmoeConfig.from_arch(arch)
+
+
+def _keyevl2_config(arch: Dict[str, Any]):
+    if arch["mpnn_type"] != "KEYEVL2":
+        return None
+    from .keyevl2 import KeyeConfig
+
+    return KeyeConfig.from_arch(arch)
 
 
 def create_model(config: Dict[str, Any]):
@@ -226,6 +235,12 @@ def create_model(config: Dict[str, Any]):
         from .afmoe import AfmoeModel
 
         return AfmoeModel(cfg=cfg)
+    if cfg.mpnn_type == "KEYEVL2":
+        # the fourth decoder stack: every layer attends the keys a learned
+        # indexer selects, softmax top-k experts (models/keyevl2.py)
+        from .keyevl2 import KeyeModel
+
+        return KeyeModel(cfg=cfg)
     return HydraModel(cfg=cfg)
 
 
@@ -239,4 +254,4 @@ def init_model(
 
 
 def available_models() -> Tuple[str, ...]:
-    return conv_registry() + ("MACE", "ZAYA", "JOYAI", "AFMOE")
+    return conv_registry() + ("MACE", "ZAYA", "JOYAI", "AFMOE", "KEYEVL2")

@@ -7,13 +7,15 @@ packed sequence's segment ids and a node's index within its graph is its
 position. Here, once: RMSNorm, RoPE from that index (rotate-half and
 interleaved), the token embedding, the route to the causal flash kernel, the
 SiLU-gated expert products on group-aligned rows, dispatch and combine around
-them for a token of several assignments (top-k), the sigmoid top-k router and
-the expert sublayer of the stacks that route so (``ExpertSpec``, ``route``,
-``expert_sublayer``), the balancing rules of a router's bias buffer, the
-initial scales, the per-layer rematerialisation (a layer keeps its incoming
-residual stream and, of its causal flash launch, ``o`` and one ``lse`` number a
-row: the forward kernel runs once a step) and the poison of a step that cannot
-stand.
+them for a token of several assignments (top-k), the top-k router (sigmoid or
+softmax scores) and the expert sublayer of the stacks that route so
+(``ExpertSpec``, ``route``, ``expert_sublayer``), the balancing rules of a
+router's bias buffer, the route to the learned sparse attention
+(``sparse_attention``), the initial scales, the per-layer rematerialisation (a
+layer keeps its incoming residual stream and, of its causal flash launch, ``o``
+and one ``lse`` number a row: the forward kernel runs once a step; of its
+indexer, the selection and the indexer loss's gradient) and the poison of a
+step that cannot stand.
 """
 
 from __future__ import annotations
@@ -26,12 +28,16 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ..ops.remat import CAUSAL_FLASH_RESIDUAL_NAMES
+from ..ops.remat import CAUSAL_FLASH_RESIDUAL_NAMES, DSA_RESIDUAL_NAMES
 from ..utils import tracer as tr
 
 # the key of a multi-token-prediction module's hidden state among a model's
 # outputs (models/joyai.py returns it, train/loss.py reads it)
 MTP_HIDDEN = "mtp_hidden"
+# a model output under this prefix is a term of the training loss, added to
+# the token loss as it is (already weighted) and reported under its name
+# (train/loss.py reads it)
+LOSS_TERM_PREFIX = "loss:"
 
 # the gain of the router bias's balancing rule (``balanced_bias``); a constant
 # of the stacks, not a key
@@ -108,6 +114,34 @@ def causal_attention(q, k, v, aux, max_nodes: int, window: Optional[int] = None)
         q, k, v, node_graph, node_mask, max_nodes,
         interpret=jax.default_backend() != "tpu", window=window,
     )
+
+
+def sparse_attention(q, k, v, qi, ki, w, aux, max_nodes: int, topk: int):
+    """Causal attention over the keys a learned indexer selects
+    (ops/pallas_dsa_indexer.py): ``qi [T, H, d]``, ``ki [T, d]``, ``w [T,
+    H]`` score every earlier key of a query's document; the query attends the
+    ``min(n_t, topk)`` of largest score. -> (o ``[T, Hq, dv]``, the indexer
+    loss ``sum over real t of KL(p_t || softmax over S_t of I)``, which
+    trains ``qi``, ``ki``, ``w`` alone). The Pallas launches where the flash
+    route is on (``hg_dsa_indexer``, the ``hg_flash_sparse`` launches,
+    ``hg_dsa_indexer_bwd``), else the plain ``jnp`` references."""
+    from ..ops import pallas_dsa_indexer as dsa
+    from ..ops.pallas_flash_attention import (
+        _flash_route_enabled, flash_causal_attention, reference_causal_attention)
+
+    node_graph, node_mask = aux["node_graph"], aux["node_mask"]
+    if not _flash_route_enabled():
+        sel, _ = dsa.reference_select(qi, ki, w, node_graph, node_mask, topk)
+        o = reference_causal_attention(q, k, v, node_graph, node_mask, select=sel)
+        return o, dsa.reference_index_loss(qi, ki, w, q, k, sel)
+    interpret = jax.default_backend() != "tpu"
+    words, _, _, lse_index = dsa.dsa_select(qi, ki, w, node_graph, node_mask, aux["pos"], topk, max_nodes,
+                                            interpret)
+    o, lse = flash_causal_attention(q, k, v, node_graph, node_mask, max_nodes, interpret=interpret,
+                                    select=words)
+    loss = dsa.dsa_index_loss(qi, ki, w, q, k, lse, words, lse_index, node_graph, node_mask, max_nodes,
+                              interpret)
+    return o, loss
 
 
 def expert_products(x_rows, w_gate, w_up, w_down, layout, block_m: int, kernel: bool):
@@ -193,8 +227,9 @@ def combine_rows(out_rows, gate_row, token, tokens: int):
 
 @dataclasses.dataclass(frozen=True)
 class ExpertSpec:
-    """What the sigmoid top-k router and the expert sublayer read of a
-    stack's keys (``JoyaiConfig.experts``, ``AfmoeConfig.experts``)."""
+    """What the top-k router and the expert sublayer read of a stack's keys
+    (``JoyaiConfig.experts``, ``AfmoeConfig.experts``,
+    ``KeyeConfig.experts``)."""
 
     num_experts: int  # the router's width: all the experts of a layer
     top_k: int
@@ -204,6 +239,7 @@ class ExpertSpec:
     scale: float  # the gates' factor
     norm_gates: bool = True
     row_capacity: float = 0.0
+    score: str = "sigmoid"  # the router's scores: "sigmoid" or "softmax" over all experts
 
     def row_budget(self, tokens: int, block_m: int) -> int:
         """Static rows of the aligned buffer for ``tokens`` token slots:
@@ -253,12 +289,19 @@ def expert_loads(choice, weight, experts: int):
                    axis=(0, 1))
 
 
-def route(p: Dict, beta, u, e: ExpertSpec):
-    """The router, in float32: ``s = sigmoid(W_r u)`` over ALL experts, the
-    choice the ``top_k`` largest of ``s + beta``, the gates the chosen ``s``
-    (normalised to sum 1 under ``norm_gates``) times ``scale``. -> (choice
-    [T, k], gate [T, k])."""
-    s = jax.nn.sigmoid(jnp.dot(u.astype(jnp.float32), p["router"].astype(jnp.float32), precision="highest"))
+def router_scores(p: Dict, u, e: ExpertSpec):
+    """``s [T, num_experts]``, float32: ``sigmoid(W_r u)``, or under
+    ``e.score`` "softmax" the softmax of ``W_r u`` over all experts."""
+    logits = jnp.dot(u.astype(jnp.float32), p["router"].astype(jnp.float32), precision="highest")
+    return jax.nn.softmax(logits, axis=-1) if e.score == "softmax" else jax.nn.sigmoid(logits)
+
+
+def route(p: Dict, beta, u, e: ExpertSpec, scores=None):
+    """The router, in float32: ``s = router_scores`` over ALL experts (or
+    ``scores``, where the stack has them already), the choice the ``top_k``
+    largest of ``s + beta``, the gates the chosen ``s`` (normalised to sum 1
+    under ``norm_gates``) times ``scale``. -> (choice [T, k], gate [T, k])."""
+    s = router_scores(p, u, e) if scores is None else scores
     # the balancing bias is a buffer: it moves the choice, takes no gradient
     _, choice = jax.lax.top_k(s + jax.lax.stop_gradient(beta.astype(jnp.float32)), e.top_k)
     gate = pick(s, choice)
@@ -268,20 +311,21 @@ def route(p: Dict, beta, u, e: ExpertSpec):
 
 
 def expert_sublayer(p: Dict, beta, u, node_mask, e: ExpertSpec, choice=None,
-                    row_budget: Optional[Callable[[int, int], int]] = None):
+                    row_budget: Optional[Callable[[int, int], int]] = None, scores=None):
     """The expert sublayer on the normalised stream ``u [T, D]``: route over
     all experts, compute the rows whose expert is in ``e.experts_held``
     (``p["experts_*"]`` hold those, in that order), nothing for the others,
     and the shared expert on every token. -> (y [T, D] before the residual
     add, the held experts' loads [held], every expert's load [num_experts],
     [rows past the budget, tokens with a row here]). ``choice`` overrides the
-    router's (tests); ``row_budget`` the spec's own rule."""
+    router's (tests); ``row_budget`` the spec's own rule; ``scores`` the
+    router's scores where the stack has computed them (``route``)."""
     from ..ops.pallas_grouped_matmul import normalize_tiles, permute_rows
 
     t, d_model = u.shape
     k = e.top_k
     with tr.scope(tr.HG_ROUTER):
-        routed, gate = route(p, beta, u, e)
+        routed, gate = route(p, beta, u, e, scores)
         choice = routed if choice is None else choice
         kernel = jax.default_backend() == "tpu"
         # each expert's rows start at a multiple of the kernel's row tile
@@ -377,11 +421,15 @@ def remat_in_training(layer_cls, train: bool):
     layer's incoming residual stream and what the causal flash launch's
     backward reads of its forward (``o [T, Hq, dv]`` and one float32 a (head,
     row) of ``lse``, tagged in ops/pallas_flash_attention.py
-    ``_causal_vjp_fwd``), so that the forward kernel runs once a step;
-    everything else of a layer is computed again in the backward pass."""
+    ``_causal_vjp_fwd``), so that the forward kernel runs once a step; of a
+    learned sparse attention, the selection and the indexer loss's gradient
+    (tagged in ops/pallas_dsa_indexer.py), so that its two launches run once
+    a step; everything else of a layer is computed again in the backward
+    pass."""
     if not train:
         return layer_cls
-    return nn.remat(layer_cls, policy=jax.checkpoint_policies.save_only_these_names(*CAUSAL_FLASH_RESIDUAL_NAMES))
+    names = CAUSAL_FLASH_RESIDUAL_NAMES + DSA_RESIDUAL_NAMES
+    return nn.remat(layer_cls, policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
 def flash_blocks(blocks: int, train: bool) -> Dict:

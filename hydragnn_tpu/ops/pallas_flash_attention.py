@@ -64,6 +64,12 @@ ends at the tile of ``col_last + W - 1``: ``_causal_windows``) and one more
 iota compare in the three kernels' mask; the launches carry the name
 ``hg_flash_window`` / ``_bwd``, and ``window=None`` traces the kernels as they
 were (the jaxprs of all three launches are the ones without the argument).
+A learned ``select`` (models/keyevl2.py: the keys an indexer picks,
+ops/pallas_dsa_indexer.py's bitmask) is one more AND in the three kernels'
+mask, the held block's bits unpacked with one shift a tile (``dk``/``dv``
+reads the transposed bitmask); every causal tile is still visited; the
+launches carry the name ``hg_flash_sparse`` / ``_bwd``, and ``select=None``
+traces the kernels as they were.
 
 Where each launch's window loop runs. The self-attention and block-summary
 launches (GPS, the ring: windows of 2-4 tiles of 128) run it as the grid's
@@ -232,12 +238,13 @@ def reference_block_summary(q, k, v, key_mask):
 
 
 def _pair_mask(gid_rows, gid_cols, row0, col0, causal, rows_are_queries=True,
-               window=None):
+               window=None, select=None):
     """Same-graph mask of one tile from the streamed graph-id column
     ``[R, 1]`` and row ``[1, C]`` (padding nodes carry -1 on the key side
     and never match); under ``causal`` also key index <= query index, from
-    the tile's first flat row/column index, and under a sliding ``window``
-    query index - key index < ``window``."""
+    the tile's first flat row/column index, under a sliding ``window``
+    query index - key index < ``window``, and under a learned selection the
+    tile's unpacked bits ``select [R, C]``."""
     keys = gid_cols if rows_are_queries else gid_rows
     mask = (gid_rows == gid_cols) & (keys >= 0)
     if causal:
@@ -247,7 +254,17 @@ def _pair_mask(gid_rows, gid_cols, row0, col0, causal, rows_are_queries=True,
         mask = mask & ((c <= r) if rows_are_queries else (r <= c))
         if window is not None:
             mask = mask & ((r - c < window) if rows_are_queries else (c - r < window))
+    if select is not None:
+        mask = mask & select
     return mask
+
+
+def _selected(sel_ref, b):
+    """The bits of tile ``b`` (of the walked side) in a held block of the
+    selection bitmask ``[groups, rows, 512]`` (ops/pallas_dsa_indexer.py's
+    layout): one leading-axis slice and one shift."""
+    groups = sel_ref.shape[0]
+    return (jax.lax.shift_right_arithmetic(sel_ref[b % groups], b // groups) & 1) != 0
 
 
 def _softmax_tile(q, k_ref, v_ref, at, mask_tile, m_scr, l_scr, acc_scr, scale):
@@ -292,10 +309,14 @@ def _tile_rows(b, block):
 
 def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             *refs, scale, emit_stats, causal=False, resident_block_k=0,
-            window=None):
+            window=None, sparse=False):
     # stats outputs exist only for the block-summary (ring) launch: the
     # self-attention launch would have to WRITE two [H, N, 128] f32 arrays
     # to HBM just to discard them (pallas outputs cannot be DCE'd)
+    sel_ref = None
+    if sparse:  # the held query block's selection bits, an input
+        sel_ref, *refs = refs
+    select = lambda b: _selected(sel_ref, b) if sparse else None
     if emit_stats == "lse":  # one array: each row's log-sum-exp
         o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     elif emit_stats:
@@ -330,7 +351,7 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             _softmax_tile(
                 q, k_ref, v_ref, (0, _tile_rows(kb, bk)),
                 lambda: _pair_mask(gidq_ref[:], gidk_ref[kb], j * q.shape[0],
-                                   kb * bk, causal, window=window),
+                                   kb * bk, causal, window=window, select=select(kb)),
                 m_scr, l_scr, acc_scr, scale,
             )
 
@@ -353,6 +374,7 @@ def _kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
             lambda: _pair_mask(
                 gidq_ref[:], gidk_ref[:], j * q.shape[0],
                 (kstart_ref[j] + kk) * k_ref.shape[1], causal, window=window,
+                select=select(kstart_ref[j] + kk),
             ),
             m_scr, l_scr, acc_scr, scale,
         )
@@ -391,9 +413,12 @@ def _compiler_params(resident: bool):
         vmem_limit_bytes=2 * CAUSAL_RESIDENT_BYTES + 32 * 2 ** 20)
 
 
-def _causal_name(window) -> str:
-    """A launch's name in the device trace: the sliding launches carry their
-    own, so that a trace tells the two kinds of one step apart."""
+def _causal_name(window, select=None) -> str:
+    """A launch's name in the device trace: the sliding launches and those
+    under a learned selection carry their own, so that a trace tells the
+    kinds of one step apart."""
+    if select is not None:
+        return tr.HG_FLASH_SPARSE
     return tr.HG_FLASH_ATTENTION if window is None else tr.HG_FLASH_WINDOW
 
 
@@ -426,7 +451,7 @@ def _walked(resident: bool, id_row, block, widths, windows, head):
 
 def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
              block_q, block_k, interpret, emit_stats=False, causal=False,
-             padded=False, window=None):
+             padded=False, window=None, select=None):
     """Shared launch: q ``[Nq, H, d]`` against k/v ``[Nk, H, d]`` with
     per-q-block key-window schedule (kstart/klast in k-block units) and
     per-node graph ids (-1 = never a valid key). Returns the normalized
@@ -441,7 +466,9 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
     and key ids are one block each and each query block's window is a loop
     inside the kernel, at its own length. ``window`` (causal launches only)
     is the sliding bound of the mask; the schedule it is given already stops
-    at it, and the launch carries the name ``hg_flash_window``."""
+    at it, and the launch carries the name ``hg_flash_window``. ``select``
+    (causal launches only) is a learned selection's bitmask over the padded
+    queries, ANDed into every tile's mask (``hg_flash_sparse``)."""
     nq, h, d = q.shape
     nk = k.shape[0]
     group = h // k.shape[1]
@@ -473,6 +500,10 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
 
     inner, gk, gidk_spec, (k_spec, v_spec) = _walked(
         resident, gk, bk, (d_pad, dv_pad), k_windows, lambda h_i: h_i // group)
+    sel_specs, sel_args = [], []
+    if select is not None:  # the query block's bits, held beside it
+        sel_specs = [pl.BlockSpec((select.shape[0], bq, select.shape[2]), lambda h_i, j, *_: (0, j, 0))]
+        sel_args = [select]
     out_specs = [pl.BlockSpec((1, bq, dv_pad), held)]
     out_shape = [jax.ShapeDtypeStruct((h, nq_pad, dv_pad), q.dtype)]
     if emit_stats:
@@ -482,7 +513,7 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
     out = pl.pallas_call(
         functools.partial(_kernel, scale=scale, emit_stats=emit_stats,
                           causal=causal, resident_block_k=bk if resident else 0,
-                          window=window),
+                          window=window, sparse=select is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(h, j_blocks) + inner,
@@ -492,7 +523,7 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
                 pl.BlockSpec((1, bq, d_pad), held),
                 k_spec,
                 v_spec,
-            ],
+            ] + sel_specs,
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((bq, 128), jnp.float32),
@@ -502,9 +533,9 @@ def _forward(q, k, v, gid_q, gid_k, kstart, klast, k_windows,
         ),
         out_shape=out_shape,
         interpret=interpret,
-        name=_causal_name(window),
+        name=_causal_name(window, select),
         compiler_params=_compiler_params(resident),
-    )(kstart, klast, gq, gk, qt, kt, vt)
+    )(kstart, klast, gq, gk, qt, kt, vt, *sel_args)
     if padded:
         return out
     o = jnp.transpose(out[0], (1, 0, 2))[:nq, :, :v.shape[2]]
@@ -683,10 +714,12 @@ def _summary_jvp(block_q, block_k, interpret, primals, tangents):
 # ---------------------------------------------------------------------------
 
 
-def reference_causal_attention(q, k, v, node_graph, node_mask, window=None):
+def reference_causal_attention(q, k, v, node_graph, node_mask, window=None,
+                               select=None):
     """Flat ``[N, N]``-masked causal grouped-query attention in plain jnp:
     node ``i`` attends node ``j`` iff both are real, share a graph and
-    ``j <= i`` (and, under a sliding ``window``, ``i - j < window``). ``q [N, Hq, d]``, ``k [N, Hk, d]``, ``v [N, Hk, dv]`` with
+    ``j <= i`` (and, under a sliding ``window``, ``i - j < window``; under a
+    learned selection, bool ``select [N, N]``, iff ``select[i, j]``). ``q [N, Hq, d]``, ``k [N, Hk, d]``, ``v [N, Hk, dv]`` with
     ``Hq`` a multiple of ``Hk``. The oracle of the kernel and the route off
     the TPU; scores and softmax in float32."""
     n, hq, d = q.shape
@@ -701,6 +734,8 @@ def reference_causal_attention(q, k, v, node_graph, node_mask, window=None):
     )
     if window is not None:
         allowed = allowed & (idx[:, None] - idx[None, :] < window)
+    if select is not None:
+        allowed = allowed & select
     logits = jnp.einsum(
         "ihd,jhd->hij", q, kf, preferred_element_type=jnp.float32
     ) * (1.0 / float(d) ** 0.5)
@@ -749,13 +784,18 @@ def _causal_windows(node_graph, node_mask, block_q, block_k, max_nodes_per_graph
 
 
 def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
-               do_ref, o_ref, lse_ref, dq_ref, delta_scr, lse_scr, acc_scr, *, scale,
-               resident_block_k=0, window=None):
+               do_ref, o_ref, lse_ref, *refs, scale,
+               resident_block_k=0, window=None, sparse=False):
     """One query block held, its key/value tiles walked for ``dq``: by the
     grid's third axis, or (``resident_block_k``) by a loop over the head's
     resident keys and values, as the forward does. ``lse_ref`` holds the
     head's statistics as lane-major rows ``[1, q_blocks, 1, Bq]``, the form
-    the ``dk``/``dv`` launch reads."""
+    the ``dk``/``dv`` launch reads. ``sparse``: the held block's selection
+    bits come first among ``refs``."""
+    sel_ref = None
+    if sparse:
+        sel_ref, *refs = refs
+    dq_ref, delta_scr, lse_scr, acc_scr = refs
     j = pl.program_id(1)
 
     def _init():
@@ -779,7 +819,7 @@ def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
         ) * scale
         mask = _pair_mask(
             gidq_ref[:], gidk, j * q.shape[0], kb * k.shape[0], True,
-            window=window,
+            window=window, select=_selected(sel_ref, kb) if sparse else None,
         )
         p = jnp.where(mask, jnp.exp(s - lse_scr[:, 0:1]), 0.0)
         dp = jax.lax.dot_general(
@@ -811,13 +851,19 @@ def _dq_kernel(kstart_ref, klast_ref, gidq_ref, gidk_ref, q_ref, k_ref, v_ref,
 
 
 def _dkv_kernel(qstart_ref, qlast_ref, gidk_ref, gidq_ref, k_ref, v_ref,
-                q_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, scale, resident_block_q=0, window=None):
+                q_ref, do_ref, lse_ref, delta_ref, *refs, scale, resident_block_q=0,
+                window=None, sparse=False):
     """One key/value block held, its query tiles walked (by the grid's third
     axis, or by a loop over the query head's resident ``q``, ``do`` and
     statistics); every product is written transposed (``s.T = k @ q.T``) so
     that no tile is transposed in the kernel: the statistics arrive as
-    lane-major rows ``[*, Bq]``."""
+    lane-major rows ``[*, Bq]``. ``sparse``: the held key block's bits of
+    the TRANSPOSED selection (rows keys, bits queries) come first among
+    ``refs``."""
+    sel_ref = None
+    if sparse:
+        sel_ref, *refs = refs
+    dk_ref, dv_ref, dk_scr, dv_scr = refs
     i = pl.program_id(1)
 
     def _init():
@@ -835,6 +881,7 @@ def _dkv_kernel(qstart_ref, qlast_ref, gidk_ref, gidq_ref, k_ref, v_ref,
         mask = _pair_mask(
             gidk_ref[:], gidq, i * k.shape[0], qb * q.shape[0], True,
             rows_are_queries=False, window=window,
+            select=_selected(sel_ref, qb) if sparse else None,
         )
         pt = jnp.where(mask, jnp.exp(st - lse), 0.0)
         dv_scr[:] += jax.lax.dot_general(
@@ -901,14 +948,15 @@ def _causal_prep(node_graph, node_mask, max_nodes_per_graph, block_q, block_k,
 
 
 def _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
-                block_q, block_k, interpret, window=None):
+                block_q, block_k, interpret, window=None, select=None):
     gid, (ks, kl, kw, _, _, _) = _causal_prep(
         node_graph, node_mask, max_nodes_per_graph, block_q, block_k, window
     )
-    with tr.scope(_causal_name(window)):
+    with tr.scope(_causal_name(window, select)):
         o_pad, lse = _forward(
             q, k, v, gid, gid, ks, kl, kw, block_q, block_k, interpret,
             emit_stats="lse", causal=True, padded=True, window=window,
+            select=select,
         )
         o = jnp.transpose(o_pad, (1, 0, 2))[:q.shape[0], :, :v.shape[2]]
     return o, lse  # lse [H, Nq_pad, 128], lane-broadcast
@@ -925,6 +973,7 @@ def flash_causal_attention(
     block_k: int = CAUSAL_BLOCK_K,
     interpret: bool = False,
     window=None,
+    select=None,
 ):
     """Causal grouped-query flash attention over the flat node array.
 
@@ -942,9 +991,23 @@ def flash_causal_attention(
     ``window`` W (a sliding layer) also bounds ``i - j < W``: every block's
     window then starts no earlier than W - 1 rows before it, the mask gains
     one compare, and the three launches are named ``hg_flash_window`` /
-    ``_bwd``; ``None`` is the launches as they were."""
+    ``_bwd``; ``None`` is the launches as they were. A learned ``select``
+    (ops/pallas_dsa_indexer.py ``dsa_select``'s bitmask over the padded
+    queries; the tiles must be its ``SELECT_TILE``) is one more AND in every
+    tile's mask of the three launches, named ``hg_flash_sparse`` / ``_bwd``,
+    and the call then returns ``(o, lse)``: each (query head, padded row)'s
+    log-sum-exp over its selection, float32 ``[Hq, N_pad]``, which takes no
+    gradient (the indexer's loss reads it); ``None`` is the launches as they
+    were."""
     if window is not None and int(window) < 1:
         raise ValueError(f"window must be a positive count of keys, got {window}")
+    if select is not None:
+        from .pallas_dsa_indexer import SELECT_TILE
+
+        if window is not None or normalize_tiles(block_q, block_k) != (SELECT_TILE, SELECT_TILE):
+            raise ValueError(f"a selection takes no window and tiles of {SELECT_TILE}")
+        return _flash_sparse_attention(q, k, v, node_graph, node_mask, select, max_nodes_per_graph,
+                                       SELECT_TILE, SELECT_TILE, interpret)
     return _flash_causal_attention(
         q, k, v, node_graph, node_mask, max_nodes_per_graph,
         *normalize_tiles(block_q, block_k), interpret,
@@ -974,7 +1037,9 @@ def _causal_vjp_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
 
 
 def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
-                    res, do):
+                    res, do, select=None):
+    """The ``dq`` and ``dk``/``dv`` launches; under a learned ``select`` the
+    ``dk``/``dv`` launch reads its transpose (``transpose_select``)."""
     q, k, v, o, lse_row, node_graph, node_mask = res
     n, hq, d = q.shape
     hk = k.shape[1]
@@ -984,7 +1049,7 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
     gid, (ks, kl, kw, qs, ql, qw) = _causal_prep(
         node_graph, node_mask, max_nodes_per_graph, bq, bk, window
     )
-    bwd_name = _causal_name(window) + tr.BWD
+    bwd_name = _causal_name(window, select) + tr.BWD
     with tr.scope(bwd_name):
         qt, dot, ot = (_heads_first(x, bq) for x in (q, do.astype(q.dtype), o))
         kt, vt = _heads_first(k, bk), _heads_first(v, bk)
@@ -1009,10 +1074,14 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
         resident = _resident(nk_pad, d_pad + dv_pad, kt.dtype)
         inner, gidk, gidk_spec, (k_spec, v_spec) = _walked(
             resident, grow(nk_pad), bk, (d_pad, dv_pad), kw, lambda h_i: h_i // group)
+        sel_specs, sel_args = [], []
+        if select is not None:
+            sel_specs = [pl.BlockSpec((select.shape[0], bq, select.shape[2]), lambda h_i, j, *_: (0, j, 0))]
+            sel_args = [select]
         dq = pl.pallas_call(
             functools.partial(_dq_kernel, scale=scale,
                               resident_block_k=bk if resident else 0,
-                              window=window),
+                              window=window, sparse=select is not None),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(hq, j_blocks) + inner,
@@ -1025,7 +1094,7 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
                     pl.BlockSpec((1, bq, dv_pad), held),
                     pl.BlockSpec((1, bq, dv_pad), held),
                     stat_rows,
-                ],
+                ] + sel_specs,
                 out_specs=pl.BlockSpec((1, bq, d_pad), held),
                 scratch_shapes=[
                     pltpu.VMEM((bq, 128), jnp.float32),
@@ -1037,7 +1106,7 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
             interpret=interpret,
             name=bwd_name,
             compiler_params=_compiler_params(resident),
-        )(ks, kl, gcol(nq_pad), gidk, qt, kt, vt, dot, ot, tiled(lse_row))
+        )(ks, kl, gcol(nq_pad), gidk, qt, kt, vt, dot, ot, tiled(lse_row), *sel_args)
 
         # ---- dk, dv: one key/value block held, per QUERY head; the group's
         # heads are summed after (float32 partials). The other way round: the
@@ -1057,10 +1126,16 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
             lse_row, delta_row = rows8(lse_row), rows8(delta_row)
             stat_spec = pl.BlockSpec((1, 8, bq), lambda h_i, i, qq, s_, l_: (
                 h_i, 0, jnp.minimum(s_[i] + qq, l_[i])))
+        if select is not None:  # the held key block's bits over the queries
+            from .pallas_dsa_indexer import transpose_select
+
+            sel_t = transpose_select(select, nk_pad)
+            sel_specs = [pl.BlockSpec((sel_t.shape[0], bk, sel_t.shape[2]), lambda h_i, i, *_: (0, i, 0))]
+            sel_args = [sel_t]
         dk_h, dv_h = pl.pallas_call(
             functools.partial(_dkv_kernel, scale=scale,
                               resident_block_q=bq if resident else 0,
-                              window=window),
+                              window=window, sparse=select is not None),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(hq, k_blocks) + inner,
@@ -1073,7 +1148,7 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
                     do_spec,
                     stat_spec,
                     stat_spec,
-                ],
+                ] + sel_specs,
                 out_specs=[pl.BlockSpec((1, bk, d_pad), out_held),
                            pl.BlockSpec((1, bk, dv_pad), out_held)],
                 scratch_shapes=[pltpu.VMEM((bk, d_pad), jnp.float32),
@@ -1084,7 +1159,7 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
             interpret=interpret,
             name=bwd_name,
             compiler_params=_compiler_params(resident),
-        )(qs, ql, gcol(nk_pad), gidq, kt, vt, qt, dot, lse_row, delta_row)
+        )(qs, ql, gcol(nk_pad), gidq, kt, vt, qt, dot, lse_row, delta_row, *sel_args)
 
         def per_kv_head(x, width):
             x = x.reshape(hk, group, nk_pad, x.shape[2]).sum(axis=1)
@@ -1096,3 +1171,32 @@ def _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, window,
 
 
 _flash_causal_attention.defvjp(_causal_vjp_fwd, _causal_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _flash_sparse_attention(q, k, v, node_graph, node_mask, select,
+                            max_nodes_per_graph, block_q, block_k, interpret):
+    o, lse = _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
+                         block_q, block_k, interpret, select=select)
+    return o, lse[:, :, 0]
+
+
+def _sparse_vjp_fwd(q, k, v, node_graph, node_mask, select,
+                    max_nodes_per_graph, block_q, block_k, interpret):
+    o, lse = _causal_fwd(q, k, v, node_graph, node_mask, max_nodes_per_graph,
+                         block_q, block_k, interpret, select=select)
+    # kept across a decoder layer's remat, as the causal launch's
+    out_name, lse_name = CAUSAL_FLASH_RESIDUAL_NAMES
+    o, lse_row = tag(o, out_name), tag(lse[:, :, 0], lse_name)
+    return (o, lse_row), (q, k, v, o, lse_row, node_graph, node_mask, select)
+
+
+def _sparse_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, res, cts):
+    # the log-sum-exp is an output without a gradient: its cotangent is dropped
+    do, _ = cts
+    *kept, select = res
+    return _causal_vjp_bwd(max_nodes_per_graph, block_q, block_k, interpret, None,
+                           tuple(kept), do, select)[:3] + (None, None, None)
+
+
+_flash_sparse_attention.defvjp(_sparse_vjp_fwd, _sparse_vjp_bwd)

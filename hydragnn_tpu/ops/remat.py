@@ -56,6 +56,11 @@ KERNEL_OUTPUT_NAMES = (
 # ``names`` policy and the steps compiled under it stay as they are
 CAUSAL_FLASH_RESIDUAL_NAMES = ("flash_causal_out", "flash_causal_lse")
 
+# what a sparse-attention layer keeps of its indexer across the same remat
+# (ops/pallas_dsa_indexer.py): the selection with its threshold, tie bound and
+# log-sum-exp, and the indexer loss's gradient, whole after the forward pass
+DSA_RESIDUAL_NAMES = ("dsa_select", "dsa_index_grads")
+
 
 def tag(x, name: str):
     """Tag a kernel output (array or pytree) for ``save_only_these_names``.

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 
 from ..data.graph import GraphBatch
 from ..models.base import ModelConfig
-from ..models.decoder import MTP_HIDDEN, follows
+from ..models.decoder import LOSS_TERM_PREFIX, MTP_HIDDEN, follows
 from ..utils import tracer as tr
 
 
@@ -177,6 +177,11 @@ def _token_head_loss(model, variables, batch, cfg, train, rng):
         # the multi-token-prediction module's loss, through the same head
         tasks["mtp"] = token_loss(outputs[MTP_HIDDEN], head, batch, chunk, ahead=2)
         loss = loss + cfg.joyai.mtp_loss_weight * tasks["mtp"]
+    for key, term in outputs.items():
+        if key.startswith(LOSS_TERM_PREFIX):
+            # a stack's own term of the loss (models/keyevl2.py), weighted there
+            tasks[key[len(LOSS_TERM_PREFIX):]] = term
+            loss = loss + term
     tasks.update({k: v for k, v in outputs.items() if k.startswith(tr.COUNTER_PREFIX)})
     return loss, tasks, mutated, {name: outputs[name]}
 

@@ -60,6 +60,7 @@ HG_MOE_COMBINE = "hg_moe_combine"  # JOYAI, AFMOE: the gate-weighted sum over a 
 HG_SHARED_EXPERT = "hg_shared_expert"  # JOYAI, AFMOE: the shared expert's MLP on every token
 HG_ATTN_PROJ = "hg_attn_proj"  # AFMOE: the query, key and value projections of an attention sublayer, the head norms and the rotation
 HG_ATTN_GATE = "hg_attn_gate"  # AFMOE: the gate's projection, its sigmoid and the product with the attention output
+HG_DSA_PROJ = "hg_dsa_proj"  # KEYEVL2: the sparse-attention indexer's projections (queries, key, weights), its key's LayerNorm and RoPE
 HG_MTP = "hg_mtp"  # JOYAI: the multi-token-prediction module (join, its own layer, its norm)
 HG_TOKEN_LOSS = "hg_token_loss"  # the chunked next-node cross-entropy
 
@@ -86,6 +87,7 @@ CT_FLASH_BLOCKS_SAVED = "count:flash_blocks_saved"
 CT_EXPERT_ROWS_HERE = "count:expert_rows_here"  # JOYAI, AFMOE: rows computed on this chip (a token is 0..k), over layers
 CT_EXPERT_ROWS_OVERRUN = "count:expert_rows_overrun"  # JOYAI, AFMOE: rows past the row budget (the step is poisoned)
 CT_MTP_PAIRS = "count:mtp_pairs"  # JOYAI: nodes whose two successors lie in their document
+CT_DSA_SELECTED_PAIRS = "count:dsa_selected_pairs"  # KEYEVL2: (query, key) pairs the indexer selects, one layer's: sum of min(n_t, topk)
 
 # -- Pallas kernels: pallas_call(name=...) inside a scope of the same name;
 #    the custom-JVP tangent rule runs under <name> + TANGENT ----------------
@@ -96,6 +98,10 @@ HG_FLASH_ATTENTION = "hg_flash_attention"
 # the causal launches of a SLIDING layer (flash_causal_attention(window=W)): the same kernels under a
 # name of their own, so a trace tells a step's two kinds of launch apart
 HG_FLASH_WINDOW = "hg_flash_window"
+# the causal launches under a learned selection (flash_causal_attention(select=...)), and the indexer
+# that makes the selection with the launch of its loss (ops/pallas_dsa_indexer.py; ``_bwd``: the loss)
+HG_FLASH_SPARSE = "hg_flash_sparse"
+HG_DSA_INDEXER = "hg_dsa_indexer"
 HG_GROUPED_EXPERT = "hg_grouped_expert"
 TANGENT = "_tangent"
 # the transpose of ops/segment.py gather(sorted_ids=True): a scope AROUND the

@@ -187,3 +187,22 @@ def pytest_chip_smoke_decoder_kernel_legs_rehearsed(leg, shapes, tags):
     for shape in ("tokens=16384", "heads=32", "kv_heads=4", "head_dim=128", "window=2048", "groups=8",
                   "width_out=1024", "topk=8", "experts=128"):
         assert shape in src, shape
+
+
+def pytest_chip_smoke_dsa_times_rehearsed():
+    """The sparse attention's leg at a tiny size in interpret mode: the
+    selection holds ``min(n_t, topk)`` keys a row, the three launches run and
+    are timed under their names; its defaults are the Keye-VL-2.0 cell's
+    shapes."""
+    import inspect
+
+    smoke = _load_smoke()
+    got = smoke.dsa_times(tokens=1024, heads=4, kv_heads=2, head_dim=32, index_heads=4, index_dim=16, topk=64,
+                          sizes=(600, 200, 100), interpret=True, dtype="float32")
+    for name in ("hg_dsa_indexer_ms", "hg_dsa_indexer_bwd_ms", "hg_flash_sparse fwd_ms", "hg_flash_sparse fwd+bwd_ms"):
+        assert f"dsa 1024 tokens top-64 {name}" in got["launch_ms"], name
+    src = inspect.getsource(smoke.dsa_times)
+    for shape in ("tokens=32768", "heads=32", "kv_heads=4", "head_dim=128", "index_heads=16", "index_dim=64",
+                  "topk=2048"):
+        assert shape in src, shape
+

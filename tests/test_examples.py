@@ -458,3 +458,11 @@ def pytest_example_trinity_mini():
     top-4 of 16 experts beside a shared one."""
     out = _run_example("examples/trinity_mini/trinity_mini.py", "--num_docs", "48", "--num_epoch", "2")
     assert "train loss by epoch" in out
+
+
+def pytest_example_keye_vl2():
+    """The fourth decoder stack's preset (examples/keye_vl2): every layer
+    attends the 16 keys a learned indexer selects, with the indexer's loss;
+    softmax top-4 of 16 experts with the auxiliary balancing loss."""
+    out = _run_example("examples/keye_vl2/keye_vl2.py", "--num_docs", "48", "--num_epoch", "2")
+    assert "train loss by epoch" in out
