@@ -15,7 +15,9 @@ It refuses any backend but ``tpu`` before building data, then drives
    its plain-``jnp`` reference, first the bf16 fused-edge call at the
    benchmark cells' own shape (forward and tangent), then the receiver
    gather's transposed kernel call and a message layer's pair of row
-   gathers (ordered against plain, bit for bit) at that shape;
+   gathers (ordered against plain, bit for bit) at that shape; then the
+   token head alone at the ZAYA and JOYAI cells' shapes, its gradient formed
+   in the forward scan against the earlier checkpointed rule (``head_times``);
 2. the main leg: SC25-shaped EGNN (hidden 866, 4 conv layers) through
    ``run_training`` -> ``run_prediction`` -> ``run_server`` in this process,
    with the lowered programs checked for Mosaic custom calls and the kernel
@@ -652,6 +654,86 @@ def dsa_times(tokens=32768, heads=32, kv_heads=4, head_dim=128, index_heads=16, 
     return {"launch_ms": times}
 
 
+def _checkpointed_cross_entropy(hidden, head, targets, weights, chunk_rows, den):
+    """The token head's earlier rule, timed beside ``chunked_cross_entropy``:
+    a scan of checkpointed chunks, so the backward scan computes each chunk's
+    logits again before its two gradient products."""
+    import jax
+    import jax.numpy as jnp
+
+    t = hidden.shape[0]
+    chunk = max(1, min(int(chunk_rows), t))
+    pad = (-t) % chunk
+    if pad:
+        hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
+        targets = jnp.pad(targets, (0, pad))
+        weights = jnp.pad(weights, (0, pad))
+    n_chunks = (t + pad) // chunk
+
+    @jax.checkpoint
+    def one(h, tgt, w):
+        logits = jnp.dot(h, head.astype(h.dtype), preferred_element_type=jnp.float32,
+                         precision="highest" if h.dtype == jnp.float32 else None)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+        return jnp.sum(w * (lse - picked))
+
+    total, _ = jax.lax.scan(lambda c, xs: (c + one(*xs), None), jnp.zeros((), jnp.float32),
+                            (hidden.reshape(n_chunks, chunk, -1), targets.reshape(n_chunks, chunk),
+                             weights.reshape(n_chunks, chunk)))
+    return total / den
+
+
+def head_times(cells=(("zaya", 32768, 32784, 1), ("joyai", 16384, 16160, 2)), width=2048, chunk=4096,
+               dtype="bfloat16", mtp_weight=0.3) -> dict:
+    """The token head alone at the decoder cells' shapes (``cells``: name,
+    rows, vocabulary, head passes; a second pass weighs ``mtp_weight``, as the
+    JOYAI cell's multi-token-prediction pass through the same head):
+    ``value_and_grad`` over the hidden rows and the head, milliseconds, under
+    the earlier rule (each chunk checkpointed, its logits computed again in
+    the backward scan) and under ``train/loss.py chunked_cross_entropy`` (the
+    gradient formed in the forward scan), with each compiled program's
+    temporary bytes. Checks that the two agree. The reading the change is
+    sized from."""
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.train.loss import chunked_cross_entropy
+
+    rng = np.random.default_rng(0)
+    times = {}
+    for name, rows, vocab, passes in cells:
+        arr = lambda shape, s=1.0: jnp.asarray(s * rng.normal(size=shape), jnp.float32).astype(jnp.dtype(dtype))
+        hidden = [arr((rows, width)) for _ in range(passes)]
+        head = arr((width, vocab), width ** -0.5)
+        targets = [jnp.asarray(rng.integers(0, vocab, rows), jnp.int32) for _ in range(passes)]
+        weights = [jnp.asarray(rng.random(rows) < 0.95, jnp.float32) for _ in range(passes)]
+        den = jnp.maximum(jnp.sum(weights[0]), 1.0)
+
+        def program(rule):
+            def loss(hs, hd):
+                return sum((mtp_weight if i else 1.0) * rule(h, hd, t, w, chunk, den)
+                           for i, (h, t, w) in enumerate(zip(hs, targets, weights)))
+            return jax.jit(jax.value_and_grad(loss, (0, 1)))
+
+        out, ms, temp = {}, {}, {}
+        for rule_name, rule in (("checkpointed", _checkpointed_cross_entropy), ("grad_in_forward",
+                                                                                 chunked_cross_entropy)):
+            fn = program(rule)
+            temp[rule_name] = int(fn.lower(hidden, head).compile().memory_analysis().temp_size_in_bytes)
+            out[rule_name], ms[rule_name] = _timed_ms(fn, hidden, head)
+        (l0, (dh0, dw0)), (l1, (dh1, dw1)) = out["checkpointed"], out["grad_in_forward"]
+        _check(f"head {name} loss", _rel_err(l1, l0), 1e-6)
+        for i in range(passes):
+            _check(f"head {name} dh[{i}]", _rel_err(dh1[i], dh0[i]), 1e-2)
+        _check(f"head {name} dhead", _rel_err(dw1, dw0), 1e-2)
+        tag = f"head {name} [{rows}, {width}] x [{width}, {vocab}] chunk {chunk} x {passes}"
+        times.update({f"{tag} {k}_ms": round(v, 2) for k, v in ms.items()})
+        times.update({f"{tag} {k}_temp_bytes": v for k, v in temp.items()})
+    print("  head times (ms): " + json.dumps(times), flush=True)
+    return {"head_ms": times}
+
+
 # ---------------------------------------------------------------------------
 # main leg
 # ---------------------------------------------------------------------------
@@ -1086,7 +1168,7 @@ def main() -> int:
     os.chdir(workdir)
     todo = [("kernels", kernel_leg), ("decoder_kernels", decoder_kernel_leg),
             ("joyai_kernels", joyai_kernel_leg), ("trinity_kernels", trinity_kernel_leg), ("dsa", dsa_times),
-            ("main", main_leg),
+            ("head", head_times), ("main", main_leg),
             ("second_order", second_order_leg)]
     if jax.local_device_count() > 1:
         todo.append(("mesh", mesh_leg))

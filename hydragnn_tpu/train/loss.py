@@ -8,6 +8,7 @@ the reference's per-batch mean over ragged tensors.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, Tuple
 
 import jax
@@ -104,37 +105,94 @@ def _per_branch_head_loss(
     return out
 
 
-def chunked_cross_entropy(hidden, head, targets, weights, chunk_rows: int):
-    """Sum over rows of ``weights * (logsumexp(hidden @ head) - logit[target])``
-    with the logits in float32 and only ``chunk_rows`` rows of them alive at
-    a time, forward and backward (each chunk is recomputed in the backward):
-    ``[32768, 32784]`` float32 logits whole would be 4.3 GB. ``hidden [T, D]``,
-    ``head [D, V]``, ``targets [T]`` int, ``weights [T]`` float32."""
+def _chunk_loss(h, head, tgt, w):
+    """One chunk's ``sum(w * (logsumexp(logits) - logit[tgt]))``, its logits
+    ``[chunk, V]`` in float32."""
+    logits = jnp.dot(h, head.astype(h.dtype), preferred_element_type=jnp.float32,
+                     precision="highest" if h.dtype == jnp.float32 else None)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+    return jnp.sum(w * (lse - picked))
+
+
+def _chunk_rows(t: int, chunk_rows: int) -> int:
+    return max(1, min(int(chunk_rows), t))
+
+
+def head_chunks(t: int, chunk_rows: int) -> int:
+    """The row chunks ``chunked_cross_entropy`` splits ``t`` rows into."""
+    return -(-t // _chunk_rows(t, chunk_rows))
+
+
+def _stacked(hidden, targets, weights, chunk_rows):
+    """The rows padded with zero-weight rows to whole chunks, ``[n, chunk, ...]``."""
     t = hidden.shape[0]
-    chunk = max(1, min(int(chunk_rows), t))
+    chunk = _chunk_rows(t, chunk_rows)
     pad = (-t) % chunk
     if pad:
         hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
         targets = jnp.pad(targets, (0, pad))
         weights = jnp.pad(weights, (0, pad))
-    n_chunks = (t + pad) // chunk
+    n = (t + pad) // chunk
+    return hidden.reshape(n, chunk, -1), targets.reshape(n, chunk), weights.reshape(n, chunk)
 
-    @jax.checkpoint
-    def one(h, tgt, w):
-        logits = jnp.dot(h, head.astype(h.dtype), preferred_element_type=jnp.float32,
-                         precision="highest" if h.dtype == jnp.float32 else None)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
-        return jnp.sum(w * (lse - picked))
 
+def _in_order(values):
+    """``values [n]`` summed one after the other from the first, as a scan's
+    carry adds them."""
+    return jax.lax.scan(lambda total, v: (total + v, None), jnp.zeros((), jnp.float32), values)[0]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _cross_entropy(hidden, head, targets, weights, den, chunk_rows):
     def body(total, xs):
-        return total + one(*xs), None
+        h, tgt, w = xs
+        return total + _chunk_loss(h, head, tgt, w), None
 
-    total, _ = jax.lax.scan(
-        body, jnp.zeros((), jnp.float32),
-        (hidden.reshape(n_chunks, chunk, -1), targets.reshape(n_chunks, chunk),
-         weights.reshape(n_chunks, chunk)))
-    return total
+    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), _stacked(hidden, targets, weights, chunk_rows))
+    return total / den
+
+
+def _cross_entropy_fwd(hidden, head, targets, weights, den, chunk_rows):
+    # each chunk's cotangent, as autodiff of ``total / den`` under a unit
+    # cotangent forms it: the gradient kept is the one autodiff would give
+    ct = jnp.ones((), jnp.float32) / den
+
+    def body(dhead, xs):
+        h, tgt, w = xs
+        value, pull = jax.vjp(lambda h_, head_: _chunk_loss(h_, head_, tgt, w), h, head)
+        dh, dhead_chunk = pull(ct)
+        return dhead + dhead_chunk, (value, dh)
+
+    # last chunk first, as the transposed scan adds the head's gradient
+    dhead, (values, dh) = jax.lax.scan(body, jnp.zeros_like(head),
+                                       _stacked(hidden, targets, weights, chunk_rows), reverse=True)
+    dh = dh.reshape(-1, hidden.shape[1])[:hidden.shape[0]]
+    return _in_order(values) / den, (dh, dhead)
+
+
+def _cross_entropy_bwd(chunk_rows, residuals, g):
+    dh, dhead = residuals
+    return (g * dh).astype(dh.dtype), (g * dhead).astype(dhead.dtype), None, None, None
+
+
+_cross_entropy.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
+
+
+def chunked_cross_entropy(hidden, head, targets, weights, chunk_rows: int, den=1.0):
+    """Sum over rows of ``weights * (logsumexp(hidden @ head) - logit[target])``,
+    divided by ``den``, with the logits in float32 and only ``chunk_rows`` rows
+    of them alive at a time: ``[32768, 32784]`` float32 logits whole would be
+    4.3 GB. ``hidden [T, D]``, ``head [D, V]``, ``targets [T]`` int,
+    ``weights [T]`` float32, ``den`` a float32 scalar.
+
+    Undifferentiated (evaluation, serving) it is one scan of one product a
+    chunk. Differentiated, the loss is a scalar whose cotangent only scales
+    the gradient, so the forward is ONE scan that forms each chunk's logits,
+    ``w * (softmax - onehot) / den`` and both gradient products (``dh`` and
+    the head's, summed over chunks), and the backward multiplies those by the
+    cotangent: three products a chunk, none in the backward."""
+    return _cross_entropy(hidden, head, targets, weights, jnp.asarray(den, jnp.float32), int(chunk_rows))
 
 
 def _follows(batch: GraphBatch, ahead: int):
@@ -153,8 +211,16 @@ def token_loss(hidden, head, batch: GraphBatch, chunk_rows: int, ahead: int = 1)
     tied). Ids ride in ``batch.z``; float32 throughout."""
     with tr.scope(tr.HG_TOKEN_LOSS):
         ids = jnp.clip(batch.z.astype(jnp.int32), 0, head.shape[1] - 1)
-        total = chunked_cross_entropy(hidden, head, jnp.roll(ids, -ahead), _follows(batch, ahead), chunk_rows)
-        return total / jnp.maximum(jnp.sum(_follows(batch, 1)), 1.0)
+        den = jnp.maximum(jnp.sum(_follows(batch, 1)), 1.0)
+        return chunked_cross_entropy(hidden, head, jnp.roll(ids, -ahead), _follows(batch, ahead), chunk_rows, den)
+
+
+def head_counts(chunks: int, train: bool) -> Dict:
+    """The head's row chunks a step and those whose gradient the forward scan
+    formed (``chunked_cross_entropy``: all of them in training), as the
+    step's two ``count:head_chunks*`` entries."""
+    return {tr.CT_HEAD_CHUNKS: jnp.float32(chunks),
+            tr.CT_HEAD_CHUNKS_GRAD_IN_FORWARD: jnp.float32(chunks if train else 0)}
 
 
 def _apply(model, variables, batch, train, rng):
@@ -172,11 +238,13 @@ def _token_head_loss(model, variables, batch, cfg, train, rng):
     # an untied head where the stack has one (models/joyai.py), else the embedding
     head, chunk = params.get("head", params["embedding"]), cfg.decoder.loss_chunk_rows
     loss = token_loss(outputs[name], head, batch, chunk)
-    tasks = {name: loss}
+    tasks, passes = {name: loss}, 1
     if MTP_HIDDEN in outputs:
         # the multi-token-prediction module's loss, through the same head
         tasks["mtp"] = token_loss(outputs[MTP_HIDDEN], head, batch, chunk, ahead=2)
         loss = loss + cfg.joyai.mtp_loss_weight * tasks["mtp"]
+        passes = 2
+    tasks.update(head_counts(passes * head_chunks(outputs[name].shape[0], chunk), train))
     for key, term in outputs.items():
         if key.startswith(LOSS_TERM_PREFIX):
             # a stack's own term of the loss (models/keyevl2.py), weighted there

@@ -84,6 +84,10 @@ CT_FLASH_WINDOW_STEPS_SCHEDULED = "count:flash_window_steps_scheduled"
 # ``lse``) the layer's remat keeps, so that the forward kernel runs once (models/decoder.py remat_in_training)
 CT_FLASH_BLOCKS = "count:flash_blocks"
 CT_FLASH_BLOCKS_SAVED = "count:flash_blocks_saved"
+# the token head's row chunks a step over its passes, and those whose gradient its forward scan formed
+# (train/loss.py chunked_cross_entropy: all of them in training, none in evaluation)
+CT_HEAD_CHUNKS = "count:head_chunks"
+CT_HEAD_CHUNKS_GRAD_IN_FORWARD = "count:head_chunks_grad_in_forward"
 CT_EXPERT_ROWS_HERE = "count:expert_rows_here"  # JOYAI, AFMOE: rows computed on this chip (a token is 0..k), over layers
 CT_EXPERT_ROWS_OVERRUN = "count:expert_rows_overrun"  # JOYAI, AFMOE: rows past the row budget (the step is poisoned)
 CT_MTP_PAIRS = "count:mtp_pairs"  # JOYAI: nodes whose two successors lie in their document

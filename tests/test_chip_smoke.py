@@ -206,3 +206,19 @@ def pytest_chip_smoke_dsa_times_rehearsed():
                   "topk=2048"):
         assert shape in src, shape
 
+
+
+def pytest_chip_smoke_head_times_rehearsed():
+    """The token head's leg at a tiny size: both rules run, agree and are
+    timed, one pass and two; its defaults are the ZAYA and JOYAI cells'
+    shapes."""
+    import inspect
+
+    smoke = _load_smoke()
+    got = smoke.head_times(cells=(("a", 100, 97, 1), ("b", 64, 50, 2)), width=32, chunk=16, dtype="float32")
+    for tag in ("head a [100, 32] x [32, 97] chunk 16 x 1", "head b [64, 32] x [32, 50] chunk 16 x 2"):
+        for rule in ("checkpointed", "grad_in_forward"):
+            assert f"{tag} {rule}_ms" in got["head_ms"] and f"{tag} {rule}_temp_bytes" in got["head_ms"]
+    src = inspect.getsource(smoke.head_times)
+    for shape in ('"zaya", 32768, 32784, 1', '"joyai", 16384, 16160, 2', "width=2048", "chunk=4096"):
+        assert shape in src, shape
