@@ -211,6 +211,14 @@ def update_config(
             arch.setdefault("experts_held", list(range(int(arch["num_experts"]))))
         KeyeConfig.from_arch(arch)
         arch.setdefault("use_sorted_aggregation", False)
+    if arch["mpnn_type"] == "OURO":
+        from ..models.ouro import OuroConfig
+
+        for key, default in (
+                ("rope_theta", 1.0e6), ("total_ut_steps", 4), ("rms_norm_eps", 1.0e-6), ("loss_chunk_rows", 4096)):
+            arch.setdefault(key, default)
+        OuroConfig.from_arch(arch)
+        arch.setdefault("use_sorted_aggregation", False)
 
     # GPS defaults (reference: config_utils.py:40-47)
     arch.setdefault("global_attn_engine", None)

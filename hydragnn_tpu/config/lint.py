@@ -161,6 +161,8 @@ _HANDLED = {
     "NeuralNetwork.Architecture.indexer_head_dim",
     "NeuralNetwork.Architecture.indexer_num_kv_heads",
     "NeuralNetwork.Architecture.indexer_topk",
+    # the fifth decoder stack (mpnn_type OURO, models/ouro.py)
+    "NeuralNetwork.Architecture.total_ut_steps",
     "NeuralNetwork.Architecture.branch_loss_weights",
     "NeuralNetwork.Architecture.branch_loss_metrics",
     "NeuralNetwork.Architecture.dropout",

@@ -163,11 +163,13 @@ class ModelConfig:
     afmoe: Optional[Any] = None
     # --- the fourth decoder stack (mpnn_type "KEYEVL2", models/keyevl2.py)
     keyevl2: Optional[Any] = None
+    # --- the fifth decoder stack (mpnn_type "OURO", models/ouro.py)
+    ouro: Optional[Any] = None
 
     @property
     def decoder(self) -> Optional[Any]:
         """The keys of whichever decoder stack this is, or None."""
-        return self.zaya or self.joyai or self.afmoe or self.keyevl2
+        return self.zaya or self.joyai or self.afmoe or self.keyevl2 or self.ouro
 
     @property
     def num_heads(self) -> int:

@@ -165,6 +165,7 @@ def model_config_from(config: Dict[str, Any]) -> ModelConfig:
         joyai=_joyai_config(arch),
         afmoe=_afmoe_config(arch),
         keyevl2=_keyevl2_config(arch),
+        ouro=_ouro_config(arch),
     )
 
 
@@ -198,6 +199,14 @@ def _keyevl2_config(arch: Dict[str, Any]):
     from .keyevl2 import KeyeConfig
 
     return KeyeConfig.from_arch(arch)
+
+
+def _ouro_config(arch: Dict[str, Any]):
+    if arch["mpnn_type"] != "OURO":
+        return None
+    from .ouro import OuroConfig
+
+    return OuroConfig.from_arch(arch)
 
 
 def create_model(config: Dict[str, Any]):
@@ -241,6 +250,12 @@ def create_model(config: Dict[str, Any]):
         from .keyevl2 import KeyeModel
 
         return KeyeModel(cfg=cfg)
+    if cfg.mpnn_type == "OURO":
+        # the fifth decoder stack: the held layers run several times a step
+        # under one set of weights, an exit after each pass (models/ouro.py)
+        from .ouro import OuroModel
+
+        return OuroModel(cfg=cfg)
     return HydraModel(cfg=cfg)
 
 
@@ -254,4 +269,4 @@ def init_model(
 
 
 def available_models() -> Tuple[str, ...]:
-    return conv_registry() + ("MACE", "ZAYA", "JOYAI", "AFMOE", "KEYEVL2")
+    return conv_registry() + ("MACE", "ZAYA", "JOYAI", "AFMOE", "KEYEVL2", "OURO")

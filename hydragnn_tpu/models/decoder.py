@@ -1,5 +1,5 @@
 """What the decoder stacks share (models/zaya.py, models/joyai.py,
-models/afmoe.py).
+models/afmoe.py, models/keyevl2.py, models/ouro.py).
 
 Token = node, document = graph, packed sequence = packed batch: the batcher
 lays graphs out contiguously along the flat node axis, so ``node_graph`` is a
@@ -34,6 +34,10 @@ from ..utils import tracer as tr
 # the key of a multi-token-prediction module's hidden state among a model's
 # outputs (models/joyai.py returns it, train/loss.py reads it)
 MTP_HIDDEN = "mtp_hidden"
+# the key of a looped stack's exit gate logits ``[passes, T]`` among its
+# outputs (models/ouro.py returns them beside its passes' exits, train/loss.py
+# ``loop_exit_loss`` reads both)
+EXIT_GATE = "exit_gate_logits"
 # a model output under this prefix is a term of the training loss, added to
 # the token loss as it is (already weighted) and reported under its name
 # (train/loss.py reads it)
@@ -416,7 +420,7 @@ def layer_params(module: nn.Module, shapes: Dict) -> Dict:
     return {name: module.param(name, INIT[kind], shape) for name, (shape, kind) in shapes.items()}
 
 
-def remat_in_training(layer_cls, train: bool):
+def remat_in_training(layer, train: bool):
     """Every layer is rematerialised in training. Kept across the remat: the
     layer's incoming residual stream and what the causal flash launch's
     backward reads of its forward (``o [T, Hq, dv]`` and one float32 a (head,
@@ -425,11 +429,15 @@ def remat_in_training(layer_cls, train: bool):
     learned sparse attention, the selection and the indexer loss's gradient
     (tagged in ops/pallas_dsa_indexer.py), so that its two launches run once
     a step; everything else of a layer is computed again in the backward
-    pass."""
+    pass. ``layer`` is a flax module class, or a function of arrays applied
+    inside a ``scan`` (models/ouro.py: the scan then keeps the same residuals
+    of every application)."""
     if not train:
-        return layer_cls
-    names = CAUSAL_FLASH_RESIDUAL_NAMES + DSA_RESIDUAL_NAMES
-    return nn.remat(layer_cls, policy=jax.checkpoint_policies.save_only_these_names(*names))
+        return layer
+    policy = jax.checkpoint_policies.save_only_these_names(*CAUSAL_FLASH_RESIDUAL_NAMES, *DSA_RESIDUAL_NAMES)
+    if isinstance(layer, type) and issubclass(layer, nn.Module):
+        return nn.remat(layer, policy=policy)
+    return jax.checkpoint(layer, policy=policy)
 
 
 def flash_blocks(blocks: int, train: bool) -> Dict:

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 
 from ..data.graph import GraphBatch
 from ..models.base import ModelConfig
-from ..models.decoder import LOSS_TERM_PREFIX, MTP_HIDDEN, follows
+from ..models.decoder import EXIT_GATE, LOSS_TERM_PREFIX, MTP_HIDDEN, follows
+from ..models.ouro import ENTROPY_WEIGHT, exit_distribution
 from ..utils import tracer as tr
 
 
@@ -106,13 +107,14 @@ def _per_branch_head_loss(
 
 
 def _chunk_loss(h, head, tgt, w):
-    """One chunk's ``sum(w * (logsumexp(logits) - logit[tgt]))``, its logits
-    ``[chunk, V]`` in float32."""
+    """One chunk's ``sum(w * ce)`` and its rows' cross-entropy ``ce =
+    logsumexp(logits) - logit[tgt]`` ``[chunk]``, the logits ``[chunk, V]`` in
+    float32."""
     logits = jnp.dot(h, head.astype(h.dtype), preferred_element_type=jnp.float32,
                      precision="highest" if h.dtype == jnp.float32 else None)
     lse = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
-    return jnp.sum(w * (lse - picked))
+    ce = lse - jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+    return jnp.sum(w * ce), ce
 
 
 def _chunk_rows(t: int, chunk_rows: int) -> int:
@@ -147,7 +149,7 @@ def _in_order(values):
 def _cross_entropy(hidden, head, targets, weights, den, chunk_rows):
     def body(total, xs):
         h, tgt, w = xs
-        return total + _chunk_loss(h, head, tgt, w), None
+        return total + _chunk_loss(h, head, tgt, w)[0], None
 
     total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), _stacked(hidden, targets, weights, chunk_rows))
     return total / den
@@ -160,20 +162,25 @@ def _cross_entropy_fwd(hidden, head, targets, weights, den, chunk_rows):
 
     def body(dhead, xs):
         h, tgt, w = xs
-        value, pull = jax.vjp(lambda h_, head_: _chunk_loss(h_, head_, tgt, w), h, head)
+        value, pull, ce = jax.vjp(lambda h_, head_: _chunk_loss(h_, head_, tgt, w), h, head, has_aux=True)
         dh, dhead_chunk = pull(ct)
-        return dhead + dhead_chunk, (value, dh)
+        return dhead + dhead_chunk, (value, dh, ce)
 
     # last chunk first, as the transposed scan adds the head's gradient
-    dhead, (values, dh) = jax.lax.scan(body, jnp.zeros_like(head),
-                                       _stacked(hidden, targets, weights, chunk_rows), reverse=True)
-    dh = dh.reshape(-1, hidden.shape[1])[:hidden.shape[0]]
-    return _in_order(values) / den, (dh, dhead)
+    dhead, (values, dh, ce) = jax.lax.scan(body, jnp.zeros_like(head),
+                                           _stacked(hidden, targets, weights, chunk_rows), reverse=True)
+    t = hidden.shape[0]
+    dh = dh.reshape(-1, hidden.shape[1])[:t]
+    return _in_order(values) / den, (dh, dhead, ce.reshape(-1)[:t], den)
 
 
 def _cross_entropy_bwd(chunk_rows, residuals, g):
-    dh, dhead = residuals
-    return (g * dh).astype(dh.dtype), (g * dhead).astype(dhead.dtype), None, None, None
+    # the weights' cotangent is each row's cross-entropy over ``den``: a
+    # weight that is a function of parameters (a looped stack's exit
+    # distribution, ``loop_exit_loss``) trains through it; a constant mask's
+    # is never used and compiles away
+    dh, dhead, ce, den = residuals
+    return (g * dh).astype(dh.dtype), (g * dhead).astype(dhead.dtype), None, g * ce / den, None
 
 
 _cross_entropy.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
@@ -191,7 +198,9 @@ def chunked_cross_entropy(hidden, head, targets, weights, chunk_rows: int, den=1
     the gradient, so the forward is ONE scan that forms each chunk's logits,
     ``w * (softmax - onehot) / den`` and both gradient products (``dh`` and
     the head's, summed over chunks), and the backward multiplies those by the
-    cotangent: three products a chunk, none in the backward."""
+    cotangent: three products a chunk, none in the backward. The weights take
+    a gradient too, ``ce / den`` a row (each row's cross-entropy, kept from
+    the forward scan)."""
     return _cross_entropy(hidden, head, targets, weights, jnp.asarray(den, jnp.float32), int(chunk_rows))
 
 
@@ -213,6 +222,30 @@ def token_loss(hidden, head, batch: GraphBatch, chunk_rows: int, ahead: int = 1)
         ids = jnp.clip(batch.z.astype(jnp.int32), 0, head.shape[1] - 1)
         den = jnp.maximum(jnp.sum(_follows(batch, 1)), 1.0)
         return chunked_cross_entropy(hidden, head, jnp.roll(ids, -ahead), _follows(batch, ahead), chunk_rows, den)
+
+
+def loop_exit_loss(exits, gate_logits, head, batch: GraphBatch, chunk_rows: int):
+    """A looped stack's head (models/ouro.py): ``exits [P, T, D]``, each
+    pass's normalised output, and the exit gate's logits ``gate_logits [P,
+    T]``. The exit distribution ``p`` (``models/ouro.py exit_distribution``)
+    weighs each exit's next-token cross-entropy ``ce_t``, less ``beta =
+    models/ouro.py ENTROPY_WEIGHT`` times its entropy: ``L = (1/den) sum_i
+    follows_i [sum_t p_t(i) ce_t(i) - beta H(p(i))]``, ``den`` the count of
+    (node, next node) pairs as ``token_loss`` counts it. The P exits are ONE
+    ``chunked_cross_entropy`` over ``[P T, D]`` rows, the targets repeated and
+    the weights ``p_t(i) follows_i``: one scan and one head-gradient
+    accumulator, and the weights' cotangent carries ``ce / den`` to the gate.
+    -> (L, the expected cross-entropy, the mean entropy)."""
+    with tr.scope(tr.HG_TOKEN_LOSS):
+        passes, t, d = exits.shape
+        ids = jnp.clip(batch.z.astype(jnp.int32), 0, head.shape[1] - 1)
+        w1 = _follows(batch, 1)
+        den = jnp.maximum(jnp.sum(w1), 1.0)
+        p, logp = exit_distribution(gate_logits.astype(jnp.float32))
+        ce = chunked_cross_entropy(exits.reshape(passes * t, d), head, jnp.tile(jnp.roll(ids, -1), passes),
+                                   (p * w1).reshape(-1), chunk_rows, den)
+        entropy = -jnp.sum(w1 * jnp.sum(p * logp, axis=0)) / den
+        return ce - ENTROPY_WEIGHT * entropy, ce, entropy
 
 
 def head_counts(chunks: int, train: bool) -> Dict:
@@ -237,14 +270,22 @@ def _token_head_loss(model, variables, batch, cfg, train, rng):
     name, params = cfg.output_names[0], variables["params"]
     # an untied head where the stack has one (models/joyai.py), else the embedding
     head, chunk = params.get("head", params["embedding"]), cfg.decoder.loss_chunk_rows
-    loss = token_loss(outputs[name], head, batch, chunk)
-    tasks, passes = {name: loss}, 1
-    if MTP_HIDDEN in outputs:
-        # the multi-token-prediction module's loss, through the same head
-        tasks["mtp"] = token_loss(outputs[MTP_HIDDEN], head, batch, chunk, ahead=2)
-        loss = loss + cfg.joyai.mtp_loss_weight * tasks["mtp"]
-        passes = 2
-    tasks.update(head_counts(passes * head_chunks(outputs[name].shape[0], chunk), train))
+    if EXIT_GATE in outputs:
+        # a looped stack's exits, one a pass, weighted by its exit gate
+        # (models/ouro.py): ONE head scan over all of them
+        exits = outputs[name]
+        loss, ce, entropy = loop_exit_loss(exits, outputs[EXIT_GATE], head, batch, chunk)
+        tasks = {name: ce, "exit_entropy": entropy}
+        tasks.update(head_counts(head_chunks(exits.shape[0] * exits.shape[1], chunk), train))
+    else:
+        loss = token_loss(outputs[name], head, batch, chunk)
+        tasks, passes = {name: loss}, 1
+        if MTP_HIDDEN in outputs:
+            # the multi-token-prediction module's loss, through the same head
+            tasks["mtp"] = token_loss(outputs[MTP_HIDDEN], head, batch, chunk, ahead=2)
+            loss = loss + cfg.joyai.mtp_loss_weight * tasks["mtp"]
+            passes = 2
+        tasks.update(head_counts(passes * head_chunks(outputs[name].shape[0], chunk), train))
     for key, term in outputs.items():
         if key.startswith(LOSS_TERM_PREFIX):
             # a stack's own term of the loss (models/keyevl2.py), weighted there

@@ -62,12 +62,13 @@ HG_ATTN_PROJ = "hg_attn_proj"  # AFMOE: the query, key and value projections of 
 HG_ATTN_GATE = "hg_attn_gate"  # AFMOE: the gate's projection, its sigmoid and the product with the attention output
 HG_DSA_PROJ = "hg_dsa_proj"  # KEYEVL2: the sparse-attention indexer's projections (queries, key, weights), its key's LayerNorm and RoPE
 HG_MTP = "hg_mtp"  # JOYAI: the multi-token-prediction module (join, its own layer, its norm)
+HG_LOOP_EXIT = "hg_loop_exit"  # OURO: a pass's exit, the final norm and the exit gate's logit
 HG_TOKEN_LOSS = "hg_token_loss"  # the chunked next-node cross-entropy
 
 # -- counters: per-step scalars a step carries among its per-task entries
 #    under COUNTER_PREFIX, summed here at the epoch drain (train/loop.py) -----
 COUNTER_PREFIX = "count:"
-CT_TOKENS = "count:tokens"  # real nodes x expert layers
+CT_TOKENS = "count:tokens"  # real nodes x expert layers (OURO, which has none: x layer applications)
 CT_TOKENS_ROUTED_HERE = "count:tokens_routed_here"  # tokens whose expert is held, over layers
 CT_EXPERT_LOAD_MAX = "count:expert_load_max"  # largest load of a held expert, summed over layers
 CT_EXPERT_LOAD_MEAN = "count:expert_load_mean"  # mean load of the held experts, summed over layers
@@ -92,6 +93,9 @@ CT_EXPERT_ROWS_HERE = "count:expert_rows_here"  # JOYAI, AFMOE: rows computed on
 CT_EXPERT_ROWS_OVERRUN = "count:expert_rows_overrun"  # JOYAI, AFMOE: rows past the row budget (the step is poisoned)
 CT_MTP_PAIRS = "count:mtp_pairs"  # JOYAI: nodes whose two successors lie in their document
 CT_DSA_SELECTED_PAIRS = "count:dsa_selected_pairs"  # KEYEVL2: (query, key) pairs the indexer selects, one layer's: sum of min(n_t, topk)
+# OURO: the layers held here, and their applications a step (each held layer runs once a pass)
+CT_LOOP_LAYERS_HELD = "count:loop_layers_held"
+CT_LOOP_LAYER_APPLICATIONS = "count:loop_layer_applications"
 
 # -- Pallas kernels: pallas_call(name=...) inside a scope of the same name;
 #    the custom-JVP tangent rule runs under <name> + TANGENT ----------------

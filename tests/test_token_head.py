@@ -4,10 +4,13 @@ chunk's ``softmax - onehot`` and both gradient products and the backward only
 scales them. Held against the rule it replaced, each chunk checkpointed and
 its logits computed again in the backward scan (``checkpointed_scan`` below,
 kept here as the oracle): directly, through ``token_loss`` and through
-``compute_loss`` of the four tiny decoder stacks of the benchmark
-(benchmarks/tests/tiny_*.py). The gradient jaxpr of a training step holds one
-scan of three products a chunk for each head pass and no head product
-elsewhere; evaluation runs one product a chunk."""
+``compute_loss`` of the five tiny decoder stacks of the benchmark
+(benchmarks/tests/tiny_*.py). The rows' weights take a gradient, each row's
+cross-entropy over the divisor, as a plain ``jnp`` cross-entropy's autodiff
+gives it (the looped stack's exit gate trains through it). The gradient jaxpr
+of a training step holds one scan of three products a chunk for each head
+pass and no head product elsewhere (the looped stack: one scan over all its
+exits); evaluation runs one product a chunk."""
 
 import copy
 import importlib
@@ -35,7 +38,7 @@ from hydragnn_tpu.utils import tracer as tr  # noqa: E402
 from reference import common as rc  # noqa: E402
 
 VOCAB = 97
-STACKS = ("zaya", "joyai", "trinity", "keyevl2")
+STACKS = ("zaya", "joyai", "trinity", "keyevl2", "ouro")
 
 
 def checkpointed_scan(hidden, head, targets, weights, chunk_rows, den=1.0):
@@ -126,6 +129,28 @@ def pytest_rule_matches_the_checkpointed_scan(dtype, rows, scale):
         assert np.max(_ulps(grads[0], want_grads[0])) <= 1.0
         chunks = ls.head_chunks(rows, 16)
         assert np.max(_ulps(grads[1], want_grads[1], np.max(np.abs(_f32(want_grads[1]))))) <= chunks
+
+
+@pytest.mark.parametrize("rows", [64, 50])
+def pytest_every_operand_takes_the_plain_cross_entropys_gradient(rows):
+    """Against ``jax.grad`` of a plain ``jnp`` cross-entropy, float32 under
+    ``jax.jit``, 16-row chunks (50 rows pad the last chunk): the gradients
+    of ``hidden``, ``head`` AND ``weights`` (each row's cross-entropy over
+    ``den``) within 1e-6, the weights drawn in [0, 1) as an exit
+    distribution's are."""
+    hidden, head, targets, _ = _operands(rows, jnp.float32, seed=5)
+    weights = jnp.asarray(np.random.default_rng(6).random(rows), jnp.float32)
+
+    def plain(h, hd, w):
+        logits = jnp.dot(h, hd, precision="highest")
+        ce = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(logits, targets[:, None], -1)[:, 0]
+        return jnp.sum(w * ce) / 7.0
+
+    rule = lambda h, hd, w: ls.chunked_cross_entropy(h, hd, targets, w, 16, 7.0)
+    got, want = (jax.jit(jax.value_and_grad(f, (0, 1, 2)))(hidden, head, weights) for f in (rule, plain))
+    assert abs(float(got[0]) - float(want[0])) <= 1e-6 * abs(float(want[0]))
+    for g, w in zip(got[1], want[1]):
+        assert _rel(g, w) <= 1e-6
 
 
 def pytest_primal_is_the_sum_over_rows():
@@ -305,3 +330,26 @@ def pytest_training_step_has_one_head_scan_of_three_products(tiny, stack, passes
     _, tasks, _ = eval_step(TrainState.create(copy.deepcopy(variables), tx), batch)
     assert float(tasks[tr.CT_HEAD_CHUNKS]) == passes * chunks
     assert float(tasks[tr.CT_HEAD_CHUNKS_GRAD_IN_FORWARD]) == 0.0
+
+
+def pytest_looped_stack_has_one_head_scan_over_every_exit(tiny):
+    """The looped stack's four exits go through ONE chunked cross-entropy:
+    the training step's gradient jaxpr has one scan over the chunks of ``4 T``
+    rows whose body holds three products, and no product over the vocabulary
+    outside it; the evaluation step one scan of one product a chunk; the
+    counters count the chunks of every exit."""
+    config, batch, model, variables = tiny("ouro")
+    tx = make_optimizer(config["NeuralNetwork"]["Training"]["Optimizer"])
+    state = TrainState.create(copy.deepcopy(variables), tx)
+    passes = model.cfg.ouro.total_ut_steps
+    chunks = ls.head_chunks(passes * batch.z.shape[0], model.cfg.decoder.loss_chunk_rows)
+    assert passes == 4 and chunks == 4 * ls.head_chunks(batch.z.shape[0], model.cfg.decoder.loss_chunk_rows)
+
+    train_step = make_train_step(model, tx, False, False)
+    outside = []
+    assert _head_scans(jax.make_jaxpr(train_step)(state, batch, jax.random.PRNGKey(0)).jaxpr, outside) == [(chunks, 3)]
+    assert not outside
+    eval_step = make_eval_step(model, False, False)
+    assert _head_scans(jax.make_jaxpr(eval_step)(state, batch).jaxpr, outside) == [(chunks, 1)] and not outside
+    _, _, tasks = train_step(state, batch, jax.random.PRNGKey(0))
+    assert float(tasks[tr.CT_HEAD_CHUNKS]) == float(tasks[tr.CT_HEAD_CHUNKS_GRAD_IN_FORWARD]) == chunks
